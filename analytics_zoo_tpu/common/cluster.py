@@ -38,35 +38,16 @@ __all__ = ["launch_local_cluster", "wait_all", "ProcessMonitor",
 
 
 def force_cpu_devices(n: int) -> None:
-    """Force the CPU backend with `n` virtual devices, across jax
-    versions: newer jax exposes a `jax_num_cpu_devices` config option;
-    older ones reject it (`Unrecognized config option`) and need the
-    `--xla_force_host_platform_device_count` XLA flag instead. Must run
-    before the CPU backend initializes (both spellings are
-    backend-construction-time knobs); the callers here sit at process
-    start, before any device use."""
+    """Force the CPU backend with `n` virtual devices and gloo
+    cross-process collectives (without a collectives implementation the
+    CPU backend refuses multi-process computations). Must run before the
+    CPU backend initializes; the callers here sit at process start,
+    before any device use."""
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-    except AttributeError:
-        import re
-        flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                       os.environ.get("XLA_FLAGS", ""))
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={int(n)}"
-        ).strip()
-    try:
-        # cross-process collectives on the CPU backend: jax versions
-        # that gate them behind a collectives implementation raise
-        # "Multiprocess computations aren't implemented on the CPU
-        # backend" until one is selected; gloo ships in jaxlib. A no-op
-        # for single-process runs and absent on jax trees that predate
-        # (or retired) the option.
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
+    jax.config.update("jax_num_cpu_devices", int(n))
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def _free_port() -> int:

@@ -140,6 +140,12 @@ def init_zoo_context(config: Optional[ZooConfig] = None,
     elif cluster_mode != "local":
         raise ValueError(f"Unknown cluster_mode: {cluster_mode}")
 
+    # JAX's persistent compilation cache for everything this process
+    # compiles: under JAX_COMPILATION_CACHE_DIR where that is set, else
+    # in the checkout (compile_cache/store.py)
+    from analytics_zoo_tpu.compile_cache import enable_jax_persistent_cache
+    xla_cache_dir = enable_jax_persistent_cache()
+
     # Fast TPU random bits for dropout et al. (rbg keys lower to the
     # hardware RngBitGenerator; threefry costs ~25% of a BERT train step on
     # v5e). TPU-only: on CPU/GPU threefry stays, keeping init draws stable.
@@ -165,9 +171,9 @@ def init_zoo_context(config: Optional[ZooConfig] = None,
     mesh = DeviceMesh(config.mesh)
     ctx = Context(config, mesh)
     _GLOBAL["context"] = ctx
-    log.info("Initialized %s on %d device(s) (%s), %d process(es)",
-             mesh, mesh.n_devices,
-             jax.devices()[0].platform, jax.process_count())
+    log.info("Initialized %s on %d device(s) (%s), %d process(es); "
+             "xla compile cache %s", mesh, mesh.n_devices,
+             jax.devices()[0].platform, jax.process_count(), xla_cache_dir)
     return ctx
 
 

@@ -13,14 +13,16 @@ and stored on disk.
 - `CompileCache` (`store.py`) — the disk store: CRC-checked entries,
   atomic write-then-rename, LRU eviction under a byte budget, and
   hit/miss/load/compile telemetry in the process-wide registry. A
-  corrupt, truncated, or format-mismatched entry is silently a miss —
-  never an exception on the load path.
+  corrupt, truncated, or format-mismatched entry is a miss, an intact
+  one that fails to deserialize a counted load error — never an
+  exception on the load path.
 - `make_key` / fingerprints (`key.py`) — the cache key anatomy: jax
   version, backend + device kind/count, model fn + params structure,
   input signature (bucket shape + dtype), placement + sharding spec.
-- `pack` / `unpack` (`serialization.py`) — executable bytes, including
-  the device-retargeting deserializer that lets ONE persisted entry
-  load onto each replica's device (persist once, load N times).
+- `pack` / `unpack` (`serialization.py`) — executable bytes plus the
+  devices they were compiled for, and the re-pinning that lets ONE
+  persisted entry load onto each replica's device (persist once, load
+  N times).
 - `AOTFunctionCache` — wraps a jitted trainer step: per input signature
   it loads/compiles-and-persists an AOT executable, falling back to the
   plain jit call (backed by JAX's built-in persistent cache, see
@@ -32,15 +34,16 @@ from analytics_zoo_tpu.compile_cache.key import (CacheKey, abstract_signature,
                                                  model_fingerprint,
                                                  structure_signature)
 from analytics_zoo_tpu.compile_cache.serialization import (
-    HAVE_AOT, compile_lowered, pack, unpack)
-from analytics_zoo_tpu.compile_cache.store import (CompileCache,
-                                                   enable_jax_persistent_cache,
-                                                   get_cache)
+    compile_lowered, pack, unpack)
+from analytics_zoo_tpu.compile_cache.store import (
+    CompileCache, default_xla_cache_dir, enable_jax_persistent_cache,
+    get_cache)
 from analytics_zoo_tpu.compile_cache.aot_fn import AOTFunctionCache
 
 __all__ = [
-    "AOTFunctionCache", "CacheKey", "CompileCache", "HAVE_AOT",
-    "abstract_signature", "compile_lowered", "enable_jax_persistent_cache",
-    "fingerprint", "get_cache", "make_key", "model_fingerprint", "pack",
+    "AOTFunctionCache", "CacheKey", "CompileCache", "abstract_signature",
+    "compile_lowered", "default_xla_cache_dir",
+    "enable_jax_persistent_cache", "fingerprint",
+    "get_cache", "make_key", "model_fingerprint", "pack",
     "structure_signature", "unpack",
 ]

@@ -7,7 +7,7 @@ executable (or lowers + compiles + persists once), then dispatches
 every later call straight to the AOT executable — a trainer re-run
 pays zero XLA compiles for shapes it has seen in any previous process.
 
-Anything that defeats AOT serialization — an unserializable backend, a
+Anything that defeats AOT serialization — an unserializable program, a
 signature that fails to lower, an executable rejecting its inputs —
 permanently falls back to the wrapped jit callable for that signature,
 where JAX's built-in persistent compilation cache (see
@@ -63,8 +63,7 @@ class AOTFunctionCache:
     def __call__(self, *args):
         csig = self._cheap_sig(args)
         ex = self._execs.get(csig)
-        if ex is None and csig not in self._failed \
-                and serialization.HAVE_AOT:
+        if ex is None and csig not in self._failed:
             ex = self._build(csig, args)
         if ex is None:
             return self._jit(*args)

@@ -33,8 +33,9 @@ from typing import Any, Dict, Tuple
 # change: v2 = ISSUE 7's ShardingRules.spec_for fsdp fallback for
 # matched-but-untrimmable rules (the same table now resolves different
 # placements on data×fsdp meshes, and a stale sharded executable would
-# reject — or silently reshard — its inputs).
-FORMAT_VERSION = 2
+# reject — or silently reshard — its inputs). v3 = the payload carries
+# the compile-time device ids (`serialization.pack`).
+FORMAT_VERSION = 3
 
 _MAX_DEPTH = 5
 _MAX_ITEMS = 64

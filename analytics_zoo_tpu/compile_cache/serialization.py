@@ -1,25 +1,24 @@
 """AOT executable (de)serialization, with device retargeting.
 
 `jax.experimental.serialize_executable.serialize` returns (payload
-bytes, in_tree, out_tree); the pytrees pickle fine, so `pack` folds the
-triple into one bytes blob. Two wrinkles this module owns:
+bytes, in_tree, out_tree); the pytrees pickle fine, so `pack` folds them
+into one bytes blob together with the ids of the devices the executable
+was compiled for, in device-assignment order. Two wrinkles this module
+owns:
 
-- **Device retargeting** (`unpack(target_device_id=...)`): a serialized
-  single-device executable bakes in its compile-time device id — both
-  in the pickled args-info shardings and in the XLA executable's device
-  assignment. The replicated serving pool persists ONE entry per bucket
-  and loads it once per replica, so the deserializer re-pins both: the
-  pickled device persistent-ids map to the target device, and the raw
-  XLA executable reloads under `CompileOptions` carrying a fresh
-  single-device `DeviceAssignment`. Multi-device (GSPMD/sharded)
-  executables never retarget — their device set IS the key.
+- **Device placement** (`unpack`): `deserialize_and_load` defaults to
+  ALL backend devices, which is wrong for anything compiled on fewer
+  (a one-device serving executable on a four-chip host, a sub-mesh) and
+  for meshes whose device order is not id order. `unpack` therefore
+  always passes `execution_devices`: the stored ids by default. A
+  single-device executable can instead be re-pinned onto one
+  `target_device_id` (the replicated serving pool persists ONE entry
+  per bucket and loads it once per replica), which takes
+  `_PinnedUnpickler`. Multi-device (GSPMD/sharded) executables never
+  retarget — their device set IS the key.
 - **Compile spy-ability** (`compile_lowered`): every fresh AOT compile
   in the codebase funnels through this one function, so tests can
   monkeypatch it and assert a cache-warm warmup performs ZERO compiles.
-
-Everything degrades: on a jax build without `serialize_executable`,
-`HAVE_AOT` is False and callers fall back to plain jit (backed by
-JAX's built-in persistent compilation cache when enabled).
 """
 
 from __future__ import annotations
@@ -29,15 +28,9 @@ import pickle
 from typing import Optional
 
 import jax
-
-try:
-    from jax.experimental import serialize_executable as _se
-    from jax._src.lib import xla_client as _xc
-    HAVE_AOT = True
-except Exception:  # noqa: BLE001 — optional capability, gated everywhere
-    _se = None
-    _xc = None
-    HAVE_AOT = False
+import numpy as np
+from jax._src.lib import xla_client as _xc
+from jax.experimental import serialize_executable as _se
 
 
 def compile_lowered(lowered):
@@ -47,32 +40,35 @@ def compile_lowered(lowered):
 
 def pack(compiled) -> bytes:
     """One bytes blob from a `jax.stages.Compiled`. Raises on anything
-    unserializable (callbacks, unsupported backends) — callers treat
+    unserializable (callbacks, closed-over constants) — callers treat
     that as 'skip persisting', never as fatal."""
-    if not HAVE_AOT:
-        raise RuntimeError("jax.experimental.serialize_executable "
-                           "unavailable on this jax build")
     payload, in_tree, out_tree = _se.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree), protocol=4)
+    device_ids = [d.id for d in
+                  compiled.runtime_executable().local_devices()]
+    return pickle.dumps((payload, in_tree, out_tree, device_ids),
+                        protocol=4)
 
 
-class _RetargetUnpickler(_se._JaxPjrtUnpickler if HAVE_AOT else object):
-    """`_JaxPjrtUnpickler` that lands every device reference — and the
-    XLA executable's device assignment — on one target device."""
-
-    def __init__(self, file, backend, target_id: int):
-        super().__init__(file, backend)
-        self.target_id = target_id
+class _PinnedUnpickler(_se._JaxPjrtUnpickler):
+    """Lands a single-device executable on ONE other device.
+    `deserialize_and_load(execution_devices=[dev])` alone does not: the
+    pickled args-info shardings still name the compile-time device
+    (KeyError when it is not among the execution devices), and the XLA
+    executable keeps its compile-time device assignment ("replica is
+    assigned to device 0") unless it reloads under `CompileOptions`
+    carrying a fresh one."""
 
     def persistent_load(self, pid):
+        target = self.execution_devices[0]
         if pid[0] == "device":
-            return self.devices_by_id[self.target_id]
+            return target
         if pid[0] == "exec":
-            import numpy as np
             opts = _xc.CompileOptions()
             opts.device_assignment = _xc.DeviceAssignment.create(
-                np.array([[self.target_id]], np.int32))
-            return self.backend.deserialize_executable(pid[1], opts)
+                np.array([[target.id]], np.int32))
+            return self.backend.deserialize_executable(
+                pid[1], executable_devices=self.execution_devices,
+                compile_options=opts)
         return super().persistent_load(pid)
 
 
@@ -109,19 +105,23 @@ def retree_call(compiled, stored_tree):
 
 
 def unpack(data: bytes, target_device_id: Optional[int] = None):
-    """Rebuild a callable `jax.stages.Compiled` from `pack` output.
-    `target_device_id` re-pins a single-device executable onto that
-    device (replica fan-out); None keeps the stored assignment (the
-    single-device default path and all multi-device executables)."""
-    if not HAVE_AOT:
-        raise RuntimeError("jax.experimental.serialize_executable "
-                           "unavailable on this jax build")
-    payload, in_tree, out_tree = pickle.loads(data)
-    if target_device_id is None:
-        return _se.deserialize_and_load(payload, in_tree, out_tree)
-    backend = jax.devices()[0].client
-    unloaded, args_info_flat, no_kwargs = _RetargetUnpickler(
-        io.BytesIO(payload), backend, target_device_id).load()
-    args_info = in_tree.unflatten(args_info_flat)
-    return jax.stages.Compiled(unloaded.load(), args_info, out_tree,
+    """Rebuild a callable `jax.stages.Compiled` from `pack` output on
+    the devices it was compiled for. `target_device_id` re-pins a
+    single-device executable onto that device instead (replica
+    fan-out); multi-device executables refuse it."""
+    payload, in_tree, out_tree, device_ids = pickle.loads(data)
+    by_id = {d.id: d for d in jax.devices()}
+    if target_device_id is None or [target_device_id] == device_ids:
+        return _se.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in device_ids])
+    if len(device_ids) != 1:
+        raise ValueError(
+            f"cannot re-pin a {len(device_ids)}-device executable onto "
+            f"device {target_device_id}")
+    target = by_id[target_device_id]
+    unloaded, args_info_flat, no_kwargs = _PinnedUnpickler(
+        io.BytesIO(payload), target.client, [target]).load()
+    return jax.stages.Compiled(unloaded.load(), [],
+                               in_tree.unflatten(args_info_flat), out_tree,
                                no_kwargs=no_kwargs)

@@ -22,12 +22,16 @@ Durability rules:
 - reads verify magic, format version, header shape, payload length and
   CRC32C (`utils/crc.py`); ANY failure — truncation, corruption, a
   different format version — deletes the entry and reports a miss.
-  The load path cannot raise.
+  An INTACT entry that then fails to deserialize is not a miss: it
+  deletes the entry too but counts `compile_cache_load_errors_total`,
+  so a cache that never hits (an API drift, a runtime mismatch) cannot
+  pass for a cold one. The load path cannot raise.
 - LRU is file mtime: a hit touches the entry (`os.utime`); eviction
   removes oldest-touched first until the byte budget holds.
 
 Telemetry (process-wide registry): `compile_cache_hits_total`,
-`compile_cache_misses_total`, `compile_cache_load_ms`,
+`compile_cache_misses_total`, `compile_cache_load_errors_total`,
+`compile_cache_load_ms`,
 `compile_cache_compile_ms`, `compile_cache_bytes`.
 """
 
@@ -214,6 +218,10 @@ class CompileCache:
             "compile_cache_misses_total",
             "persistent compilation cache lookups that fell back to a "
             "fresh compile")
+        self._load_errors = registry.counter(
+            "compile_cache_load_errors_total",
+            "intact cache entries that failed to deserialize into an "
+            "executable (entry deleted, fresh compile follows)")
         self._load_ms = registry.histogram(
             "compile_cache_load_ms",
             "wall time to read + deserialize one cached executable")
@@ -248,33 +256,46 @@ class CompileCache:
     def contains(self, key: CacheKey) -> bool:
         return os.path.exists(self._entry_path(key))
 
+    def _drop(self, fp: str) -> None:
+        """Unlink one unusable entry and take it out of the accounting."""
+        with self._lock:
+            try:
+                size = os.path.getsize(fp)
+                os.unlink(fp)
+            except OSError:
+                return
+            self._account(-1, -size)
+
     def load(self, key: CacheKey,
              target_device_id: Optional[int] = None):
         """Hit → a callable `jax.stages.Compiled` (optionally re-pinned
-        onto `target_device_id`); miss/corrupt/version-mismatch → None.
-        Never raises."""
+        onto `target_device_id`); miss/corrupt/version-mismatch → None
+        (counted as a miss); an intact entry that will not deserialize
+        → None, counted as a load error. Never raises."""
         fp = self._entry_path(key)
         t0 = time.perf_counter()
         try:
-            header, payload = read_entry(fp)
-            compiled = serialization.unpack(
-                payload, target_device_id=target_device_id)
+            _, payload = read_entry(fp)
         except FileNotFoundError:
             self._misses.inc()
             return None
-        except Exception as e:  # noqa: BLE001 — degrade to recompile
-            log.warning("compile cache entry %s unusable (%s: %s); "
+        except (OSError, ValueError) as e:
+            log.warning("compile cache entry %s corrupt (%s: %s); "
                         "falling back to fresh compile",
                         os.path.basename(fp), type(e).__name__, e)
-            with self._lock:
-                try:
-                    size = os.path.getsize(fp)
-                    os.unlink(fp)
-                except OSError:
-                    pass
-                else:
-                    self._account(-1, -size)
+            self._drop(fp)
             self._misses.inc()
+            return None
+        try:
+            compiled = serialization.unpack(
+                payload, target_device_id=target_device_id)
+        except Exception as e:  # noqa: BLE001 — serving must stay up
+            log.warning("compile cache entry %s is intact but failed to "
+                        "deserialize (%s: %s); falling back to fresh "
+                        "compile", os.path.basename(fp),
+                        type(e).__name__, e)
+            self._drop(fp)
+            self._load_errors.inc()
             return None
         try:
             os.utime(fp)            # LRU touch
@@ -333,6 +354,7 @@ class CompileCache:
                     "bytes": self._n_bytes,
                     "hits": self._hits.value(),
                     "misses": self._misses.value(),
+                    "load_errors": self._load_errors.value(),
                     "max_bytes": self.max_bytes}
 
     def prune(self, max_bytes: int) -> Tuple[int, int]:
@@ -366,23 +388,33 @@ def get_cache(path: str, max_bytes: Optional[int] = None) -> CompileCache:
         return cc
 
 
-def enable_jax_persistent_cache(cache_dir: str) -> bool:
-    """The fallback layer: JAX's built-in persistent compilation cache
-    (`jax_compilation_cache_dir`) under `<cache_dir>/xla`. Catches every
-    compile AOT serialization can't (shapes lowered mid-run, eval/
-    predict jits, backends without executable serialization) at the XLA
-    level. Best-effort: False on jax builds without the knobs."""
-    xla_dir = os.path.join(os.path.abspath(os.path.expanduser(cache_dir)),
-                           "xla")
-    try:
-        os.makedirs(xla_dir, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", xla_dir)
-        # serving/trainer cold-start cares about EVERY compile, not just
-        # the >1s ones jax defaults to persisting
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        return True
-    except Exception as e:  # noqa: BLE001 — fallback layer is optional
-        log.info("jax persistent compilation cache unavailable "
-                 "(%s: %s)", type(e).__name__, e)
-        return False
+def default_xla_cache_dir() -> str:
+    """`<checkout>/.xla_cache`, resolved from this package's location:
+    the cache directory is part of every entry's key, so a path built
+    from the cwd, a pid, the time or `tempfile` would never hit twice."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".xla_cache")
+
+
+def enable_jax_persistent_cache() -> str:
+    """Turn on JAX's built-in persistent compilation cache — the layer
+    under the `.aotc` store that catches every compile AOT
+    serialization does not carry (shapes lowered mid-run, eval/predict
+    jits, Pallas kernels) — and return the directory in use.
+
+    Placement belongs to whoever runs the process: where
+    `JAX_COMPILATION_CACHE_DIR` is set JAX has already taken that
+    directory from the environment and this never overrides it; where it
+    is not, the cache goes to `default_xla_cache_dir()`. Called from the
+    normal entry points (`init_zoo_context`, `serving/cli.py` start), so
+    a fit, a server and the benches share one cache without asking."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        xla_dir = default_xla_cache_dir()
+        if jax.config.jax_compilation_cache_dir != xla_dir:
+            os.makedirs(xla_dir, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", xla_dir)
+    # cold start pays for EVERY compile, not just the >1 s ones jax
+    # persists by default
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return jax.config.jax_compilation_cache_dir

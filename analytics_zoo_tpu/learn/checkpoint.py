@@ -605,6 +605,24 @@ def _optstate_to_tree(opt_state: Any) -> Any:
     return jax.tree_util.tree_map(gather_leaf, opt_state)
 
 
+def remap_param_subtrees(tree: Any, param_names, remap) -> Any:
+    """Rename the params-shaped subtrees of a saved optimizer state (the
+    Adam moments mirror the params tree) with the SAME remap the saved
+    params went through. `restore_opt_state` pours leaves by position,
+    and jax orders dict leaves by sorted key: where the saving and the
+    resuming model's auto-numbered layer names sort differently
+    ("dense_99", "dense_100" saved; "dense_101", "dense_102" live) the
+    two layers' moments would otherwise swap."""
+    if isinstance(tree, dict):
+        if set(tree) == param_names:
+            return remap(tree)
+        return {k: remap_param_subtrees(v, param_names, remap)
+                for k, v in tree.items()}
+    if isinstance(tree, list):
+        return [remap_param_subtrees(v, param_names, remap) for v in tree]
+    return tree
+
+
 def restore_opt_state(template: Any, tree: Any) -> Any:
     """Pour saved leaves back into an optax state built by opt.init."""
     leaves_saved = jax.tree_util.tree_leaves(tree)

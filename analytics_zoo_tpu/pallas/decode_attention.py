@@ -36,8 +36,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from analytics_zoo_tpu.pallas.dropout import _tpu_params
-
 
 def _attend_window(q, k, v, lengths, kv_bucket):
     """The shared exact-attention core: q [S, H, D] against a
@@ -102,12 +100,21 @@ def _decode_cost(q, kv_bucket, n_heads, itemsize):
     from jax.experimental import pallas as pl
 
     S, H, D = q.shape[0], n_heads, q.shape[-1]
-    kv_bytes = 2.0 * S * H * kv_bucket * D * itemsize
-    qo_bytes = 2.0 * S * H * D * itemsize + 4.0 * S
+    kv_bytes = 2 * S * H * kv_bucket * D * itemsize
+    qo_bytes = 2 * S * H * D * itemsize + 4 * S
     return pl.CostEstimate(
-        flops=4.0 * S * H * kv_bucket * D,          # QKᵀ + PV
-        bytes_accessed=float(kv_bytes + qo_bytes),
-        transcendentals=float(S * H * kv_bucket))
+        flops=4 * S * H * kv_bucket * D,            # QKᵀ + PV
+        bytes_accessed=kv_bytes + qo_bytes,
+        transcendentals=S * H * kv_bucket)
+
+
+def _row_spec(D, index_map):
+    """One slot's one head's [1, D] query / output row, carried as a
+    `[S, H, 1, D]` array: Mosaic wants a block's last two dims to be
+    multiples of (8, 128) or the array's whole extent, and a (1, D) block
+    of `[S, H, D]` has 1 of H rows in its second-to-last dim."""
+    from jax.experimental import pallas as pl
+    return pl.BlockSpec((1, 1, 1, D), index_map)
 
 
 def _decode_kernel(scale, n_kb, q_ref, k_ref, v_ref, len_ref, o_ref,
@@ -124,7 +131,7 @@ def _decode_kernel(scale, n_kb, q_ref, k_ref, v_ref, len_ref, o_ref,
         m_sc[...] = jnp.full_like(m_sc, -1e30)
         l_sc[...] = jnp.zeros_like(l_sc)
 
-    qb = q_ref[0]                                          # [1, D]
+    qb = q_ref[0, 0]                                       # [1, D]
     kb = k_ref[0, 0]                                       # [bk, D]
     vb = v_ref[0, 0]
     scores = jnp.dot(qb, kb.T,
@@ -143,7 +150,7 @@ def _decode_kernel(scale, n_kb, q_ref, k_ref, v_ref, len_ref, o_ref,
 
     @pl.when(ki == n_kb - 1)
     def _flush():
-        o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
 
 
 def decode_attention(q, k_pool, v_pool, lengths, kv_bucket: int,
@@ -171,10 +178,11 @@ def decode_attention(q, k_pool, v_pool, lengths, kv_bucket: int,
 
     block_k = min(block_k, kv_bucket)
     if kv_bucket % block_k:
-        # bucket ladders are powers of two >= 1; a non-dividing block
-        # falls back to the exact path rather than padding the pool
-        return _reference_decode_attention(q, k_pool, v_pool, lengths,
-                                           kv_bucket)
+        # bucket ladders are powers of two >= 1; the kernel does not pad
+        # the pool, and does not quietly hand a TPU step to the reference
+        raise ValueError(
+            f"kv_bucket {kv_bucket} is not a multiple of block_k "
+            f"{block_k}")
     n_kb = kv_bucket // block_k
     scale = 1.0 / math.sqrt(D)
     item = jnp.dtype(q.dtype).itemsize
@@ -182,24 +190,24 @@ def decode_attention(q, k_pool, v_pool, lengths, kv_bucket: int,
         functools.partial(_decode_kernel, scale, n_kb),
         grid=(S, H, n_kb),
         in_specs=[
-            pl.BlockSpec((1, 1, D), lambda s, h, j: (s, h, 0)),
+            _row_spec(D, lambda s, h, j: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda s, h, j: (s, h, j, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda s, h, j: (s, h, j, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda s, h, j: (s, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
+        out_specs=_row_spec(D, lambda s, h, j: (s, h, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((1, D), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=_tpu_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=_decode_cost(q, kv_bucket, H, item),
         interpret=bool(interpret) if interpret is not None else False,
-    )(q, k_pool, v_pool, lengths.reshape(S, 1))
-    return out
+    )(q[:, :, None, :], k_pool, v_pool, lengths.reshape(S, 1))
+    return out[:, :, 0, :]
 
 
 # ---------------------------------------------------------------------------
@@ -213,13 +221,13 @@ def _paged_cost(q, kv_bucket, n_heads, block_len, itemsize):
     from jax.experimental import pallas as pl
 
     S, H, D = q.shape[0], n_heads, q.shape[-1]
-    kv_bytes = 2.0 * S * H * kv_bucket * D * itemsize
-    qo_bytes = 2.0 * S * H * D * itemsize + 4.0 * S
-    table_bytes = 4.0 * S * (kv_bucket // block_len)
+    kv_bytes = 2 * S * H * kv_bucket * D * itemsize
+    qo_bytes = 2 * S * H * D * itemsize + 4 * S
+    table_bytes = 4 * S * (kv_bucket // block_len)
     return pl.CostEstimate(
-        flops=4.0 * S * H * kv_bucket * D,          # QKᵀ + PV
-        bytes_accessed=float(kv_bytes + qo_bytes + table_bytes),
-        transcendentals=float(S * H * kv_bucket))
+        flops=4 * S * H * kv_bucket * D,            # QKᵀ + PV
+        bytes_accessed=kv_bytes + qo_bytes + table_bytes,
+        transcendentals=S * H * kv_bucket)
 
 
 def _paged_kernel(scale, n_kb, block_len, tbl_ref, q_ref, k_ref, v_ref,
@@ -240,7 +248,7 @@ def _paged_kernel(scale, n_kb, block_len, tbl_ref, q_ref, k_ref, v_ref,
         m_sc[...] = jnp.full_like(m_sc, -1e30)
         l_sc[...] = jnp.zeros_like(l_sc)
 
-    qb = q_ref[0]                                          # [1, D]
+    qb = q_ref[0, 0]                                       # [1, D]
     kb = k_ref[0, 0]                                       # [bl, D]
     vb = v_ref[0, 0]
     scores = jnp.dot(qb, kb.T,
@@ -259,7 +267,7 @@ def _paged_kernel(scale, n_kb, block_len, tbl_ref, q_ref, k_ref, v_ref,
 
     @pl.when(ki == n_kb - 1)
     def _flush():
-        o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
@@ -310,14 +318,14 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
         num_scalar_prefetch=1,            # tables[:, :n_kb]
         grid=(S, H, n_kb),
         in_specs=[
-            pl.BlockSpec((1, 1, D), lambda s, h, j, tbl: (s, h, 0)),
+            _row_spec(D, lambda s, h, j, tbl: (s, h, 0, 0)),
             pl.BlockSpec((1, 1, block_len, D),
                          lambda s, h, j, tbl: (tbl[s, j], h, 0, 0)),
             pl.BlockSpec((1, 1, block_len, D),
                          lambda s, h, j, tbl: (tbl[s, j], h, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda s, h, j, tbl: (s, h, 0)),
+        out_specs=_row_spec(D, lambda s, h, j, tbl: (s, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, D), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
@@ -327,10 +335,11 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale, n_kb, block_len),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        compiler_params=_tpu_params(
+        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=_paged_cost(q, kv_bucket, H, block_len, item),
         interpret=bool(interpret) if interpret is not None else False,
-    )(tables[:, :n_kb], q, k_pool, v_pool, lengths.reshape(S, 1))
-    return out
+    )(tables[:, :n_kb], q[:, :, None, :], k_pool, v_pool,
+      lengths.reshape(S, 1))
+    return out[:, :, 0, :]

@@ -8,7 +8,8 @@ select). Profiled on v5e (BERT-base, batch 256, seq 128, dropout on all
 sites): 16.4 ms/step of rng-bit-generator time plus ~15 ms/step of u32
 copies/slices — the whole measured dropout tax.
 
-Three implementations, selected by `ZOO_DROPOUT_IMPL`:
+Three implementations, selected by `ZOO_DROPOUT_IMPL` (a named
+implementation runs or raises; it is never swapped for another):
 
 - `u8` (default on TPU) — draw ONE random byte per element
   (`jax.random.bits(..., uint8)`) and keep iff byte < t where
@@ -42,17 +43,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-
-def _tpu_params(**kwargs):
-    """`pltpu.CompilerParams(...)` across the jax rename: jax ≤0.4.x
-    spells it `TPUCompilerParams`, newer trees `CompilerParams` — the
-    pre-rename spelling raised AttributeError on this jaxlib and took
-    every Pallas kernel (and its tier-1 tests) down with it."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
 
 
 def _dropout_threshold(rate: float) -> int:
@@ -140,10 +130,10 @@ def _apply(x2d, seed, rate, interpret):
         # analytic roofline model (check_pallas_cost lint): one read +
         # one write of x, ~3 elementwise ops (threshold/scale/select) —
         # the PRNG bits never touch HBM
-        cost_estimate=pl.CostEstimate(flops=3.0 * M * C,
-                                      bytes_accessed=float(2 * M * C * item),
+        cost_estimate=pl.CostEstimate(flops=3 * M * C,
+                                      bytes_accessed=2 * M * C * item,
                                       transcendentals=0),
-        compiler_params=_tpu_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2d, seed)
@@ -201,12 +191,19 @@ def fused_dropout(x, rate: float, *, rng=None,
         rng = jax.random.PRNGKey(jnp.asarray(seed, jnp.int32))
     if impl == "u32":
         return _plain_dropout(rng, rate, x)
-    shape2d = (_view_2d(x)
-               if impl == "pallas" and jax.default_backend() == "tpu"
-               else None)
-    if shape2d is None:
-        # pallas needs a TPU and a lane-aligned view; next-best is u8
+    if impl == "u8":
         return _u8_dropout(rng, rate, x)
+    # pallas was asked for by name: it runs the kernel or it fails —
+    # quietly drawing u8 bytes instead would time the wrong program
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "ZOO_DROPOUT_IMPL=pallas needs a TPU backend, found "
+            f"{jax.default_backend()!r}")
+    shape2d = _view_2d(x)
+    if shape2d is None:
+        raise ValueError(
+            f"ZOO_DROPOUT_IMPL=pallas: shape {tuple(x.shape)} has no "
+            "reshape-only [M, C] view with C a multiple of 128 lanes")
     if seed is None:
         seed = jax.random.randint(rng, (), 0, 2 ** 31 - 1, jnp.int32)
     seed = jnp.asarray(seed, jnp.int32).reshape(1, 1)

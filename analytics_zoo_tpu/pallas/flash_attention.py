@@ -36,7 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from analytics_zoo_tpu.pallas.dropout import _byte_threshold, _tpu_params
+from analytics_zoo_tpu.pallas.dropout import _byte_threshold
 
 
 def _reference_attention(q, k, v, mask=None, dropout_rate: float = 0.0,
@@ -269,10 +269,9 @@ def _attn_cost(n_matmuls, q, extra_f32_out_elems=0):
     item = jnp.dtype(q.dtype).itemsize
     streams = 4 + n_matmuls  # rough: q,k,v(+dout...) in, grads/out out
     return pl.CostEstimate(
-        flops=2.0 * n_matmuls * bh * T * T * D,
-        bytes_accessed=float(bh * T * D * item * streams
-                             + extra_f32_out_elems * 4),
-        transcendentals=float(bh * T * T))
+        flops=2 * n_matmuls * bh * T * T * D,
+        bytes_accessed=bh * T * D * item * streams + extra_f32_out_elems * 4,
+        transcendentals=bh * T * T)
 
 
 def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret):
@@ -310,7 +309,7 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_tpu_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=_attn_cost(2, q,                    # QKᵀ + PV
                                  extra_f32_out_elems=B * H * T),
@@ -522,7 +521,7 @@ def _flash_bwd(rate, _fwd_block_q, _fwd_block_k, block_q, block_k, interpret,
                 pltpu.VMEM((block_k, D), jnp.float32),
                 pltpu.VMEM((block_k, D), jnp.float32),
             ],
-            compiler_params=_tpu_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             # scores, dv, dw, dq-partial, dk matmuls; the dqp partials
             # buffer is an extra n_kb×T×D f32 write stream
@@ -554,7 +553,7 @@ def _flash_bwd(rate, _fwd_block_q, _fwd_block_k, block_q, block_k, interpret,
                                    lambda b, i, j: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            compiler_params=_tpu_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             cost_estimate=_attn_cost(3, q),   # scores, dw/ds, dq
             interpret=interpret,
@@ -584,7 +583,7 @@ def _flash_bwd(rate, _fwd_block_q, _fwd_block_k, block_q, block_k, interpret,
                 pltpu.VMEM((block_k, D), jnp.float32),
                 pltpu.VMEM((block_k, D), jnp.float32),
             ],
-            compiler_params=_tpu_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             cost_estimate=_attn_cost(4, q),   # scores, dv, ds, dk
             interpret=interpret,

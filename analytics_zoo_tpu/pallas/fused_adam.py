@@ -28,25 +28,24 @@ cost analysis cannot see inside a custom call), so the roofline layer
 bytes — `update_cost()` is that model, exported for tests and benches.
 
 `interpret=None` auto-selects interpreter mode off-TPU so tier-1
-exercises the exact kernel code path on the CPU rig; `fused_available`
-probes one tiny compile so any Pallas lowering failure degrades to
-plain optax with a single WARNING instead of a mid-fit crash.
+exercises the exact kernel code path on the CPU; on a TPU backend it
+never resolves to the interpreter, and a kernel Mosaic refuses raises
+out of the fit's first step — nothing probes ahead and nothing swaps
+plain optax in for a fused step that was asked for.
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-log = logging.getLogger("analytics_zoo_tpu.pallas")
-
 # Per-operand VMEM budget for a block: 7 live buffers (4 in + 3 out)
-# double-buffered must fit comfortably under ~16 MB/core; 512 KB/block
-# → ≤ 7 MB resident, big enough to amortize DMA issue overhead.
+# double-buffered must fit under Mosaic's 16 MB scoped-VMEM limit;
+# 512 KB/block → ≤ 7 MB resident, big enough to amortize DMA issue
+# overhead.
 _BLOCK_BYTES = 512 * 1024
 
 
@@ -61,8 +60,12 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
 def _block_rows(rows: int, cols: int) -> int:
     """Largest multiple-of-8 row count whose f32 block stays under the
     VMEM budget (min 8 — smaller blocks pad to the (8, 128) f32 tile
-    anyway)."""
-    bm = max(8, _BLOCK_BYTES // (4 * max(cols, 1)))
+    anyway). The budget counts the block as VMEM holds it, lanes padded
+    to a multiple of 128: at the unpadded width a 64-wide NCF table got
+    2048-row blocks, 16.43 MB of scoped VMEM, and Mosaic refused the
+    step (RESOURCE_EXHAUSTED, limit 16 MB; PR 21 chip run)."""
+    lanes = -(-max(cols, 1) // 128) * 128
+    bm = max(8, _BLOCK_BYTES // (4 * lanes))
     bm -= bm % 8
     return min(max(bm, 8), max(rows, 1))
 
@@ -101,20 +104,20 @@ def _fused_kernel(b1, b2, s_ref, p_ref, m_ref, v_ref, g_ref,
     v_out[...] = v_new
 
 
-def leaf_cost(shape, dtype) -> Tuple[float, float]:
+def leaf_cost(shape, dtype) -> Tuple[int, int]:
     """(flops, HBM bytes) of one fused update of one leaf: read g +
     read/write each of p (param dtype), m, v (f32) — the 7-pass floor
     the kernel achieves. ~12 elementwise flops + one sqrt per element."""
     import numpy as np
     n = int(np.prod(shape)) if shape else 1
     pbytes = jnp.dtype(dtype).itemsize
-    return 12.0 * n, float(n * (4 + 2 * pbytes + 4 * 4))
+    return 12 * n, n * (4 + 2 * pbytes + 4 * 4)
 
 
-def update_cost(params) -> Tuple[float, float]:
+def update_cost(params) -> Tuple[int, int]:
     """Analytic (flops, bytes) of one fused sweep over a whole tree —
     the roofline model benches and tests compare gauges against."""
-    flops = bytes_ = 0.0
+    flops = bytes_ = 0
     for leaf in jax.tree_util.tree_leaves(params):
         f, b = leaf_cost(jnp.shape(leaf), leaf.dtype)
         flops += f
@@ -184,35 +187,3 @@ def fused_adam_step(params, mu, nu, grads, count, *, lr,
            for p, m, v, g in zip(flat_p, flat_m, flat_v, flat_g)]
     return tuple(jax.tree_util.tree_unflatten(treedef, [o[i] for o in out])
                  for i in range(3))
-
-
-# ---------------------------------------------------------------------------
-# availability probe: lowering failure → plain optax, one WARNING
-# ---------------------------------------------------------------------------
-_probe_cache = {}
-
-
-def fused_available(interpret: Optional[bool] = None) -> bool:
-    """One tiny end-to-end kernel compile+run per (backend, interpret)
-    mode. Any Pallas/Mosaic failure is caught HERE — once, with one
-    WARNING — so the trainer degrades to plain optax instead of dying
-    mid-fit on the first real step."""
-    interpret = _resolve_interpret(interpret)
-    key = (jax.default_backend(), interpret)
-    if key in _probe_cache:
-        return _probe_cache[key]
-    try:
-        p = jnp.ones((8, 128), jnp.float32)
-        z = jnp.zeros((8, 128), jnp.float32)
-        out = jax.jit(lambda p, z: fused_adam_step(
-            {"w": p}, {"w": z}, {"w": z}, {"w": z + 0.5}, 1, lr=1e-3,
-            interpret=interpret))(p, z)
-        jax.block_until_ready(out)
-        ok = True
-    except Exception as e:  # noqa: BLE001 — degrade, never crash the fit
-        log.warning(
-            "fused optimizer kernels unavailable on this backend "
-            "(%s: %s); falling back to plain optax", type(e).__name__, e)
-        ok = False
-    _probe_cache[key] = ok
-    return ok

@@ -93,13 +93,13 @@ def _row_kernel(b1, b2, uids_ref, valid_ref, s_ref, p_ref, m_ref, v_ref,
 
 
 def segment_adam_cost(n_slots: int, dim: int,
-                      p_dtype=jnp.float32) -> Tuple[float, float]:
+                      p_dtype=jnp.float32) -> Tuple[int, int]:
     """(flops, bytes): 7 row-passes over the TOUCHED rows only — the
     whole point of the sparse path, and what the cost_estimate tells
     the roofline layer instead of a dense-table sweep."""
     n = n_slots * dim
     pbytes = jnp.dtype(p_dtype).itemsize
-    return 12.0 * n, float(n * (4 + 2 * pbytes + 4 * 4))
+    return 12 * n, n * (4 + 2 * pbytes + 4 * 4)
 
 
 def segment_adam_update(table, mu, nu, ids, d_rows, count, *, lr,

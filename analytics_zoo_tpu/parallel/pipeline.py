@@ -28,7 +28,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from analytics_zoo_tpu.common.mesh import BATCH_AXES, DeviceMesh
-from analytics_zoo_tpu.parallel.compat import pvary, shard_map
 
 
 def _pipeline_shard(params, mbs, stage_fn: Callable, axis: str, n_stages: int):
@@ -49,9 +48,8 @@ def _pipeline_shard(params, mbs, stage_fn: Callable, axis: str, n_stages: int):
         return out, out
 
     # carry becomes pipeline-varying after the first ppermute; mark the
-    # initial value to match (shard_map vma typing; identity on jax
-    # versions whose shard_map tracks replication instead — compat.pvary)
-    act0 = pvary(jnp.zeros_like(mbs[0]), axis)
+    # initial value to match (shard_map vma typing)
+    act0 = lax.pcast(jnp.zeros_like(mbs[0]), axis, to="varying")
     _, ys = lax.scan(body, act0, jnp.arange(T))
     valid = ys[n_stages - 1:]                      # [M, mb, ...]
     out = jnp.where(idx == n_stages - 1, valid, jnp.zeros_like(valid))
@@ -100,9 +98,9 @@ def pipeline_apply(stage_fn: Callable, stacked_params, microbatches,
             lambda p: jnp.squeeze(p, axis=0), params)
         return _pipeline_shard(params, mbs, stage_fn, axis, S)
 
-    fn = shard_map(shard, mesh=mesh.mesh,
-                   in_specs=(param_specs, mb_spec),
-                   out_specs=mb_spec)
+    fn = jax.shard_map(shard, mesh=mesh.mesh,
+                       in_specs=(param_specs, mb_spec),
+                       out_specs=mb_spec, check_vma=False)
     return fn(stacked_params, microbatches)
 
 

@@ -26,7 +26,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from analytics_zoo_tpu.common.mesh import BATCH_AXES, DeviceMesh
-from analytics_zoo_tpu.parallel.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -102,13 +101,13 @@ def ring_attention(q, k, v, mask: Optional[jax.Array] = None, *,
 
     shard_fn = functools.partial(_ring_attention_shard, axis=axis)
     if mask is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v: shard_fn(q, k, v, None),
             mesh=mesh.mesh, in_specs=(qkv_spec, qkv_spec, qkv_spec),
-            out_specs=qkv_spec)
+            out_specs=qkv_spec, check_vma=False)
         return fn(q, k, v)
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh.mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
-        out_specs=qkv_spec)
+        out_specs=qkv_spec, check_vma=False)
     return fn(q, k, v, mask)

@@ -67,6 +67,8 @@ def cmd_start(args) -> int:
         raise SystemExit(
             "secure.model_encrypted needs http_port: the secret/salt "
             "arrive via the frontend's POST /model-secure")
+    from analytics_zoo_tpu.compile_cache import enable_jax_persistent_cache
+    print(f"xla compile cache: {enable_jax_persistent_cache()}", flush=True)
     broker = connect_broker(cfg.broker_url)
     frontend = None
     if cfg.http_port is not None:

@@ -681,7 +681,7 @@ class InferenceModel:
             if key in self._exec_cost:
                 return
             from analytics_zoo_tpu.observability.roofline import cost_of
-            c = cost_of(stages_obj)
+            c = cost_of(stages_obj, span=self._program_span())
             if c is not None:
                 self._exec_cost[key] = c
         except Exception:  # noqa: BLE001 — telemetry only
@@ -689,8 +689,8 @@ class InferenceModel:
 
     def _harvest_jit_cost(self, params, batch):
         """Jit-path warmup harvest: lowering is cheap next to the XLA
-        compile warmup is already paying, and `Lowered.cost_analysis()`
-        matches the compiled numbers on this backend."""
+        compile warmup is already paying (`cost_of` says what a backend
+        that does not cost lowered modules gets)."""
         if self._cost_key(batch) in self._exec_cost:
             return
         try:
@@ -1285,10 +1285,7 @@ class InferenceModel:
         return self
 
     def _use_compile_cache(self) -> bool:
-        if self.compile_cache is None:
-            return False
-        from analytics_zoo_tpu.compile_cache import HAVE_AOT
-        return HAVE_AOT
+        return self.compile_cache is not None
 
     def _warmup_replicas(self, sample, buckets, tag,
                          use_cache: bool = False) -> "InferenceModel":

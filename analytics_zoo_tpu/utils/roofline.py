@@ -2,7 +2,10 @@
 
 Single source of truth for the benches (`bench.py`, `bench_ncf.py`) and
 any profiling hook that wants achieved-vs-peak ratios. Values are the
-published per-chip peaks; lookup is by `device_kind` substring."""
+published per-chip peaks; lookup is by `device_kind` substring, and a
+device the tables do not list raises `UnknownDeviceError`: the bench
+scripts fail on it, the library gauges that divide by a peak stay
+unpublished."""
 
 from __future__ import annotations
 
@@ -27,19 +30,29 @@ PEAK_HBM_BYTES = [  # device_kind substring -> peak HBM bytes/s per chip
 ]
 
 
-def _lookup(device, table, default: float) -> float:
-    kind = getattr(device, "device_kind", "cpu").lower()
+class UnknownDeviceError(LookupError):
+    """The device's `device_kind` is in neither peak table (the CPU
+    backend, a TPU generation not listed). There is no default: a rate
+    divided by another chip's peak is not a utilization."""
+
+
+def _lookup(device, table, what: str) -> float:
+    kind = str(getattr(device, "device_kind", "")).lower()
     for sub, peak in table:
         if sub in kind:
             return peak
-    return default
+    raise UnknownDeviceError(
+        f"no published {what} for device_kind {kind!r}; add it to "
+        "utils/roofline.py with its source")
 
 
 def peak_flops(device) -> float:
-    """Peak bf16 matmul FLOP/s; unknown TPUs assume v5e."""
-    return _lookup(device, PEAK_BF16_FLOPS, 197e12)
+    """Peak bf16 matmul FLOP/s of one chip; UnknownDeviceError for a
+    device_kind the table does not list."""
+    return _lookup(device, PEAK_BF16_FLOPS, "peak bf16 FLOP/s")
 
 
 def peak_hbm(device) -> float:
-    """Peak HBM bytes/s; unknown TPUs assume v5e."""
-    return _lookup(device, PEAK_HBM_BYTES, 819e9)
+    """Peak HBM bytes/s of one chip; UnknownDeviceError for a
+    device_kind the table does not list."""
+    return _lookup(device, PEAK_HBM_BYTES, "peak HBM bytes/s")

@@ -53,6 +53,7 @@ DEFAULT_ROOTS = ("analytics_zoo_tpu", "scripts", "bench_serving.py",
 REQUIRED = {
     "compile_cache_hits_total": "counter",
     "compile_cache_misses_total": "counter",
+    "compile_cache_load_errors_total": "counter",
     "compile_cache_load_ms": "histogram",
     "compile_cache_compile_ms": "histogram",
     "compile_cache_bytes": "gauge",

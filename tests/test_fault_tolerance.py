@@ -705,6 +705,33 @@ class TestAutoResume:
         assert hist_resumed["loss"] == hist_full["loss"][2:]
         assert _counter_value("training_resumes_total") == before + 1
 
+    def test_moments_follow_their_layers_across_a_name_sort_flip(
+            self, tmp_path, monkeypatch):
+        """Saved as (dense_99, dense_100), resumed as (dense_101,
+        dense_102): jax orders dict leaves by sorted key, so the saved
+        pair sorts the other way round. Pouring the optimizer moments by
+        position swapped the two layers' state (shape error here, silent
+        corruption for equal shapes); they take the params' remap now."""
+        from analytics_zoo_tpu.keras import engine
+        counters = dict(engine._name_counters)
+        monkeypatch.setattr(engine, "_name_counters",
+                            engine.collections.defaultdict(int, counters))
+
+        def adam_model():                       # Adam: state has moments
+            m = _trainer_model()
+            m.compile(optimizer="adam", loss="mse")
+            return m
+        x, y = _trainer_data()
+        hist_full = _fit(adam_model(), x, y, epochs=3)
+        engine._name_counters["Dense"] = 98
+        m_a = adam_model()                      # dense_99, dense_100
+        m_a.set_checkpoint(str(tmp_path))
+        _fit(m_a, x, y, epochs=2)
+        m_b = adam_model()                      # dense_101, dense_102
+        m_b.set_checkpoint(str(tmp_path))
+        hist_resumed = _fit(m_b, x, y, epochs=3, auto_resume=True)
+        assert hist_resumed["loss"] == hist_full["loss"][2:]
+
     def test_resume_without_checkpoint_trains_fresh(self, tmp_path):
         x, y = _trainer_data()
         before = _counter_value("training_resumes_total")

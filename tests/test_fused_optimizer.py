@@ -12,8 +12,8 @@ Covered:
 - fused fit == plain fit losses (dense, multi-step, lazy, sharded on
   the conftest 8-device mesh), config/env engagement, no-twin fallback;
 - donation stays in-place + leak_check flat over steps;
-- lowering failure → plain optax with one WARNING (real Mosaic failure
-  on the CPU backend via interpret=False);
+- lowering failure raises out of the fit, never plain optax (real
+  Mosaic failure on the CPU backend via interpret=False);
 - compile-cache keying: fused vs unfused never share an executable;
 - auto-resume: bitwise continuation with fused state, actionable error
   on a toggled restore;
@@ -41,7 +41,6 @@ from analytics_zoo_tpu.ops.optimizers import (FusedAdamState, as_fused,
 from analytics_zoo_tpu.ops.optimizers import get as get_optimizer
 from analytics_zoo_tpu.pallas import fused_adam as fused_mod
 from analytics_zoo_tpu.pallas.fused_adam import (fused_adam_step,
-                                                 fused_available,
                                                  update_cost)
 from analytics_zoo_tpu.pallas.segment_update import (segment_adam_update,
                                                      segment_compact)
@@ -163,6 +162,20 @@ class TestFusedTransformation:
         assert as_fused(optax.adam(5e-4), None) is None
         fused = fused_adam(1e-3)
         assert as_fused(fused, None) is fused
+
+
+class TestBlockBudget:
+    def test_budget_counts_lanes_as_vmem_pads_them(self):
+        """7 operands, double-buffered, must sit well under Mosaic's 16 MB
+        scoped-VMEM limit at the width VMEM holds (lanes padded to 128).
+        The NCF bench's 64-wide table ran out of it on the chip when the
+        budget used the unpadded width."""
+        for rows, cols in ((138001, 64), (27001, 64), (30522, 768),
+                           (768, 3072), (1, 768), (64, 2)):
+            bm = fused_mod._block_rows(rows, cols)
+            lanes = -(-cols // 128) * 128
+            assert 14 * bm * lanes * 4 <= 8 * 2 ** 20, (rows, cols, bm)
+            assert bm == rows or bm % 8 == 0
 
 
 class TestSegmentPath:
@@ -443,32 +456,35 @@ class TestShardedFused:
         assert h_res["loss"] == h_full["loss"][2:]
 
 
-class TestFallback:
-    def test_probe_detects_real_lowering_failure(self, caplog):
+class TestNoFallback:
+    def test_kernel_that_fails_to_lower_raises(self, monkeypatch, caplog):
         # interpret=False on the CPU backend IS a real Mosaic lowering
-        # failure — the probe must catch it once, warn once, and cache
-        fused_mod._probe_cache.pop((jax.default_backend(), False), None)
-        with caplog.at_level("WARNING"):
-            assert fused_available(interpret=False) is False
-            assert fused_available(interpret=False) is False  # cached
-        warns = [r for r in caplog.records
-                 if "fused optimizer kernels unavailable" in r.message]
-        assert len(warns) == 1
-
-    def test_interpret_probe_available_here(self):
-        assert fused_available() is True
-
-    def test_trainer_degrades_to_plain_optax(self, monkeypatch):
-        # a backend where the kernels cannot lower: the fit must run the
-        # plain path and produce the same losses as fused_optimizer=False
-        monkeypatch.setattr(fused_mod, "fused_available", lambda *a: False)
+        # failure. A fit that was asked for the fused step must die on
+        # it — running plain optax under the fused name is how an A/B
+        # ends up comparing optax with optax.
+        monkeypatch.setattr(fused_mod, "_resolve_interpret",
+                            lambda interpret: False)
         x, y = _dense_data()
-        h_off = fit_keras(_dense_model(), x, y, epochs=2,
-                          fused_optimizer=False, **FIT_KW)
-        h_deg = fit_keras(_dense_model(), x, y, epochs=2,
-                          fused_optimizer=True, **FIT_KW)
-        np.testing.assert_allclose(h_deg["loss"], h_off["loss"],
-                                   rtol=1e-7)
+        with pytest.raises(Exception, match="(?i)interpret|mosaic|tpu"):
+            fit_keras(_dense_model(), x, y, epochs=1,
+                      fused_optimizer=True, **FIT_KW)
+        assert not [r for r in caplog.records
+                    if "falling back to plain optax" in r.message]
+
+    def test_env_request_raises_too(self, monkeypatch):
+        monkeypatch.setenv("ZOO_FUSED_OPT", "1")
+        monkeypatch.setattr(fused_mod, "_resolve_interpret",
+                            lambda interpret: False)
+        x, y = _dense_data()
+        with pytest.raises(Exception, match="(?i)interpret|mosaic|tpu"):
+            fit_keras(_dense_model(), x, y, epochs=1, **FIT_KW)
+
+    def test_interpreter_is_never_resolved_on_a_tpu_backend(
+            self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert fused_mod._resolve_interpret(None) is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert fused_mod._resolve_interpret(None) is True
 
 
 class TestCompileCacheKeying:

@@ -303,8 +303,6 @@ class TestGreedyParity:
         assert [int(t) for t in r] == ref[:3]
 
 
-@pytest.mark.skipif(not ccser.HAVE_AOT,
-                    reason="jax build lacks serialize_executable")
 class TestZeroCompile:
     def test_no_compiles_on_decode_request_path(self, tmp_path,
                                                 monkeypatch):

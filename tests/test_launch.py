@@ -51,6 +51,12 @@ class TestBuildCommands:
         assert argv[:5] == ["kubectl", "exec", "-i", "pod-0", "--"]
         assert env is None and "ZOO_PROCESS_ID=0" in argv[5]
 
+    def test_nproc_without_simulate_devices_is_refused(self):
+        """N local processes would all open the same chips; the launcher
+        neither binds them nor pretends."""
+        with pytest.raises(ValueError, match="--simulate-devices"):
+            zl.launch(["localhost"], nproc=2, script=SCRIPT)
+
     def test_detect_hosts_tpu_pod(self, monkeypatch):
         monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "t1k-w0, t1k-w1")
         assert zl.detect_hosts() == ["t1k-w0", "t1k-w1"]

@@ -1,0 +1,103 @@
+"""Every Pallas kernel cross-lowered for the TPU platform, from the CPU.
+
+`jit(...).trace(...).lower(lowering_platforms=("tpu",))` runs jax's Mosaic
+lowering without a chip: `pl.CostEstimate` validation, the block-shape rule
+(last two block dims multiples of (8, 128) or the whole array extent) and
+unsupported primitives all raise here, in seconds. It is the cheap half of
+the on-chip suite (`tests/tpu`, which also runs libtpu's compiler and checks
+numerics): the CPU tests run the kernels through the interpreter only, which
+is how six CostEstimate sites and both decode kernels stopped lowering while
+tier-1 stayed green.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from analytics_zoo_tpu.pallas import dropout as dropout_mod
+from analytics_zoo_tpu.pallas.decode_attention import (decode_attention,
+                                                       paged_decode_attention)
+from analytics_zoo_tpu.pallas.flash_attention import flash_attention
+from analytics_zoo_tpu.pallas.fused_adam import fused_adam_step
+from analytics_zoo_tpu.pallas.segment_update import segment_adam_update
+
+sds = jax.ShapeDtypeStruct
+
+
+@pytest.fixture(autouse=True)
+def tpu_backend(monkeypatch):
+    """The kernels pick Mosaic over their reference / the interpreter from
+    `jax.default_backend()`; nothing here executes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _mosaic_calls(fn, *args) -> int:
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return text.count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("T,n_bwd", [(128, 1), (2048, 2)])
+def test_flash_attention_fwd_bwd_dropout(T, n_bwd):
+    q = sds((2, 12, T, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, dropout_rate=0.1,
+                              dropout_seed=jnp.int32(3))
+        return out.astype(jnp.float32).sum()
+    # seq 2048 runs 1024x1024 tiles: past the fused backward's VMEM bound,
+    # so dq and dk/dv are two kernels
+    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) \
+        == 1 + n_bwd
+
+
+@pytest.mark.parametrize("H,D,L", [(4, 64, 256), (2, 8, 128)])
+def test_decode_attention(H, D, L):
+    S = 8
+    pool = sds((S, H, L, D), jnp.float32)
+    assert _mosaic_calls(
+        lambda q, k, v, n: decode_attention(q, k, v, n, kv_bucket=L // 2),
+        sds((S, H, D), jnp.float32), pool, pool, sds((S,), jnp.int32)) == 1
+
+
+@pytest.mark.parametrize("H,D,block_len", [(4, 64, 64), (2, 8, 16)])
+def test_paged_decode_attention(H, D, block_len):
+    S, n_kb = 8, 4
+    pool = sds((S * n_kb + 1, H, block_len, D), jnp.float32)
+    assert _mosaic_calls(
+        lambda q, k, v, t, n: paged_decode_attention(
+            q, k, v, t, n, kv_bucket=n_kb * block_len),
+        sds((S, H, D), jnp.float32), pool, pool, sds((S, n_kb), jnp.int32),
+        sds((S,), jnp.int32)) == 1
+
+
+def test_fused_adam_bert_and_ncf_leaves():
+    shapes = [(768, 3072), (768,), (30522, 768), (768, 2), (3, 5, 11),
+              (138000, 64), (32,)]
+    p = {f"p{i}": sds(s, jnp.float32) for i, s in enumerate(shapes)}
+    assert _mosaic_calls(
+        lambda p, m, v, g: fused_adam_step(p, m, v, g, 1, lr=1e-3),
+        p, p, p, p) == len(shapes)
+
+
+def test_pallas_dropout(monkeypatch):
+    monkeypatch.setenv("ZOO_DROPOUT_IMPL", "pallas")
+
+    def loss(x):
+        return dropout_mod.fused_dropout(x, 0.1, seed=jnp.int32(1)).sum()
+    assert _mosaic_calls(jax.grad(loss), sds((256, 384), jnp.float32)) == 1
+    # a named implementation runs or raises: (5, 7) has no lane-aligned view
+    with pytest.raises(ValueError, match="multiple of 128"):
+        _mosaic_calls(loss, sds((5, 7), jnp.float32))
+
+
+def test_segment_adam_update_does_not_lower():
+    """ROADMAP D12, pinned so a repair has to come through here: the kernel
+    addresses ONE table row per grid step with a (1, dim) block."""
+    V, dim, B = 138000, 64, 8192
+    t = sds((V, dim), jnp.float32)
+    with pytest.raises(ValueError, match="divisible by 8 and 128"):
+        _mosaic_calls(
+            lambda t, m, v, i, r: segment_adam_update(t, m, v, i, r, 1,
+                                                      lr=1e-3),
+            t, t, t, sds((B,), jnp.int32), sds((B, dim), jnp.float32))

@@ -112,6 +112,34 @@ class TestCostOf:
                 raise RuntimeError("no cost model on this backend")
         assert cost_of(Broken()) is None
 
+    def test_lowered_without_an_answer_is_compiled_when_single_device(
+            self, caplog):
+        """The TPU backend of the installed jax: `Lowered.cost_analysis()`
+        is None, `Compiled.cost_analysis()` a dict (seen on the chip in
+        PR 21, where every roofline gauge was silently absent)."""
+        class Compiled:
+            def cost_analysis(self):
+                return {"flops": 8.0, "bytes accessed": 2.0}
+
+        class Lowered:
+            compiles = 0
+
+            def cost_analysis(self):
+                return None
+
+            def compile(self):
+                Lowered.compiles += 1
+                return Compiled()
+        c = cost_of(Lowered())
+        assert (c.flops, c.bytes, Lowered.compiles) == (8.0, 2.0, 1)
+        # a partitioned program's compiled cost is per device — the wrong
+        # basis: no number, a WARNING, no compile
+        with caplog.at_level("WARNING"):
+            assert cost_of(Lowered(), span=4) is None
+        assert Lowered.compiles == 1
+        assert [r for r in caplog.records
+                if "spanning 4 devices" in r.message]
+
 
 @pytest.fixture()
 def isolated_registry():
@@ -163,6 +191,57 @@ class TestAccountant:
         assert acct.snapshot("serving")["seconds"] == 0.0
 
 
+class TestNoDefaultPeak:
+    """An unlisted device has no peak: the old lookup handed the CPU a
+    v5e's 197 TFLOP/s / 819 GB/s and every utilization divided by it."""
+
+    @pytest.fixture()
+    def no_session(self, monkeypatch):
+        from analytics_zoo_tpu.observability import roofline as rmod
+        monkeypatch.delenv("ZOO_SESSION_HBM_GBPS", raising=False)
+        monkeypatch.delenv("ZOO_SESSION_TFLOPS", raising=False)
+        monkeypatch.setattr(rmod, "_session",
+                            {"hbm_gbps": None, "tflops": None})
+
+    def test_cpu_device_raises_listed_kind_resolves(self):
+        from analytics_zoo_tpu.utils.roofline import (UnknownDeviceError,
+                                                      peak_flops, peak_hbm)
+        cpu = jax.devices()[0]
+        assert cpu.platform == "cpu"
+        with pytest.raises(UnknownDeviceError):
+            peak_flops(cpu)
+        with pytest.raises(UnknownDeviceError):
+            peak_hbm(cpu)
+
+        class V5e:
+            device_kind = "TPU v5 lite"
+        assert peak_flops(V5e()) == 197e12
+        assert peak_hbm(V5e()) == 819e9
+
+    def test_utilization_gauges_stay_unpublished(self, isolated_registry,
+                                                 no_session):
+        reg = isolated_registry
+        acct = RooflineAccountant(registry=reg)
+        acct.account("train", flops=2e12, bytes_=20e9, seconds=2.0,
+                     device=jax.devices()[0])
+        # the achieved rates need no peak and still publish
+        assert reg.get("roofline_achieved_tflops").value(
+            kind="train") == pytest.approx(1.0)
+        assert reg.get("roofline_mfu").label_keys() == []
+        assert reg.get("roofline_hbm_utilization").label_keys() == []
+        snap = acct.snapshot("train")
+        assert "mfu" not in snap and "hbm_utilization" not in snap
+        assert snap["achieved_tflops"] == pytest.approx(1.0)
+
+    def test_training_mfu_unpublished_on_cpu(self, isolated_registry):
+        from analytics_zoo_tpu.learn.trainer import _TrainingMetrics
+        reg = isolated_registry
+        _TrainingMetrics(reg).epoch(steps=4, n_seen=64, dt=1.0,
+                                    mean_loss=0.5, flops_per_step=1e9)
+        assert reg.get("training_samples_per_sec").value() == 64
+        assert reg.get("training_mfu").label_keys() == []
+
+
 class TestServingRoofline:
     def test_warmup_harvests_and_predict_accounts(self):
         W = np.random.RandomState(0).randn(16, 8).astype(np.float32)
@@ -205,6 +284,27 @@ class TestServingRoofline:
 
 
 class TestTrainerRoofline:
+    def test_cost_skeleton_lowers_to_the_module_the_call_lowered(
+            self, devices8):
+        """The harvest lowers from avals after the call donated its
+        buffers. Identical module → compiling it finds the executable the
+        call just built; when the skeleton dropped a one-device mesh's
+        shardings the TPU harvest compiled BERT-base a second time."""
+        import jax.numpy as jnp
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from analytics_zoo_tpu.learn.trainer import _StepCostTracker
+        f = jax.jit(lambda p, x, r: (p + x.sum() + jax.random.uniform(r),),
+                    donate_argnums=(0,))
+        for devs in (devices8[:1], devices8):
+            mesh = Mesh(np.array(devs), ("data",))
+            args = (jax.device_put(jnp.ones((4, 4)),
+                                   NamedSharding(mesh, P())),
+                    jax.device_put(jnp.ones((8, 4)),
+                                   NamedSharding(mesh, P("data"))),
+                    jax.random.PRNGKey(0))            # uncommitted
+            assert f.lower(*_StepCostTracker._skeleton(args)).as_text() \
+                == f.lower(*args).as_text()
+
     def _fit_mlp(self, n_layers, d=64, batch=32, n=128, **fit_kw):
         from analytics_zoo_tpu.keras import Sequential
         from analytics_zoo_tpu.keras import layers as L

@@ -279,9 +279,7 @@ class TestSwapParams:
 
     def test_same_structure_swap_zero_compiles_aot_path(self, tmp_path,
                                                         monkeypatch):
-        from analytics_zoo_tpu.compile_cache import HAVE_AOT, CompileCache
-        if not HAVE_AOT:
-            pytest.skip("jax without AOT serialization")
+        from analytics_zoo_tpu.compile_cache import CompileCache
         import analytics_zoo_tpu.compile_cache.serialization as ccser
         cache = CompileCache(str(tmp_path), registry=MetricsRegistry())
         im = InferenceModel(compile_cache=cache).load_fn(

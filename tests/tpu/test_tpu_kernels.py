@@ -118,8 +118,8 @@ class TestFitOnChip:
         assert jax.devices()[0].platform == "tpu"
 
     def test_sharded_train_step_mesh1_on_chip(self):
-        """build_sharded_train_step at mesh=1 ON the chip (VERDICT r4
-        weak #6): Mosaic/GSPMD interactions the CPU suite can't see."""
+        """build_sharded_train_step at mesh=1 ON the chip: Mosaic/GSPMD
+        interactions the CPU suite can't see."""
         import optax
 
         from analytics_zoo_tpu.common.context import (get_context,
@@ -151,8 +151,8 @@ class TestFitOnChip:
         assert np.isfinite(float(loss))
 
     def test_lazy_embeddings_fit_on_chip(self):
-        """lazy_embeddings=True through Estimator.fit on the real chip
-        (VERDICT r4 weak #6): the row-adam scatter path under Mosaic."""
+        """lazy_embeddings=True through Estimator.fit on the real chip:
+        the XLA row-adam scatter path (no fused kernels)."""
         from analytics_zoo_tpu.common.context import (init_orca_context,
                                                       stop_orca_context)
         from analytics_zoo_tpu.learn.estimator import Estimator
@@ -370,6 +370,135 @@ class TestDecodeAttentionOnChip:
                                                      kv_bucket=64))
         assert np.isfinite(got).all()
         np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-3)
+
+
+class TestPagedDecodeAttentionOnChip:
+    """`paged_decode_attention` under Mosaic: block tables ride in as a
+    scalar-prefetch operand and the k/v index maps dereference them. The
+    CPU suite runs the kernel through the interpreter only."""
+
+    def _case(self, S, H, D, block_len, n_kb, num_blocks, seed=0):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        q = jax.random.normal(ks[0], (S, H, D), jnp.float32) * 0.3
+        k = jax.random.normal(ks[1], (num_blocks, H, block_len, D),
+                              jnp.float32) * 0.3
+        v = jax.random.normal(ks[2], (num_blocks, H, block_len, D),
+                              jnp.float32) * 0.3
+        # every slot's logical blocks scattered over the pool, no sharing
+        perm = np.random.RandomState(seed).permutation(num_blocks)
+        tables = jnp.asarray(perm[:S * n_kb].reshape(S, n_kb), jnp.int32)
+        return q, k, v, tables
+
+    def test_matches_reference_scattered_blocks(self):
+        from analytics_zoo_tpu.pallas.decode_attention import (
+            _reference_paged_decode_attention, paged_decode_attention)
+        q, k, v, tables = self._case(S=8, H=4, D=64, block_len=64, n_kb=4,
+                                     num_blocks=40)
+        # length 1, block boundaries, a fully-masked trailing block
+        lengths = jnp.asarray([1, 7, 64, 65, 128, 129, 255, 256], jnp.int32)
+        got = np.asarray(paged_decode_attention(q, k, v, tables, lengths,
+                                                kv_bucket=256))
+        ref = np.asarray(_reference_paged_decode_attention(
+            q, k, v, tables, lengths, kv_bucket=256))
+        np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-3)
+
+    def test_bucket_reads_only_its_table_prefix(self):
+        from analytics_zoo_tpu.pallas.decode_attention import (
+            _reference_paged_decode_attention, paged_decode_attention)
+        q, k, v, tables = self._case(S=8, H=4, D=64, block_len=64, n_kb=4,
+                                     num_blocks=40, seed=1)
+        # kv_bucket covers the first 2 table entries: poison every block
+        # the later entries name, so any read past the bucket shows as NaN
+        late = np.asarray(tables)[:, 2:].reshape(-1)
+        k = k.at[late].set(jnp.nan)
+        v = v.at[late].set(jnp.nan)
+        lengths = jnp.asarray([3, 9, 17, 33, 64, 100, 127, 128], jnp.int32)
+        got = np.asarray(paged_decode_attention(q, k, v, tables, lengths,
+                                                kv_bucket=128))
+        ref = np.asarray(_reference_paged_decode_attention(
+            q, k, v, tables, lengths, kv_bucket=128))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-3)
+
+    def test_engine_default_geometry(self):
+        """The shapes DecodeServing runs today: TinyDecoder's 2 heads of
+        8 dims over block_len-16 blocks (`ServingConfig.decode_block_len`)."""
+        from analytics_zoo_tpu.pallas.decode_attention import (
+            _reference_paged_decode_attention, paged_decode_attention)
+        q, k, v, tables = self._case(S=4, H=2, D=8, block_len=16, n_kb=4,
+                                     num_blocks=33, seed=2)
+        lengths = jnp.asarray([1, 16, 17, 64], jnp.int32)
+        got = np.asarray(paged_decode_attention(q, k, v, tables, lengths,
+                                                kv_bucket=64))
+        ref = np.asarray(_reference_paged_decode_attention(
+            q, k, v, tables, lengths, kv_bucket=64))
+        np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-3)
+
+
+class TestFusedAdamOnChip:
+    """The fused Adam kernel at BERT-base leaf shapes, 1-row bias leaves
+    included (`_block_rows` on a (1, 768) view), and at the NCF bench's
+    64-wide embedding table (lanes pad to 128 in VMEM: the block budget
+    must count them, or Mosaic runs out of scoped VMEM), against optax."""
+
+    def test_bert_leaf_shapes_match_optax(self):
+        import optax
+
+        from analytics_zoo_tpu.pallas.fused_adam import fused_adam_step
+        rs = np.random.RandomState(0)
+        shapes = {"qkv": (768, 2304), "bias": (768,), "ln": (768,),
+                  "cls": (768, 2), "odd": (3, 5, 11), "pos": (128, 768),
+                  "ncf_table": (138001, 64)}
+        p = {k: jnp.asarray(rs.randn(*s), jnp.float32) * 0.1
+             for k, s in shapes.items()}
+        g = jax.tree_util.tree_map(lambda a: a * 0.01 + 1e-3, p)
+        z = jax.tree_util.tree_map(jnp.zeros_like, p)
+        step = jax.jit(lambda p, m, v, g, t: fused_adam_step(
+            p, m, v, g, t, lr=1e-3, weight_decay=0.01))
+        cur, mu, nu = p, z, z
+        for t in (1, 2, 3):
+            cur, mu, nu = step(cur, mu, nu, g, t)
+        opt = optax.adamw(1e-3, weight_decay=0.01)
+        ref, state = p, opt.init(p)
+        for _ in range(3):
+            upd, state = opt.update(g, state, ref)
+            ref = optax.apply_updates(ref, upd)
+        for name in shapes:
+            np.testing.assert_allclose(np.asarray(cur[name]),
+                                       np.asarray(ref[name]),
+                                       rtol=1e-5, atol=1e-6)
+
+
+class TestSegmentAdamOnChip:
+    @pytest.mark.xfail(
+        strict=True, raises=ValueError,
+        reason="ROADMAP D12: the (1, dim) row blocks of "
+               "pallas/segment_update.py break Mosaic's block rule (last "
+               "two block dims multiples of (8, 128) or the whole array "
+               "extent); the kernel raises on TPU and has no fallback")
+    def test_matches_row_adam_update(self):
+        from analytics_zoo_tpu.learn.lazy_embedding import (
+            LazyEmbeddingSpec, row_adam_update)
+        from analytics_zoo_tpu.pallas.segment_update import \
+            segment_adam_update
+        rs = np.random.RandomState(1)
+        V, D, B = 1000, 64, 256                  # NCF-bench embedding width
+        table = jnp.asarray(rs.randn(V, D), jnp.float32)
+        z = jnp.zeros((V, D))
+        ids = jnp.asarray(rs.randint(0, V, B), jnp.int32)   # duplicates
+        rows = jnp.asarray(rs.randn(B, D), jnp.float32)
+        g_table = jnp.zeros((V, D)).at[ids].add(rows)
+        spec = LazyEmbeddingSpec(path=("t",), ids_fn=None, lr=1e-3)
+        rt, rm, rv = row_adam_update(spec, table, z, z, g_table, ids,
+                                     jnp.asarray(1, jnp.int32))
+        ft, fm, fv = jax.jit(lambda *a: segment_adam_update(
+            *a, 1, lr=1e-3))(table, z, z, ids, rows)
+        touched = np.zeros(V, bool)
+        touched[np.asarray(ids)] = True
+        assert (np.asarray(ft)[~touched] == np.asarray(table)[~touched]).all()
+        for ref, got in ((rt, ft), (rm, fm), (rv, fv)):
+            np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
+                                       rtol=1e-5, atol=1e-7)
 
 
 class TestFusedDropout:
