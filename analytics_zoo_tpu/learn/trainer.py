@@ -18,6 +18,7 @@ per-device `batch_per_thread`.
 from __future__ import annotations
 
 import functools
+import itertools
 import logging
 import os
 import time
@@ -31,6 +32,7 @@ import optax
 from analytics_zoo_tpu.common.context import get_context
 from analytics_zoo_tpu.common import triggers as tg
 from analytics_zoo_tpu.observability.registry import get_registry
+from analytics_zoo_tpu.observability.tracing import get_tracer
 
 log = logging.getLogger("analytics_zoo_tpu.trainer")
 
@@ -91,6 +93,13 @@ class _TrainingMetrics:
             "over the model's parameter tree (observed once per "
             "model/step-program build, not per fit — warm re-fits "
             "skip the probe)")
+        self.fit_phase_ms = reg.histogram(
+            "training_fit_phase_ms",
+            "wall time of each leaf span of a fit call (`_FitTrace`), "
+            "observed when the span closes: phase = the span's name "
+            "without `fit.`; scope = call (once a fit call), epoch "
+            "(inside an epoch, on the loop's thread) or worker (the "
+            "prefetch thread, overlapping the loop)")
 
     def mesh_axes(self, mesh) -> None:
         """Publish the sharded fit's mesh factorization (one series per
@@ -141,6 +150,80 @@ class _TrainingMetrics:
         get_accountant().account("train", flops, bytes_, dt,
                                  device=jax.devices()[0],
                                  n_devices=n_devices)
+
+
+_fit_call_ids = itertools.count(1)   # process-wide: `fit-<n>` names a call
+
+
+class _FitTrace:
+    """One fit call as a span tree in the process-wide tracer
+    (`cat="training"`, one `trace_id` for the call), and the one counter
+    family observed at the same boundaries, `training_fit_phase_ms`:
+
+        fit                  root: `with _FitTrace(...) as trace`
+          fit.prepare ...    the call's phases, one after another: `enter`
+          fit.epoch          `begin_epoch`
+            fit.dispatch ... leaves inside an epoch: `with trace.phase(..)`
+          fit.finish         `enter("finish")` in the `finally`
+
+    The call's phases and its epochs follow one another and never nest,
+    so each is opened by closing the one before it, and the root's exit
+    closes what an exception left open. Every span is a scoped span of
+    `observability/tracing.py`, so a profiler capture that runs meanwhile
+    holds it as a host event on the device's clock."""
+
+    def __init__(self, telemetry: _TrainingMetrics, **root_args):
+        self._tracer = get_tracer()
+        self._telemetry = telemetry
+        self.trace_id = f"fit-{next(_fit_call_ids)}"
+        self._root = self._span("fit", root_args)
+        self._open: List[Any] = []      # the open phase or epoch
+
+    def _span(self, name: str, args: Dict[str, Any]):
+        """The root or an epoch; a streaming dataset does not say how
+        many steps an epoch has, and the argument is then left out."""
+        return self._tracer.span(
+            name, trace_id=self.trace_id, cat="training",
+            args={k: v for k, v in args.items() if v is not None})
+
+    def __enter__(self) -> "_FitTrace":
+        self._root.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._advance(None)
+        return self._root.__exit__(*exc)
+
+    def _advance(self, span) -> None:
+        while self._open:
+            self._open.pop().__exit__(None, None, None)
+        if span is not None:
+            self._open.append(span.__enter__())
+
+    def phase(self, phase: str, scope: str, **args):
+        """A leaf span `fit.<phase>` that observes its duration in
+        `training_fit_phase_ms{phase, scope}` as it closes."""
+        return self._tracer.phase(
+            "fit." + phase, self._telemetry.fit_phase_ms,
+            trace_id=self.trace_id, cat="training", args=args,
+            phase=phase, scope=scope)
+
+    def enter(self, phase: str, **args) -> None:
+        """The call's next phase (`scope="call"`)."""
+        self._advance(self.phase(phase, "call", **args))
+
+    def begin_epoch(self, epoch: int, steps: Optional[int]) -> None:
+        """`fit.epoch` has no series: `training_step_ms` has one
+        observation an epoch already."""
+        self._advance(self._span("fit.epoch",
+                                 {"epoch": epoch, "steps": steps}))
+
+    def input_wait(self):
+        """`fit.input_wait`: the loop blocked on the prefetch queue. Its
+        close is the one observation of `training_input_wait_ms`."""
+        return self._tracer.phase(
+            "fit.input_wait", self._telemetry.input_wait_ms,
+            trace_id=self.trace_id, cat="training")
 
 
 # ---------------------------------------------------------------------------
@@ -478,21 +561,23 @@ class _Prefetcher:
     Stall accounting (ISSUE 15): every consumer `__next__` times how
     long it sat blocked on the queue — that wait IS the device's input
     stall (the step can't dispatch until the batch exists). `wait_s`
-    accumulates the epoch total; `on_wait(seconds)` fires per get for
-    the per-step histogram. An always-full queue reads ~0: the host
-    pipeline is keeping up."""
+    accumulates the epoch total; `wait_span()` gives the scoped span
+    put around each get (the fit's `fit.input_wait`, whose close is the
+    per-step histogram's observation). An always-full queue reads ~0:
+    the host pipeline is keeping up."""
 
     _END = object()
 
     def __init__(self, source_iter, transfer, depth: int = 2,
-                 on_wait=None):
+                 wait_span=None):
         import queue
         import threading
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         self._err = None
         self._stop = False
         self._queue_mod = queue
-        self._on_wait = on_wait
+        self._wait_span = wait_span or functools.partial(
+            get_tracer().span, "fit.input_wait", cat="training")
         self.wait_s = 0.0
 
         def worker():
@@ -526,15 +611,10 @@ class _Prefetcher:
         return self
 
     def __next__(self):
-        t0 = time.perf_counter()
-        item = self._q.get()
-        waited = time.perf_counter() - t0
-        self.wait_s += waited
-        if self._on_wait is not None:
-            try:
-                self._on_wait(waited)
-            except Exception:  # noqa: BLE001 — telemetry only
-                pass
+        span = self._wait_span()
+        with span:
+            item = self._q.get()
+        self.wait_s += span.duration
         if item is self._END:
             if self._err is not None:
                 raise self._err
@@ -768,20 +848,26 @@ def _make_one_step(apply_fn, loss_fn, optimizer, apply_and_state_fn,
                     lambda a: a.astype(jnp.float32), pred)
             return loss_fn(yb, pred), state_upd
 
-        (loss, state_upd), grads = jax.value_and_grad(
-            compute_loss, has_aux=True)(params)
-        if mixed_precision:
-            grads = _cast_tree(grads, jnp.float32, only=jnp.bfloat16)
-            # stateful updates (BatchNorm moving stats) were computed from
-            # the bf16-cast params — cast back so the f32 master tree never
-            # picks up bf16 leaves (dtype drift + donation mismatch)
-            state_upd = _cast_tree(state_upd, jnp.float32,
-                                   only=jnp.bfloat16)
-        if fused_apply is not None:
-            params, opt_state = fused_apply(grads, opt_state, params)
-        else:
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+        # the scopes land in every operation's `op_name`, which xprof
+        # groups device time by
+        with jax.named_scope("fit_step/forward_backward"):
+            (loss, state_upd), grads = jax.value_and_grad(
+                compute_loss, has_aux=True)(params)
+            if mixed_precision:
+                grads = _cast_tree(grads, jnp.float32, only=jnp.bfloat16)
+                # stateful updates (BatchNorm moving stats) were computed
+                # from the bf16-cast params — cast back so the f32 master
+                # tree never picks up bf16 leaves (dtype drift + donation
+                # mismatch)
+                state_upd = _cast_tree(state_upd, jnp.float32,
+                                       only=jnp.bfloat16)
+        with jax.named_scope("fit_step/optimizer_update"):
+            if fused_apply is not None:
+                params, opt_state = fused_apply(grads, opt_state, params)
+            else:
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = optax.apply_updates(params, updates)
         params = _merge_state(params, state_upd)
         return params, opt_state, loss
 
@@ -877,15 +963,17 @@ def build_device_epoch_run(apply_fn: Callable, loss_fn: Callable,
     def epoch_run(params, opt_state, x, y, rng):
         n = _tree_len(x)
         shuffle_rng, step_rng0 = jax.random.split(rng)
-        idx = (jax.random.permutation(shuffle_rng, n) if shuffle
-               else jnp.arange(n))[:steps * batch].reshape(steps, batch)
+        with jax.named_scope("fit_epoch/shuffle"):
+            idx = (jax.random.permutation(shuffle_rng, n) if shuffle
+                   else jnp.arange(n))[:steps * batch].reshape(steps, batch)
 
         def body(carry, ids):
             params, opt_state, rng = carry
             rng, sub = jax.random.split(rng)
-            xb = jax.tree_util.tree_map(lambda a: a[ids], x)
-            yb = (jax.tree_util.tree_map(lambda a: a[ids], y)
-                  if y is not None else None)
+            with jax.named_scope("fit_epoch/gather_batch"):
+                xb = jax.tree_util.tree_map(lambda a: a[ids], x)
+                yb = (jax.tree_util.tree_map(lambda a: a[ids], y)
+                      if y is not None else None)
             params, opt_state, loss = one_step(params, opt_state, xb, yb,
                                                sub)
             return (params, opt_state, rng), loss
@@ -953,13 +1041,18 @@ def _data_fingerprint(tree) -> tuple:
     return tuple(parts)
 
 
-def _device_cached_data(model, x, y, mesh):
+def _device_cached_data(model, x, y, mesh, trace):
     """device_put once per distinct (x, y) CONTENT; cached on the model
     so repeated fit calls (warm restarts, bench epochs) skip the
-    transfer. Strong refs to the host arrays keep the key's ids valid."""
+    transfer. Strong refs to the host arrays keep the key's ids valid.
+    The transfer, or finding that there is none to make, is the fit's
+    `fit.place_data` phase."""
     key = _data_fingerprint((x, y))
     cached = getattr(model, "_device_data", None)
-    if cached is not None and cached[0] == key:
+    hit = cached is not None and cached[0] == key
+    trace.enter("place_data", hit=hit, bytes=sum(
+        np.asarray(a).nbytes for a in jax.tree_util.tree_leaves((x, y))))
+    if hit:
         return cached[1], cached[2]
     x_dev = _put_batch(x, mesh)
     y_dev = _put_batch(y, mesh) if y is not None else None
@@ -1115,6 +1208,12 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
     failed step N times before writing an emergency checkpoint and
     raising; `step_timeout_s` additionally runs each step under a
     watchdog thread so a hung dispatch surfaces as TimeoutError.
+    Every call records itself as a span tree in the process-wide tracer
+    (`_FitTrace`: `fit` > `fit.prepare` .. `fit.epoch` > `fit.dispatch`,
+    `fit.loss_sync` ..; docs/ProgrammingGuide/observability.md "Training
+    spans") and observes each leaf in `training_fit_phase_ms`; any
+    profiler capture that runs meanwhile holds the same spans on the
+    device's clock.
     After fit, `model.params` holds DEVICE arrays (no gratuitous
     device→host pull; save/checkpoint paths transfer on demand)."""
     if flat_optimizer:
@@ -1211,8 +1310,10 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
                 "shard and pass arrays instead")
         local_batch = batch_size // n_proc
 
+    steps_per_epoch = None      # a streaming factory does not say
     if batch_iter_factory is None:
         n = _tree_len(x)
+        steps_per_epoch = n // local_batch
         if n_proc > 1:
             # unequal shards would desync the per-step collectives and
             # deadlock mid-epoch; gather counts BEFORE any local raise
@@ -1251,642 +1352,678 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
                 "datasets (TFRecord/FeatureSet/batch_iter_factory) have "
                 "no host copy to pin in HBM")
 
-    rng = jax.random.PRNGKey(seed)
-    rng, init_rng = jax.random.split(rng)
-    if model.params is None:
-        # shape probe — skipped when already built (streaming datasets
-        # prebuild from a cheap first_sample instead of paying a full
-        # shuffle-buffer fill here)
-        try:
-            sample = next(iter(batch_iter_factory(0)))[0]
-        except StopIteration:
-            raise ValueError(
-                "Dataset produced no full batches; lower batch_size")
-        model.ensure_built(sample, init_rng)
-
-    optimizer = model.optimizer
-    if optimizer is None:
-        raise RuntimeError("Model must be compiled before fit "
-                           "(`Topology.scala:139` contract)")
-
-    # -- auto-resume (ISSUE 5): continue from the newest intact
-    # epoch-boundary checkpoint instead of step 0 -------------------------
-    start_epoch = 0
-    iteration = 0
-    resume_opt_tree = None
-    resume_meta = None
-    if auto_resume:
-        if not model._checkpoint_path:
-            raise ValueError(
-                "auto_resume=True needs a checkpoint directory; call "
-                "model.set_checkpoint(path) first")
-        from analytics_zoo_tpu.learn.checkpoint import (
-            find_resume_checkpoint, load_checkpoint, remap_param_subtrees)
-        found = find_resume_checkpoint(model._checkpoint_path)
-        if found is not None:
-            run_dir, version, _ = found
-            # verify=False: find_resume_checkpoint CRC-verified exactly
-            # this version moments ago — no second full-file pass
-            r_params, resume_opt_tree, resume_meta = load_checkpoint(
-                run_dir, version, verify=False)
-            # a fresh process's auto-generated layer names differ from
-            # the checkpointing process's — remap onto this instance
-            remap = getattr(model, "_remap_loaded", None)
-            if remap is not None:
-                model.params = remap(r_params)
-                if resume_opt_tree is not None:
-                    # the moments are keyed by the same saved names
-                    resume_opt_tree = remap_param_subtrees(
-                        resume_opt_tree, set(r_params), remap)
-            else:
-                model.params = r_params
-            start_epoch = int(resume_meta.get("epoch", 0))
-            iteration = int(resume_meta.get("iteration", version))
-            if "rng" in resume_meta:
-                # the checkpointed key IS the key the uninterrupted run
-                # held at this boundary — restoring it (plus the
-                # seed+epoch shuffle order) is what makes continuation
-                # bitwise-identical
-                rng = jnp.asarray(
-                    np.asarray(resume_meta["rng"], dtype=np.uint32))
-            else:
-                log.warning(
-                    "auto-resume: checkpoint has no RNG state (pre-"
-                    "ISSUE-5 layout); continuing with a fresh key — "
-                    "losses will diverge from the uninterrupted run")
-            log.info(
-                "auto-resume: continuing from %s/model.%d "
-                "(epoch %d, iteration %d)",
-                run_dir, version, start_epoch, iteration)
-
-    param_shardings = step_shardings = None
-    if shard_rules is not None:
-        from analytics_zoo_tpu.parallel.sharding import (
-            check_fsdp_divisibility, tree_shardings)
-        # fail at config time, not at OOM time: a large param that
-        # can't shard over fsdp would silently replicate everywhere
-        check_fsdp_divisibility(model.params, mesh, shard_rules)
-        param_shardings = tree_shardings(model.params, mesh, shard_rules)
-        # host params (fresh build or checkpoint restore) land DIRECTLY
-        # on the rule layout — the resume path never materializes a
-        # replicated copy
-        params = _put_with_shardings(model.params, param_shardings)
-    else:
-        params = _put_replicated(model.params, mesh)
-    lazy_specs = None
-    if lazy_embeddings:
-        from analytics_zoo_tpu.learn.lazy_embedding import resolve_specs
-        lazy_specs = resolve_specs(model)
-    # -- fused-kernel optimizer (ISSUE 9): one HBM pass per leaf ----------
-    fused = fused_optimizer
-    if fused is None:
-        fused = bool(getattr(getattr(ctx, "config", None),
-                             "fused_optimizer", False)) \
-            or os.environ.get("ZOO_FUSED_OPT", "0") == "1"
-    fused = bool(fused)
-    if fused:
-        from analytics_zoo_tpu.ops.optimizers import as_fused
-        # the twin memoizes on the model: a fresh transformation per
-        # fit would change id(optimizer) in the step cache key and
-        # re-jit every warm restart
-        spec = getattr(model, "_optimizer_spec", None)
-        tkey = (id(optimizer), str(spec))
-        twin = getattr(model, "_fused_twin_cache", None)
-        if twin is not None and twin[0] == tkey:
-            fused_opt, warn = twin[1], False
-        else:
-            fused_opt, warn = as_fused(optimizer, spec), True
-            model._fused_twin_cache = (tkey, fused_opt)
-        if fused_opt is not None:
-            optimizer = fused_opt
-        elif lazy_specs:
-            # the declared tables still take the sparse fused path;
-            # only the rest-of-model sweep stays plain optax. One
-            # WARNING per model (the no-twin result is cached): a
-            # fleet-wide ZOO_FUSED_OPT=1 retrain loop must not log
-            # per fit
-            if warn:
-                log.warning(
-                    "fused_optimizer: compiled optimizer %r has no "
-                    "exact fused twin; embedding tables take the "
-                    "fused segment path, the rest stays on plain "
-                    "optax", spec)
-        else:
-            if warn:
-                log.warning(
-                    "fused_optimizer requested but the compiled "
-                    "optimizer (%r) has no exact fused twin (only "
-                    "default-hyperparameter adam/adamw specs map); "
-                    "keeping the plain optax path", spec)
-            fused = False
-
-    # the layout marker auto-resume uses to refuse a structurally
-    # mismatched restore: a fused fit's state tree (FusedAdamState /
-    # fused rest) differs from the stock optax chain's
-    opt_layout = "fused" if getattr(optimizer, "fused_apply", None) \
-        is not None else "tree"
-    opt_shardings = None
-    if lazy_specs:
-        from analytics_zoo_tpu.learn.lazy_embedding import init_state
-        opt_state = _put_replicated(
-            init_state(params, lazy_specs, optimizer), mesh)
-    elif shard_rules is not None:
-        # eager init on sharded params: elementwise leaves (Adam moments)
-        # inherit their param's sharding; the explicit re-put mirrors the
-        # rule table onto EVERY leaf (step counters and any moment the
-        # propagation missed land replicated / rule-sharded exactly) —
-        # the match_partition_rules pattern: one table resolves params
-        # and optimizer state
-        opt_state = optimizer.init(params)
-        from analytics_zoo_tpu.parallel.sharding import tree_shardings
-        opt_shardings = tree_shardings(opt_state, mesh, shard_rules)
-        opt_state = _put_with_shardings(opt_state, opt_shardings)
-        step_shardings = _step_shardings(mesh, param_shardings,
-                                         opt_shardings)
-    else:
-        opt_state = _put_replicated(optimizer.init(params), mesh)
-    if resume_opt_tree is not None:
-        from analytics_zoo_tpu.learn.checkpoint import restore_opt_state
-        saved_layout = (resume_meta or {}).get("opt_state_layout", "tree")
-        if saved_layout != opt_layout:
-            raise ValueError(
-                f"auto_resume: checkpoint optimizer state is "
-                f"{saved_layout!r} but this fit would build "
-                f"{opt_layout!r} (fused_optimizer toggled between "
-                "runs?); re-run with the original setting")
-        restored = restore_opt_state(jax.device_get(opt_state),
-                                     resume_opt_tree)
-        # sharded resume: saved host leaves re-shard DIRECTLY onto the
-        # rule-derived layout (no replicate-then-reshard hop)
-        opt_state = _put_with_shardings(restored, opt_shardings) \
-            if opt_shardings is not None else _put_replicated(restored,
-                                                              mesh)
-
-    # Cache the jitted step on the model: repeated fit calls (warm restarts,
-    # per-round loops) must hit the compile cache, not rebuild a fresh
-    # closure every call.
-    multi = steps_per_run > 1
-    dc_steps = (_tree_len(x) // local_batch) if use_device_cache else 0
-    cc_dir = compile_cache_dir if compile_cache_dir is not None \
-        else os.environ.get("ZOO_COMPILE_CACHE_DIR") or None
-    # sharding descriptor: mesh axis extents + the rule table's content
-    # hash. Part of BOTH the in-process step memo key and the on-disk
-    # AOT key — a replicated fit and an fsdp-sharded fit (or two
-    # different rule tables / mesh factorizations) are different
-    # programs and must never share an executable. Stable across
-    # processes (no id()), so a sharded re-fit in a fresh process still
-    # hits its own entries.
-    shard_desc = ""
-    if shard_rules is not None:
-        from analytics_zoo_tpu.parallel.sharding import sharding_descriptor
-        shard_desc = sharding_descriptor(mesh, shard_rules)
-    if use_device_cache:
-        cache_key = (id(optimizer), id(model.loss), "devcache",
-                     mixed_precision, lazy_embeddings, dc_steps,
-                     local_batch, shuffle, fused, cc_dir,
-                     shard_desc)
-    else:
-        cache_key = (id(optimizer), id(model.loss), multi,
-                     mixed_precision, lazy_embeddings, fused, cc_dir,
-                     shard_desc)
-    cached = getattr(model, "_train_cache", None)
-    if cached is not None and cached[0] == cache_key:
-        train_step = cached[1]
-    else:
-        if use_device_cache:
-            builder = lambda *a, **kw: build_device_epoch_run(  # noqa: E731
-                *a, steps=dc_steps, batch=local_batch, shuffle=shuffle,
-                **kw)
-        else:
-            builder = build_train_run if multi else build_train_step
-        train_step = builder(
-            model.apply, model.loss, optimizer,
-            apply_and_state_fn=getattr(model, "apply_and_state", None),
-            mixed_precision=mixed_precision, lazy_specs=lazy_specs,
-            fused=fused, shardings=step_shardings)
-        if cc_dir:
-            # persistent compilation cache: AOT-serialize the step/run
-            # executable per input signature — a re-run in a fresh
-            # process loads its program from disk instead of
-            # re-compiling (jax's own persistent cache, enabled by
-            # init_zoo_context, is the layer under it)
-            from analytics_zoo_tpu.compile_cache import (
-                AOTFunctionCache, fingerprint, get_cache)
-            # every program discriminator the in-memory cache_key
-            # carries must reach the DISK key too: a single-step
-            # executable and a multi-step run with coinciding arg
-            # shapes are different programs (3- vs 4-tuple outputs).
-            # steps_per_run itself stays OUT: the run program scans
-            # the leading axis, so k only lives in the arg shapes and
-            # a tail group may legitimately hit another run's entry.
-            # `fused` is an explicit key component (ISSUE 9): the fused
-            # and plain programs share every arg shape, so WITHOUT it a
-            # toggle could load the other mode's stale executable
-            step_fp = fingerprint(
-                [model, model.loss, optimizer.update, mixed_precision,
-                 lazy_embeddings, multi, bool(use_device_cache), dc_steps,
-                 shuffle if use_device_cache else None,
-                 fused, shard_desc])
-            train_step = AOTFunctionCache(train_step, get_cache(cc_dir),
-                                          step_fp, sharding=shard_desc)
-        model._train_cache = (cache_key, train_step)
-    x_dev = y_dev = None
-    if use_device_cache:
-        x_dev, y_dev = _device_cached_data(model, x, y, mesh)
-
-    ckpt_mgr = None
-    if model._checkpoint_path:
-        from analytics_zoo_tpu.learn.checkpoint import (CheckpointManager,
-                                                        gather_tree)
-        ckpt_mgr = CheckpointManager(model._checkpoint_path)
-        if checkpoint_trigger is None:
-            checkpoint_trigger = tg.EveryEpoch()
-
-    writer = None
-    if model._tensorboard_dir:
-        from analytics_zoo_tpu.utils.tensorboard import SummaryWriter
-        writer = SummaryWriter(model._tensorboard_dir + "/train")
-
     telemetry = _TrainingMetrics()
-    telemetry.mesh_axes(mesh if shard_rules is not None else None)
-    reporter = None
-    if metrics_report_s:
-        from analytics_zoo_tpu.observability.reporter import MetricsReporter
-        reporter = MetricsReporter(interval_s=metrics_report_s,
-                                   writer=writer).start()
-
-    if resume_meta is not None:
-        telemetry.resumes.inc()
-
-    # cost-analysis roofline (ISSUE 6): XLA-counted FLOPs/bytes per step
-    # signature, accounted per epoch — the MFU/HBM gauges without a
-    # hand-supplied flops_per_step
-    cost_tracker = None
-    if os.environ.get("ZOO_ROOFLINE", "1") != "0":
-        memo_root = getattr(model, "_roofline_cost_memo", None)
-        if memo_root is None:
-            memo_root = model._roofline_cost_memo = {}
-        # sub-dict per train-step program, under the SAME cache_key the
-        # step cache memoizes on: two fits that share an executable
-        # share harvested costs, two that don't cannot alias
-        step_memo = memo_root.setdefault(cache_key, {})
-        cost_tracker = _StepCostTracker(train_step, step_memo)
-        try:
-            from analytics_zoo_tpu.observability.roofline import \
-                get_accountant
-            get_accountant().reset("train")
-        except Exception:  # noqa: BLE001 — telemetry only
-            cost_tracker = None
-        if cost_tracker is not None and fused:
-            # Pallas regions are invisible to HLO cost analysis — patch
-            # the tracker with the analytic kernel model so the MFU/HBM
-            # gauges stay honest (memoized beside the sig-keyed costs;
-            # string key cannot collide with signature tuples)
-            if "__fused_correction__" not in step_memo:
-                step_memo["__fused_correction__"] = \
-                    _fused_kernel_correction(optimizer, lazy_specs, params,
-                                             opt_state, step_shardings,
-                                             local_batch)
-            cost_tracker.correction = step_memo["__fused_correction__"]
-
-    if fused and not lazy_specs \
-            and getattr(optimizer, "fused_apply", None) is not None:
-        # one measured fused sweep, compile excluded: the direct A/B
-        # lever benches read against the unfused update's share of step
-        # time. Observed only when the probe is built (once per
-        # model/cache_key, NOT per fit): a warm re-fit re-timing it
-        # would add two full sweeps of HBM traffic inside the very
-        # bench loops the histogram exists to explain
-        try:
-            sw_cached = getattr(model, "_fused_sweep_cache", None)
-            if sw_cached is None or sw_cached[0] != cache_key:
-                # under a sharded fit the probe must time the SAME
-                # shard_mapped sweep the step runs — a bare jit would
-                # replicate the full params/moments on every device
-                # (the memory blow-up the sharded fit exists to avoid)
-                fa = optimizer.fused_apply
-                if step_shardings is not None:
-                    fa = _shard_mapped_fused(fa, step_shardings)
-                sweep = jax.jit(fa)
-                model._fused_sweep_cache = (cache_key, sweep)
-                zg = jax.tree_util.tree_map(jnp.zeros_like, params)
-                jax.block_until_ready(sweep(zg, opt_state, params))
-                t_sw = time.time()
-                jax.block_until_ready(sweep(zg, opt_state, params))
-                telemetry.fused_update_ms.observe(
-                    (time.time() - t_sw) * 1e3)
-        except Exception as e:  # noqa: BLE001 — telemetry only
-            log.debug("fused sweep timing skipped: %s: %s",
-                      type(e).__name__, e)
-
-    # on-demand profiler window (ISSUE 6): capture iterations
-    # [start, stop) into a bounded, rotated artifact dir
-    profiler = None
-    profile_state = {"active": False, "done": False}
-    if profile_steps is not None:
-        p_start, p_stop = (int(profile_steps[0]), int(profile_steps[1]))
-        if not (0 <= p_start < p_stop):
-            raise ValueError(
-                f"profile_steps={profile_steps!r} must be (start, stop) "
-                "with 0 <= start < stop")
-        from analytics_zoo_tpu.observability.capture import ProfileCapture
-        profiler = ProfileCapture(
-            profile_dir or os.environ.get("ZOO_PROFILE_DIR")
-            or "zoo_profiles")
-
-    def _profile_tick(it: int):
-        """Crossing-edge profiler control: start when the iteration
-        counter reaches `start`, stop once it reaches `stop` (multi-step
-        runs cross in jumps of k — the window rounds up to run
-        boundaries, same granularity trade as every trigger)."""
-        if profiler is None or profile_state["done"]:
-            return
-        try:
-            if not profile_state["active"] and it >= p_start:
-                profiler.start(tag=f"fit-it{it}")
-                profile_state["active"] = True
-            elif profile_state["active"] and it >= p_stop:
-                manifest = profiler.stop()
-                profile_state["active"] = False
-                profile_state["done"] = True
-                history.setdefault("profile_artifacts", []).append(
-                    manifest["dir"])
-                log.info("profiler capture written to %s (%d files)",
-                         manifest["dir"], len(manifest["files"]))
-        except Exception as e:  # noqa: BLE001 — profiling must never
-            # take down the fit it watches
-            log.warning("profiler capture failed: %s: %s",
-                        type(e).__name__, e)
-            profile_state["done"] = True
-
-    def _call_step(*step_args):
-        """Every branch's train_step dispatch funnels through the step
-        watchdog (retries + optional timeout); with step_retries=0 and
-        no timeout this is a plain call. Roofline cost harvest and the
-        profiler edge-check run first — both need the pre-dispatch
-        (donation-alive) view."""
-        if cost_tracker is not None:
-            cost_tracker.before(step_args)
-        _profile_tick(iteration)
-        out = _step_with_watchdog(train_step, step_args, step_retries,
-                                  step_timeout_s, telemetry.step_retries,
-                                  iteration)
-        if cost_tracker is not None:
-            # post-call: a just-built AOT executable answers
-            # cost_analysis directly; only the plain-jit path lowers
-            cost_tracker.after()
-        return out
-
-    def _ckpt_extra(ep: int, finished: bool) -> Dict[str, Any]:
-        """Checkpoint sidecar: everything auto-resume needs for bitwise
-        continuation — epoch/iteration cursors, the live RNG key, and
-        the opt-state layout marker."""
-        return {"epoch": ep, "iteration": iteration,
-                "epoch_finished": finished,
-                "rng": np.asarray(jax.device_get(rng)).ravel().tolist(),
-                "opt_state_layout": opt_layout}
-
-    def _ckpt_save(extra: Dict[str, Any]) -> None:
-        """ONE checkpoint-commit funnel for every save site (mid-epoch
-        trigger, epoch boundary, emergency): gather the sharded state to
-        host exactly once, commit the checkpoint set, and — with
-        `int8_sidecar` — run the post-training quantization pass on the
-        SAME gathered params so the sidecar always matches the version
-        it sits beside. Sidecar failure is one warning, never a failed
-        fit (serving falls back to quantize-at-load).
-
-        Publication (ISSUE 14) is the LAST act: the publish marker —
-        what the fleet's rollout watcher keys on — commits only once
-        params, opt_state AND the sidecar are all durable. A kill
-        anywhere before the marker rename leaves the version resumable
-        but UNPUBLISHED; a sidecar failure skips the marker too (the
-        version the fleet would quantize-at-load is not the version
-        the trainer meant to publish)."""
-        host_params = gather_tree(params)
-        ckpt_mgr.save(iteration, host_params, gather_tree(opt_state),
-                      extra=extra)
-        publishable = True
-        if int8_sidecar:
+    with _FitTrace(
+            telemetry, epochs=epochs, steps_per_epoch=steps_per_epoch,
+            batch=batch_size, devices=mesh.n_devices if mesh else 1,
+            path="device_epoch" if use_device_cache else
+            "multi_step" if steps_per_run > 1 else "single_step") as trace:
+        trace.enter("prepare")
+        rng = jax.random.PRNGKey(seed)
+        rng, init_rng = jax.random.split(rng)
+        if model.params is None:
+            # shape probe — skipped when already built (streaming datasets
+            # prebuild from a cheap first_sample instead of paying a full
+            # shuffle-buffer fill here)
             try:
-                from analytics_zoo_tpu.serving.quantization import \
-                    write_int8_sidecar
-                write_int8_sidecar(ckpt_mgr.run_dir, iteration, model,
-                                   params=host_params)
-            except Exception as e:  # noqa: BLE001 — sidecar is optional
-                publishable = False
-                log.warning("int8 sidecar write failed at iteration %d "
-                            "(%s: %s); serving will quantize at load "
-                            "and the version stays unpublished",
-                            iteration, type(e).__name__, e)
-        if publishable:
-            try:
-                from analytics_zoo_tpu.learn.checkpoint import \
-                    write_publish_marker
-                write_publish_marker(ckpt_mgr.run_dir, iteration,
-                                     extra=extra)
-            except Exception as e:  # noqa: BLE001 — resume still works
-                log.warning("publish marker failed at iteration %d "
-                            "(%s: %s); the version resumes but will "
-                            "not roll out", iteration,
-                            type(e).__name__, e)
+                sample = next(iter(batch_iter_factory(0)))[0]
+            except StopIteration:
+                raise ValueError(
+                    "Dataset produced no full batches; lower batch_size")
+            model.ensure_built(sample, init_rng)
 
-    history: Dict[str, List[float]] = {"loss": []}
-    batches = None
-    epoch = start_epoch
-    try:
-        for epoch in range(start_epoch, epochs):
-          it0 = iteration
-          losses_dev: List[Any] = []   # device scalars/vectors; sync at end
-          t0 = time.time()
-          n_seen = 0
+        optimizer = model.optimizer
+        if optimizer is None:
+            raise RuntimeError("Model must be compiled before fit "
+                               "(`Topology.scala:139` contract)")
 
-          if use_device_cache:
-              # whole epoch in ONE dispatch over device-resident data:
-              # zero per-step host transfer. Mid-epoch (iteration) trigger
-              # checks collapse to the epoch boundary — the same
-              # granularity trade as steps_per_run=steps.
-              batches = None
-              rng, erng = jax.random.split(rng)
-              params, opt_state, ep_losses = _call_step(
-                  params, opt_state, x_dev, y_dev, erng)
-              losses_dev.append(ep_losses)
-              iteration += dc_steps
-              n_seen = dc_steps * local_batch
-          else:
-            if multi:
-                def transfer(group):
-                    return _stack_group(group, mesh)
-                source = _chunk_batches(batch_iter_factory(epoch),
-                                        steps_per_run)
-            else:
-                def transfer(item):
-                    xb, yb, real = item
-                    return (_put_batch(xb, mesh),
-                            _put_batch(yb, mesh) if yb is not None
-                            else None,
-                            real, 1)
-                source = batch_iter_factory(epoch)
-            batches = _Prefetcher(
-                source, transfer, depth=depth,
-                on_wait=lambda w: telemetry.input_wait_ms.observe(
-                    w * 1e3)) if prefetch else map(transfer, source)
-
-            for xb, yb, real, k in batches:
-                if multi:
-                    rng, run_rng = jax.random.split(rng)
-                    params, opt_state, _, loss = _call_step(
-                        params, opt_state, xb, yb, run_rng)
+        # -- auto-resume (ISSUE 5): continue from the newest intact
+        # epoch-boundary checkpoint instead of step 0 -------------------------
+        start_epoch = 0
+        iteration = 0
+        resume_opt_tree = None
+        resume_meta = None
+        if auto_resume:
+            if not model._checkpoint_path:
+                raise ValueError(
+                    "auto_resume=True needs a checkpoint directory; call "
+                    "model.set_checkpoint(path) first")
+            from analytics_zoo_tpu.learn.checkpoint import (
+                find_resume_checkpoint, load_checkpoint, remap_param_subtrees)
+            found = find_resume_checkpoint(model._checkpoint_path)
+            if found is not None:
+                run_dir, version, _ = found
+                # verify=False: find_resume_checkpoint CRC-verified exactly
+                # this version moments ago — no second full-file pass
+                r_params, resume_opt_tree, resume_meta = load_checkpoint(
+                    run_dir, version, verify=False)
+                # a fresh process's auto-generated layer names differ from
+                # the checkpointing process's — remap onto this instance
+                remap = getattr(model, "_remap_loaded", None)
+                if remap is not None:
+                    model.params = remap(r_params)
+                    if resume_opt_tree is not None:
+                        # the moments are keyed by the same saved names
+                        resume_opt_tree = remap_param_subtrees(
+                            resume_opt_tree, set(r_params), remap)
                 else:
-                    rng, step_rng = jax.random.split(rng)
-                    params, opt_state, loss = _call_step(params, opt_state,
-                                                         xb, yb, step_rng)
-                iteration += k
-                n_seen += real * n_proc       # local count × processes
-                losses_dev.append(loss)
-                # loss stays a device scalar: triggers that read .loss
-                # (Min/MaxLoss) force their own sync; counter triggers
-                # stay async
-                last_loss = loss[-1] if multi else loss
-                if checkpoint_trigger and ckpt_mgr and checkpoint_trigger(
-                        tg.TriggerState(epoch=epoch, iteration=iteration,
-                                        loss=last_loss)):
-                    # the meta sidecar records the opt-state layout
-                    # (plus the resume cursors/RNG), so a future
-                    # restore can't silently structurally mismatch a
-                    # fused fit's state against a plain one.
-                    # gather_tree, not bare device_get: correct (and
-                    # actionably failing cross-host) for sharded leaves
-                    _ckpt_save(_ckpt_extra(epoch, False))
-                if end_trigger and end_trigger(
-                        tg.TriggerState(epoch=epoch, iteration=iteration,
-                                        loss=last_loss)):
-                    break
-            if isinstance(batches, _Prefetcher):
-                batches.close()  # early break leaves the worker mid-queue
-          # ONE host sync per epoch: materialize every step loss together.
-          # This blocks until the last step's program has finished, so dt
-          # measures device compute, not dispatch.
-          if epoch == 0 and not losses_dev:
-              # prebuilt models skip the shape probe, so an empty/too-small
-              # dataset must still fail loudly rather than "train" 0 steps
-              raise ValueError(
-                  "Dataset produced no full batches; lower batch_size")
-          step_losses = np.concatenate(
-              [np.atleast_1d(v) for v in _materialize(losses_dev)]) \
-              if losses_dev else np.zeros((0,))
-          dt = time.time() - t0
-          mean_loss = float(step_losses.mean()) if len(step_losses) else 0.0
-          history["loss"].append(mean_loss)
-          throughput = n_seen / max(dt, 1e-9)
-          step_ms = telemetry.epoch(iteration - it0, n_seen, dt, mean_loss,
-                                    flops_per_step=flops_per_step)
-          # device-wait vs host-wait verdict (ISSUE 15): the prefetch
-          # queue's measured blocked time over the epoch wall time is
-          # the fraction of the fit that was input-bound — a measured
-          # answer, not a guess. Also lands in the roofline snapshot's
-          # input-stall column.
-          input_wait_s = batches.wait_s \
-              if isinstance(batches, _Prefetcher) else 0.0
-          telemetry.input_bound.set(
-              min(1.0, input_wait_s / max(dt, 1e-9)))
-          if input_wait_s > 0:
-              try:
-                  from analytics_zoo_tpu.observability.roofline import \
-                      get_accountant
-                  get_accountant().account_stall("train", input_wait_s)
-              except Exception as ie:  # noqa: BLE001 — telemetry only
-                  log.debug("input-stall accounting failed: %s", ie)
-          if cost_tracker is not None and cost_tracker.calls:
-              # dt is device wall time (the _materialize above synced),
-              # so achieved = XLA-counted work / measured epoch seconds.
-              # The harvested cost is PER-STEP (cost analysis counts a
-              # scan body once — see _StepCostTracker), so scale the
-              # per-call mean by the iterations this epoch ran: exact
-              # for single-step, multi-step (steps_per_run) and
-              # device-cache epoch programs alike.
-              steps_done = max(iteration - it0, cost_tracker.calls)
-              scale = steps_done / cost_tracker.calls
-              telemetry.roofline(cost_tracker.flops * scale,
-                                 cost_tracker.bytes * scale, dt,
-                                 n_devices=cost_tracker.devices)
-              cost_tracker.reset_epoch()
-          if writer:
-              writer.scalar("Loss", mean_loss, iteration)
-              writer.scalar("Throughput", throughput, iteration)
-              writer.scalar("StepTime_ms", step_ms, iteration)
-          log.info("Epoch %d/%d  loss=%.4f  %.0f samples/s",
-                   epoch + 1, epochs, mean_loss, throughput)
+                    model.params = r_params
+                start_epoch = int(resume_meta.get("epoch", 0))
+                iteration = int(resume_meta.get("iteration", version))
+                if "rng" in resume_meta:
+                    # the checkpointed key IS the key the uninterrupted run
+                    # held at this boundary — restoring it (plus the
+                    # seed+epoch shuffle order) is what makes continuation
+                    # bitwise-identical
+                    rng = jnp.asarray(
+                        np.asarray(resume_meta["rng"], dtype=np.uint32))
+                else:
+                    log.warning(
+                        "auto-resume: checkpoint has no RNG state (pre-"
+                        "ISSUE-5 layout); continuing with a fresh key — "
+                        "losses will diverge from the uninterrupted run")
+                log.info(
+                    "auto-resume: continuing from %s/model.%d "
+                    "(epoch %d, iteration %d)",
+                    run_dir, version, start_epoch, iteration)
 
-          if validation_data is not None:
-              vx, vy = validation_data
-              model.params = params  # device-resident hand-off
-              val = evaluate_keras(model, vx, vy,
-                                   batch_per_thread=max(batch_size // dp, 1))
-              for k, v in val.items():
-                  history.setdefault("val_" + k, []).append(v)
-                  telemetry.val.set(v, name=k)
+        param_shardings = step_shardings = None
+        if shard_rules is not None:
+            from analytics_zoo_tpu.parallel.sharding import (
+                check_fsdp_divisibility, tree_shardings)
+            # fail at config time, not at OOM time: a large param that
+            # can't shard over fsdp would silently replicate everywhere
+            check_fsdp_divisibility(model.params, mesh, shard_rules)
+            param_shardings = tree_shardings(model.params, mesh, shard_rules)
+            # host params (fresh build or checkpoint restore) land DIRECTLY
+            # on the rule layout — the resume path never materializes a
+            # replicated copy
+            params = _put_with_shardings(model.params, param_shardings)
+        else:
+            params = _put_replicated(model.params, mesh)
+        lazy_specs = None
+        if lazy_embeddings:
+            from analytics_zoo_tpu.learn.lazy_embedding import resolve_specs
+            lazy_specs = resolve_specs(model)
+        # -- fused-kernel optimizer (ISSUE 9): one HBM pass per leaf ----------
+        fused = fused_optimizer
+        if fused is None:
+            fused = bool(getattr(getattr(ctx, "config", None),
+                                 "fused_optimizer", False)) \
+                or os.environ.get("ZOO_FUSED_OPT", "0") == "1"
+        fused = bool(fused)
+        if fused:
+            from analytics_zoo_tpu.ops.optimizers import as_fused
+            # the twin memoizes on the model: a fresh transformation per
+            # fit would change id(optimizer) in the step cache key and
+            # re-jit every warm restart
+            spec = getattr(model, "_optimizer_spec", None)
+            tkey = (id(optimizer), str(spec))
+            twin = getattr(model, "_fused_twin_cache", None)
+            if twin is not None and twin[0] == tkey:
+                fused_opt, warn = twin[1], False
+            else:
+                fused_opt, warn = as_fused(optimizer, spec), True
+                model._fused_twin_cache = (tkey, fused_opt)
+            if fused_opt is not None:
+                optimizer = fused_opt
+            elif lazy_specs:
+                # the declared tables still take the sparse fused path;
+                # only the rest-of-model sweep stays plain optax. One
+                # WARNING per model (the no-twin result is cached): a
+                # fleet-wide ZOO_FUSED_OPT=1 retrain loop must not log
+                # per fit
+                if warn:
+                    log.warning(
+                        "fused_optimizer: compiled optimizer %r has no "
+                        "exact fused twin; embedding tables take the "
+                        "fused segment path, the rest stays on plain "
+                        "optax", spec)
+            else:
+                if warn:
+                    log.warning(
+                        "fused_optimizer requested but the compiled "
+                        "optimizer (%r) has no exact fused twin (only "
+                        "default-hyperparameter adam/adamw specs map); "
+                        "keeping the plain optax path", spec)
+                fused = False
+
+        # the layout marker auto-resume uses to refuse a structurally
+        # mismatched restore: a fused fit's state tree (FusedAdamState /
+        # fused rest) differs from the stock optax chain's
+        opt_layout = "fused" if getattr(optimizer, "fused_apply", None) \
+            is not None else "tree"
+        trace.enter("optimizer_init")
+        opt_shardings = None
+        if lazy_specs:
+            from analytics_zoo_tpu.learn.lazy_embedding import init_state
+            opt_state = _put_replicated(
+                init_state(params, lazy_specs, optimizer), mesh)
+        elif shard_rules is not None:
+            # eager init on sharded params: elementwise leaves (Adam moments)
+            # inherit their param's sharding; the explicit re-put mirrors the
+            # rule table onto EVERY leaf (step counters and any moment the
+            # propagation missed land replicated / rule-sharded exactly) —
+            # the match_partition_rules pattern: one table resolves params
+            # and optimizer state
+            opt_state = optimizer.init(params)
+            from analytics_zoo_tpu.parallel.sharding import tree_shardings
+            opt_shardings = tree_shardings(opt_state, mesh, shard_rules)
+            opt_state = _put_with_shardings(opt_state, opt_shardings)
+            step_shardings = _step_shardings(mesh, param_shardings,
+                                             opt_shardings)
+        else:
+            opt_state = _put_replicated(optimizer.init(params), mesh)
+        if resume_opt_tree is not None:
+            from analytics_zoo_tpu.learn.checkpoint import restore_opt_state
+            saved_layout = (resume_meta or {}).get("opt_state_layout", "tree")
+            if saved_layout != opt_layout:
+                raise ValueError(
+                    f"auto_resume: checkpoint optimizer state is "
+                    f"{saved_layout!r} but this fit would build "
+                    f"{opt_layout!r} (fused_optimizer toggled between "
+                    "runs?); re-run with the original setting")
+            restored = restore_opt_state(jax.device_get(opt_state),
+                                         resume_opt_tree)
+            # sharded resume: saved host leaves re-shard DIRECTLY onto the
+            # rule-derived layout (no replicate-then-reshard hop)
+            opt_state = _put_with_shardings(restored, opt_shardings) \
+                if opt_shardings is not None else _put_replicated(restored,
+                                                                  mesh)
+
+        # Cache the jitted step on the model: repeated fit calls (warm
+        # restarts, per-round loops) must hit the compile cache, not
+        # rebuild a fresh closure every call.
+        trace.enter("build_step")
+        multi = steps_per_run > 1
+        dc_steps = (_tree_len(x) // local_batch) if use_device_cache else 0
+        cc_dir = compile_cache_dir if compile_cache_dir is not None \
+            else os.environ.get("ZOO_COMPILE_CACHE_DIR") or None
+        # sharding descriptor: mesh axis extents + the rule table's content
+        # hash. Part of BOTH the in-process step memo key and the on-disk
+        # AOT key — a replicated fit and an fsdp-sharded fit (or two
+        # different rule tables / mesh factorizations) are different
+        # programs and must never share an executable. Stable across
+        # processes (no id()), so a sharded re-fit in a fresh process still
+        # hits its own entries.
+        shard_desc = ""
+        if shard_rules is not None:
+            from analytics_zoo_tpu.parallel.sharding import sharding_descriptor
+            shard_desc = sharding_descriptor(mesh, shard_rules)
+        if use_device_cache:
+            cache_key = (id(optimizer), id(model.loss), "devcache",
+                         mixed_precision, lazy_embeddings, dc_steps,
+                         local_batch, shuffle, fused, cc_dir,
+                         shard_desc)
+        else:
+            cache_key = (id(optimizer), id(model.loss), multi,
+                         mixed_precision, lazy_embeddings, fused, cc_dir,
+                         shard_desc)
+        cached = getattr(model, "_train_cache", None)
+        if cached is not None and cached[0] == cache_key:
+            train_step = cached[1]
+        else:
+            if use_device_cache:
+                builder = functools.partial(
+                    build_device_epoch_run, steps=dc_steps,
+                    batch=local_batch, shuffle=shuffle)
+            else:
+                builder = build_train_run if multi else build_train_step
+            train_step = builder(
+                model.apply, model.loss, optimizer,
+                apply_and_state_fn=getattr(model, "apply_and_state", None),
+                mixed_precision=mixed_precision, lazy_specs=lazy_specs,
+                fused=fused, shardings=step_shardings)
+            if cc_dir:
+                # persistent compilation cache: AOT-serialize the step/run
+                # executable per input signature — a re-run in a fresh
+                # process loads its program from disk instead of
+                # re-compiling (jax's own persistent cache, enabled by
+                # init_zoo_context, is the layer under it)
+                from analytics_zoo_tpu.compile_cache import (
+                    AOTFunctionCache, fingerprint, get_cache)
+                # every program discriminator the in-memory cache_key
+                # carries must reach the DISK key too: a single-step
+                # executable and a multi-step run with coinciding arg
+                # shapes are different programs (3- vs 4-tuple outputs).
+                # steps_per_run itself stays OUT: the run program scans
+                # the leading axis, so k only lives in the arg shapes and
+                # a tail group may legitimately hit another run's entry.
+                # `fused` is an explicit key component (ISSUE 9): the fused
+                # and plain programs share every arg shape, so WITHOUT it a
+                # toggle could load the other mode's stale executable
+                step_fp = fingerprint(
+                    [model, model.loss, optimizer.update, mixed_precision,
+                     lazy_embeddings, multi, bool(use_device_cache), dc_steps,
+                     shuffle if use_device_cache else None,
+                     fused, shard_desc])
+                train_step = AOTFunctionCache(train_step, get_cache(cc_dir),
+                                              step_fp, sharding=shard_desc)
+            model._train_cache = (cache_key, train_step)
+        ckpt_mgr = None
+        if model._checkpoint_path:
+            from analytics_zoo_tpu.learn.checkpoint import (CheckpointManager,
+                                                            gather_tree)
+            ckpt_mgr = CheckpointManager(model._checkpoint_path)
+            if checkpoint_trigger is None:
+                checkpoint_trigger = tg.EveryEpoch()
+
+        writer = None
+        if model._tensorboard_dir:
+            from analytics_zoo_tpu.utils.tensorboard import SummaryWriter
+            writer = SummaryWriter(model._tensorboard_dir + "/train")
+
+        telemetry.mesh_axes(mesh if shard_rules is not None else None)
+        reporter = None
+        if metrics_report_s:
+            from analytics_zoo_tpu.observability.reporter import \
+                MetricsReporter
+            reporter = MetricsReporter(interval_s=metrics_report_s,
+                                       writer=writer).start()
+
+        if resume_meta is not None:
+            telemetry.resumes.inc()
+
+        # cost-analysis roofline (ISSUE 6): XLA-counted FLOPs/bytes per step
+        # signature, accounted per epoch — the MFU/HBM gauges without a
+        # hand-supplied flops_per_step
+        cost_tracker = None
+        if os.environ.get("ZOO_ROOFLINE", "1") != "0":
+            memo_root = getattr(model, "_roofline_cost_memo", None)
+            if memo_root is None:
+                memo_root = model._roofline_cost_memo = {}
+            # sub-dict per train-step program, under the SAME cache_key the
+            # step cache memoizes on: two fits that share an executable
+            # share harvested costs, two that don't cannot alias
+            step_memo = memo_root.setdefault(cache_key, {})
+            cost_tracker = _StepCostTracker(train_step, step_memo)
+            try:
+                from analytics_zoo_tpu.observability.roofline import \
+                    get_accountant
+                get_accountant().reset("train")
+            except Exception:  # noqa: BLE001 — telemetry only
+                cost_tracker = None
+            if cost_tracker is not None and fused:
+                # Pallas regions are invisible to HLO cost analysis — patch
+                # the tracker with the analytic kernel model so the MFU/HBM
+                # gauges stay honest (memoized beside the sig-keyed costs;
+                # string key cannot collide with signature tuples)
+                if "__fused_correction__" not in step_memo:
+                    step_memo["__fused_correction__"] = \
+                        _fused_kernel_correction(optimizer, lazy_specs, params,
+                                                 opt_state, step_shardings,
+                                                 local_batch)
+                cost_tracker.correction = step_memo["__fused_correction__"]
+
+        if fused and not lazy_specs \
+                and getattr(optimizer, "fused_apply", None) is not None:
+            # one measured fused sweep, compile excluded: the direct A/B
+            # lever benches read against the unfused update's share of step
+            # time. Observed only when the probe is built (once per
+            # model/cache_key, NOT per fit): a warm re-fit re-timing it
+            # would add two full sweeps of HBM traffic inside the very
+            # bench loops the histogram exists to explain
+            try:
+                sw_cached = getattr(model, "_fused_sweep_cache", None)
+                if sw_cached is None or sw_cached[0] != cache_key:
+                    # under a sharded fit the probe must time the SAME
+                    # shard_mapped sweep the step runs — a bare jit would
+                    # replicate the full params/moments on every device
+                    # (the memory blow-up the sharded fit exists to avoid)
+                    fa = optimizer.fused_apply
+                    if step_shardings is not None:
+                        fa = _shard_mapped_fused(fa, step_shardings)
+                    sweep = jax.jit(fa)
+                    model._fused_sweep_cache = (cache_key, sweep)
+                    zg = jax.tree_util.tree_map(jnp.zeros_like, params)
+                    jax.block_until_ready(sweep(zg, opt_state, params))
+                    t_sw = time.time()
+                    jax.block_until_ready(sweep(zg, opt_state, params))
+                    telemetry.fused_update_ms.observe(
+                        (time.time() - t_sw) * 1e3)
+            except Exception as e:  # noqa: BLE001 — telemetry only
+                log.debug("fused sweep timing skipped: %s: %s",
+                          type(e).__name__, e)
+
+        # on-demand profiler window (ISSUE 6): capture iterations
+        # [start, stop) into a bounded, rotated artifact dir
+        profiler = None
+        profile_state = {"active": False, "done": False}
+        if profile_steps is not None:
+            p_start, p_stop = (int(profile_steps[0]), int(profile_steps[1]))
+            if not (0 <= p_start < p_stop):
+                raise ValueError(
+                    f"profile_steps={profile_steps!r} must be (start, stop) "
+                    "with 0 <= start < stop")
+            from analytics_zoo_tpu.observability.capture import ProfileCapture
+            profiler = ProfileCapture(
+                profile_dir or os.environ.get("ZOO_PROFILE_DIR")
+                or "zoo_profiles")
+
+        def _profile_tick(it: int):
+            """Crossing-edge profiler control: start when the iteration
+            counter reaches `start`, stop once it reaches `stop` (multi-step
+            runs cross in jumps of k — the window rounds up to run
+            boundaries, same granularity trade as every trigger)."""
+            if profiler is None or profile_state["done"]:
+                return
+            try:
+                if not profile_state["active"] and it >= p_start:
+                    profiler.start(tag=f"fit-it{it}")
+                    profile_state["active"] = True
+                elif profile_state["active"] and it >= p_stop:
+                    manifest = profiler.stop()
+                    profile_state["active"] = False
+                    profile_state["done"] = True
+                    history.setdefault("profile_artifacts", []).append(
+                        manifest["dir"])
+                    log.info("profiler capture written to %s (%d files)",
+                             manifest["dir"], len(manifest["files"]))
+            except Exception as e:  # noqa: BLE001 — profiling must never
+                # take down the fit it watches
+                log.warning("profiler capture failed: %s: %s",
+                            type(e).__name__, e)
+                profile_state["done"] = True
+
+        def _call_step(steps, xb, yb):
+            """Every branch's train_step dispatch of `steps` steps funnels
+            through the step watchdog (retries + optional timeout); with
+            step_retries=0 and no timeout this is a plain call. Roofline
+            cost harvest and the profiler edge-check run first — both need
+            the pre-dispatch (donation-alive) view. All of it, with the
+            split of the call's key, is the `fit.dispatch` span: the
+            host's side of a step, which returns while the device still
+            runs."""
+            nonlocal rng
+            with trace.phase("dispatch", "epoch", steps=steps,
+                             iteration=iteration):
+                rng, step_rng = jax.random.split(rng)
+                step_args = (params, opt_state, xb, yb, step_rng)
+                if cost_tracker is not None:
+                    cost_tracker.before(step_args)
+                _profile_tick(iteration)
+                out = _step_with_watchdog(
+                    train_step, step_args, step_retries, step_timeout_s,
+                    telemetry.step_retries, iteration)
+                if cost_tracker is not None:
+                    # post-call: a just-built AOT executable answers
+                    # cost_analysis directly; only the plain-jit path lowers
+                    cost_tracker.after()
+            return out
+
+        def _ckpt_extra(ep: int, finished: bool) -> Dict[str, Any]:
+            """Checkpoint sidecar: everything auto-resume needs for bitwise
+            continuation — epoch/iteration cursors, the live RNG key, and
+            the opt-state layout marker."""
+            return {"epoch": ep, "iteration": iteration,
+                    "epoch_finished": finished,
+                    "rng": np.asarray(jax.device_get(rng)).ravel().tolist(),
+                    "opt_state_layout": opt_layout}
+
+        def _ckpt_save(extra: Dict[str, Any]) -> None:
+            with trace.phase("checkpoint", "epoch"):
+                _ckpt_commit(extra)
+
+        def _ckpt_commit(extra: Dict[str, Any]) -> None:
+            """ONE checkpoint-commit funnel for every save site (mid-epoch
+            trigger, epoch boundary, emergency): gather the sharded state to
+            host exactly once, commit the checkpoint set, and — with
+            `int8_sidecar` — run the post-training quantization pass on the
+            SAME gathered params so the sidecar always matches the version
+            it sits beside. Sidecar failure is one warning, never a failed
+            fit (serving falls back to quantize-at-load).
+
+            Publication (ISSUE 14) is the LAST act: the publish marker —
+            what the fleet's rollout watcher keys on — commits only once
+            params, opt_state AND the sidecar are all durable. A kill
+            anywhere before the marker rename leaves the version resumable
+            but UNPUBLISHED; a sidecar failure skips the marker too (the
+            version the fleet would quantize-at-load is not the version
+            the trainer meant to publish)."""
+            host_params = gather_tree(params)
+            ckpt_mgr.save(iteration, host_params, gather_tree(opt_state),
+                          extra=extra)
+            publishable = True
+            if int8_sidecar:
+                try:
+                    from analytics_zoo_tpu.serving.quantization import \
+                        write_int8_sidecar
+                    write_int8_sidecar(ckpt_mgr.run_dir, iteration, model,
+                                       params=host_params)
+                except Exception as e:  # noqa: BLE001 — sidecar is optional
+                    publishable = False
+                    log.warning("int8 sidecar write failed at iteration %d "
+                                "(%s: %s); serving will quantize at load "
+                                "and the version stays unpublished",
+                                iteration, type(e).__name__, e)
+            if publishable:
+                try:
+                    from analytics_zoo_tpu.learn.checkpoint import \
+                        write_publish_marker
+                    write_publish_marker(ckpt_mgr.run_dir, iteration,
+                                         extra=extra)
+                except Exception as e:  # noqa: BLE001 — resume still works
+                    log.warning("publish marker failed at iteration %d "
+                                "(%s: %s); the version resumes but will "
+                                "not roll out", iteration,
+                                type(e).__name__, e)
+
+        x_dev = y_dev = None
+        if use_device_cache:
+            x_dev, y_dev = _device_cached_data(model, x, y, mesh, trace)
+
+        history: Dict[str, List[float]] = {"loss": []}
+        batches = None
+        epoch = start_epoch
+        try:
+            for epoch in range(start_epoch, epochs):
+              trace.begin_epoch(epoch, steps_per_epoch)
+              it0 = iteration
+              losses_dev: List[Any] = []   # device values; sync at end
+              t0 = time.time()
+              n_seen = 0
+
+              if use_device_cache:
+                  # whole epoch in ONE dispatch over device-resident data:
+                  # zero per-step host transfer. Mid-epoch (iteration) trigger
+                  # checks collapse to the epoch boundary — the same
+                  # granularity trade as steps_per_run=steps.
+                  batches = None
+                  params, opt_state, ep_losses = _call_step(
+                      dc_steps, x_dev, y_dev)
+                  losses_dev.append(ep_losses)
+                  iteration += dc_steps
+                  n_seen = dc_steps * local_batch
+              else:
+                # `fit.transfer` runs in the prefetch thread beside the
+                # loop (scope "worker": no part of the call's wall time),
+                # or without prefetch in the loop itself
+                transfer_scope = "worker" if prefetch else "epoch"
+                if multi:
+                    def transfer(group):
+                        with trace.phase("transfer", transfer_scope,
+                                         steps=len(group)):
+                            return _stack_group(group, mesh)
+                    source = _chunk_batches(batch_iter_factory(epoch),
+                                            steps_per_run)
+                else:
+                    def transfer(item):
+                        xb, yb, real = item
+                        with trace.phase("transfer", transfer_scope,
+                                         steps=1):
+                            return (_put_batch(xb, mesh),
+                                    _put_batch(yb, mesh) if yb is not None
+                                    else None,
+                                    real, 1)
+                    source = batch_iter_factory(epoch)
+                batches = _Prefetcher(
+                    source, transfer, depth=depth,
+                    wait_span=trace.input_wait) if prefetch \
+                    else map(transfer, source)
+
+                for xb, yb, real, k in batches:
+                    if multi:
+                        params, opt_state, _, loss = _call_step(k, xb, yb)
+                    else:
+                        params, opt_state, loss = _call_step(k, xb, yb)
+                    iteration += k
+                    n_seen += real * n_proc       # local count × processes
+                    losses_dev.append(loss)
+                    if checkpoint_trigger or end_trigger:
+                        # loss stays a device scalar: triggers that read
+                        # .loss (Min/MaxLoss) force their own sync; counter
+                        # triggers stay async. Without a trigger nobody
+                        # reads it, and the slice would be one more
+                        # dispatch a run (on the CPU backend, a wait for
+                        # the run itself, outside `fit.loss_sync`)
+                        last_loss = loss[-1] if multi else loss
+                    if checkpoint_trigger and ckpt_mgr and checkpoint_trigger(
+                            tg.TriggerState(epoch=epoch, iteration=iteration,
+                                            loss=last_loss)):
+                        # the meta sidecar records the opt-state layout
+                        # (plus the resume cursors/RNG), so a future
+                        # restore can't silently structurally mismatch a
+                        # fused fit's state against a plain one.
+                        # gather_tree, not bare device_get: correct (and
+                        # actionably failing cross-host) for sharded leaves
+                        _ckpt_save(_ckpt_extra(epoch, False))
+                    if end_trigger and end_trigger(
+                            tg.TriggerState(epoch=epoch, iteration=iteration,
+                                            loss=last_loss)):
+                        break
+                if isinstance(batches, _Prefetcher):
+                    batches.close()  # early break leaves the worker mid-queue
+              # ONE host sync per epoch: materialize every step loss together.
+              # This blocks until the last step's program has finished, so dt
+              # measures device compute, not dispatch.
+              if epoch == 0 and not losses_dev:
+                  # prebuilt models skip the shape probe, so an empty/too-small
+                  # dataset must still fail loudly rather than "train" 0 steps
+                  raise ValueError(
+                      "Dataset produced no full batches; lower batch_size")
+              with trace.phase("loss_sync", "epoch"):
+                  # the host blocked on the device
+                  losses_host = _materialize(losses_dev)
+              step_losses = np.concatenate(
+                  [np.atleast_1d(v) for v in losses_host]) \
+                  if losses_host else np.zeros((0,))
+              dt = time.time() - t0
+              mean_loss = float(step_losses.mean()) \
+                  if len(step_losses) else 0.0
+              history["loss"].append(mean_loss)
+              throughput = n_seen / max(dt, 1e-9)
+              step_ms = telemetry.epoch(iteration - it0, n_seen, dt, mean_loss,
+                                        flops_per_step=flops_per_step)
+              # device-wait vs host-wait verdict (ISSUE 15): the prefetch
+              # queue's measured blocked time over the epoch wall time is
+              # the fraction of the fit that was input-bound — a measured
+              # answer, not a guess. Also lands in the roofline snapshot's
+              # input-stall column.
+              input_wait_s = batches.wait_s \
+                  if isinstance(batches, _Prefetcher) else 0.0
+              telemetry.input_bound.set(
+                  min(1.0, input_wait_s / max(dt, 1e-9)))
+              if input_wait_s > 0:
+                  try:
+                      from analytics_zoo_tpu.observability.roofline import \
+                          get_accountant
+                      get_accountant().account_stall("train", input_wait_s)
+                  except Exception as ie:  # noqa: BLE001 — telemetry only
+                      log.debug("input-stall accounting failed: %s", ie)
+              if cost_tracker is not None and cost_tracker.calls:
+                  # dt is device wall time (the _materialize above synced),
+                  # so achieved = XLA-counted work / measured epoch seconds.
+                  # The harvested cost is PER-STEP (cost analysis counts a
+                  # scan body once — see _StepCostTracker), so scale the
+                  # per-call mean by the iterations this epoch ran: exact
+                  # for single-step, multi-step (steps_per_run) and
+                  # device-cache epoch programs alike.
+                  steps_done = max(iteration - it0, cost_tracker.calls)
+                  scale = steps_done / cost_tracker.calls
+                  telemetry.roofline(cost_tracker.flops * scale,
+                                     cost_tracker.bytes * scale, dt,
+                                     n_devices=cost_tracker.devices)
+                  cost_tracker.reset_epoch()
               if writer:
+                  writer.scalar("Loss", mean_loss, iteration)
+                  writer.scalar("Throughput", throughput, iteration)
+                  writer.scalar("StepTime_ms", step_ms, iteration)
+              log.info("Epoch %d/%d  loss=%.4f  %.0f samples/s",
+                       epoch + 1, epochs, mean_loss, throughput)
+
+              if validation_data is not None:
+                  vx, vy = validation_data
+                  model.params = params  # device-resident hand-off
+                  with trace.phase("validation", "epoch"):
+                      val = evaluate_keras(
+                          model, vx, vy,
+                          batch_per_thread=max(batch_size // dp, 1))
                   for k, v in val.items():
-                      writer.scalar("val_" + k, v, iteration)
+                      history.setdefault("val_" + k, []).append(v)
+                      telemetry.val.set(v, name=k)
+                  if writer:
+                      for k, v in val.items():
+                          writer.scalar("val_" + k, v, iteration)
 
-          # epoch-boundary checkpoint trigger (EveryEpoch semantics)
-          if checkpoint_trigger and ckpt_mgr and checkpoint_trigger(
-                  tg.TriggerState(epoch=epoch + 1, iteration=iteration,
-                                  epoch_finished=True)):
-              _ckpt_save(_ckpt_extra(epoch + 1, True))
-          if end_trigger and end_trigger(
-                  tg.TriggerState(epoch=epoch + 1, iteration=iteration,
-                                  epoch_finished=True)):
-              break
+              # epoch-boundary checkpoint trigger (EveryEpoch semantics)
+              if checkpoint_trigger and ckpt_mgr and checkpoint_trigger(
+                      tg.TriggerState(epoch=epoch + 1, iteration=iteration,
+                                      epoch_finished=True)):
+                  _ckpt_save(_ckpt_extra(epoch + 1, True))
+              if end_trigger and end_trigger(
+                      tg.TriggerState(epoch=epoch + 1, iteration=iteration,
+                                      epoch_finished=True)):
+                  break
 
-    except Exception:
-        # the step watchdog exhausted its retries, or any other mid-run
-        # failure: leave an emergency checkpoint behind so auto_resume
-        # (or an operator) can continue instead of restarting at step 0.
-        # Best-effort — a step that died mid-execution may have consumed
-        # the donated parameter buffers, in which case the last periodic
-        # checkpoint on disk remains the resume point.
-        if ckpt_mgr is not None and iteration > 0 \
-                and iteration not in ckpt_mgr._saved:
-            # (skipped when this iteration is already on disk — an
-            # emergency save would demote a boundary checkpoint's
-            # metadata to mid-epoch for identical params)
-            try:
-                # through the SAME commit funnel as every other save
-                # site — the emergency checkpoint gets the int8 sidecar
-                # too, so a crash can't leave a newest version serving
-                # falls back to quantize-at-load on
-                _ckpt_save(dict(_ckpt_extra(epoch, False),
-                                emergency=True))
-                log.warning("emergency checkpoint written at iteration "
-                            "%d", iteration)
-            except Exception as ce:  # noqa: BLE001 — already failing
-                log.warning("emergency checkpoint failed (%s: %s); the "
-                            "last periodic checkpoint is the resume "
-                            "point", type(ce).__name__, ce)
-        raise
-    finally:
-        # Keep parameters on device (even on an interrupted fit, so the
-        # model never points at donated/deleted buffers): repeated
-        # fit/evaluate/predict chains stay in HBM; save/checkpoint
-        # paths device_get on demand.
-        model.params = params
-        if isinstance(batches, _Prefetcher):
-            batches.close()
-        if profiler is not None and profile_state["active"]:
-            # a fit that ends (or dies) inside the window still leaves
-            # a finished, loadable artifact behind
-            try:
-                manifest = profiler.stop()
-                history.setdefault("profile_artifacts", []).append(
-                    manifest["dir"])
-            except Exception:  # noqa: BLE001 — already tearing down
-                pass
-        if reporter is not None:
-            reporter.stop()   # logs a final digest (before writer closes)
-        if writer:
-            writer.close()
-    return history
+        except Exception:
+            # the step watchdog exhausted its retries, or any other mid-run
+            # failure: leave an emergency checkpoint behind so auto_resume
+            # (or an operator) can continue instead of restarting at step 0.
+            # Best-effort — a step that died mid-execution may have consumed
+            # the donated parameter buffers, in which case the last periodic
+            # checkpoint on disk remains the resume point.
+            if ckpt_mgr is not None and iteration > 0 \
+                    and iteration not in ckpt_mgr._saved:
+                # (skipped when this iteration is already on disk — an
+                # emergency save would demote a boundary checkpoint's
+                # metadata to mid-epoch for identical params)
+                try:
+                    # through the SAME commit funnel as every other save
+                    # site — the emergency checkpoint gets the int8 sidecar
+                    # too, so a crash can't leave a newest version serving
+                    # falls back to quantize-at-load on
+                    _ckpt_save(dict(_ckpt_extra(epoch, False),
+                                    emergency=True))
+                    log.warning("emergency checkpoint written at iteration "
+                                "%d", iteration)
+                except Exception as ce:  # noqa: BLE001 — already failing
+                    log.warning("emergency checkpoint failed (%s: %s); the "
+                                "last periodic checkpoint is the resume "
+                                "point", type(ce).__name__, ce)
+            raise
+        finally:
+            # Keep parameters on device (even on an interrupted fit, so the
+            # model never points at donated/deleted buffers): repeated
+            # fit/evaluate/predict chains stay in HBM; save/checkpoint
+            # paths device_get on demand.
+            trace.enter("finish")
+            model.params = params
+            if isinstance(batches, _Prefetcher):
+                batches.close()
+            if profiler is not None and profile_state["active"]:
+                # a fit that ends (or dies) inside the window still leaves
+                # a finished, loadable artifact behind
+                try:
+                    manifest = profiler.stop()
+                    history.setdefault("profile_artifacts", []).append(
+                        manifest["dir"])
+                except Exception:  # noqa: BLE001 — already tearing down
+                    pass
+            if reporter is not None:
+                reporter.stop()   # logs a final digest (before writer closes)
+            if writer:
+                writer.close()
+        return history
 
 
 def _localize_params(model):

@@ -7,8 +7,10 @@ plus the deep-profiling layer that makes the stack self-measuring.
   `serving/timer.py`, generalized.
 - `render_prometheus(registry)` — Prometheus 0.0.4 text, served by the
   HTTP frontend's `GET /metrics` under `Accept: text/plain`.
-- `Tracer` — request-scoped spans with Chrome trace-event JSON export
-  (Perfetto-viewable), threaded through the serving pipeline.
+- `Tracer` / `get_tracer()` — scoped spans with Chrome trace-event JSON
+  export (Perfetto-viewable), threaded through the serving pipeline and
+  the fit loop; a scoped span is a host event of any running
+  `jax.profiler` capture too.
 - `MetricsReporter` — periodic one-line digest thread (optionally
   evaluating an `SLOTracker` each report).
 - `RooflineAccountant` / `cost_of` / `set_session_roofline` — hardware
@@ -47,6 +49,7 @@ from analytics_zoo_tpu.observability.roofline import (ExecCost,
                                                       set_session_roofline)
 from analytics_zoo_tpu.observability.slo import SLOObjectives, SLOTracker
 from analytics_zoo_tpu.observability.tracing import (Span, Tracer,
+                                                     get_tracer,
                                                      span_coverage,
                                                      span_from_dict,
                                                      span_to_dict)
@@ -57,7 +60,7 @@ __all__ = [
     "LogHistogram", "MetricsRegistry", "MetricsReporter",
     "ProfileCapture", "RooflineAccountant", "SLOObjectives", "SLOTracker",
     "Span", "StackSampler", "Tracer", "cost_of", "device_memory_snapshot",
-    "digest", "get_accountant", "get_registry", "leak_check",
+    "digest", "get_accountant", "get_registry", "get_tracer", "leak_check",
     "load_trace_events", "render_prometheus", "session_roofline",
     "set_session_roofline", "span_coverage", "span_from_dict",
     "span_to_dict",

@@ -10,7 +10,12 @@ Two ways to produce spans:
 
 - `with tracer.span("decode", trace_id=uri): ...` — scoped, nests via a
   thread-local stack (children inherit the enclosing span's trace_id and
-  record their parent's name).
+  record their parent's name). A scoped span is also a
+  `jax.profiler.TraceAnnotation`: while a profiler capture runs it is a
+  host event on `/host:CPU`, on the clock of the device's op line.
+  `tracer.phase(name, histogram, **labels)` is a scoped span that
+  observes its own duration (ms) in `histogram` when it closes, so a
+  span and its counter share their endpoints.
 - `tracer.add_span("queue_wait", t0, t1, ...)` — explicit timestamps,
   for intervals that start in one thread and end in another (the
   inter-stage queue waits in `serving/server.py`).
@@ -31,6 +36,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+
+from analytics_zoo_tpu.observability.registry import get_registry
 
 
 class Span:
@@ -70,39 +77,53 @@ class _ScopedSpan:
     """Context manager returned by `Tracer.span`."""
 
     __slots__ = ("_tracer", "name", "cat", "trace_id", "trace_ids",
-                 "args", "_t0", "_parent")
+                 "args", "duration", "_t0", "_parent", "_annotation",
+                 "_observe")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  trace_id: Optional[str],
                  trace_ids: Optional[Sequence[str]],
-                 args: Optional[Dict[str, Any]]):
+                 args: Optional[Dict[str, Any]],
+                 observe: Optional[Tuple[Any, Dict[str, Any]]] = None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.trace_id = trace_id
         self.trace_ids = tuple(trace_ids) if trace_ids else None
         self.args = args
+        self.duration = 0.0           # seconds, once closed
+        self._observe = observe       # (histogram, labels) of a phase
 
     def __enter__(self) -> "_ScopedSpan":
+        # imported here: the module stays importable without jax, and the
+        # annotation costs one atomic check while no capture runs
+        from jax.profiler import TraceAnnotation
         stack = self._tracer._stack()
         self._parent = stack[-1] if stack else None
         if self.trace_id is None and self._parent is not None:
             self.trace_id = self._parent.trace_id
         stack.append(self)
+        self._annotation = TraceAnnotation(self.name, **(self.args or {}))
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         end = time.perf_counter()
+        self._annotation.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
+        self.duration = duration = end - self._t0
         self._tracer._emit(Span(
-            self.name, self.cat, self._t0, end - self._t0,
+            self.name, self.cat, self._t0, duration,
             trace_id=self.trace_id, trace_ids=self.trace_ids,
             tid=threading.current_thread().name,
             parent=self._parent.name if self._parent else None,
             args=self.args))
+        if self._observe is not None:
+            histogram, labels = self._observe
+            histogram.observe(duration * 1e3, **labels)
         return False
 
 
@@ -175,6 +196,15 @@ class Tracer:
              args: Optional[Dict[str, Any]] = None) -> _ScopedSpan:
         return _ScopedSpan(self, name, cat, trace_id, trace_ids, args)
 
+    def phase(self, name: str, histogram, trace_id: Optional[str] = None,
+              cat: str = "serving", args: Optional[Dict[str, Any]] = None,
+              **labels) -> _ScopedSpan:
+        """A scoped span whose close also observes its duration, in ms,
+        in `histogram` under `labels`: the span and the counter are taken
+        from the same two clock readings and cannot disagree."""
+        return _ScopedSpan(self, name, cat, trace_id, None, args,
+                           observe=(histogram, labels))
+
     def add_span(self, name: str, start: float, end: float,
                  trace_id: Optional[str] = None, cat: str = "serving",
                  trace_ids: Optional[Sequence[str]] = None,
@@ -237,6 +267,15 @@ class Tracer:
         with open(path, "w") as fh:
             json.dump(self.chrome_trace(trace_id), fh)
         return path
+
+
+# The process-wide default, beside `get_registry()`: the trainer's spans
+# land here; serving engines keep tracers of their own, named by engine.
+_default_tracer = Tracer(registry=get_registry())
+
+
+def get_tracer() -> Tracer:
+    return _default_tracer
 
 
 def span_to_dict(span: Span, epoch: float = 0.0) -> Dict[str, Any]:

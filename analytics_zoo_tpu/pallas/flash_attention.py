@@ -314,6 +314,7 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret):
         cost_estimate=_attn_cost(2, q,                    # QKᵀ + PV
                                  extra_f32_out_elems=B * H * T),
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf, mf, seed)
     out = out.reshape(B, H, T, D)
     return out, (q, k, v, mask, seed, out, lse)
@@ -529,6 +530,7 @@ def _flash_bwd(rate, _fwd_block_q, _fwd_block_k, block_q, block_k, interpret,
                                      extra_f32_out_elems=B * H * n_kb
                                      * T * D),
             interpret=interpret,
+            name="flash_bwd_fused",
         )(qf, kf, vf, mf, seed, dof, lse, delta)
         # the transposed-order accumulation, done where it is cheap: n_kb
         # partials summed by XLA (f32), then scaled — bytes ≈ one
@@ -557,6 +559,7 @@ def _flash_bwd(rate, _fwd_block_q, _fwd_block_k, block_q, block_k, interpret,
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             cost_estimate=_attn_cost(3, q),   # scores, dw/ds, dq
             interpret=interpret,
+            name="flash_dq",
         )(qf, kf, vf, mf, seed, dof, lse, delta)
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel, rate, scale, n_qb, n_kb),
@@ -587,6 +590,7 @@ def _flash_bwd(rate, _fwd_block_q, _fwd_block_k, block_q, block_k, interpret,
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             cost_estimate=_attn_cost(4, q),   # scores, dv, ds, dk
             interpret=interpret,
+            name="flash_dkv",
         )(qf, kf, vf, mf, seed, dof, lse, delta)
 
     shape = (B, H, T, D)

@@ -41,11 +41,6 @@ def device_trace(log_dir: str) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named region inside a trace (`jax.profiler.TraceAnnotation`)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
 class StepTimer:
     """Per-step wall-clock + throughput accounting; the `Throughput` scalar
     the reference writes to its train summary (`Topology.scala:224`)."""

@@ -535,3 +535,55 @@ class TestFusedDropout:
                          np.float32)
         t = round(0.9 * 256)
         np.testing.assert_allclose(out[out != 0], 256.0 / t, rtol=1e-2)
+
+
+class TestKernelNamesOnChip:
+    def test_compiled_flash_fit_step_names_each_kernel(self):
+        """The compiled train step of a flash fit holds one instruction
+        named after each kernel (`pl.pallas_call(name=...)`): the
+        benchmark's per-kernel shares rest on these names, and the
+        pattern of the accepted `flash_time_share` still matches all of
+        them."""
+        import json
+        import os
+        import re
+
+        import optax
+
+        from analytics_zoo_tpu.learn import trainer
+        from analytics_zoo_tpu.models.bert import BERTClassifier
+        from analytics_zoo_tpu.ops import objectives
+        from benchmark import trace_reduce
+        T = 2048       # 1024x1024 tiles: dq and dk/dv are two kernels
+        model = BERTClassifier(num_classes=2, vocab=128, hidden_size=128,
+                               n_block=1, n_head=2, seq_len=T,
+                               intermediate_size=128, use_flash=True)
+        rs = np.random.RandomState(0)
+        xb = [jnp.asarray(rs.randint(0, 128, (2, T)).astype(np.int32)),
+              jnp.ones((2, T), jnp.float32)]
+        yb = jnp.asarray(rs.randint(0, 2, (2,)).astype(np.int32))
+        model.ensure_built(xb, jax.random.PRNGKey(0))
+        opt = optax.adamw(1e-4)
+        step = trainer.build_train_step(
+            model.apply, objectives.get("sparse_categorical_crossentropy",
+                                        from_logits=True),
+            opt, mixed_precision=True)
+        text = step.lower(model.params, opt.init(model.params), xb, yb,
+                          jax.random.PRNGKey(1)).compile().as_text()
+        kernels = [trace_reduce.op_name(re.sub(r"^\s*(ROOT )?", "", ln))
+                   for ln in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln]
+        for word in ("flash_fwd", "flash_dq", "flash_dkv"):
+            assert sum(word in k for k in kernels) == 1, (word, kernels)
+        metrics_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))), "benchmark", "layer_metrics")
+
+        def matched(metric):
+            with open(os.path.join(metrics_dir, metric + ".json")) as fh:
+                pattern = re.compile(json.load(fh)["pattern"])
+            return sum(bool(pattern.search(k)) for k in kernels)
+        assert matched("flash_time_share") == len(kernels) == 3, kernels
+        for metric in ("flash_fwd_time_share", "flash_dq_time_share",
+                       "flash_dkv_time_share"):
+            assert matched(metric) == 1, (metric, kernels)
