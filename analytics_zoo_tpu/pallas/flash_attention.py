@@ -9,10 +9,19 @@ is (batch·heads, q-blocks, k-blocks) with the k axis innermost and marked
 "arbitrary", so Pallas pipelines K/V block DMAs while online-softmax state
 (acc, m, l) lives in VMEM scratch across k steps — VMEM stays O(block²)
 at any sequence length. Matmuls run in the input dtype (bf16 on the MXU)
-with f32 accumulation; softmax statistics stay f32. The backward pass is a
-custom VJP with two more kernels (dQ over q-blocks, dK/dV over k-blocks)
-recomputing weights from the saved logsumexp instead of materializing [T,T]
-— so training (BERT, ring attention shards) runs flash end-to-end.
+with f32 accumulation; softmax statistics stay f32.
+
+The backward pass is a custom VJP that recomputes the weights from the saved
+output and logsumexp instead of materializing [T,T], at the forward's tiling,
+in ONE kernel (`flash_bwd_fused`): each (q-block, k-block) tile's weights,
+dropout mask and dW are computed once and feed all three gradients — five
+T×T×D products. The kernel walks the DMA tile in column chunks (a quarter of
+`block_k`), so its live f32 intermediates are a quarter of the tile; dK/dV
+accumulate in scratch over the q-blocks of one k-block, dQ in a [T, D]
+scratch that stays on the chip for a whole head-batch. Shapes whose resident
+dQ or whose chunks do not fit scoped VMEM (`_bwd_fused_fits`: very long T,
+oversized tiles) run the same mathematics as two kernels (`flash_dq`,
+`flash_dkv`) that recompute the weights in each — seven products.
 
 Attention dropout runs INSIDE the kernels: `pltpu.prng_seed(seed, tile)`
 reseeds per (batch·head, q-block, k-block) tile, so the backward kernels
@@ -30,7 +39,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
@@ -85,8 +93,6 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
                     dropout_seed: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    bwd_block_q: Optional[int] = None,
-                    bwd_block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """q,k,v: [B, H, T, Dh]. mask: additive [B,1,1,T] (padding) or
     [B,1,T,T] (full; reference path only). `dropout_rate` > 0 needs
@@ -125,37 +131,9 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         block_q = _auto_block(T)
     if block_k is None:
         block_k = _auto_block(T)
-    # Backward kernels hold more VMEM live per tile (pnorm, dw, plus the
-    # dq/dk/dv accumulators) than the forward, so their sweet spot can be
-    # smaller; default to the forward blocks.
-    env_bwd = os.environ.get("ZOO_FLASH_BWD_BLOCK")
-    if env_bwd and bwd_block_q is None and bwd_block_k is None:
-        # tuning HINT, not a contract: applied only where it is legal for
-        # THIS call — a process can hold models with several seq lengths
-        try:
-            env_val = int(env_bwd)
-        except ValueError:
-            raise ValueError(f"ZOO_FLASH_BWD_BLOCK={env_bwd!r}: not an int")
-        applicable = (env_val > 0 and env_val % 128 == 0
-                      and T % env_val == 0
-                      # dropout masks regenerate per (qi, ki) tile — the
-                      # backward must match the forward tiling exactly
-                      and (not use_dropout
-                           or (env_val == block_q and env_val == block_k)))
-        if applicable:
-            bwd_block_q = bwd_block_k = env_val
-    if bwd_block_q is None:
-        bwd_block_q = block_q
-    if bwd_block_k is None:
-        bwd_block_k = block_k
-    if use_dropout and (bwd_block_q != block_q or bwd_block_k != block_k):
-        # explicit caller-passed mismatch is a programming error
-        raise ValueError("flash_attention: in-kernel dropout requires "
-                         "bwd blocks == fwd blocks (mask regeneration is "
-                         "tile-indexed)")
     if mask is None:
         mask = jnp.zeros((B, 1, 1, T), jnp.float32)
-    block = math.lcm(block_q, block_k, bwd_block_q, bwd_block_k)
+    block = math.lcm(block_q, block_k)
     if T % block:
         pad = (-T) % block
         qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
@@ -164,42 +142,29 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         maskp = jnp.pad(mask, ((0, 0), (0, 0), (0, 0), (0, pad)),
                         constant_values=-1e9)
         out = flash_attention(qp, kp, vp, maskp, dropout_rate, dropout_seed,
-                              block_q, block_k, bwd_block_q, bwd_block_k,
-                              interpret)
+                              block_q, block_k, interpret)
         return out[:, :, :T]
     seed = jnp.asarray(dropout_seed if use_dropout else 0,
                        jnp.int32).reshape(1, 1)
     rate = float(dropout_rate) if use_dropout else 0.0
     return _flash(q, k, v, mask, seed, rate, block_q, block_k,
-                  bwd_block_q, bwd_block_k,
                   bool(interpret) if interpret is not None else False)
 
 
 # ---------------------------------------------------------------------------
 # custom-VJP core (assumes T % lcm(block_q, block_k) == 0, mask [B,1,1,T])
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, mask, seed, rate, block_q, block_k, bwd_block_q,
-           bwd_block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, mask, seed, rate, block_q, block_k, interpret):
     out, _ = _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k,
                         interpret)
     return out
 
 
-def _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki, shape):
-    """Deterministic per-tile dropout scale: 1/keep where kept, 0 where
-    dropped. Identical bits in forward and both backward kernels (the tile
-    index folds (bh, qi, ki); prng_seed on this mosaic takes 2 scalars).
-
-    The PRNG is the expensive part (~20 cycles/word on v5e — measured
-    45 ms/step across the three kernels at seq 2048 when drawing one
-    uint32 per element), so draw one word per FOUR elements and use each
-    byte as an independent keep-draw: keep iff byte < t, t =
-    round(keep*256), scaled by the exact keep probability t/256 (unbiased;
-    rate quantized to 1/256 like `pallas/dropout._u8_dropout`). Which
-    byte lands on which column is an arbitrary fixed bijection — the mask
-    stays iid Bernoulli and regenerates bit-identically in the backward
-    kernels."""
+def _tile_words(s_ref, n_qb, n_kb, qi, ki, shape):
+    """The dropout draw of one (bh, qi, ki) tile: a [shape[0], shape[1]/4]
+    array of uint32 words, one byte an element. The tile index folds
+    (bh, qi, ki); prng_seed on this mosaic takes 2 scalars."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -207,11 +172,42 @@ def _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki, shape):
     tile = (bh * n_qb + qi) * n_kb + ki
     pltpu.prng_seed(s_ref[0, 0], tile)
     words = pltpu.prng_random_bits((shape[0], shape[1] // 4))
-    words = words.astype(jnp.uint32)
+    return words.astype(jnp.uint32)
+
+
+def _keep_of(words, rate, lo, hi):
+    """Dropout scale of the tile's columns [lo, hi): 1/keep where kept, 0
+    where dropped. Byte j of a word is the draw of column j·(block_k/4) +
+    (the word's own column), so byte plane j IS the mask of the j-th
+    quarter of the tile and a chunk of columns needs no more than a shift
+    of the words it covers."""
+    plane = words.shape[1]
     t = _byte_threshold(rate)
-    bytes_ = jnp.concatenate(
-        [(words >> (8 * j)) & jnp.uint32(0xFF) for j in range(4)], axis=1)
+    bytes_ = []
+    for j in range(4):
+        a = max(lo, j * plane) - j * plane
+        b = min(hi, (j + 1) * plane) - j * plane
+        if a < b:
+            bytes_.append((words[:, a:b] >> (8 * j)) & jnp.uint32(0xFF))
+    bytes_ = bytes_[0] if len(bytes_) == 1 else jnp.concatenate(bytes_,
+                                                                axis=1)
     return jnp.where(bytes_ < jnp.uint32(t), 256.0 / t, 0.0)
+
+
+def _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki, shape):
+    """Deterministic per-tile dropout scale. Identical bits in the forward
+    and the backward kernels.
+
+    The PRNG is the expensive part (~20 cycles/word on v5e when drawing
+    one uint32 per element), so draw one word per FOUR elements and use
+    each byte as an independent keep-draw: keep iff byte < t, t =
+    round(keep*256), scaled by the exact keep probability t/256 (unbiased;
+    rate quantized to 1/256 like `pallas/dropout._u8_dropout`). Which
+    byte lands on which column is an arbitrary fixed bijection — the mask
+    stays iid Bernoulli and regenerates bit-identically in the backward
+    kernels."""
+    return _keep_of(_tile_words(s_ref, n_qb, n_kb, qi, ki, shape), rate,
+                    0, shape[1])
 
 
 def _fwd_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
@@ -256,7 +252,7 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
 
 
 def _attn_cost(n_matmuls, q, extra_f32_out_elems=0):
-    """Analytic roofline model for one attention kernel over [B,H,T,D]
+    """Analytic roofline model for one attention kernel over [..., T, D]
     (check_pallas_cost lint: HLO cost analysis sees ~0 inside a Mosaic
     call). `n_matmuls` counts the T×T×D matmul-shaped products the
     kernel runs per head (2 flops each); bytes are the O(T·D) streams —
@@ -264,8 +260,8 @@ def _attn_cost(n_matmuls, q, extra_f32_out_elems=0):
     IO-aware point of flash attention; exp() is one per score."""
     from jax.experimental import pallas as pl
 
-    B, H, T, D = q.shape
-    bh = B * H
+    *lead, T, D = q.shape
+    bh = math.prod(lead)
     item = jnp.dtype(q.dtype).itemsize
     streams = 4 + n_matmuls  # rough: q,k,v(+dout...) in, grads/out out
     return pl.CostEstimate(
@@ -320,11 +316,20 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret):
     return out, (q, k, v, mask, seed, out, lse)
 
 
+def _delta(do_ref, o_ref):
+    """delta[i] = rowsum(dO * O), the softmax-jacobian diagonal term of a
+    q-block, [bq, 1] f32: 64 multiply-adds a row beside the tile's
+    thousands, so every backward kernel computes it where it needs it and
+    no [T, 1] array (128 lanes wide in HBM) is written or read."""
+    return jnp.sum(do_ref[0].astype(jnp.float32)
+                   * o_ref[0].astype(jnp.float32), axis=1, keepdims=True)
+
+
 def _dq_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
-               do_ref, lse_ref, delta_ref, dq_ref, dq_sc):
-    """Standalone dq (accumulate over ki in scratch): the fallback when
-    n_kb is large enough that the fused kernel's per-ki dq partials
-    (n_kb × T × D f32 in HBM) would cost real memory — see _flash_bwd."""
+               do_ref, lse_ref, o_ref, dq_ref, dq_sc):
+    """Standalone dq (accumulate over ki in scratch): half of the
+    two-kernel backward for shapes the fused kernel's VMEM need rules out
+    — see _flash_bwd."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -342,7 +347,7 @@ def _dq_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
     mb = m_ref[0]
     dob = do_ref[0]
     lse = lse_ref[0]                                       # [bq, 1]
-    delta = delta_ref[0]                                   # [bq, 1]
+    delta = _delta(do_ref, o_ref)                          # [bq, 1]
     pnorm = jnp.exp(jnp.dot(qb, kb.T,
                             preferred_element_type=jnp.float32)
                     * scale + mb - lse)                    # softmax weights
@@ -360,8 +365,8 @@ def _dq_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
 
 
 def _dkv_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
-                do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_sc, dv_sc):
-    """dk/dv-only companion of _dq_kernel for the large-n_kb fallback."""
+                do_ref, lse_ref, o_ref, dk_ref, dv_ref, dk_sc, dv_sc):
+    """dk/dv-only companion of _dq_kernel."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
@@ -380,7 +385,7 @@ def _dkv_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
     mb = m_ref[0]                                          # [1, bk]
     dob = do_ref[0]
     lse = lse_ref[0]                                       # [bq, 1]
-    delta = delta_ref[0]
+    delta = _delta(do_ref, o_ref)
     pnorm = jnp.exp(jnp.dot(qb, kb.T,
                             preferred_element_type=jnp.float32)
                     * scale + mb - lse)                    # [bq, bk]
@@ -402,56 +407,107 @@ def _dkv_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
     def _flush():
         dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
+
+def _bwd_chunk(block_k: int) -> int:
+    """Columns the fused backward computes at a time inside its
+    [block_q, block_k] DMA tile: a quarter of the tile, which is one byte
+    plane of the dropout words (`_keep_of`), where that is lane-aligned;
+    else the whole tile (tiles of 128 and 256 columns are small enough)."""
+    quarter = block_k // 4
+    return quarter if quarter % 128 == 0 else block_k
+
+
+# What the fused backward may hold of the 16 MiB of scoped VMEM a kernel gets
+# by default on the v5e; the last MiB is room for what `_bwd_fused_fits` does
+# not count (the mask blocks, semaphores). The kernel raises no
+# `vmem_limit_bytes`: what does not fit runs as the pair.
+_BWD_FUSED_VMEM_BYTES = 15 * 2 ** 20
+
+
+def _bwd_fused_fits(block_q, block_k, T, D, itemsize) -> bool:
+    """Whether the one-kernel backward fits scoped VMEM at these shapes,
+    reckoned from what it holds, every [rows, D] buffer padded to 128
+    lanes as Mosaic lays it out at deployment sizes: dq for the whole
+    head-batch (f32 scratch plus its double-buffered output block); the
+    double-buffered q/dO/O and k/v/dk/dv blocks, the dk/dv accumulators
+    and the lse column; the tile's dropout words and 4.5 live f32
+    [block_q, chunk] intermediates. Held against the chip's compiler over
+    T = 512..8192, D = 32..256, bf16 and f32 (PERF.md, PR 25): Mosaic
+    allocates 3.4 such intermediates where it pads every buffer (192
+    head-batches) and as little as half the total where it does not (4
+    head-batches), so the reckoning reads high, never low."""
+    lanes = -(-D // 128) * 128
+    chunk = _bwd_chunk(block_k)
+    resident_dq = T * lanes * (4 + 2 * itemsize)
+    streams = ((6 * block_q + 8 * block_k) * lanes * itemsize
+               + 2 * block_k * lanes * 4 + 2 * block_q * 128 * 4)
+    live = block_q * block_k + int(4.5 * block_q * chunk * 4)
+    return resident_dq + streams + live <= _BWD_FUSED_VMEM_BYTES
 
 
 def _bwd_fused_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref,
-                      s_ref, do_ref, lse_ref, delta_ref, dqp_ref, dk_ref,
-                      dv_ref, dk_sc, dv_sc):
-    """ONE backward kernel (round-5 fusion): the previous dq/dkv pair each
-    recomputed `pnorm` and `dw` — 7 matmuls per tile where 5 suffice (and
-    two dropout-mask regenerations where one does). dk/dv accumulate over
-    qi exactly as before; dq has the transposed accumulation order, so
-    each grid step writes its PARTIAL contribution ds·K to its own
-    [ki]-indexed output block (no revisited-output accumulation) and the
-    caller reduces the n_kb partials — at 1024-blocks that is a 2-term
-    sum, trivially XLA-fused against the matmul that consumes dq."""
+                      s_ref, do_ref, lse_ref, o_ref, dq_ref, dk_ref,
+                      dv_ref, dq_sc, dk_sc, dv_sc):
+    """ONE backward kernel: the weights, dW and the dropout mask of a tile
+    are computed once and feed dq, dk and dv — 5 matmuls a tile where the
+    dq/dkv pair runs 7 (and one mask regeneration where it runs two).
+
+    The DMA tile is [block_q, block_k]; the body walks it in column chunks
+    (`_bwd_chunk`), so the live f32 intermediates are [block_q, chunk].
+    dk/dv accumulate over qi in scratch rows of their chunk. dq has the
+    transposed accumulation order: its [T, D] f32 scratch and its output
+    block belong to the head-batch (block index (b, 0, 0)), so they stay
+    in VMEM over both inner grid axes and go back to HBM once."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     block_k = k_ref.shape[1]
     block_q = q_ref.shape[1]
+    chunk = _bwd_chunk(block_k)
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
     @pl.when(qi == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
+    @pl.when(ki == 0)
+    def _init_dq():
+        dq_sc[rows, :] = jnp.zeros((block_q, dq_sc.shape[1]), jnp.float32)
+
     qb = q_ref[0]
-    kb = k_ref[0]
-    vb = v_ref[0]
-    mb = m_ref[0]                                          # [1, bk]
     dob = do_ref[0]
     lse = lse_ref[0]                                       # [bq, 1]
-    delta = delta_ref[0]
-    pnorm = jnp.exp(jnp.dot(qb, kb.T,
-                            preferred_element_type=jnp.float32)
-                    * scale + mb - lse)                    # [bq, bk]
-    dw = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
+    delta = _delta(do_ref, o_ref)
     if rate > 0.0:
-        keep_scale = _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki,
-                                 (block_q, block_k))
-        dw = dw * keep_scale
-        dv_p = pnorm * keep_scale
-    else:
-        dv_p = pnorm
-    ds = pnorm * (dw - delta)
-    dqp_ref[0, 0] = jnp.dot(ds.astype(k_ref.dtype), kb,
-                            preferred_element_type=jnp.float32)
-    dk_sc[...] += jnp.dot(ds.T.astype(q_ref.dtype), qb,
-                          preferred_element_type=jnp.float32)
-    dv_sc[...] += jnp.dot(dv_p.T.astype(do_ref.dtype), dob,
-                          preferred_element_type=jnp.float32)
+        words = _tile_words(s_ref, n_qb, n_kb, qi, ki, (block_q, block_k))
+    for lo in range(0, block_k, chunk):
+        cols = slice(lo, lo + chunk)
+        kc = k_ref[0, cols, :]
+        vc = v_ref[0, cols, :]
+        pnorm = jnp.exp(jnp.dot(qb, kc.T,
+                                preferred_element_type=jnp.float32)
+                        * scale + m_ref[0, :, cols] - lse)  # [bq, chunk]
+        dw = jnp.dot(dob, vc.T, preferred_element_type=jnp.float32)
+        if rate > 0.0:
+            keep_scale = _keep_of(words, rate, lo, lo + chunk)
+            dw = dw * keep_scale
+            dv_p = pnorm * keep_scale
+        else:
+            dv_p = pnorm
+        ds = pnorm * (dw - delta)
+        dq_sc[rows, :] += jnp.dot(ds.astype(k_ref.dtype), kc,
+                                  preferred_element_type=jnp.float32)
+        dk_sc[cols, :] += jnp.dot(ds.T.astype(q_ref.dtype), qb,
+                                  preferred_element_type=jnp.float32)
+        dv_sc[cols, :] += jnp.dot(dv_p.T.astype(do_ref.dtype), dob,
+                                  preferred_element_type=jnp.float32)
+
+    @pl.when(ki == n_kb - 1)
+    def _flush_dq():
+        dq_ref[0, rows, :] = (dq_sc[rows, :] * scale).astype(dq_ref.dtype)
 
     @pl.when(qi == n_qb - 1)
     def _flush():
@@ -459,150 +515,116 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(rate, _fwd_block_q, _fwd_block_k, block_q, block_k, interpret,
-               res, dout):
-    # _fwd_block_* are unused: mask regeneration derives its tile indices
-    # from the bwd blocks, which flash_attention() forces equal to the fwd
-    # blocks whenever dropout is active.
+def _bwd_in_specs(block_q, block_k, D, q_major):
+    """Input BlockSpecs shared by the three backward kernels (q, k, v,
+    mask, seed, dO, lse, O), with the q- and the k-block spec. A
+    `q_major` grid is (bh, qi, ki), the other (bh, ki, qi)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    def spec(shape, of_q, at):
+        axis = 1 if of_q == q_major else 2
+        return pl.BlockSpec(shape, lambda *g: at(g[0], g[axis]))
+    q_spec = spec((1, block_q, D), True, lambda b, i: (b, i, 0))
+    k_spec = spec((1, block_k, D), False, lambda b, j: (b, j, 0))
+    m_spec = spec((1, 1, block_k), False, lambda b, j: (b, 0, j))
+    lse_spec = spec((1, block_q, 1), True, lambda b, i: (b, i, 0))
+    return ([q_spec, k_spec, k_spec, m_spec,
+             pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, lse_spec,
+             q_spec], q_spec, k_spec)
+
+
+def _bwd_fused(rate, scale, block_q, block_k, interpret, operands):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    qf = operands[0]
+    BH, T, D = qf.shape
+    n_qb, n_kb = T // block_q, T // block_k
+    in_specs, _, k_spec = _bwd_in_specs(block_q, block_k, D, q_major=False)
+    grad = jax.ShapeDtypeStruct((BH, T, D), qf.dtype)
+    return pl.pallas_call(
+        functools.partial(_bwd_fused_kernel, rate, scale, n_qb, n_kb),
+        grid=(BH, n_kb, n_qb),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, T, D), lambda b, j, i: (b, 0, 0)),
+                   k_spec, k_spec],
+        out_shape=[grad, grad, grad],
+        scratch_shapes=[
+            pltpu.VMEM((T, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
+        ],
+        # dq is revisited over both inner axes
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        cost_estimate=_attn_cost(5, qf),  # scores, dw, dq, dk, dv
+        interpret=interpret,
+        name="flash_bwd_fused",
+    )(*operands)
+
+
+def _bwd_pair(rate, scale, block_q, block_k, interpret, operands):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    qf = operands[0]
+    BH, T, D = qf.shape
+    n_qb, n_kb = T // block_q, T // block_k
+    grad = jax.ShapeDtypeStruct((BH, T, D), qf.dtype)
+    semantics = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+    in_specs, q_spec, _ = _bwd_in_specs(block_q, block_k, D, q_major=True)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, rate, scale, n_qb, n_kb),
+        grid=(BH, n_qb, n_kb),
+        in_specs=in_specs,
+        out_specs=q_spec,
+        out_shape=grad,
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        compiler_params=semantics,
+        cost_estimate=_attn_cost(3, qf),   # scores, dw/ds, dq
+        interpret=interpret,
+        name="flash_dq",
+    )(*operands)
+    in_specs, _, k_spec = _bwd_in_specs(block_q, block_k, D, q_major=False)
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, rate, scale, n_qb, n_kb),
+        grid=(BH, n_kb, n_qb),
+        in_specs=in_specs,
+        out_specs=[k_spec, k_spec],
+        out_shape=[grad, grad],
+        scratch_shapes=[
+            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
+        ],
+        compiler_params=semantics,
+        cost_estimate=_attn_cost(4, qf),   # scores, dv, ds, dk
+        interpret=interpret,
+        name="flash_dkv",
+    )(*operands)
+    return dq, dk, dv
+
+
+def _flash_bwd(rate, block_q, block_k, interpret, res, dout):
     q, k, v, mask, seed, out, lse = res
     B, H, T, D = q.shape
     scale = 1.0 / math.sqrt(D)
-    n_qb, n_kb = T // block_q, T // block_k
-    qf = q.reshape(B * H, T, D)
-    kf = k.reshape(B * H, T, D)
-    vf = v.reshape(B * H, T, D)
-    dof = dout.reshape(B * H, T, D)
+    qf, kf, vf, dof, of = (x.reshape(B * H, T, D)
+                           for x in (q, k, v, dout, out))
     mf = jnp.repeat(mask[:, 0, :, :], H, axis=0)
-    # delta[i] = rowsum(dO * O) — the softmax-jacobian diagonal term
-    delta = jnp.sum(dof.astype(jnp.float32)
-                    * out.reshape(B * H, T, D).astype(jnp.float32),
-                    axis=-1, keepdims=True)                # [BH, T, 1]
-
-    # Fused single-kernel backward when (a) the dq-partials buffer is
-    # cheap (n_kb × T × D f32 per head-batch; ≤4 partials ≈ ≤2 dq-sized
-    # f32 buffers) and (b) the tile fits scoped VMEM — the fused kernel
-    # holds pnorm/dw/ds (+ the dropout mask) live together, ~19.7 MB of
-    # f32 tiles at 1024². Round-5 measured the alternative of raising
-    # `vmem_limit_bytes` to 48 MB so 1024² compiles: 12.2 ms bwd vs the
-    # two-kernel pair's 9.6 ms at the same tiling (B=16,H=12,T=2048,
-    # D=64, all three grads consumed) — that much live VMEM destroys
-    # Mosaic's DMA/compute overlap, so the fused form only pays at
-    # tiles ≤512k where it measured ~9.0 ms (1024×512). Otherwise fall
-    # back to the two-kernel form — its dq accumulates in VMEM scratch
-    # with O(T·D) HBM, paying the duplicated pnorm/dw matmuls instead.
-    if n_kb <= 4 and block_q * block_k <= 512 * 1024:
-        dqp, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, rate, scale, n_qb, n_kb),
-            grid=(B * H, n_kb, n_qb),
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b, 0, j)),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, block_q, D),
-                             lambda b, j, i: (b, j, i, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * H, n_kb, T, D), jnp.float32),
-                jax.ShapeDtypeStruct((B * H, T, D), k.dtype),
-                jax.ShapeDtypeStruct((B * H, T, D), v.dtype),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            # scores, dv, dw, dq-partial, dk matmuls; the dqp partials
-            # buffer is an extra n_kb×T×D f32 write stream
-            cost_estimate=_attn_cost(5, q,
-                                     extra_f32_out_elems=B * H * n_kb
-                                     * T * D),
-            interpret=interpret,
-            name="flash_bwd_fused",
-        )(qf, kf, vf, mf, seed, dof, lse, delta)
-        # the transposed-order accumulation, done where it is cheap: n_kb
-        # partials summed by XLA (f32), then scaled — bytes ≈ one
-        # dq-sized read per partial, noise next to the matmuls it
-        # replaced
-        dq = (dqp.sum(axis=1) * scale).astype(q.dtype)
-    else:
-        dq = pl.pallas_call(
-            functools.partial(_dq_kernel, rate, scale, n_qb, n_kb),
-            grid=(B * H, n_qb, n_kb),
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j)),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, block_q, D),
-                                   lambda b, i, j: (b, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            cost_estimate=_attn_cost(3, q),   # scores, dw/ds, dq
-            interpret=interpret,
-            name="flash_dq",
-        )(qf, kf, vf, mf, seed, dof, lse, delta)
-        dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, rate, scale, n_qb, n_kb),
-            grid=(B * H, n_kb, n_qb),
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b, 0, j)),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * H, T, D), k.dtype),
-                jax.ShapeDtypeStruct((B * H, T, D), v.dtype),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            cost_estimate=_attn_cost(4, q),   # scores, dv, ds, dk
-            interpret=interpret,
-            name="flash_dkv",
-        )(qf, kf, vf, mf, seed, dof, lse, delta)
-
+    # One algorithm, two forms, chosen from the shapes alone: the fused
+    # kernel wherever its VMEM need fits, else the pair that pays the
+    # duplicated pnorm/dw matmuls with O(block) VMEM at any T. Both run at
+    # the forward's tiling — the dropout mask is keyed by tile.
+    fused = _bwd_fused_fits(block_q, block_k, T, D, q.dtype.itemsize)
+    dq, dk, dv = (_bwd_fused if fused else _bwd_pair)(
+        rate, scale, block_q, block_k, interpret,
+        (qf, kf, vf, mf, seed, dof, lse, of))
     shape = (B, H, T, D)
     # padding masks are data, not parameters — zero cotangent
     return (dq.reshape(shape), dk.reshape(shape), dv.reshape(shape),
             jnp.zeros_like(mask), jnp.zeros_like(seed))
 
 
-def _flash_fwd_rule(q, k, v, mask, seed, rate, block_q, block_k,
-                    bwd_block_q, bwd_block_k, interpret):
-    return _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k,
-                      interpret)
-
-
-_flash.defvjp(_flash_fwd_rule, _flash_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)

@@ -1,4 +1,6 @@
-"""A/B flash-attention fwd+bwd at a given tile shape on the real chip.
+"""A/B flash-attention fwd+bwd at a given tile shape on the real chip; the
+backward runs at the forward's tiling, as one kernel where that fits VMEM
+(scripts/bench_flash_decomp.py times both forms).
 
     python scripts/bench_flash_blocks.py <block_q> <block_k> [rate]
 """
@@ -30,8 +32,7 @@ def main():
 
     def loss(q, k, v):
         o = flash_attention(q, k, v, dropout_rate=rate,
-                            dropout_seed=7, block_q=bq, block_k=bk,
-                            bwd_block_q=bq, bwd_block_k=bk)
+                            dropout_seed=7, block_q=bq, block_k=bk)
         return jnp.sum(o.astype(jnp.float32))
 
     iters = 10
@@ -41,9 +42,8 @@ def main():
         gq, gk, gv = jax.grad(loss, argnums=(0, 1, 2))(
             q + (acc * 1e-20).astype(q.dtype), k, v)
         # consume ALL grads: with gq alone, XLA dead-code-eliminates the
-        # separate dk/dv pallas_call and the two-kernel backward times
-        # only its dq half (round-5 finding — made the fused kernel look
-        # slower than the pair at equal tiles when it wasn't)
+        # separate dk/dv pallas_call where the backward is the two-kernel
+        # form, and it times only its dq half (docs/ROOFLINE.md round 5)
         return (acc + jnp.sum(gq.astype(jnp.float32))
                 + jnp.sum(gk.astype(jnp.float32))
                 + jnp.sum(gv.astype(jnp.float32)),)

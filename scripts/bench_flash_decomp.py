@@ -1,12 +1,22 @@
-"""Decompose flash fwd vs bwd cost per (fwd_block, bwd_block) combo.
+"""Decompose the flash-attention cost on the real chip: forward alone, and
+the backward as ONE kernel (`flash_bwd_fused`) against the dq/dkv pair, at
+the tiling `flash_attention` picks for each sequence length.
 
-At dropout rate 0 the fwd/bwd tilings decouple, so this isolates where
-the backward time goes and whether the fused bwd kernel wins at shapes
-the dropout-coupled path cannot reach today.
+    python scripts/bench_flash_decomp.py [rate] [T ...]
 
-    python scripts/bench_flash_decomp.py
+The gate (`_bwd_fused_fits`) decides from the shapes which form a model
+runs; here both are called directly, so the table shows what the gate's
+choice is worth at every T (PERF.md, PR 25, holds one). B·T is held at
+32,768 tokens, H = 12, D = 64, bf16. All three gradients are outputs of the
+timed call: consuming dq alone lets XLA dead-code-eliminate the pair's
+dk/dv kernel and times half a backward (docs/ROOFLINE.md round 5). A lone
+jitted kernel reads about 2 ms over its time inside a model's step (operand
+copies around the custom call), the same in both forms: compare the forms
+here, and take a kernel's own time from the benchmark's traced run.
 """
 
+import functools
+import math
 import os
 import sys
 import time
@@ -20,75 +30,67 @@ import numpy as np
 if jax.default_backend() == "tpu":
     jax.config.update("jax_default_prng_impl", "rbg")
 
-from analytics_zoo_tpu.pallas.flash_attention import flash_attention
+from analytics_zoo_tpu.pallas import flash_attention as fa
 
 
-def timeit(run, iters):
-    float(run())
+def timeit(f, *args, iters=10):
+    out = jax.block_until_ready(f(*args))
     best = float("inf")
-    for _ in range(4):
+    for _ in range(3):
         t0 = time.perf_counter()
-        float(run())
+        for _ in range(iters):
+            g = f(*args)
+        jax.block_until_ready(g)
         best = min(best, time.perf_counter() - t0)
-    return best / iters * 1e3
+    return best / iters * 1e3, out
 
 
 def main():
-    rate = float(sys.argv[1]) if len(sys.argv) > 1 else 0.0
-    B, H, T, D = 16, 12, 2048, 64
-    rs = np.random.RandomState(0)
-    q = jnp.asarray(rs.randn(B, H, T, D), jnp.bfloat16)
-    k = jnp.asarray(rs.randn(B, H, T, D), jnp.bfloat16)
-    v = jnp.asarray(rs.randn(B, H, T, D), jnp.bfloat16)
-    iters = 10
-
-    def fwd_only(bq, bk):
-        def f():
-            def body(i, acc):
-                o = flash_attention(q + (acc * 1e-20).astype(q.dtype), k, v,
-                                    dropout_rate=rate, dropout_seed=7,
-                                    block_q=bq, block_k=bk)
-                return acc + jnp.sum(o.astype(jnp.float32))
-            return jax.lax.fori_loop(0, iters, body, jnp.float32(0))
-        return timeit(jax.jit(f), iters)
-
-    def fwd_bwd(bq, bk, bbq, bbk):
-        def loss(q, k, v):
-            o = flash_attention(q, k, v, dropout_rate=rate, dropout_seed=7,
-                                block_q=bq, block_k=bk,
-                                bwd_block_q=bbq, bwd_block_k=bbk)
-            return jnp.sum(o.astype(jnp.float32))
-
-        def f():
-            def body(i, acc):
-                # consume ALL grads: with gq alone, XLA dead-code-
-                # eliminates the separate dk/dv pallas_call and the
-                # two-kernel path times only HALF its backward
-                gq, gk, gv = jax.grad(loss, argnums=(0, 1, 2))(
-                    q + (acc * 1e-20).astype(q.dtype), k, v)
-                return (acc + jnp.sum(gq.astype(jnp.float32))
-                        + jnp.sum(gk.astype(jnp.float32))
-                        + jnp.sum(gv.astype(jnp.float32)))
-            return jax.lax.fori_loop(0, iters, body, jnp.float32(0))
-        return timeit(jax.jit(f), iters)
-
-    for bq, bk in [(1024, 1024), (1024, 512)]:
-        print(f"fwd-only {bq}x{bk} rate {rate}: {fwd_only(bq, bk):.2f} ms",
+    rate = float(sys.argv[1]) if len(sys.argv) > 1 else 0.1
+    lengths = [int(a) for a in sys.argv[2:]] or [512, 1024, 2048, 4096]
+    H, D = 12, 64
+    # off the chip the kernels run interpreted (rate 0 only: no TPU PRNG):
+    # a rehearsal of the script, never a timing
+    interpret = jax.default_backend() != "tpu"
+    for T in lengths:
+        B = max(1, 32768 // T)
+        rs = np.random.RandomState(0)
+        q, k, v, dout = (jnp.asarray(rs.randn(B, H, T, D) * 0.5, jnp.bfloat16)
+                         for _ in range(4))
+        mask = jnp.zeros((B, 1, 1, T), jnp.float32)
+        seed = jnp.full((1, 1), 7, jnp.int32)
+        block = fa._auto_block(T)
+        scale = 1.0 / math.sqrt(D)
+        # the forward's residuals come back as outputs, so its time here
+        # includes a copy of q, k and v that a model does not pay
+        ms_fwd, (out, res) = timeit(jax.jit(functools.partial(
+            fa._flash_fwd, rate=rate, block_q=block, block_k=block,
+            interpret=interpret)), q, k, v, mask, seed)
+        flat = tuple(x.reshape(B * H, T, D) for x in (q, k, v))
+        operands = flat + (jnp.repeat(mask[:, 0], H, axis=0), seed,
+                           dout.reshape(B * H, T, D), res[-1],
+                           out.reshape(B * H, T, D))
+        line = (f"RESULT T {T} B {B} blocks {block}x{block} rate {rate}: "
+                f"fwd {ms_fwd:.2f} ms")
+        grads = {}
+        for name, form in (("pair", fa._bwd_pair), ("fused", fa._bwd_fused)):
+            try:
+                ms, grads[name] = timeit(jax.jit(functools.partial(
+                    form, rate, scale, block, block, interpret)), operands)
+                line += f", bwd {name} {ms:.2f} ms"
+            except Exception as e:  # noqa: BLE001
+                line += (f", bwd {name} FAILED {type(e).__name__}: "
+                         f"{' '.join(str(e).split())[-120:]}")
+        if len(grads) == 2:
+            worst = max(
+                float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                      - b.astype(jnp.float32)))
+                      / jnp.max(jnp.abs(b.astype(jnp.float32))))
+                for a, b in zip(grads["fused"], grads["pair"]))
+            line += f", fused-pair largest difference {worst:.1e} of max"
+        fits = fa._bwd_fused_fits(block, block, T, D, q.dtype.itemsize)
+        print(line + f"; the gate runs {'fused' if fits else 'pair'}",
               flush=True)
-    combos = [
-        (1024, 1024, 1024, 1024),   # bwd: two-kernel
-        (1024, 1024, 1024, 512),    # bwd: fused (n_kb=4, 512k tile)
-        (1024, 1024, 512, 512),     # bwd: fused small
-        (1024, 1024, 2048, 512),    # bwd: gated? n_kb=4 but 1M tile -> pair
-    ]
-    for bq, bk, bbq, bbk in combos:
-        try:
-            ms = fwd_bwd(bq, bk, bbq, bbk)
-            print(f"fwd {bq}x{bk} + bwd {bbq}x{bbk} rate {rate}: "
-                  f"{ms:.2f} ms", flush=True)
-        except Exception as e:  # noqa: BLE001
-            print(f"fwd {bq}x{bk} + bwd {bbq}x{bbk}: FAILED "
-                  f"{type(e).__name__}: {str(e)[:120]}", flush=True)
 
 
 if __name__ == "__main__":

@@ -7,6 +7,8 @@ real chip, and was validated on v5e by extracting the kernel's masks and
 comparing against dense attention with identical masks (fwd) and dense
 autodiff (bwd)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,32 @@ import pytest
 
 from analytics_zoo_tpu.pallas.flash_attention import (_reference_attention,
                                                       flash_attention)
+
+
+def _bwd_kernels(q, block):
+    """Names of the Pallas calls in the backward of a flash call on
+    q-shaped operands (traced, nothing runs)."""
+    def loss(q, k, v):
+        return flash_attention(q, k, v, block_q=block, block_k=block,
+                               interpret=True).astype(jnp.float32).sum()
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    return sorted(set(re.findall(r"name=(flash_(?!fwd)\w+)", str(jaxpr))))
+
+
+def _assert_grad_parity(q, k, v, mask=None, **blocks):
+    """flash (interpreted) against reference autodiff, all three grads."""
+    def lf(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, mask=mask, interpret=True,
+                                       **blocks) ** 2)
+
+    def lr(q, k, v):
+        return jnp.sum(_reference_attention(q, k, v, mask) ** 2)
+
+    gf = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
 
 
 def _qkv(B=2, H=3, T=256, D=64, seed=0):
@@ -44,19 +72,7 @@ class TestFlashVJP:
         T = q.shape[2]
         mask = jnp.where(jnp.arange(T)[None, None, None, :] < T - 9,
                          0.0, -1e9) * jnp.ones((2, 1, 1, T))
-
-        def lf(q, k, v):
-            return jnp.sum(flash_attention(q, k, v, mask=mask,
-                                           interpret=True) ** 2)
-
-        def lr(q, k, v):
-            return jnp.sum(_reference_attention(q, k, v, mask) ** 2)
-
-        gf = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
+        _assert_grad_parity(q, k, v, mask)
 
     def test_non_multiple_seq_len_pads(self):
         q, k, v = _qkv(T=200)
@@ -80,26 +96,39 @@ class TestFlashVJP:
                                            interpret=True))
             np.testing.assert_allclose(o, o_ref, rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("padded", [False, True])
+    def test_gradient_parity_fused_chunks_and_blocks(self, padded):
+        # T=1024 at 512 tiles: two q-blocks, two k-blocks and four
+        # 128-column chunks a tile, all through the one-kernel backward
+        # (dq accumulates over ki in its resident scratch, dk/dv over qi)
+        q, k, v = _qkv(B=1, H=2, T=1024)
+        T = q.shape[2]
+        assert _bwd_kernels(q, block=512) == ["flash_bwd_fused"]
+        mask = None
+        if padded:
+            mask = jnp.where(jnp.arange(T)[None, None, None, :] < T - 77,
+                             0.0, -1e9) * jnp.ones((1, 1, 1, T))
+        _assert_grad_parity(q, k, v, mask, block_q=512, block_k=512)
+
     def test_gradient_parity_two_kernel_fallback(self):
-        # n_kb > 4 routes the backward through the two-kernel (dq + dkv)
-        # fallback instead of the fused kernel + dq-partials buffer —
+        # a 2048 x 2048 tile's chunks are past the fused kernel's VMEM
+        # reckoning, so the backward is the two-kernel (dq + dkv) form —
         # both must match reference autodiff
-        q, k, v = _qkv(T=768)
+        q, k, v = _qkv(B=1, H=1, T=2048, D=16)
+        assert _bwd_kernels(q, block=2048) == ["flash_dkv", "flash_dq"]
+        _assert_grad_parity(q, k, v, block_q=2048, block_k=2048)
 
-        def lf(q, k, v):
-            return jnp.sum(flash_attention(q, k, v, block_q=128,
-                                           block_k=128, bwd_block_q=128,
-                                           bwd_block_k=128,
-                                           interpret=True) ** 2)
-
-        def lr(q, k, v):
-            return jnp.sum(_reference_attention(q, k, v) ** 2)
-
-        gf = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
+    @pytest.mark.parametrize("T,block,kernels", [
+        (128, 128, ["flash_bwd_fused"]),
+        (768, 128, ["flash_bwd_fused"]),      # six k-blocks: dq resident
+        (2048, 1024, ["flash_bwd_fused"]),    # the seq-2048 fit's shape
+        (4096, 1024, ["flash_bwd_fused"]),
+        (8192, 1024, ["flash_dkv", "flash_dq"]),    # dq no longer fits
+        (1536, 768, ["flash_dkv", "flash_dq"]),     # no aligned quarter
+    ])
+    def test_backward_form_follows_the_shapes(self, T, block, kernels):
+        q = jax.ShapeDtypeStruct((2, 12, T, 64), jnp.bfloat16)
+        assert _bwd_kernels(q, block=block) == kernels
 
     def test_full_mask_takes_reference_path_even_interpreted(self):
         q, k, v = _qkv(T=128)

@@ -37,16 +37,17 @@ def _mosaic_calls(fn, *args) -> int:
     return text.count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("T,n_bwd", [(128, 1), (2048, 2)])
+@pytest.mark.parametrize("T,n_bwd", [(128, 1), (2048, 1), (8192, 2)])
 def test_flash_attention_fwd_bwd_dropout(T, n_bwd):
-    q = sds((2, 12, T, 64), jnp.bfloat16)
+    q = sds((1, 2, T, 64), jnp.bfloat16)
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, dropout_rate=0.1,
                               dropout_seed=jnp.int32(3))
         return out.astype(jnp.float32).sum()
-    # seq 2048 runs 1024x1024 tiles: past the fused backward's VMEM bound,
-    # so dq and dk/dv are two kernels
+    # one backward kernel wherever its VMEM reckoning fits (seq 2048 runs
+    # 1024x1024 tiles in 256-column chunks); at 8192 tokens dq for a whole
+    # head-batch no longer stays on the chip, so dq and dk/dv are two kernels
     assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) \
         == 1 + n_bwd
 

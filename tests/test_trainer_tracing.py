@@ -253,21 +253,25 @@ def test_the_epoch_program_names_its_parts_in_op_name():
 def test_each_flash_kernel_carries_its_name(monkeypatch):
     """Cross-lowered for the TPU from here (as
     tests/test_pallas_tpu_lowering.py does): the Mosaic calls of a flash
-    forward and backward at 2048 tokens are named flash_fwd, flash_dq and
-    flash_dkv, in the kernel's own attribute and in the name stack that
-    the compiler takes the instruction's name from."""
+    forward and backward are named flash_fwd and flash_bwd_fused (2048
+    tokens), flash_dq and flash_dkv (8192 tokens, where the backward is
+    two kernels), in the kernel's own attribute and in the name stack
+    that the compiler takes the instruction's name from."""
     from analytics_zoo_tpu.pallas.flash_attention import flash_attention
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    q = jax.ShapeDtypeStruct((2, 12, 2048, 64), jnp.bfloat16)
 
     def loss(q, k, v):
         return flash_attention(q, k, v).astype(jnp.float32).sum()
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, q).lower(
-        lowering_platforms=("tpu",)).as_text(debug_info=True)
-    assert sorted(re.findall(r'kernel_name = "([^"]+)"', text)) \
-        == ["flash_dkv", "flash_dq", "flash_fwd"]
-    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert re.search(r'loc\("[^"]*' + name + r'\)*/pallas_call"', text)
+    for T, names in ((2048, ["flash_bwd_fused", "flash_fwd"]),
+                     (8192, ["flash_dkv", "flash_dq", "flash_fwd"])):
+        q = jax.ShapeDtypeStruct((1, 2, T, 64), jnp.bfloat16)
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            q, q, q).lower(lowering_platforms=("tpu",)).as_text(
+                debug_info=True)
+        assert sorted(re.findall(r'kernel_name = "([^"]+)"', text)) == names
+        for name in names:
+            assert re.search(r'loc\("[^"]*' + name + r'\)*/pallas_call"',
+                             text)
 
 
 def test_a_span_costs_microseconds_while_no_capture_runs():
