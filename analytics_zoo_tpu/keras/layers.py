@@ -473,6 +473,26 @@ class LayerNormalization(Layer):
         return y * params["gamma"] + params["beta"]
 
 
+class RMSNormalization(Layer):
+    """Root-mean-square norm over the last axis, scale only:
+    `x / sqrt(mean(x^2) + eps) * gamma` (Zhang & Sennrich 2019; the norm
+    of today's decoder blocks). The statistic is taken in float32 whatever
+    the input's type, and the result goes back to the input's type."""
+
+    def __init__(self, epsilon: float = 1e-6, **kw):
+        super().__init__(**kw)
+        self.epsilon = epsilon
+
+    def build(self, rng, input_shape):
+        return {"gamma": jnp.ones((input_shape[-1],), jnp.float32)}
+
+    def call(self, params, x, *, training=False, rng=None):
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+        return y.astype(x.dtype) * params["gamma"]
+
+
 # ---------------------------------------------------------------------------
 # Convolutions & pooling (channels_last native)
 # ---------------------------------------------------------------------------

@@ -13,7 +13,11 @@ ops. This build is TPU-first:
 - additive attention masks broadcast [B, 1, 1, T] so GSPMD can shard B and
   heads without re-layout;
 - post-norm residual blocks matching BERT semantics (gelu FFN, LayerNorm
-  eps 1e-12).
+  eps 1e-12);
+- a decoder block of today's kind beside them (`TransformerDecoderBlock`):
+  RMS norm before and after each branch, bias-free causal self-attention
+  with rotary positions, a gated FFN; the causal mask is a flag of the
+  flash kernels, never a [T, T] operand.
 """
 
 from __future__ import annotations
@@ -25,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from analytics_zoo_tpu.keras.engine import Layer
-from analytics_zoo_tpu.keras.layers import (LayerNormalization, get_activation,
+from analytics_zoo_tpu.keras.layers import (LayerNormalization,
+                                            RMSNormalization, get_activation,
                                             get_init)
 from analytics_zoo_tpu.pallas.dropout import fused_dropout
 from analytics_zoo_tpu.pallas.flash_attention import (_reference_attention,
@@ -182,6 +187,132 @@ class TransformerEncoderBlock(Layer):
         if isinstance(input_shape, list):
             return input_shape[0]
         return input_shape
+
+
+def rotary_tables(seq_len: int, head_dim: int, theta: float = 10000.0):
+    """cos and sin of the rotary angles, each [seq_len, head_dim / 2]
+    float32: position t turns the pair (i, i + head_dim/2) by
+    t * theta^(-2i / head_dim) (Su et al. 2021, no scaling)."""
+    half = head_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rotary(x, cos, sin):
+    """Rotary positions on x [B, H, T, Dh], rotate-half pairing (dimension
+    i with i + Dh/2), over all Dh dimensions; computed in float32 and
+    returned in x's type."""
+    half = x.shape[-1] // 2
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class CausalSelfAttention(Layer):
+    """Bias-free causal self-attention with rotary positions on q and k
+    (fused [d, 3d] QKV, as many K/V heads as query heads). `call` takes
+    `[x, (cos, sin)]`: the rotary tables are made once a forward
+    (`rotary_tables`) and shared by every block. With `use_flash` the
+    Pallas kernels run with their `causal` flag; else the exact path
+    materialises the triangular mask."""
+
+    def __init__(self, hidden_size: int, n_head: int,
+                 use_flash: bool = False, **kw):
+        super().__init__(**kw)
+        if hidden_size % n_head:
+            raise ValueError(f"hidden_size {hidden_size} not divisible by "
+                             f"n_head {n_head}")
+        self.hidden_size = hidden_size
+        self.n_head = n_head
+        self.head_dim = hidden_size // n_head
+        self.use_flash = use_flash
+
+    def build(self, rng, input_shape):
+        k1, k2 = jax.random.split(rng)
+        init = get_init("glorot_uniform")
+        return {
+            "qkv_kernel": init(k1, (self.hidden_size, 3 * self.hidden_size),
+                               jnp.float32),
+            "out_kernel": init(k2, (self.hidden_size, self.hidden_size),
+                               jnp.float32),
+        }
+
+    def call(self, params, x, *, training=False, rng=None):
+        x, (cos, sin) = x
+        B, T, D = x.shape
+        qkv = maybe_int8_matmul(x, params, "qkv_kernel").astype(x.dtype)
+        qkv = qkv.reshape(B, T, 3, self.n_head, self.head_dim)
+        q, k, v = [jnp.transpose(qkv[:, :, i], (0, 2, 1, 3))
+                   for i in range(3)]
+        q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+        if self.use_flash:
+            ctx = flash_attention(q, k, v, causal=True)
+        else:
+            ctx = _reference_attention(q, k, v, causal=True)
+        ctx = jnp.transpose(ctx, (0, 2, 1, 3)).reshape(B, T, D)
+        return maybe_int8_matmul(ctx, params, "out_kernel").astype(x.dtype)
+
+    def compute_output_shape(self, input_shape):
+        return input_shape[0]
+
+
+class TransformerDecoderBlock(Layer):
+    """Decoder block with sandwich norms (four RMS norms a block):
+    `h + RMSNorm(Attn(RMSNorm(h)))`, then `h + RMSNorm(FFN(RMSNorm(h)))`
+    with the gated FFN `down(act(gate u) * (up u))`; no bias anywhere, no
+    dropout. `call` takes `[h, (cos, sin)]`; the two branches are methods
+    of their own so that a model can name each in its trace."""
+
+    def __init__(self, hidden_size: int, n_head: int, intermediate_size: int,
+                 hidden_act: str = "silu", rms_eps: float = 1e-6,
+                 use_flash: bool = False, **kw):
+        super().__init__(**kw)
+        self.hidden_size = hidden_size
+        self.intermediate_size = intermediate_size
+        self.attn = CausalSelfAttention(hidden_size, n_head,
+                                        use_flash=use_flash,
+                                        name=self.name + "_attn")
+        self.norm = RMSNormalization(rms_eps, name=self.name + "_norm")
+        self.act = get_activation(hidden_act)
+
+    def build(self, rng, input_shape):
+        shape = input_shape[0] if isinstance(input_shape, list) else input_shape
+        k1, k2, k3, k4 = jax.random.split(rng, 4)
+        init = get_init("glorot_uniform")
+        wide = (self.hidden_size, self.intermediate_size)
+        p = {name: self.norm.build(rng, shape)
+             for name in ("attn_in_norm", "attn_out_norm", "ffn_in_norm",
+                          "ffn_out_norm")}
+        p.update({
+            "attn": self.attn.build(k1, shape),
+            "ffn_gate_kernel": init(k2, wide, jnp.float32),
+            "ffn_up_kernel": init(k3, wide, jnp.float32),
+            "ffn_down_kernel": init(k4, wide[::-1], jnp.float32),
+        })
+        return p
+
+    def attention_branch(self, params, h, rotary):
+        a = self.attn.call(params["attn"], [
+            self.norm.call(params["attn_in_norm"], h), rotary])
+        return h + self.norm.call(params["attn_out_norm"], a)
+
+    def ffn_branch(self, params, h):
+        u = self.norm.call(params["ffn_in_norm"], h)
+        f = self.act(maybe_int8_matmul(u, params, "ffn_gate_kernel")) \
+            * maybe_int8_matmul(u, params, "ffn_up_kernel")
+        f = maybe_int8_matmul(f.astype(h.dtype), params,
+                              "ffn_down_kernel").astype(h.dtype)
+        return h + self.norm.call(params["ffn_out_norm"], f)
+
+    def call(self, params, x, *, training=False, rng=None):
+        h, rotary = x
+        return self.ffn_branch(params,
+                               self.attention_branch(params, h, rotary))
+
+    def compute_output_shape(self, input_shape):
+        return input_shape[0]
 
 
 class TransformerLayer(Layer):

@@ -33,6 +33,7 @@ from analytics_zoo_tpu.common.context import get_context
 from analytics_zoo_tpu.common import triggers as tg
 from analytics_zoo_tpu.observability.registry import get_registry
 from analytics_zoo_tpu.observability.tracing import get_tracer
+from analytics_zoo_tpu.ops.objectives import ProjectedLogits
 
 log = logging.getLogger("analytics_zoo_tpu.trainer")
 
@@ -843,7 +844,9 @@ def _make_one_step(apply_fn, loss_fn, optimizer, apply_and_state_fn,
             else:
                 pred, state_upd = apply_fn(p, xb, training=True,
                                            rng=rng), {}
-            if mixed_precision:
+            if mixed_precision and not isinstance(pred, ProjectedLogits):
+                # unformed logits stay in the step's type: the loss forms
+                # them block by block and accumulates in float32 itself
                 pred = jax.tree_util.tree_map(
                     lambda a: a.astype(jnp.float32), pred)
             return loss_fn(yb, pred), state_upd

@@ -17,7 +17,7 @@ Conventions (Keras semantics, as the reference follows Keras):
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +40,61 @@ def _align(y_true, y_pred):
     elif y_pred.ndim == y_true.ndim - 1 and y_true.shape[-1] == 1:
         y_pred = y_pred[..., None]
     return y_true, y_pred
+
+
+class ProjectedLogits(NamedTuple):
+    """Logits that a model hands to its loss unformed: `features`
+    [..., H] and the head's `kernel` [H, V], with logits = features @
+    kernel. A language model's training output: [B, T, V] logits in
+    float32 (and their cotangent) are gigabytes at a real vocabulary,
+    while `SparseCategoricalCrossEntropy` needs them one block of tokens
+    at a time. Any other loss calls `materialize()`."""
+    features: Array
+    kernel: Array
+
+    def materialize(self) -> Array:
+        return jnp.dot(self.features, self.kernel,
+                       preferred_element_type=jnp.float32)
+
+
+# tokens whose logits the blockwise loss forms at a time: at a vocabulary
+# of 49,152 one block is 0.2 GB in float32, and the matmul still has 1024
+# rows for the MXU
+_LOSS_BLOCK_TOKENS = 1024
+
+
+def _blockwise_sparse_nll(pred: ProjectedLogits, labels: Array) -> Array:
+    """Sum over all tokens of -log softmax(features @ kernel)[label],
+    float32, formed `_LOSS_BLOCK_TOKENS` tokens at a time: a `lax.scan`
+    over token blocks whose body is recomputed in the backward pass
+    (`jax.checkpoint`), so one block's [block, V] logits are the most
+    that is ever live, forward or backward. The kernel's gradient is
+    summed over the blocks by the scan's transpose."""
+    feats = pred.features.reshape(-1, pred.features.shape[-1])
+    labels = labels.reshape(-1)
+    n = feats.shape[0]
+    block = min(_LOSS_BLOCK_TOKENS, n)
+    pad = (-n) % block
+    weight = jnp.ones((n,), jnp.float32)
+    if pad:
+        feats = jnp.pad(feats, ((0, pad), (0, 0)))
+        labels = jnp.pad(labels, (0, pad))
+        weight = jnp.pad(weight, (0, pad))
+
+    @jax.checkpoint
+    def block_nll(kernel, f, y, w):
+        logits = jnp.dot(f, kernel, preferred_element_type=jnp.float32)
+        picked = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+        return jnp.sum(w * (jax.nn.logsumexp(logits, axis=-1) - picked))
+
+    def body(total, xs):
+        return total + block_nll(pred.kernel, *xs), None
+
+    total, _ = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        (feats.reshape(-1, block, feats.shape[-1]),
+         labels.reshape(-1, block), weight.reshape(-1, block)))
+    return total
 
 
 class Objective:
@@ -112,12 +167,22 @@ class CategoricalCrossEntropy(Objective):
 
 
 class SparseCategoricalCrossEntropy(Objective):
-    """Integer (0-based) class labels (`SparseCategoricalCrossEntropy.scala`)."""
+    """Integer (0-based) class labels (`SparseCategoricalCrossEntropy.scala`).
+    A prediction that arrives as `ProjectedLogits` (a language model's
+    training output) is never formed whole: the mean is taken a block
+    of tokens at a time, to the same number."""
 
     def __init__(self, from_logits: bool = False):
         self.from_logits = from_logits
 
     def __call__(self, y_true, y_pred):
+        if isinstance(y_pred, ProjectedLogits):
+            if not self.from_logits:
+                raise ValueError("ProjectedLogits are logits: compile the "
+                                 "loss with from_logits=True")
+            labels = jnp.asarray(y_true, jnp.int32)
+            with jax.named_scope("loss/blockwise_nll"):
+                return _blockwise_sparse_nll(y_pred, labels) / labels.size
         y_pred = _f32(y_pred)
         labels = jnp.asarray(y_true, jnp.int32)
         if labels.ndim == y_pred.ndim:  # squeeze trailing [*, 1] label dim

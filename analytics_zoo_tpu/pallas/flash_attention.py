@@ -30,6 +30,14 @@ denominator uses undropped weights (dropout applies to the normalized
 weights — `drop(p)/l == drop(p/l)`), matching the semantics of dropping
 softmax output.
 
+A causal mask is a FLAG of the kernels (`causal=True`, static), not an
+operand: tiles wholly above the diagonal are skipped in the grid's body
+(`pl.when`; their K/V or Q blocks are not fetched either — the index maps
+repeat the last needed block), tiles the diagonal crosses are masked from two
+iotas, tiles below it run the unmasked body. The causal kernels carry a
+`_causal` suffix on their names and count the causal half in their cost
+estimates. A full `[B,1,T,T]` mask operand still takes the reference path.
+
 `flash_attention` falls back to a jnp implementation when Pallas is
 unavailable for the current backend (e.g. CPU tests) — same math, no
 tiling; dropout there uses jax.random (different bits, same distribution).
@@ -48,14 +56,19 @@ from analytics_zoo_tpu.pallas.dropout import _byte_threshold
 
 
 def _reference_attention(q, k, v, mask=None, dropout_rate: float = 0.0,
-                         dropout_key=None):
+                         dropout_key=None, causal: bool = False):
     """Exact O(L²) attention — the shared non-flash numerics (also what
-    `keras.transformer.dot_product_attention` delegates to)."""
+    `keras.transformer.dot_product_attention` delegates to). `causal`
+    adds a materialised lower-triangular [T, T] mask."""
     depth = q.shape[-1]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(depth)
     scores = scores.astype(jnp.float32)
     if mask is not None:
         scores = scores + mask
+    if causal:
+        T = q.shape[2]
+        scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores,
+                           _MASKED)
     weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     if dropout_rate > 0.0 and dropout_key is not None:
         keep = 1.0 - dropout_rate
@@ -64,10 +77,16 @@ def _reference_attention(q, k, v, mask=None, dropout_rate: float = 0.0,
     return jnp.einsum("bhqk,bhkd->bhqd", weights, v)
 
 
+# what a masked score reads: finite, so a row's running maximum never
+# meets inf - inf, and far enough down that exp() of it is exactly 0
+_MASKED = -1e30
+
+
 def _flash_supported(mask) -> bool:
     """The Pallas kernel runs on TPU and supports padding masks
-    ([B,1,1,T]); full [B,1,T,T] masks or other backends use the exact
-    reference path (decided statically — no exception-driven fallback)."""
+    ([B,1,1,T]) and, as the static flag `causal`, the causal mask; a full
+    [B,1,T,T] mask operand or another backend uses the exact reference
+    path (decided statically — no exception-driven fallback)."""
     if jax.default_backend() != "tpu":
         return False
     if mask is not None and mask.ndim == 4 and mask.shape[2] != 1:
@@ -93,12 +112,14 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
                     dropout_seed: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    causal: bool = False):
     """q,k,v: [B, H, T, Dh]. mask: additive [B,1,1,T] (padding) or
-    [B,1,T,T] (full; reference path only). `dropout_rate` > 0 needs
-    `dropout_seed` (scalar int32). Differentiable (custom VJP); the mask
-    receives a zero cotangent (padding masks are data, not parameters).
-    Returns [B, H, T, Dh].
+    [B,1,T,T] (full; reference path only). `causal` (static) masks every
+    key after the query's own position, inside the kernels. `dropout_rate`
+    > 0 needs `dropout_seed` (scalar int32). Differentiable (custom VJP);
+    the mask receives a zero cotangent (padding masks are data, not
+    parameters). Returns [B, H, T, Dh].
 
     Block sizes default to the largest 128-multiple divisor of T up to
     1024: per-tile work must amortize the DMA + softmax-state overhead —
@@ -116,7 +137,7 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         key = jax.random.PRNGKey(dropout_seed) if use_dropout else None
         return _reference_attention(q, k, v, mask,
                                     dropout_rate if use_dropout else 0.0,
-                                    key)
+                                    key, causal)
     if not (_flash_supported(mask) or interpret):
         key = None
         if use_dropout:
@@ -125,7 +146,7 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
                                      else dropout_seed)
         return _reference_attention(q, k, v, mask,
                                     dropout_rate if use_dropout else 0.0,
-                                    key)
+                                    key, causal)
     B, H, T, D = q.shape
     if block_q is None:
         block_q = _auto_block(T)
@@ -142,23 +163,81 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         maskp = jnp.pad(mask, ((0, 0), (0, 0), (0, 0), (0, pad)),
                         constant_values=-1e9)
         out = flash_attention(qp, kp, vp, maskp, dropout_rate, dropout_seed,
-                              block_q, block_k, interpret)
+                              block_q, block_k, interpret, causal)
         return out[:, :, :T]
     seed = jnp.asarray(dropout_seed if use_dropout else 0,
                        jnp.int32).reshape(1, 1)
     rate = float(dropout_rate) if use_dropout else 0.0
     return _flash(q, k, v, mask, seed, rate, block_q, block_k,
-                  bool(interpret) if interpret is not None else False)
+                  bool(interpret) if interpret is not None else False,
+                  bool(causal))
 
 
 # ---------------------------------------------------------------------------
 # custom-VJP core (assumes T % lcm(block_q, block_k) == 0, mask [B,1,1,T])
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash(q, k, v, mask, seed, rate, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, mask, seed, rate, block_q, block_k, interpret, causal):
     out, _ = _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k,
-                        interpret)
+                        interpret, causal)
     return out
+
+
+# -- the causal mask, tile by tile --------------------------------------------
+# A [block_q, block_k] tile (qi, ki) holds query rows qi·block_q.. and key
+# columns ki·block_k..; key c is seen by query r iff c <= r.
+def _tile_needed(qi, ki, block_q, block_k):
+    """Not wholly above the diagonal: its first column is seen by its
+    last row."""
+    return ki * block_k <= qi * block_q + (block_q - 1)
+
+
+def _tile_unmasked(qi, ki, block_q, block_k):
+    """Wholly on or below the diagonal: its last column is seen by its
+    first row."""
+    return ki * block_k + (block_k - 1) <= qi * block_q
+
+
+def _last_k_block(qi, block_q, block_k):
+    """The last k-block a q-block needs (`_tile_needed`)."""
+    return (qi * block_q + (block_q - 1)) // block_k
+
+
+def _first_q_block(ki, block_q, block_k):
+    """The first q-block that needs a k-block (`_tile_needed`)."""
+    return (ki * block_k) // block_q
+
+
+def _causal_scores(scores, row0, col0):
+    """`scores` of query rows row0.. and key columns col0.. with every key
+    after the query's own position set to `_MASKED`. Column 0 of the first
+    k-block is seen by every row, so a row's running maximum is finite
+    before it meets a fully masked stretch."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    return jnp.where(cols <= rows, scores, _MASKED)
+
+
+def _kernel_name(name, causal):
+    """The name the compiler puts on the kernel's instruction, which the
+    benchmark's per-kernel metrics match (docs/ProgrammingGuide/
+    observability.md)."""
+    return name + "_causal" if causal else name
+
+
+def _on_causal_tiles(causal, qi, ki, block_q, block_k, tile):
+    """Run `tile(masked)` for grid step (qi, ki): always and unmasked
+    without `causal`; else not at all above the diagonal, masked where the
+    diagonal crosses the tile, unmasked below it."""
+    from jax.experimental import pallas as pl
+
+    if not causal:
+        tile(False)
+        return
+    unmasked = _tile_unmasked(qi, ki, block_q, block_k)
+    pl.when(unmasked)(lambda: tile(False))
+    pl.when(jnp.logical_and(_tile_needed(qi, ki, block_q, block_k),
+                            jnp.logical_not(unmasked)))(lambda: tile(True))
 
 
 def _tile_words(s_ref, n_qb, n_kb, qi, ki, shape):
@@ -210,8 +289,8 @@ def _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki, shape):
                     0, shape[1])
 
 
-def _fwd_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
-                o_ref, lse_ref, acc_sc, m_sc, l_sc):
+def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
+                s_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -225,25 +304,31 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
         m_sc[...] = jnp.full_like(m_sc, -jnp.inf)
         l_sc[...] = jnp.zeros_like(l_sc)
 
-    qb = q_ref[0]                                          # [bq, D]
-    kb = k_ref[0]
-    vb = v_ref[0]
-    mb = m_ref[0]                                          # [1, bk]
-    scores = jnp.dot(qb, kb.T,
-                     preferred_element_type=jnp.float32) * scale + mb
-    m_prev, l_prev = m_sc[...], l_sc[...]
-    m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)
-    if rate > 0.0:
-        p_drop = p * _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki,
-                                 (block_q, block_k))
-    else:
-        p_drop = p
-    acc_sc[...] = acc_sc[...] * alpha + jnp.dot(
-        p_drop.astype(v_ref.dtype), vb, preferred_element_type=jnp.float32)
-    m_sc[...] = m_new
-    l_sc[...] = l_prev * alpha + p.sum(axis=1, keepdims=True)
+    def tile(masked):
+        qb = q_ref[0]                                      # [bq, D]
+        kb = k_ref[0]
+        vb = v_ref[0]
+        mb = m_ref[0]                                      # [1, bk]
+        scores = jnp.dot(qb, kb.T,
+                         preferred_element_type=jnp.float32) * scale + mb
+        if masked:
+            scores = _causal_scores(scores, qi * block_q, ki * block_k)
+        m_prev, l_prev = m_sc[...], l_sc[...]
+        m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        if rate > 0.0:
+            p_drop = p * _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki,
+                                     (block_q, block_k))
+        else:
+            p_drop = p
+        acc_sc[...] = acc_sc[...] * alpha + jnp.dot(
+            p_drop.astype(v_ref.dtype), vb,
+            preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+        l_sc[...] = l_prev * alpha + p.sum(axis=1, keepdims=True)
+
+    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile)
 
     @pl.when(ki == n_kb - 1)
     def _flush():
@@ -251,26 +336,31 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
         lse_ref[0] = m_sc[...] + jnp.log(l_sc[...])        # [bq, 1]
 
 
-def _attn_cost(n_matmuls, q, extra_f32_out_elems=0):
+def _attn_cost(n_matmuls, q, extra_f32_out_elems=0, causal=False):
     """Analytic roofline model for one attention kernel over [..., T, D]
     (check_pallas_cost lint: HLO cost analysis sees ~0 inside a Mosaic
     call). `n_matmuls` counts the T×T×D matmul-shaped products the
     kernel runs per head (2 flops each); bytes are the O(T·D) streams —
     q/k/v-sized reads and writes — NOT the O(T²) scores, which is the
-    IO-aware point of flash attention; exp() is one per score."""
+    IO-aware point of flash attention; exp() is one per score. A causal
+    kernel is counted at the lower triangle: half the products and half
+    the scores (what the algorithm needs; the tiles the diagonal crosses
+    are computed whole)."""
     from jax.experimental import pallas as pl
 
     *lead, T, D = q.shape
     bh = math.prod(lead)
     item = jnp.dtype(q.dtype).itemsize
     streams = 4 + n_matmuls  # rough: q,k,v(+dout...) in, grads/out out
+    half = 2 if causal else 1
     return pl.CostEstimate(
-        flops=2 * n_matmuls * bh * T * T * D,
+        flops=2 * n_matmuls * bh * T * T * D // half,
         bytes_accessed=bh * T * D * item * streams + extra_f32_out_elems * 4,
-        transcendentals=bh * T * T)
+        transcendentals=bh * T * T // half)
 
 
-def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
+               causal):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -282,14 +372,19 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret):
     vf = v.reshape(B * H, T, D)
     mf = jnp.repeat(mask[:, 0, :, :], H, axis=0)           # [B*H, 1, T]
 
+    def kj(i, j):
+        # a skipped step names the block it already holds: no DMA
+        return jnp.minimum(j, _last_k_block(i, block_q, block_k)) \
+            if causal else j
+
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, rate, scale, n_qb, n_kb),
+        functools.partial(_fwd_kernel, rate, scale, n_qb, n_kb, causal),
         grid=(B * H, n_qb, n_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kj(i, j), 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kj(i, j), 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, kj(i, j))),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
@@ -308,9 +403,10 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=_attn_cost(2, q,                    # QKᵀ + PV
-                                 extra_f32_out_elems=B * H * T),
+                                 extra_f32_out_elems=B * H * T,
+                                 causal=causal),
         interpret=interpret,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", causal),
     )(qf, kf, vf, mf, seed)
     out = out.reshape(B, H, T, D)
     return out, (q, k, v, mask, seed, out, lse)
@@ -325,8 +421,8 @@ def _delta(do_ref, o_ref):
                    * o_ref[0].astype(jnp.float32), axis=1, keepdims=True)
 
 
-def _dq_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
-               do_ref, lse_ref, o_ref, dq_ref, dq_sc):
+def _dq_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
+               s_ref, do_ref, lse_ref, o_ref, dq_ref, dq_sc):
     """Standalone dq (accumulate over ki in scratch): half of the
     two-kernel backward for shapes the fused kernel's VMEM need rules out
     — see _flash_bwd."""
@@ -341,31 +437,36 @@ def _dq_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    qb = q_ref[0]
-    kb = k_ref[0]
-    vb = v_ref[0]
-    mb = m_ref[0]
-    dob = do_ref[0]
-    lse = lse_ref[0]                                       # [bq, 1]
-    delta = _delta(do_ref, o_ref)                          # [bq, 1]
-    pnorm = jnp.exp(jnp.dot(qb, kb.T,
-                            preferred_element_type=jnp.float32)
-                    * scale + mb - lse)                    # softmax weights
-    dw = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
-    if rate > 0.0:
-        dw = dw * _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki,
-                              (block_q, block_k))
-    ds = pnorm * (dw - delta)                              # [bq, bk]
-    dq_sc[...] += jnp.dot(ds.astype(k_ref.dtype), kb,
-                          preferred_element_type=jnp.float32)
+    def tile(masked):
+        qb = q_ref[0]
+        kb = k_ref[0]
+        vb = v_ref[0]
+        mb = m_ref[0]
+        dob = do_ref[0]
+        lse = lse_ref[0]                                   # [bq, 1]
+        delta = _delta(do_ref, o_ref)                      # [bq, 1]
+        scores = jnp.dot(qb, kb.T,
+                         preferred_element_type=jnp.float32) * scale + mb
+        if masked:
+            scores = _causal_scores(scores, qi * block_q, ki * block_k)
+        pnorm = jnp.exp(scores - lse)                      # softmax weights
+        dw = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
+        if rate > 0.0:
+            dw = dw * _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki,
+                                  (block_q, block_k))
+        ds = pnorm * (dw - delta)                          # [bq, bk]
+        dq_sc[...] += jnp.dot(ds.astype(k_ref.dtype), kb,
+                              preferred_element_type=jnp.float32)
+
+    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile)
 
     @pl.when(ki == n_kb - 1)
     def _flush():
         dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
-                do_ref, lse_ref, o_ref, dk_ref, dv_ref, dk_sc, dv_sc):
+def _dkv_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
+                s_ref, do_ref, lse_ref, o_ref, dk_ref, dv_ref, dk_sc, dv_sc):
     """dk/dv-only companion of _dq_kernel."""
     from jax.experimental import pallas as pl
 
@@ -379,29 +480,34 @@ def _dkv_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref, s_ref,
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    qb = q_ref[0]
-    kb = k_ref[0]
-    vb = v_ref[0]
-    mb = m_ref[0]                                          # [1, bk]
-    dob = do_ref[0]
-    lse = lse_ref[0]                                       # [bq, 1]
-    delta = _delta(do_ref, o_ref)
-    pnorm = jnp.exp(jnp.dot(qb, kb.T,
-                            preferred_element_type=jnp.float32)
-                    * scale + mb - lse)                    # [bq, bk]
-    dw = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
-    if rate > 0.0:
-        keep_scale = _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki,
-                                 (block_q, block_k))
-        dw = dw * keep_scale
-        dv_p = pnorm * keep_scale
-    else:
-        dv_p = pnorm
-    ds = pnorm * (dw - delta)
-    dk_sc[...] += jnp.dot(ds.T.astype(q_ref.dtype), qb,
-                          preferred_element_type=jnp.float32)
-    dv_sc[...] += jnp.dot(dv_p.T.astype(do_ref.dtype), dob,
-                          preferred_element_type=jnp.float32)
+    def tile(masked):
+        qb = q_ref[0]
+        kb = k_ref[0]
+        vb = v_ref[0]
+        mb = m_ref[0]                                      # [1, bk]
+        dob = do_ref[0]
+        lse = lse_ref[0]                                   # [bq, 1]
+        delta = _delta(do_ref, o_ref)
+        scores = jnp.dot(qb, kb.T,
+                         preferred_element_type=jnp.float32) * scale + mb
+        if masked:
+            scores = _causal_scores(scores, qi * block_q, ki * block_k)
+        pnorm = jnp.exp(scores - lse)                      # [bq, bk]
+        dw = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
+        if rate > 0.0:
+            keep_scale = _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki,
+                                     (block_q, block_k))
+            dw = dw * keep_scale
+            dv_p = pnorm * keep_scale
+        else:
+            dv_p = pnorm
+        ds = pnorm * (dw - delta)
+        dk_sc[...] += jnp.dot(ds.T.astype(q_ref.dtype), qb,
+                              preferred_element_type=jnp.float32)
+        dv_sc[...] += jnp.dot(dv_p.T.astype(do_ref.dtype), dob,
+                              preferred_element_type=jnp.float32)
+
+    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile)
 
     @pl.when(qi == n_qb - 1)
     def _flush():
@@ -446,8 +552,8 @@ def _bwd_fused_fits(block_q, block_k, T, D, itemsize) -> bool:
     return resident_dq + streams + live <= _BWD_FUSED_VMEM_BYTES
 
 
-def _bwd_fused_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref,
-                      s_ref, do_ref, lse_ref, o_ref, dq_ref, dk_ref,
+def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
+                      m_ref, s_ref, do_ref, lse_ref, o_ref, dq_ref, dk_ref,
                       dv_ref, dq_sc, dk_sc, dv_sc):
     """ONE backward kernel: the weights, dW and the dropout mask of a tile
     are computed once and feed dq, dk and dv — 5 matmuls a tile where the
@@ -477,33 +583,40 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref,
     def _init_dq():
         dq_sc[rows, :] = jnp.zeros((block_q, dq_sc.shape[1]), jnp.float32)
 
-    qb = q_ref[0]
-    dob = do_ref[0]
-    lse = lse_ref[0]                                       # [bq, 1]
-    delta = _delta(do_ref, o_ref)
-    if rate > 0.0:
-        words = _tile_words(s_ref, n_qb, n_kb, qi, ki, (block_q, block_k))
-    for lo in range(0, block_k, chunk):
-        cols = slice(lo, lo + chunk)
-        kc = k_ref[0, cols, :]
-        vc = v_ref[0, cols, :]
-        pnorm = jnp.exp(jnp.dot(qb, kc.T,
-                                preferred_element_type=jnp.float32)
-                        * scale + m_ref[0, :, cols] - lse)  # [bq, chunk]
-        dw = jnp.dot(dob, vc.T, preferred_element_type=jnp.float32)
+    def tile(masked):
+        qb = q_ref[0]
+        dob = do_ref[0]
+        lse = lse_ref[0]                                   # [bq, 1]
+        delta = _delta(do_ref, o_ref)
         if rate > 0.0:
-            keep_scale = _keep_of(words, rate, lo, lo + chunk)
-            dw = dw * keep_scale
-            dv_p = pnorm * keep_scale
-        else:
-            dv_p = pnorm
-        ds = pnorm * (dw - delta)
-        dq_sc[rows, :] += jnp.dot(ds.astype(k_ref.dtype), kc,
-                                  preferred_element_type=jnp.float32)
-        dk_sc[cols, :] += jnp.dot(ds.T.astype(q_ref.dtype), qb,
-                                  preferred_element_type=jnp.float32)
-        dv_sc[cols, :] += jnp.dot(dv_p.T.astype(do_ref.dtype), dob,
-                                  preferred_element_type=jnp.float32)
+            words = _tile_words(s_ref, n_qb, n_kb, qi, ki,
+                                (block_q, block_k))
+        for lo in range(0, block_k, chunk):
+            cols = slice(lo, lo + chunk)
+            kc = k_ref[0, cols, :]
+            vc = v_ref[0, cols, :]
+            scores = jnp.dot(qb, kc.T, preferred_element_type=jnp.float32) \
+                * scale + m_ref[0, :, cols]
+            if masked:
+                scores = _causal_scores(scores, qi * block_q,
+                                        ki * block_k + lo)
+            pnorm = jnp.exp(scores - lse)                  # [bq, chunk]
+            dw = jnp.dot(dob, vc.T, preferred_element_type=jnp.float32)
+            if rate > 0.0:
+                keep_scale = _keep_of(words, rate, lo, lo + chunk)
+                dw = dw * keep_scale
+                dv_p = pnorm * keep_scale
+            else:
+                dv_p = pnorm
+            ds = pnorm * (dw - delta)
+            dq_sc[rows, :] += jnp.dot(ds.astype(k_ref.dtype), kc,
+                                      preferred_element_type=jnp.float32)
+            dk_sc[cols, :] += jnp.dot(ds.T.astype(q_ref.dtype), qb,
+                                      preferred_element_type=jnp.float32)
+            dv_sc[cols, :] += jnp.dot(dv_p.T.astype(do_ref.dtype), dob,
+                                      preferred_element_type=jnp.float32)
+
+    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile)
 
     @pl.when(ki == n_kb - 1)
     def _flush_dq():
@@ -515,16 +628,24 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, q_ref, k_ref, v_ref, m_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _bwd_in_specs(block_q, block_k, D, q_major):
+def _bwd_in_specs(block_q, block_k, D, q_major, causal):
     """Input BlockSpecs shared by the three backward kernels (q, k, v,
     mask, seed, dO, lse, O), with the q- and the k-block spec. A
-    `q_major` grid is (bh, qi, ki), the other (bh, ki, qi)."""
+    `q_major` grid is (bh, qi, ki), the other (bh, ki, qi). With `causal`
+    the inner axis's blocks stop at the diagonal: a skipped step names the
+    nearest needed block, which the pipeline already holds."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     def spec(shape, of_q, at):
         axis = 1 if of_q == q_major else 2
-        return pl.BlockSpec(shape, lambda *g: at(g[0], g[axis]))
+        if not causal or axis == 1:
+            return pl.BlockSpec(shape, lambda *g: at(g[0], g[axis]))
+        if q_major:     # inner axis walks k-blocks: none past the diagonal
+            return pl.BlockSpec(shape, lambda b, i, j: at(b, jnp.minimum(
+                j, _last_k_block(i, block_q, block_k))))
+        return pl.BlockSpec(shape, lambda b, j, i: at(b, jnp.maximum(
+            i, _first_q_block(j, block_q, block_k))))
     q_spec = spec((1, block_q, D), True, lambda b, i: (b, i, 0))
     k_spec = spec((1, block_k, D), False, lambda b, j: (b, j, 0))
     m_spec = spec((1, 1, block_k), False, lambda b, j: (b, 0, j))
@@ -534,17 +655,19 @@ def _bwd_in_specs(block_q, block_k, D, q_major):
              q_spec], q_spec, k_spec)
 
 
-def _bwd_fused(rate, scale, block_q, block_k, interpret, operands):
+def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     qf = operands[0]
     BH, T, D = qf.shape
     n_qb, n_kb = T // block_q, T // block_k
-    in_specs, _, k_spec = _bwd_in_specs(block_q, block_k, D, q_major=False)
+    in_specs, _, k_spec = _bwd_in_specs(block_q, block_k, D, q_major=False,
+                                        causal=causal)
     grad = jax.ShapeDtypeStruct((BH, T, D), qf.dtype)
     return pl.pallas_call(
-        functools.partial(_bwd_fused_kernel, rate, scale, n_qb, n_kb),
+        functools.partial(_bwd_fused_kernel, rate, scale, n_qb, n_kb,
+                          causal),
         grid=(BH, n_kb, n_qb),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, T, D), lambda b, j, i: (b, 0, 0)),
@@ -558,13 +681,13 @@ def _bwd_fused(rate, scale, block_q, block_k, interpret, operands):
         # dq is revisited over both inner axes
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        cost_estimate=_attn_cost(5, qf),  # scores, dw, dq, dk, dv
-        interpret=interpret,
-        name="flash_bwd_fused",
+        cost_estimate=_attn_cost(5, qf, causal=causal),  # scores, dw, dq,
+        interpret=interpret,                             # dk, dv
+        name=_kernel_name("flash_bwd_fused", causal),
     )(*operands)
 
 
-def _bwd_pair(rate, scale, block_q, block_k, interpret, operands):
+def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -574,22 +697,24 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, operands):
     grad = jax.ShapeDtypeStruct((BH, T, D), qf.dtype)
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
-    in_specs, q_spec, _ = _bwd_in_specs(block_q, block_k, D, q_major=True)
+    in_specs, q_spec, _ = _bwd_in_specs(block_q, block_k, D, q_major=True,
+                                        causal=causal)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, rate, scale, n_qb, n_kb),
+        functools.partial(_dq_kernel, rate, scale, n_qb, n_kb, causal),
         grid=(BH, n_qb, n_kb),
         in_specs=in_specs,
         out_specs=q_spec,
         out_shape=grad,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=semantics,
-        cost_estimate=_attn_cost(3, qf),   # scores, dw/ds, dq
+        cost_estimate=_attn_cost(3, qf, causal=causal),  # scores, dw/ds, dq
         interpret=interpret,
-        name="flash_dq",
+        name=_kernel_name("flash_dq", causal),
     )(*operands)
-    in_specs, _, k_spec = _bwd_in_specs(block_q, block_k, D, q_major=False)
+    in_specs, _, k_spec = _bwd_in_specs(block_q, block_k, D, q_major=False,
+                                        causal=causal)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, rate, scale, n_qb, n_kb),
+        functools.partial(_dkv_kernel, rate, scale, n_qb, n_kb, causal),
         grid=(BH, n_kb, n_qb),
         in_specs=in_specs,
         out_specs=[k_spec, k_spec],
@@ -599,14 +724,14 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, operands):
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         compiler_params=semantics,
-        cost_estimate=_attn_cost(4, qf),   # scores, dv, ds, dk
+        cost_estimate=_attn_cost(4, qf, causal=causal),  # scores, dv, ds, dk
         interpret=interpret,
-        name="flash_dkv",
+        name=_kernel_name("flash_dkv", causal),
     )(*operands)
     return dq, dk, dv
 
 
-def _flash_bwd(rate, block_q, block_k, interpret, res, dout):
+def _flash_bwd(rate, block_q, block_k, interpret, causal, res, dout):
     q, k, v, mask, seed, out, lse = res
     B, H, T, D = q.shape
     scale = 1.0 / math.sqrt(D)
@@ -619,7 +744,7 @@ def _flash_bwd(rate, block_q, block_k, interpret, res, dout):
     # the forward's tiling — the dropout mask is keyed by tile.
     fused = _bwd_fused_fits(block_q, block_k, T, D, q.dtype.itemsize)
     dq, dk, dv = (_bwd_fused if fused else _bwd_pair)(
-        rate, scale, block_q, block_k, interpret,
+        rate, scale, block_q, block_k, interpret, causal,
         (qf, kf, vf, mf, seed, dof, lse, of))
     shape = (B, H, T, D)
     # padding masks are data, not parameters — zero cotangent

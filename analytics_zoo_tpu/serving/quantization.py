@@ -97,6 +97,7 @@ def maybe_int8_matmul(x, params, key: str):
 _RAW_INT8_KERNELS = frozenset({
     "qkv_kernel", "out_kernel", "ffn_in_kernel", "ffn_out_kernel",
     "pooler_kernel", "cls_kernel", "ner_kernel", "qa_kernel",
+    "ffn_gate_kernel", "ffn_up_kernel", "ffn_down_kernel", "lm_head_kernel",
 })
 
 
@@ -170,6 +171,10 @@ def quantize_model_params(model, params) -> Dict[str, Any]:
                 q, scale = _quantize_tensor(out[head], (0,))
                 del out[head]
                 out[head + "_q"], out[head + "_scale"] = q, scale
+    from analytics_zoo_tpu.models.looped_decoder import LoopedDecoderLM
+    if isinstance(model, LoopedDecoderLM):
+        # no layer list either, and the whole tree is the model's own
+        return _quantize_raw_kernels(out)
     for layer in _iter_layers(model):
         sub = out.get(layer.name)
         if sub is None:

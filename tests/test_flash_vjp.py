@@ -18,24 +18,26 @@ from analytics_zoo_tpu.pallas.flash_attention import (_reference_attention,
                                                       flash_attention)
 
 
-def _bwd_kernels(q, block):
+def _bwd_kernels(q, block, causal=False):
     """Names of the Pallas calls in the backward of a flash call on
     q-shaped operands (traced, nothing runs)."""
     def loss(q, k, v):
         return flash_attention(q, k, v, block_q=block, block_k=block,
-                               interpret=True).astype(jnp.float32).sum()
+                               interpret=True, causal=causal
+                               ).astype(jnp.float32).sum()
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
     return sorted(set(re.findall(r"name=(flash_(?!fwd)\w+)", str(jaxpr))))
 
 
-def _assert_grad_parity(q, k, v, mask=None, **blocks):
+def _assert_grad_parity(q, k, v, mask=None, causal=False, **blocks):
     """flash (interpreted) against reference autodiff, all three grads."""
     def lf(q, k, v):
         return jnp.sum(flash_attention(q, k, v, mask=mask, interpret=True,
-                                       **blocks) ** 2)
+                                       causal=causal, **blocks) ** 2)
 
     def lr(q, k, v):
-        return jnp.sum(_reference_attention(q, k, v, mask) ** 2)
+        return jnp.sum(_reference_attention(q, k, v, mask,
+                                            causal=causal) ** 2)
 
     gf = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
@@ -129,6 +131,71 @@ class TestFlashVJP:
     def test_backward_form_follows_the_shapes(self, T, block, kernels):
         q = jax.ShapeDtypeStruct((2, 12, T, 64), jnp.bfloat16)
         assert _bwd_kernels(q, block=block) == kernels
+
+    @pytest.mark.parametrize("T,D,kernels", [
+        # the seq-4096 decoder fit's shape: 16 heads of 128, 1024 tiles
+        (4096, 128, ["flash_bwd_fused_causal"]),
+        (4096, 64, ["flash_bwd_fused_causal"]),
+        (8192, 128, ["flash_dkv_causal", "flash_dq_causal"]),
+    ])
+    def test_causal_backward_form_and_names(self, T, D, kernels):
+        # the causal flag changes the kernels' names, never their form
+        q = jax.ShapeDtypeStruct((2, 16, T, D), jnp.bfloat16)
+        assert _bwd_kernels(q, block=1024, causal=True) == kernels
+        assert _bwd_kernels(q, block=1024) == [
+            k.replace("_causal", "") for k in kernels]
+
+    @pytest.mark.parametrize("D", [64, 128])
+    @pytest.mark.parametrize("T,bq,bk,kernels", [
+        # one-kernel backward: a diagonal of masked tiles, tiles skipped
+        # above it, unmasked tiles below it, four chunks a tile
+        (1024, 512, 512, ["flash_bwd_fused_causal"]),
+        # oblong tiles either way: the diagonal crosses two k-blocks of a
+        # q-block, or two q-blocks of a k-block
+        (512, 256, 128, ["flash_bwd_fused_causal"]),
+        (512, 128, 256, ["flash_bwd_fused_causal"]),
+        # two-kernel backward (a 768 tile has no 128-aligned quarter)
+        (1536, 768, 768, ["flash_dkv_causal", "flash_dq_causal"]),
+    ])
+    def test_causal_forward_and_gradient_parity(self, T, bq, bk, kernels, D):
+        q, k, v = _qkv(B=1, H=2, T=T, D=D)
+        if bq == bk:
+            assert _bwd_kernels(q, block=bq, causal=True) == kernels
+        o1 = np.asarray(flash_attention(q, k, v, block_q=bq, block_k=bk,
+                                        interpret=True, causal=True))
+        o2 = np.asarray(_reference_attention(q, k, v, causal=True))
+        np.testing.assert_allclose(o1, o2, rtol=1e-5, atol=1e-5)
+        _assert_grad_parity(q, k, v, causal=True, block_q=bq, block_k=bk)
+
+    def test_causal_with_a_padding_mask_and_a_padded_length(self):
+        # T = 200 pads to 256 with masked keys; causal on top of it
+        q, k, v = _qkv(B=2, H=2, T=200)
+        T = q.shape[2]
+        mask = jnp.where(jnp.arange(T)[None, None, None, :] < T - 9,
+                         0.0, -1e9) * jnp.ones((2, 1, 1, T))
+        o1 = np.asarray(flash_attention(q, k, v, mask=mask, interpret=True,
+                                        causal=True))
+        o2 = np.asarray(_reference_attention(q, k, v, mask, causal=True))
+        np.testing.assert_allclose(o1, o2, rtol=1e-5, atol=1e-5)
+
+    def test_causal_off_the_tpu_is_the_reference_with_a_triangle(self):
+        # decided statically: no kernel, a materialised lower triangle
+        q, k, v = _qkv(T=128)
+        T = 128
+        tri = jnp.where(jnp.arange(T)[:, None] >= jnp.arange(T)[None, :],
+                        0.0, -1e9)[None, None]
+        jaxpr = str(jax.make_jaxpr(
+            lambda *a: flash_attention(*a, causal=True))(q, k, v))
+        assert "pallas_call" not in jaxpr
+        np.testing.assert_allclose(
+            np.asarray(flash_attention(q, k, v, causal=True)),
+            np.asarray(_reference_attention(q, k, v, tri)),
+            rtol=1e-5, atol=1e-5)
+        # later keys move nothing earlier
+        v2 = v.at[:, :, 100:].add(3.0)
+        np.testing.assert_array_equal(
+            np.asarray(flash_attention(q, k, v, causal=True))[:, :, :100],
+            np.asarray(flash_attention(q, k, v2, causal=True))[:, :, :100])
 
     def test_full_mask_takes_reference_path_even_interpreted(self):
         q, k, v = _qkv(T=128)

@@ -52,6 +52,69 @@ def test_flash_attention_fwd_bwd_dropout(T, n_bwd):
         == 1 + n_bwd
 
 
+@pytest.mark.parametrize("T,D,n_bwd", [(4096, 128, 1), (4096, 64, 1),
+                                       (8192, 128, 2)])
+def test_flash_attention_causal_fwd_bwd(T, D, n_bwd):
+    q = sds((2, 16, T, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+    # the causal flag keeps the backward's form: one kernel at the
+    # seq-4096 decoder fit's shape (16 heads of 128), the pair at 8192
+    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) \
+        == 1 + n_bwd
+
+
+def _mosaic_digests(fn, *args):
+    """A digest of every Mosaic module in the TPU lowering of `fn`, printed
+    WITHOUT source locations: the module that `tpu_custom_call` carries is
+    MLIR bytecode with the kernel's Python line numbers in it, so the
+    lowered text itself moves with every edit of the file."""
+    import base64
+    import hashlib
+    import re
+
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    out = []
+    for m in re.finditer(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text):
+        ctx = jmlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(m.group(1))) \
+                .operation.get_asm(enable_debug_info=False)
+        out.append(hashlib.sha256(asm.encode()).hexdigest()[:16])
+    return out
+
+
+@pytest.mark.parametrize("B,T,digests", [
+    # bert-base-pos2048.fit-seq2048-flash: forward and the one backward
+    (16, 2048, ["6d1e6e9ef29c8231", "1db37abbc2ba0f14"]),
+    # the two-kernel backward: forward, dq, dk/dv
+    (2, 8192, ["630378003e1117a3", "374bc763c8822ec1", "223abe34395f4d43"]),
+])
+def test_noncausal_kernels_are_pr25s_instruction_for_instruction(B, T,
+                                                                 digests):
+    """The causal flag is static: without it the kernels lower to the
+    modules they lowered to at commit 07fb4ab (PR 25), whose digests
+    these are (made by this function on that tree). A change to the
+    non-causal kernels has to change them here, knowingly."""
+    q = sds((B, 12, T, 64), jnp.bfloat16)
+    mask = sds((B, 1, 1, T), jnp.float32)
+
+    def loss(q, k, v, m):
+        out = flash_attention(q, k, v, mask=m, dropout_rate=0.1,
+                              dropout_seed=jnp.int32(3))
+        return out.astype(jnp.float32).sum()
+    assert _mosaic_digests(jax.grad(loss, argnums=(0, 1, 2)),
+                           q, q, q, mask) == digests
+
+
 @pytest.mark.parametrize("H,D,L", [(4, 64, 256), (2, 8, 128)])
 def test_decode_attention(H, D, L):
     S = 8

@@ -159,6 +159,71 @@ class TestFusedBackwardFits:
         assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
+class TestCausalFlashOnChip:
+    """The causal form of the kernels at the seq-4096 decoder fit's shape
+    (heads of 128, 1024 x 1024 tiles): numbers against plain attention
+    with a triangular mask, and the edges of `_bwd_fused_fits` there."""
+
+    def test_seq4096_d128_forward_and_grads_match_reference(self):
+        from analytics_zoo_tpu.pallas.flash_attention import (
+            _reference_attention, flash_attention)
+        q, k, v = (x.astype(jnp.bfloat16)
+                   for x in _qkv(B=1, H=2, T=4096, D=128, seed=5))
+        got = flash_attention(q, k, v, causal=True).astype(jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+            ref = _reference_attention(*f32, causal=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-2, atol=4e-3)
+
+        def loss(attn, q, k, v):
+            return jnp.sum(attn(q, k, v, causal=True).astype(
+                jnp.float32) ** 2)
+        gf = jax.grad(lambda *a: loss(flash_attention, *a),
+                      argnums=(0, 1, 2))(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            gr = jax.grad(lambda *a: loss(_reference_attention, *a),
+                          argnums=(0, 1, 2))(*f32)
+        for a, b in zip(gf, gr):
+            a, b = np.asarray(a.astype(jnp.float32)), np.asarray(b)
+            assert np.linalg.norm(a - b) <= 0.02 * np.linalg.norm(b)
+        # later keys and values move nothing earlier
+        v2 = v.at[:, :, 3000:].add(1.0)
+        np.testing.assert_array_equal(
+            np.asarray(got[:, :, :3000]),
+            np.asarray(flash_attention(q, k, v2, causal=True).astype(
+                jnp.float32)[:, :, :3000]))
+
+    @pytest.mark.parametrize("T,D,dtype,kernels", [
+        # T = 4096 at 1024 tiles reckons 15.0 MiB of the 15 allowed; one
+        # more tile of T and dq no longer stays on the chip
+        (4096, 128, jnp.bfloat16, ["flash_bwd_fused_causal",
+                                   "flash_fwd_causal"]),
+        (5120, 128, jnp.bfloat16, ["flash_dkv_causal", "flash_dq_causal",
+                                   "flash_fwd_causal"]),
+    ])
+    def test_edges_of_the_gate_at_heads_of_128_compile(self, T, D, dtype,
+                                                       kernels):
+        import re
+
+        from analytics_zoo_tpu.pallas import flash_attention as fa
+        block = fa._auto_block(T)
+        assert fa._bwd_fused_fits(block, block, T, D, jnp.dtype(
+            dtype).itemsize) == (kernels[0] == "flash_bwd_fused_causal")
+        # the fit's 32 head-batches (2 sequences x 16 heads)
+        x = jax.ShapeDtypeStruct((2, 16, T, D), dtype)
+
+        def loss(q, k, v):
+            return fa.flash_attention(q, k, v, causal=True).astype(
+                jnp.float32).sum()
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile().as_text()
+        assert sorted(set(re.findall(
+            r"(flash_(?:fwd|bwd_fused|dq|dkv)_causal)", text))) == kernels
+        assert text.count('custom_call_target="tpu_custom_call"') \
+            == len(kernels)
+
+
 class TestFitOnChip:
     def test_one_fit_step_through_estimator(self):
         import optax
