@@ -34,7 +34,8 @@ from analytics_zoo_tpu.keras.layers import (LayerNormalization,
                                             get_init)
 from analytics_zoo_tpu.pallas.dropout import fused_dropout
 from analytics_zoo_tpu.pallas.flash_attention import (_reference_attention,
-                                                      flash_attention)
+                                                      flash_attention,
+                                                      save_flash_residuals)
 from analytics_zoo_tpu.serving.quantization import maybe_int8_matmul
 
 
@@ -389,6 +390,17 @@ def unstack_block_params(params: dict, n_block: int, prefix: str) -> dict:
     return out
 
 
+# What `BERT(remat=True)` keeps of a checkpointed block: the matmul outputs
+# with no batch dims (none: every dot of the block carries the batch) and,
+# with `use_flash`, the attention kernel's output and log-sum-exp, so that
+# the backward pass computes the block again but not the kernel. One policy
+# for every recomputed fit in the package (`models/looped_decoder.py` uses
+# the second half alone).
+_REMAT_POLICY = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    save_flash_residuals)
+
+
 class BERT(Layer):
     """BERT encoder as a layer (`keras/layers/BERT.scala:66`). Inputs:
     [token_ids, token_type_ids, attention_mask] (position ids are implicit);
@@ -466,9 +478,7 @@ class BERT(Layer):
             fn = lambda p, a, m, r: blk.call(  # noqa: E731
                 p, [a, m], training=training, rng=r)
             if self.remat:
-                fn = jax.checkpoint(
-                    fn, policy=jax.checkpoint_policies
-                    .dots_with_no_batch_dims_saveable)
+                fn = jax.checkpoint(fn, policy=_REMAT_POLICY)
             return fn(bp, hh, mask, key)
 
         if rng is not None:
@@ -525,17 +535,15 @@ class BERT(Layer):
                     rng, sub = jax.random.split(rng)
                 if self.remat:
                     # activation rematerialization per block: save only
-                    # the matmul outputs with no batch dims (i.e. nothing
-                    # — all block dots carry the batch), recompute the
-                    # rest in the backward pass. Trades ~1/3 more FLOPs
+                    # what `_REMAT_POLICY` names, recompute the rest in
+                    # the backward pass. Trades ~1/3 more FLOPs
                     # on the block for O(1) blocks of live activations,
                     # unlocking batch sizes (and seq lengths) the
                     # non-remat program cannot fit.
                     h = jax.checkpoint(
                         lambda p, hh, mm, rr, _blk=blk: _blk.call(
                             p, [hh, mm], training=training, rng=rr),
-                        policy=jax.checkpoint_policies
-                        .dots_with_no_batch_dims_saveable)(
+                        policy=_REMAT_POLICY)(
                             params[blk.name], h, mask, sub)
                 else:
                     h = blk.call(params[blk.name], [h, mask],

@@ -21,9 +21,13 @@ TPU-first layout, as `keras.transformer.BERT(stacked=True)`: the blocks'
 parameters are ONE `[n_block, ...]` buffer per tensor (`stack_block_params`'
 layout), `lax.scan`ned over the blocks inside a `lax.scan` over the passes,
 so the block compiles once and its gradients are born stacked. With `remat`
-every layer application is a `jax.checkpoint`: the backward keeps one
-[B, T, H] input per application (`n_pass * n_block` of them) and computes
-the rest again. In training `apply` hands the loss `ProjectedLogits` (the
+every layer application is a `jax.checkpoint`: the backward keeps, per
+application (`n_pass * n_block` of them), the [B, T, H] input and, with
+`use_flash`, the attention kernel's two residuals, its [B, heads, T, D]
+output and its [B * heads, 1, T] log-sum-exp
+(`pallas.flash_attention.save_flash_residuals`), and computes the rest
+again: norms, projections, rotary positions and the FFN, but no second
+forward kernel call. In training `apply` hands the loss `ProjectedLogits` (the
 hidden state and the head's kernel), so the [B, T, vocab] logits are never
 formed whole (`ops/objectives.py`); in inference it returns the logits.
 """
@@ -39,6 +43,7 @@ from analytics_zoo_tpu.keras.transformer import (TransformerDecoderBlock,
                                                  rotary_tables)
 from analytics_zoo_tpu.observability.registry import get_registry
 from analytics_zoo_tpu.ops.objectives import ProjectedLogits
+from analytics_zoo_tpu.pallas.flash_attention import save_flash_residuals
 from analytics_zoo_tpu.serving.quantization import maybe_int8_matmul
 
 
@@ -72,6 +77,11 @@ class LoopedDecoderLM(KerasNet):
         gauge("model_recompute", "1 if every block application is "
               "recomputed in the backward pass").set(int(remat),
                                                      model=self.name)
+        gauge("model_recompute_attention_kernel", "1 if the backward pass "
+              "runs the attention forward again, 0 if its output is kept "
+              "(no recomputation, or the flash kernel's residuals saved "
+              "across it)").set(int(remat and not use_flash),
+                                model=self.name)
 
     def build(self, rng, input_shape=None):
         k_emb, k_head, k_gate, *k_blocks = jax.random.split(
@@ -106,7 +116,8 @@ class LoopedDecoderLM(KerasNet):
                 return self.block.ffn_branch(bp, hh)
 
         if self.remat:
-            apply_block = jax.checkpoint(apply_block)
+            apply_block = jax.checkpoint(apply_block,
+                                         policy=save_flash_residuals)
 
         def one_pass(hh, _):
             with jax.named_scope("looplm/pass"):

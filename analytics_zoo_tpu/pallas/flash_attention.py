@@ -23,6 +23,25 @@ dQ or whose chunks do not fit scoped VMEM (`_bwd_fused_fits`: very long T,
 oversized tiles) run the same mathematics as two kernels (`flash_dq`,
 `flash_dkv`) that recompute the weights in each — seven products.
 
+The residuals. The forward kernel leaves two arrays for the backward: its
+output `[B, H, T, D]` and the rows' log-sum-exp, float32 `[B*H, 1, T]` with
+T on the lanes (blocks `(1, 1, block_q)`, the layout of the additive mask
+rows). The kernels compute with a row statistic as a `[block_q, 128]` array
+equal along its lanes (`_lanes`): the forward, which keeps its running
+max and sum that way, transposes the sum of the two into a row once a
+q-block, and every backward kernel transposes the row block back once a
+tile, outside its chunk loop (`_stat_of`). A `[.., T, 1]` array is never
+kept: HBM lays a last dimension of 1 out 128 lanes wide, 67 MB a call at
+32 x 4096 rows for 0.5 MB of values, written by the forward, read twice
+by the backward and, saved across a checkpoint, 2 GB of a 32-application
+step. `_flash_fwd` names
+both residuals (`jax.ad_checkpoint.checkpoint_name`: `FLASH_OUT_NAME`,
+`FLASH_LSE_NAME`) and `save_flash_residuals` is the `jax.checkpoint` policy
+that keeps exactly them: a checkpointed block with that policy computes
+everything around the kernel again in its backward pass and the kernel's
+forward not at all, with the gradients it had, bit for bit. Outside such a
+checkpoint a name is the identity and lowers to nothing.
+
 Attention dropout runs INSIDE the kernels: `pltpu.prng_seed(seed, tile)`
 reseeds per (batch·head, q-block, k-block) tile, so the backward kernels
 regenerate bit-identical masks without storing them. The softmax
@@ -51,8 +70,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.pallas.dropout import _byte_threshold
+
+# The forward kernel's two residuals by name, and the `jax.checkpoint` policy
+# that keeps exactly them (module docstring, "The residuals").
+FLASH_OUT_NAME = "attention_kernel_out"
+FLASH_LSE_NAME = "attention_kernel_lse"
+save_flash_residuals = jax.checkpoint_policies.save_only_these_names(
+    FLASH_OUT_NAME, FLASH_LSE_NAME)
 
 
 def _reference_attention(q, k, v, mask=None, dropout_rate: float = 0.0,
@@ -289,6 +316,33 @@ def _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki, shape):
                     0, shape[1])
 
 
+# -- a row statistic in the kernels --------------------------------------------
+# One value a query row (a running max or sum, the log-sum-exp) lives in the
+# kernels as a [block_q, 128] array that is EQUAL ALONG ITS LANES, the layout
+# jax's own TPU flash kernel keeps its statistics in: meeting a [block_q, w]
+# tile it is sliced or laid side by side (`_lanes`), which moves no data,
+# where a [block_q, 1] column is broadcast along the lanes by an operation a
+# vreg every time it is used (the backward used its column once a chunk, four
+# times a tile: 7% of the kernel at T = 2048, PERF.md, PR 27). HBM holds the
+# log-sum-exp as a [1, block_q] row (T on the lanes); one aligned
+# [block_q, 128] <-> [128, block_q] transpose turns one into the other.
+_LANES = 128
+
+
+def _lanes(stat, width):
+    """A [n, 128] statistic, equal along its lanes, as [n, width]."""
+    if width <= _LANES:
+        return stat[:, :width]
+    if width % _LANES:
+        return jnp.broadcast_to(stat[:, :1], (stat.shape[0], width))
+    return jnp.tile(stat, (1, width // _LANES))
+
+
+def _stat_of(row):
+    """A [1, n] row block of HBM -> the [n, 128] statistic."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
 def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
                 s_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc):
     from jax.experimental import pallas as pl
@@ -313,16 +367,16 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
                          preferred_element_type=jnp.float32) * scale + mb
         if masked:
             scores = _causal_scores(scores, qi * block_q, ki * block_k)
-        m_prev, l_prev = m_sc[...], l_sc[...]
+        m_prev, l_prev = m_sc[...], l_sc[...]              # [bq, 128]
         m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)
+        p = jnp.exp(scores - _lanes(m_new, block_k))
         if rate > 0.0:
             p_drop = p * _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki,
                                      (block_q, block_k))
         else:
             p_drop = p
-        acc_sc[...] = acc_sc[...] * alpha + jnp.dot(
+        acc_sc[...] = acc_sc[...] * _lanes(alpha, acc_sc.shape[1]) + jnp.dot(
             p_drop.astype(v_ref.dtype), vb,
             preferred_element_type=jnp.float32)
         m_sc[...] = m_new
@@ -332,8 +386,9 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
 
     @pl.when(ki == n_kb - 1)
     def _flush():
-        o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
-        lse_ref[0] = m_sc[...] + jnp.log(l_sc[...])        # [bq, 1]
+        o_ref[0] = (acc_sc[...] / _lanes(l_sc[...], acc_sc.shape[1])
+                    ).astype(o_ref.dtype)
+        lse_ref[0] = (m_sc[...] + jnp.log(l_sc[...])).T[:1]    # [1, bq]
 
 
 def _attn_cost(n_matmuls, q, extra_f32_out_elems=0, causal=False):
@@ -389,16 +444,16 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -408,7 +463,8 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
         interpret=interpret,
         name=_kernel_name("flash_fwd", causal),
     )(qf, kf, vf, mf, seed)
-    out = out.reshape(B, H, T, D)
+    out = checkpoint_name(out.reshape(B, H, T, D), FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return out, (q, k, v, mask, seed, out, lse)
 
 
@@ -443,7 +499,7 @@ def _dq_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
         vb = v_ref[0]
         mb = m_ref[0]
         dob = do_ref[0]
-        lse = lse_ref[0]                                   # [bq, 1]
+        lse = _lanes(_stat_of(lse_ref[0]), block_k)        # [bq, bk]
         delta = _delta(do_ref, o_ref)                      # [bq, 1]
         scores = jnp.dot(qb, kb.T,
                          preferred_element_type=jnp.float32) * scale + mb
@@ -486,7 +542,7 @@ def _dkv_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
         vb = v_ref[0]
         mb = m_ref[0]                                      # [1, bk]
         dob = do_ref[0]
-        lse = lse_ref[0]                                   # [bq, 1]
+        lse = _lanes(_stat_of(lse_ref[0]), block_k)        # [bq, bk]
         delta = _delta(do_ref, o_ref)
         scores = jnp.dot(qb, kb.T,
                          preferred_element_type=jnp.float32) * scale + mb
@@ -536,18 +592,23 @@ def _bwd_fused_fits(block_q, block_k, T, D, itemsize) -> bool:
     reckoned from what it holds, every [rows, D] buffer padded to 128
     lanes as Mosaic lays it out at deployment sizes: dq for the whole
     head-batch (f32 scratch plus its double-buffered output block); the
-    double-buffered q/dO/O and k/v/dk/dv blocks, the dk/dv accumulators
-    and the lse column; the tile's dropout words and 4.5 live f32
-    [block_q, chunk] intermediates. Held against the chip's compiler over
-    T = 512..8192, D = 32..256, bf16 and f32 (PERF.md, PR 25): Mosaic
-    allocates 3.4 such intermediates where it pads every buffer (192
-    head-batches) and as little as half the total where it does not (4
-    head-batches), so the reckoning reads high, never low."""
+    double-buffered q/dO/O and k/v/dk/dv blocks, the dk/dv accumulators,
+    the log-sum-exp's double-buffered [1, block_q] row blocks (8 sublanes
+    each) and the [block_q, 128] statistic a tile turns one into;
+    the tile's dropout words and 4.5 live f32 [block_q, chunk]
+    intermediates. Held against the chip's compiler over T = 512..8192,
+    D = 32..256, bf16 and f32 (PERF.md, PR 25): Mosaic allocates 3.4 such
+    intermediates where it pads every buffer (192 head-batches) and as
+    little as half the total where it does not (4 head-batches), so the
+    reckoning reads high, never low. With the log-sum-exp as rows Mosaic
+    asks for 0.8-1.0 MiB less at 1024 tiles than with [block_q, 1] column
+    blocks (PERF.md, PR 27), the reckoning for 0.44 MiB less."""
     lanes = -(-D // 128) * 128
     chunk = _bwd_chunk(block_k)
     resident_dq = T * lanes * (4 + 2 * itemsize)
     streams = ((6 * block_q + 8 * block_k) * lanes * itemsize
-               + 2 * block_k * lanes * 4 + 2 * block_q * 128 * 4)
+               + 2 * block_k * lanes * 4
+               + 2 * 8 * block_q * 4 + block_q * _LANES * 4)
     live = block_q * block_k + int(4.5 * block_q * chunk * 4)
     return resident_dq + streams + live <= _BWD_FUSED_VMEM_BYTES
 
@@ -586,7 +647,7 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
     def tile(masked):
         qb = q_ref[0]
         dob = do_ref[0]
-        lse = lse_ref[0]                                   # [bq, 1]
+        lse = _lanes(_stat_of(lse_ref[0]), chunk)   # [bq, chunk], once a tile
         delta = _delta(do_ref, o_ref)
         if rate > 0.0:
             words = _tile_words(s_ref, n_qb, n_kb, qi, ki,
@@ -649,7 +710,7 @@ def _bwd_in_specs(block_q, block_k, D, q_major, causal):
     q_spec = spec((1, block_q, D), True, lambda b, i: (b, i, 0))
     k_spec = spec((1, block_k, D), False, lambda b, j: (b, j, 0))
     m_spec = spec((1, 1, block_k), False, lambda b, j: (b, 0, j))
-    lse_spec = spec((1, block_q, 1), True, lambda b, i: (b, i, 0))
+    lse_spec = spec((1, 1, block_q), True, lambda b, i: (b, 0, i))
     return ([q_spec, k_spec, k_spec, m_spec,
              pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, lse_spec,
              q_spec], q_spec, k_spec)

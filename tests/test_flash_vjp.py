@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from analytics_zoo_tpu.pallas import flash_attention as fa
 from analytics_zoo_tpu.pallas.flash_attention import (_reference_attention,
                                                       flash_attention)
 
@@ -166,6 +167,49 @@ class TestFlashVJP:
         o2 = np.asarray(_reference_attention(q, k, v, causal=True))
         np.testing.assert_allclose(o1, o2, rtol=1e-5, atol=1e-5)
         _assert_grad_parity(q, k, v, causal=True, block_q=bq, block_k=bk)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("T,block,kernels", [
+        (1024, 512, ["flash_bwd_fused"]),
+        (1536, 768, ["flash_dkv", "flash_dq"]),
+    ])
+    def test_log_sum_exp_is_a_row_and_both_backward_forms_read_it(
+            self, T, block, kernels, causal):
+        # the residual the forward kernel leaves for the backward: T on the
+        # lanes ([B*H, 1, T], never [.., T, 1], which HBM holds 128 lanes
+        # wide), equal to logsumexp of the reference's scores
+        q, k, v = _qkv(B=1, H=2, T=T)
+        D = q.shape[-1]
+        mask = jnp.where(jnp.arange(T)[None, None, None, :] < T - 77,
+                         0.0, -1e9) * jnp.ones((1, 1, 1, T))
+        _, res = fa._flash_fwd(q, k, v, mask, jnp.zeros((1, 1), jnp.int32),
+                               0.0, block, block, True, causal)
+        lse = res[-1]
+        assert lse.shape == (2, 1, T) and lse.dtype == jnp.float32
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D) + mask
+        if causal:
+            scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores,
+                               -1e30)
+        np.testing.assert_allclose(
+            np.asarray(lse[:, 0]),
+            np.asarray(jax.nn.logsumexp(scores, axis=-1)[0]),
+            rtol=1e-5, atol=1e-5)
+        # and the backward that turns it into a column again, in the
+        # one-kernel and in the two-kernel form
+        assert _bwd_kernels(q, block=block, causal=causal) == [
+            n + "_causal" if causal else n for n in kernels]
+        _assert_grad_parity(q, k, v, mask, causal=causal, block_q=block,
+                            block_k=block)
+
+    @pytest.mark.parametrize("width", [64, 128, 192, 256])
+    def test_a_row_statistic_meets_a_tile_of_any_width(self, width):
+        # what the kernels do with a [1, n] row block of HBM: a [n, 128]
+        # statistic equal along its lanes, sliced, laid side by side or
+        # (no whole number of lane tiles) broadcast to the tile's width
+        row = jnp.arange(256, dtype=jnp.float32)[None, :] * 0.5
+        got = fa._lanes(fa._stat_of(row), width)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.broadcast_to(np.asarray(row).T, (256, width)))
 
     def test_causal_with_a_padding_mask_and_a_padded_length(self):
         # T = 200 pads to 256 with masked keys; causal on top of it
