@@ -200,12 +200,11 @@ def abstract_signature(tree: Any) -> Tuple[str, Tuple]:
 
 def cheap_signature(tree: Any) -> Tuple:
     """Per-leaf (shape, dtype-name) tuple — the hot-path dispatch key
-    shared by `AOTFunctionCache`, the trainer's step-cost tracker, and
-    `InferenceModel`'s roofline cost table. Discriminating only when
-    the tree STRUCTURE is fixed per consumer (one wrapper per model);
-    pay `abstract_signature` when structure can vary. One
-    implementation so the three consumers can never drift on dtype
-    spelling."""
+    shared by `AOTFunctionCache` and `InferenceModel`'s roofline cost
+    table. Discriminating only when the tree STRUCTURE is fixed per
+    consumer (one wrapper per model); pay `abstract_signature` when
+    structure can vary. One implementation so the two consumers can
+    never drift on dtype spelling."""
     import jax
     return tuple(
         (tuple(l.shape), l.dtype.name) if hasattr(l, "shape")

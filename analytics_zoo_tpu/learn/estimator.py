@@ -165,7 +165,7 @@ class Estimator:
         fuses k steps per dispatch, `mixed_precision=True` runs bf16
         compute with f32 masters, `prefetch=False` disables the
         background batch pipeline, `metrics_report_s=30` logs a periodic
-        registry digest, `flops_per_step=...` enables the MFU gauge,
+        registry digest,
         `sharding_rules=True` (or a `parallel.sharding.ShardingRules`)
         runs the GSPMD-sharded fit — params/opt_state sharded over the
         mesh's fsdp axis with the same rule table serving's sharded

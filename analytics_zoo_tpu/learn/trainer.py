@@ -60,10 +60,6 @@ class _TrainingMetrics:
         self.loss = reg.gauge("training_loss", "mean loss of the last epoch")
         self.throughput = reg.gauge("training_samples_per_sec",
                                     "last epoch's training throughput")
-        self.mfu = reg.gauge(
-            "training_mfu",
-            "model FLOPs utilization vs per-chip peak (needs "
-            "flops_per_step)")
         self.val = reg.gauge("training_validation_metric",
                              "last validation metrics, labeled by name")
         self.resumes = reg.counter(
@@ -88,12 +84,6 @@ class _TrainingMetrics:
             "spent blocked on the prefetch queue (0 = device-bound, "
             "1 = fully input-bound; the measured verdict on whether "
             "a fit needs more pipeline_workers)")
-        self.fused_update_ms = reg.histogram(
-            "training_fused_update_ms",
-            "measured wall time of one fused-kernel optimizer sweep "
-            "over the model's parameter tree (observed once per "
-            "model/step-program build, not per fit — warm re-fits "
-            "skip the probe)")
         self.fit_phase_ms = reg.histogram(
             "training_fit_phase_ms",
             "wall time of each leaf span of a fit call (`_FitTrace`), "
@@ -116,8 +106,7 @@ class _TrainingMetrics:
         for ax, size in sizes.items():
             self.mesh_axis.set(size, axis=ax)
 
-    def epoch(self, steps: int, n_seen: int, dt: float, mean_loss: float,
-              flops_per_step: Optional[float] = None):
+    def epoch(self, steps: int, n_seen: int, dt: float, mean_loss: float):
         step_ms = dt / max(steps, 1) * 1e3
         self.step_ms.observe(step_ms)
         self.steps.inc(steps)
@@ -125,32 +114,7 @@ class _TrainingMetrics:
         self.epochs.inc()
         self.loss.set(mean_loss)
         self.throughput.set(n_seen / max(dt, 1e-9))
-        if flops_per_step:
-            from analytics_zoo_tpu.utils.roofline import (
-                UnknownDeviceError, peak_flops)
-            try:
-                peak = peak_flops(jax.devices()[0]) * jax.device_count()
-            except UnknownDeviceError:
-                pass    # no published peak (CPU): no MFU to publish
-            else:
-                self.mfu.set(
-                    flops_per_step * steps / max(dt, 1e-9) / peak)
         return step_ms
-
-    def roofline(self, flops: float, bytes_: float, dt: float,
-                 n_devices: int = 1):
-        """Cost-analysis roofline for one epoch (ISSUE 6): publishes
-        `roofline_mfu{kind="train"}` / `roofline_hbm_utilization` etc.
-        from the XLA-counted FLOPs/bytes over the epoch's device wall
-        time — no hand-supplied flops_per_step, and HBM utilization
-        against the measured session roofline. `flops`/`bytes_` are
-        GLOBAL (all participating devices); `n_devices` is the step
-        program's device span, scaling the roofline denominator so a
-        sharded fit's MFU reads against the whole slice's peak."""
-        from analytics_zoo_tpu.observability.roofline import get_accountant
-        get_accountant().account("train", flops, bytes_, dt,
-                                 device=jax.devices()[0],
-                                 n_devices=n_devices)
 
 
 _fit_call_ids = itertools.count(1)   # process-wide: `fit-<n>` names a call
@@ -397,162 +361,6 @@ def _step_with_watchdog(step_fn, args, retries: int,
                 iteration, type(e).__name__, e, attempts, retries)
 
 
-class _StepCostTracker:
-    """Per-fit accumulation of XLA cost-analysis FLOPs/bytes for the
-    live train step (ISSUE 6 roofline). Two-phase per distinct argument
-    signature:
-
-    - `before(args)` (pre-dispatch): memo hit → accumulate; miss →
-      record the signature as pending with a ShapeDtypeStruct skeleton
-      (shape/dtype/sharding — the only parts lowering needs, and the
-      only parts safe to keep once the call donates the buffers).
-    - `after()` (post-dispatch): resolve pending signatures — prefer
-      `cost_analysis()` straight off the executable the call just built
-      (an `AOTFunctionCache` exposes it via `executables()`, so a warm
-      AOT re-run never lowers at all); plain-jit steps fall back to one
-      lowering of the SDS skeleton, which costs a trace but no compile.
-
-    Any failure marks the signature un-costed and the roofline gauges
-    simply stay absent — never an error in the hot loop. `memo` is the
-    per-train-step sub-dict of the model's cost memo, selected by the
-    SAME cache key the trainer's step cache uses (`id()`-keying the
-    step object would resurrect a stale program's cost after CPython
-    address reuse), so warm restarts and repeated bench fits never
-    re-harvest.
-
-    Units: XLA's cost analysis visits a While body ONCE (a k-step
-    `lax.scan` run program and the whole-epoch device-cache program
-    both report ≈ one step's flops/bytes — verified on this backend),
-    and the single-step program trivially reports one step's. So the
-    accumulated `flops`/`bytes` are PER-STEP costs × `calls`; the
-    epoch accounting in `fit_keras` scales the per-call mean by the
-    epoch's iteration count, which is exact for every program shape.
-
-    Basis: harvested costs are the LOGICAL GLOBAL cost of one step
-    (the ExecCost contract — model work counted once). A partitioned
-    executable's `cost_analysis()` counts its per-device module, and
-    per-device × span over-counts work that replicates across a mesh
-    axis, so for multi-device programs the tracker ALWAYS harvests by
-    lowering the SDS skeleton (one trace per signature, no compile);
-    the zero-lowering executable fast path is kept for single-device
-    programs, where the two bases agree. `self.devices` records the
-    program span for the accountant's roofline denominator — classic
-    MFU: model flops over the participating slice's peak."""
-
-    def __init__(self, train_step, memo: Dict):
-        self._step = train_step
-        self._memo = memo
-        self._pending: Dict[Tuple, Any] = {}   # sig -> (sds_args, calls)
-        self.flops = 0.0
-        self.bytes = 0.0
-        self.calls = 0
-        self.devices = 1
-        self._span_known = False
-        # per-step ExecCost DELTA for Pallas kernel regions (ISSUE 9):
-        # cost analysis cannot see inside a pallas_call (Mosaic reports
-        # ~0; the interpreter emulation over-counts), so the fit adds
-        # (analytic − XLA-counted) for the fused sweep here
-        self.correction = None
-
-    def reset_epoch(self):
-        self.flops = 0.0
-        self.bytes = 0.0
-        self.calls = 0
-
-    @staticmethod
-    def _sig(args) -> Tuple:
-        from analytics_zoo_tpu.compile_cache.key import cheap_signature
-        return cheap_signature(args)
-
-    @staticmethod
-    def _skeleton(args):
-        """Avals of the live args, for a post-donation lowering: shape,
-        dtype, and the sharding of every COMMITTED leaf (mesh-placed
-        params, optimizer state and batches). That is exactly what the
-        jit call itself lowered from, so the module comes out identical
-        and compiling it (`cost_of` on a backend that costs compiled
-        programs only) finds the executable the call just built instead
-        of compiling the step a second time — 35-40 s for BERT-base on a
-        v5e when the skeleton dropped the one-device mesh's shardings
-        (PR 21 chip run). An uncommitted leaf (the rng key) stays
-        unconstrained: pinning its default-device placement would add a
-        sharding the live call never had, and next to 8-device params
-        jit.lower rejects it as incompatible devices."""
-        def sds(a):
-            if not hasattr(a, "shape"):
-                return a
-            if getattr(a, "committed", False):
-                return jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                            sharding=a.sharding)
-            return jax.ShapeDtypeStruct(a.shape, a.dtype)
-        return jax.tree_util.tree_map(sds, args)
-
-    def _accumulate(self, cost, calls=1):
-        if cost is not None:
-            corr = self.correction
-            cf = corr.flops if corr is not None else 0.0
-            cb = corr.bytes if corr is not None else 0.0
-            self.flops += max(cost.flops + cf, 0.0) * calls
-            self.bytes += max(cost.bytes + cb, 0.0) * calls
-            self.calls += calls
-
-    def before(self, args):
-        try:
-            if not self._span_known:
-                # one walk per fit: the step program's device span is
-                # fixed by the (mesh, placement) the fit chose
-                from analytics_zoo_tpu.observability.roofline import \
-                    device_span
-                self.devices = device_span(args)
-                self._span_known = True
-            key = self._sig(args)
-            if key in self._memo:
-                self._accumulate(self._memo[key])
-                return
-            entry = self._pending.get(key)
-            if entry is not None:
-                entry[1] += 1
-            else:
-                self._pending[key] = [self._skeleton(args), 1]
-        except Exception:  # noqa: BLE001 — telemetry only
-            pass
-
-    def after(self):
-        if not self._pending:
-            return
-        try:
-            pending, self._pending = self._pending, {}
-            for key, (sds_args, calls) in pending.items():
-                if key not in self._memo:
-                    self._memo[key] = self._harvest(key, sds_args)
-                self._accumulate(self._memo[key], calls)
-        except Exception as e:  # noqa: BLE001 — telemetry only
-            log.debug("step cost harvest failed: %s: %s",
-                      type(e).__name__, e)
-
-    def _harvest(self, sig, sds_args):
-        from analytics_zoo_tpu.observability.roofline import cost_of
-        step = self._step
-        try:
-            execs_fn = getattr(step, "executables", None)
-            if execs_fn is not None and self.devices == 1:
-                # single-device: the executable answers directly (no
-                # lowering at all on a warm AOT re-run)
-                cost = cost_of(execs_fn().get(sig))
-                if cost is not None:
-                    return cost
-            fn = getattr(step, "wrapped", step)
-            # multi-device (and the plain-jit fallback): the lowered,
-            # UNPARTITIONED module is the logical basis — a partitioned
-            # executable's per-device count can't be scaled back
-            # exactly (see ExecCost)
-            return cost_of(fn.lower(*sds_args), span=self.devices)
-        except Exception as e:  # noqa: BLE001 — telemetry only
-            log.debug("step cost harvest failed: %s: %s",
-                      type(e).__name__, e)
-            return None
-
-
 class _Prefetcher:
     """Background-thread batch prefetch: prepares + device_puts the next
     item while the device runs the current one. Depth-bounded so host
@@ -747,76 +555,6 @@ def _shard_mapped_fused(fused_apply, shardings):
     return jax.shard_map(fused_apply, mesh=mesh,
                          in_specs=(p_specs, o_specs, p_specs),
                          out_specs=(p_specs, o_specs), check_vma=False)
-
-
-def _fused_kernel_correction(optimizer, lazy_specs, params, opt_state,
-                             shardings, batch: int):
-    """Per-step ExecCost DELTA (analytic − XLA-counted) of the fused
-    Pallas regions, for `_StepCostTracker.correction` (ISSUE 9).
-
-    HLO cost analysis cannot see inside a `pallas_call`: a Mosaic
-    custom call reports ~0 bytes, and the CPU interpreter's emulated
-    block walk over-counts them ~10×. Each kernel carries the analytic
-    `cost_estimate` (`fused_adam.update_cost` / `segment_adam_cost`),
-    but the tracker harvests the WHOLE step module — so the honest
-    count is: harvested − (what XLA counted for the kernel region
-    alone) + (the analytic model). This lowers each kernel region once
-    per fit (a trace, no compile) to get the subtraction term; any
-    failure returns None and the gauges keep the uncorrected count."""
-    from analytics_zoo_tpu.observability.roofline import ExecCost, cost_of
-
-    span = 1 if shardings is None else jax.tree_util.tree_leaves(
-        shardings["params"])[0].mesh.size
-
-    def lowered(fn, *args):
-        sds = _StepCostTracker._skeleton(args)
-        return cost_of(jax.jit(fn).lower(*sds), span=span)
-
-    flops = bytes_ = 0.0
-    try:
-        if lazy_specs:
-            from analytics_zoo_tpu.learn.lazy_embedding import _get, _key
-            from analytics_zoo_tpu.pallas.segment_update import (
-                kernel_apply, segment_adam_cost)
-            for s in lazy_specs:
-                table = _get(params, s.path)
-                mu, nu = opt_state["tables"][_key(s)]
-                dim = table.shape[1]
-                a_f, a_b = segment_adam_cost(batch, dim, table.dtype)
-                raw = lowered(
-                    functools.partial(kernel_apply, b1=s.b1, b2=s.b2),
-                    table, mu, nu, jnp.zeros((batch,), jnp.int32),
-                    jnp.zeros((batch,), jnp.int32),
-                    jnp.zeros((batch, dim), jnp.float32),
-                    jnp.zeros((3,), jnp.float32))
-                if raw is None:
-                    return None
-                flops += a_f - raw.flops
-                bytes_ += a_b - raw.bytes
-        fused_apply = getattr(optimizer, "fused_apply", None)
-        if fused_apply is not None:
-            from analytics_zoo_tpu.learn.lazy_embedding import split_rest
-            from analytics_zoo_tpu.pallas.fused_adam import update_cost
-            if lazy_specs:
-                sweep_params = split_rest(params, lazy_specs)
-                sweep_state = opt_state["rest"]
-            else:
-                sweep_params = params
-                sweep_state = opt_state
-            if shardings is not None:
-                fused_apply = _shard_mapped_fused(fused_apply, shardings)
-            a_f, a_b = update_cost(sweep_params)
-            raw = lowered(fused_apply, sweep_params, sweep_state,
-                          sweep_params)
-            if raw is None:
-                return None
-            flops += a_f - raw.flops
-            bytes_ += a_b - raw.bytes
-        return ExecCost(flops, bytes_)
-    except Exception as e:  # noqa: BLE001 — telemetry only
-        log.debug("fused roofline correction unavailable: %s: %s",
-                  type(e).__name__, e)
-        return None
 
 
 def _make_one_step(apply_fn, loss_fn, optimizer, apply_and_state_fn,
@@ -1101,10 +839,8 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
               prefetch_depth: Optional[int] = None,
               lazy_embeddings: bool = False,
               device_cache: Optional[bool] = None,
-              flat_optimizer: bool = False,
               fused_optimizer: Optional[bool] = None,
               sharding_rules=None,
-              flops_per_step: Optional[float] = None,
               metrics_report_s: Optional[float] = None,
               compile_cache_dir: Optional[str] = None,
               auto_resume: bool = False,
@@ -1126,20 +862,18 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
     bounds the transferred-batch backlog; the time the step loop spends
     BLOCKED on that queue is measured per step into
     `training_input_wait_ms` and per epoch into the
-    `training_input_bound` gauge (+ the roofline snapshot's input-stall
-    column) — the device-wait vs host-wait accounting that says whether
-    a file-backed fit needs more `pipeline_workers`
+    `training_input_bound` gauge — the device-wait vs host-wait
+    accounting that says whether a file-backed fit needs more
+    `pipeline_workers`
     (docs/ProgrammingGuide/distributed-training.md "Input pipeline"). `steps_per_run=k` fuses k
     steps into one `lax.scan` program — one dispatch per k steps —
     trading trigger granularity (checked every k iterations) for dispatch
     overhead. `mixed_precision` runs fwd/bwd in bf16 with f32 masters.
-    `flops_per_step` (fwd+bwd FLOPs of one step, e.g. from
-    `utils.profiling.transformer_train_flops`) enables the
-    `training_mfu` gauge; `metrics_report_s` runs a `MetricsReporter`
-    for the duration of the fit, logging a one-line registry digest at
-    that interval. Step/throughput/loss telemetry always publishes to
-    the process-wide `MetricsRegistry` (and mirrors to TensorBoard when
-    `set_tensorboard` is on).
+    `metrics_report_s` runs a `MetricsReporter` for the duration of the
+    fit, logging a one-line registry digest at that interval.
+    Step/throughput/loss telemetry always publishes to the process-wide
+    `MetricsRegistry` (and mirrors to TensorBoard when `set_tensorboard`
+    is on).
     `fused_optimizer=True` (config `ZooConfig.fused_optimizer` / env
     `ZOO_FUSED_OPT=1`; None consults those) swaps a default-
     hyperparameter `adam`/`adamw` compile spec for the fused Pallas
@@ -1151,10 +885,6 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
     written — no dense table gradient is ever materialized. An
     optimizer with no fused twin keeps the plain optax path with one
     WARNING; a kernel that fails to lower raises from the first step.
-    (`flat_optimizer`, the earlier structural-repacking experiment, is
-    retired — passing True raises with a pointer here; see
-    docs/ROOFLINE.md round 5 for why repacking could not beat the
-    per-pass cost the kernels remove.)
     `sharding_rules` turns the fit into a GSPMD-sharded pjit program
     (the training twin of serving's sharded placement): params and
     optimizer state shard over the mesh's `fsdp` axis per the SAME
@@ -1187,11 +917,7 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
     bounded `jax.profiler` capture (`observability/capture.py`): the
     trace artifact lands in a rotated dir under `profile_dir` (or
     `$ZOO_PROFILE_DIR`, default ./zoo_profiles) and its path is
-    appended to `history["profile_artifacts"]`. Cost-analysis roofline
-    gauges (`roofline_mfu{kind="train"}`,
-    `roofline_hbm_utilization{kind="train"}` — no flops_per_step
-    needed) publish automatically each epoch; set `ZOO_ROOFLINE=0` to
-    skip the one-time per-signature lowering they cost.
+    appended to `history["profile_artifacts"]`.
     `int8_sidecar=True` runs the post-training quantization pass at
     every checkpoint save (ISSUE 12): per-output-channel scales are
     calibrated from the just-saved weights and persisted as an int8
@@ -1219,12 +945,6 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
     device's clock.
     After fit, `model.params` holds DEVICE arrays (no gratuitous
     device→host pull; save/checkpoint paths transfer on demand)."""
-    if flat_optimizer:
-        raise ValueError(
-            "flat_optimizer was retired by ISSUE 9: the bucket-packed "
-            "sweep is superseded by the fused Pallas optimizer kernels "
-            "— use fused_optimizer=True (config fused_optimizer / "
-            "ZOO_FUSED_OPT=1) instead")
     ctx = get_context()
     mesh = ctx.mesh if distributed else None
     dp = mesh.data_parallel_size if mesh else 1
@@ -1627,67 +1347,6 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
         if resume_meta is not None:
             telemetry.resumes.inc()
 
-        # cost-analysis roofline (ISSUE 6): XLA-counted FLOPs/bytes per step
-        # signature, accounted per epoch — the MFU/HBM gauges without a
-        # hand-supplied flops_per_step
-        cost_tracker = None
-        if os.environ.get("ZOO_ROOFLINE", "1") != "0":
-            memo_root = getattr(model, "_roofline_cost_memo", None)
-            if memo_root is None:
-                memo_root = model._roofline_cost_memo = {}
-            # sub-dict per train-step program, under the SAME cache_key the
-            # step cache memoizes on: two fits that share an executable
-            # share harvested costs, two that don't cannot alias
-            step_memo = memo_root.setdefault(cache_key, {})
-            cost_tracker = _StepCostTracker(train_step, step_memo)
-            try:
-                from analytics_zoo_tpu.observability.roofline import \
-                    get_accountant
-                get_accountant().reset("train")
-            except Exception:  # noqa: BLE001 — telemetry only
-                cost_tracker = None
-            if cost_tracker is not None and fused:
-                # Pallas regions are invisible to HLO cost analysis — patch
-                # the tracker with the analytic kernel model so the MFU/HBM
-                # gauges stay honest (memoized beside the sig-keyed costs;
-                # string key cannot collide with signature tuples)
-                if "__fused_correction__" not in step_memo:
-                    step_memo["__fused_correction__"] = \
-                        _fused_kernel_correction(optimizer, lazy_specs, params,
-                                                 opt_state, step_shardings,
-                                                 local_batch)
-                cost_tracker.correction = step_memo["__fused_correction__"]
-
-        if fused and not lazy_specs \
-                and getattr(optimizer, "fused_apply", None) is not None:
-            # one measured fused sweep, compile excluded: the direct A/B
-            # lever benches read against the unfused update's share of step
-            # time. Observed only when the probe is built (once per
-            # model/cache_key, NOT per fit): a warm re-fit re-timing it
-            # would add two full sweeps of HBM traffic inside the very
-            # bench loops the histogram exists to explain
-            try:
-                sw_cached = getattr(model, "_fused_sweep_cache", None)
-                if sw_cached is None or sw_cached[0] != cache_key:
-                    # under a sharded fit the probe must time the SAME
-                    # shard_mapped sweep the step runs — a bare jit would
-                    # replicate the full params/moments on every device
-                    # (the memory blow-up the sharded fit exists to avoid)
-                    fa = optimizer.fused_apply
-                    if step_shardings is not None:
-                        fa = _shard_mapped_fused(fa, step_shardings)
-                    sweep = jax.jit(fa)
-                    model._fused_sweep_cache = (cache_key, sweep)
-                    zg = jax.tree_util.tree_map(jnp.zeros_like, params)
-                    jax.block_until_ready(sweep(zg, opt_state, params))
-                    t_sw = time.time()
-                    jax.block_until_ready(sweep(zg, opt_state, params))
-                    telemetry.fused_update_ms.observe(
-                        (time.time() - t_sw) * 1e3)
-            except Exception as e:  # noqa: BLE001 — telemetry only
-                log.debug("fused sweep timing skipped: %s: %s",
-                          type(e).__name__, e)
-
         # on-demand profiler window (ISSUE 6): capture iterations
         # [start, stop) into a bounded, rotated artifact dir
         profiler = None
@@ -1731,28 +1390,19 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
         def _call_step(steps, xb, yb):
             """Every branch's train_step dispatch of `steps` steps funnels
             through the step watchdog (retries + optional timeout); with
-            step_retries=0 and no timeout this is a plain call. Roofline
-            cost harvest and the profiler edge-check run first — both need
-            the pre-dispatch (donation-alive) view. All of it, with the
-            split of the call's key, is the `fit.dispatch` span: the
-            host's side of a step, which returns while the device still
-            runs."""
+            step_retries=0 and no timeout this is a plain call. The
+            profiler edge-check runs first. All of it, with the split of
+            the call's key, is the `fit.dispatch` span: the host's side of
+            a step, which returns while the device still runs."""
             nonlocal rng
             with trace.phase("dispatch", "epoch", steps=steps,
                              iteration=iteration):
                 rng, step_rng = jax.random.split(rng)
-                step_args = (params, opt_state, xb, yb, step_rng)
-                if cost_tracker is not None:
-                    cost_tracker.before(step_args)
                 _profile_tick(iteration)
-                out = _step_with_watchdog(
-                    train_step, step_args, step_retries, step_timeout_s,
-                    telemetry.step_retries, iteration)
-                if cost_tracker is not None:
-                    # post-call: a just-built AOT executable answers
-                    # cost_analysis directly; only the plain-jit path lowers
-                    cost_tracker.after()
-            return out
+                return _step_with_watchdog(
+                    train_step, (params, opt_state, xb, yb, step_rng),
+                    step_retries, step_timeout_s, telemetry.step_retries,
+                    iteration)
 
         def _ckpt_extra(ep: int, finished: bool) -> Dict[str, Any]:
             """Checkpoint sidecar: everything auto-resume needs for bitwise
@@ -1915,38 +1565,15 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
                   if len(step_losses) else 0.0
               history["loss"].append(mean_loss)
               throughput = n_seen / max(dt, 1e-9)
-              step_ms = telemetry.epoch(iteration - it0, n_seen, dt, mean_loss,
-                                        flops_per_step=flops_per_step)
+              step_ms = telemetry.epoch(iteration - it0, n_seen, dt, mean_loss)
               # device-wait vs host-wait verdict (ISSUE 15): the prefetch
               # queue's measured blocked time over the epoch wall time is
               # the fraction of the fit that was input-bound — a measured
-              # answer, not a guess. Also lands in the roofline snapshot's
-              # input-stall column.
+              # answer, not a guess.
               input_wait_s = batches.wait_s \
                   if isinstance(batches, _Prefetcher) else 0.0
               telemetry.input_bound.set(
                   min(1.0, input_wait_s / max(dt, 1e-9)))
-              if input_wait_s > 0:
-                  try:
-                      from analytics_zoo_tpu.observability.roofline import \
-                          get_accountant
-                      get_accountant().account_stall("train", input_wait_s)
-                  except Exception as ie:  # noqa: BLE001 — telemetry only
-                      log.debug("input-stall accounting failed: %s", ie)
-              if cost_tracker is not None and cost_tracker.calls:
-                  # dt is device wall time (the _materialize above synced),
-                  # so achieved = XLA-counted work / measured epoch seconds.
-                  # The harvested cost is PER-STEP (cost analysis counts a
-                  # scan body once — see _StepCostTracker), so scale the
-                  # per-call mean by the iterations this epoch ran: exact
-                  # for single-step, multi-step (steps_per_run) and
-                  # device-cache epoch programs alike.
-                  steps_done = max(iteration - it0, cost_tracker.calls)
-                  scale = steps_done / cost_tracker.calls
-                  telemetry.roofline(cost_tracker.flops * scale,
-                                     cost_tracker.bytes * scale, dt,
-                                     n_devices=cost_tracker.devices)
-                  cost_tracker.reset_epoch()
               if writer:
                   writer.scalar("Loss", mean_loss, iteration)
                   writer.scalar("Throughput", throughput, iteration)

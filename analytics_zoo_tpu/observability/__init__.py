@@ -13,9 +13,9 @@ plus the deep-profiling layer that makes the stack self-measuring.
   `jax.profiler` capture too.
 - `MetricsReporter` — periodic one-line digest thread (optionally
   evaluating an `SLOTracker` each report).
-- `RooflineAccountant` / `cost_of` / `set_session_roofline` — hardware
-  utilization (achieved TFLOP/s, MFU, HBM GB/s vs the measured session
-  roofline) derived from XLA cost analysis, no hand-supplied FLOPs.
+- `RooflineAccountant` / `cost_of` — serving's hardware utilization
+  (achieved TFLOP/s, MFU, HBM GB/s vs the nameplate peaks of
+  `utils/roofline.py`) derived from XLA cost analysis.
 - `ProfileCapture` / `StackSampler` — bounded on-demand `jax.profiler`
   captures (`POST /profile`, `fit_keras(profile_steps=...)`) and a
   host-side stack-sampling profiler for the pipeline threads.
@@ -44,9 +44,7 @@ from analytics_zoo_tpu.observability.reporter import MetricsReporter, digest
 from analytics_zoo_tpu.observability.roofline import (ExecCost,
                                                       RooflineAccountant,
                                                       cost_of,
-                                                      get_accountant,
-                                                      session_roofline,
-                                                      set_session_roofline)
+                                                      get_accountant)
 from analytics_zoo_tpu.observability.slo import SLOObjectives, SLOTracker
 from analytics_zoo_tpu.observability.tracing import (Span, Tracer,
                                                      get_tracer,
@@ -61,7 +59,6 @@ __all__ = [
     "ProfileCapture", "RooflineAccountant", "SLOObjectives", "SLOTracker",
     "Span", "StackSampler", "Tracer", "cost_of", "device_memory_snapshot",
     "digest", "get_accountant", "get_registry", "get_tracer", "leak_check",
-    "load_trace_events", "render_prometheus", "session_roofline",
-    "set_session_roofline", "span_coverage", "span_from_dict",
-    "span_to_dict",
+    "load_trace_events", "render_prometheus", "span_coverage",
+    "span_from_dict", "span_to_dict",
 ]

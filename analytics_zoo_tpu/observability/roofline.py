@@ -1,12 +1,12 @@
 """Roofline accounting from XLA cost analysis (ISSUE 6 tentpole, part 1).
 
 PR 2 gave latencies and counts; this module answers *hardware
-utilization*: how many FLOPs and HBM bytes did each executable actually
-move per second, against what the chip can do. The FLOP/byte counts come
-from XLA itself — `compiled.cost_analysis()` on the executables the
-serving warmup, the trainer step, and the AOT compile cache already hold
-— so no hand-supplied `flops_per_step` is needed and the numbers track
-the REAL program (fusion included), not an analytic model.
+utilization* for the serving path: how many FLOPs and HBM bytes did each
+executable move per second, against what the chip can do. The FLOP/byte
+counts come from XLA itself — `compiled.cost_analysis()` on the
+executables the serving warmup and the AOT compile cache already hold.
+A fit's utilization is not estimated here: it is the benchmark's
+`fit_mfu` (docs/ProgrammingGuide/observability.md "Utilization of a fit").
 
 Two layers:
 
@@ -15,26 +15,16 @@ Two layers:
   deserialized AOT executable works too). Returns None when the backend
   exposes no cost model — every caller degrades to "no roofline gauges",
   never an error.
-- `RooflineAccountant` — per-`kind` ("serving", "train") accumulation of
-  (flops, bytes, busy-seconds) publishing both cumulative counters and
-  live derived gauges: achieved TFLOP/s, achieved HBM GB/s, MFU, and HBM
-  utilization as a fraction of the **session roofline**.
-
-The session roofline is the *measured* achievable bound
-(`bench.py session_hbm_gbps` / `session_mxu_tflops`, the Adam-shaped
-sweep + chained-matmul calibration in `bench_ncf.py`), installed via
-`set_session_roofline(...)` or the `ZOO_SESSION_HBM_GBPS` /
-`ZOO_SESSION_TFLOPS` env vars; absent those it falls back to the
-nameplate peaks in `utils/roofline.py`, and on a device that has none
-(the CPU backend) the utilization gauges are not published at all.
-That makes "NCF at N% of achievable bound" a live gauge
-(`roofline_hbm_utilization{kind="train"}`) instead of one-off analysis.
+- `RooflineAccountant` — per-`kind` accumulation of (flops, bytes,
+  busy-seconds) publishing both cumulative counters and live derived
+  gauges: achieved TFLOP/s, achieved HBM GB/s, MFU and HBM utilization
+  against the nameplate peaks in `utils/roofline.py`. On a device that
+  has none (the CPU backend) the utilization gauges are not published.
 """
 
 from __future__ import annotations
 
 import logging
-import os
 import threading
 from typing import Any, Dict, Optional, Tuple
 
@@ -93,10 +83,8 @@ def cost_of(stages_obj, span: int = 1) -> Optional[ExecCost]:
 
     Caveat: XLA's HLO cost analysis counts a While-loop body ONCE, not
     times its trip count — a `lax.scan`/`fori_loop` program reports one
-    iteration's cost. The trainer exploits this (the per-step cost is
-    exactly what it scales by the iteration count); a model whose
-    FORWARD hides work inside a loop will have its serving cost
-    understated by the trip count."""
+    iteration's cost: a model whose FORWARD hides work inside a loop will
+    have its serving cost understated by the trip count."""
     if stages_obj is None:
         return None
     try:
@@ -122,84 +110,16 @@ def cost_of(stages_obj, span: int = 1) -> Optional[ExecCost]:
     return ExecCost(flops, bytes_)
 
 
-def device_span(tree) -> int:
-    """The SPMD partition count of a program called with `tree` as (part
-    of) its arguments: the largest device set any leaf is committed to.
-    1 for single-device programs; the mesh size for GSPMD programs whose
-    params/batch are NamedSharding'd over a mesh. Used to convert XLA's
-    per-device executable cost to the global basis (see ExecCost)."""
-    span = 1
-    try:
-        import jax
-        for leaf in jax.tree_util.tree_leaves(tree):
-            sharding = getattr(leaf, "sharding", None)
-            if sharding is None:
-                continue
-            try:
-                span = max(span, len(sharding.device_set))
-            except Exception:  # noqa: BLE001 — exotic sharding object
-                continue
-    except Exception:  # noqa: BLE001 — telemetry only
-        return span
-    return span
-
-
-# ---------------------------------------------------------------------------
-# Session roofline: the measured achievable bound (falls back to nameplate)
-# ---------------------------------------------------------------------------
-_session_lock = threading.Lock()
-_session: Dict[str, Optional[float]] = {"hbm_gbps": None, "tflops": None}
-
-
-def set_session_roofline(hbm_gbps: Optional[float] = None,
-                         tflops: Optional[float] = None,
-                         registry=None) -> None:
-    """Install the session's MEASURED achievable bounds (the bench
-    calibration sweeps) as the roofline denominator, and publish them as
-    gauges so every scrape shows what "100%" meant."""
-    from analytics_zoo_tpu.observability.registry import get_registry
-    reg = registry if registry is not None else get_registry()
-    with _session_lock:
-        if hbm_gbps is not None:
-            _session["hbm_gbps"] = float(hbm_gbps)
-        if tflops is not None:
-            _session["tflops"] = float(tflops)
-    if hbm_gbps is not None:
-        reg.gauge("roofline_session_hbm_gbps",
-                  "measured achievable HBM GB/s this session (the "
-                  "utilization denominator; nameplate when unset)"
-                  ).set(float(hbm_gbps))
-    if tflops is not None:
-        reg.gauge("roofline_session_tflops",
-                  "measured achievable bf16 TFLOP/s this session (the "
-                  "MFU denominator; nameplate when unset)"
-                  ).set(float(tflops))
-
-
-def session_roofline(device=None) -> Tuple[float, float]:
-    """(HBM bytes/s, FLOP/s) roofline denominators: the measured session
-    bound when installed (`set_session_roofline` / env
-    ZOO_SESSION_HBM_GBPS / ZOO_SESSION_TFLOPS), else the nameplate peak
-    of `device` (default: device 0). Raises `UnknownDeviceError` when a
-    nameplate peak is needed and the device has none (the CPU backend):
-    there is no roofline to measure against."""
-    with _session_lock:
-        hbm_gbps = _session["hbm_gbps"]
-        tflops = _session["tflops"]
-    if hbm_gbps is None:
-        env = os.environ.get("ZOO_SESSION_HBM_GBPS")
-        hbm_gbps = float(env) if env else None
-    if tflops is None:
-        env = os.environ.get("ZOO_SESSION_TFLOPS")
-        tflops = float(env) if env else None
-    if hbm_gbps is not None and tflops is not None:
-        return hbm_gbps * 1e9, tflops * 1e12
+def _nameplate(device=None) -> Tuple[float, float]:
+    """(HBM bytes/s, FLOP/s) peaks of `device` (default: device 0) from
+    `utils/roofline.py`, the program's one table of peaks. Raises
+    `UnknownDeviceError` for a device the table does not list (the CPU
+    backend): there is no roofline to measure against."""
     from analytics_zoo_tpu.utils.roofline import peak_flops, peak_hbm
     if device is None:
         import jax
         device = jax.devices()[0]
-    return (hbm_gbps * 1e9 if hbm_gbps is not None else peak_hbm(device),
-            tflops * 1e12 if tflops is not None else peak_flops(device))
+    return peak_hbm(device), peak_flops(device)
 
 
 # ---------------------------------------------------------------------------
@@ -210,16 +130,14 @@ class RooflineAccountant:
 
     `account(kind, flops, bytes, seconds)` is the single entry point:
     the serving predict path calls it per materialized batch (with the
-    batch's measured dispatch+materialize seconds), the trainer once per
-    epoch (with the epoch's device wall time). Counters accumulate
+    batch's measured dispatch+materialize seconds). Counters accumulate
     forever (the Prometheus model); the derived gauges are computed from
-    THIS call's window — the latest batch / latest epoch — so a cold
-    fit's compile-laden first epoch depresses only its own reading and
-    the gauges recover to the true steady-state rate from the next
-    window on (cumulative-since-reset rates would stay diluted for the
-    whole run). `snapshot(kind)` still reports the accumulation since
-    the last `reset(kind)` — a model reload or a fresh fit resets its
-    kind so the bench-facing averages describe the CURRENT program.
+    THIS call's window — the latest batch — so a compile-laden first
+    window depresses only its own reading (cumulative-since-reset rates
+    would stay diluted for the whole run). `snapshot(kind)` still
+    reports the accumulation since the last `reset(kind)` — a model
+    reload resets its kind so the bench-facing averages describe the
+    CURRENT program.
 
     Never raises out of `account` — one bad division must not take down
     a dispatch path."""
@@ -228,7 +146,7 @@ class RooflineAccountant:
         from analytics_zoo_tpu.observability.registry import get_registry
         self._registry = registry if registry is not None else get_registry()
         self._lock = threading.Lock()
-        # kind -> [flops, bytes, seconds] since last reset(kind)
+        # kind -> [flops, bytes, seconds, devices] since last reset(kind)
         self._acc: Dict[str, list] = {}
 
     # registration is get-or-create and therefore safe to repeat per
@@ -250,11 +168,11 @@ class RooflineAccountant:
             reg.gauge("roofline_achieved_hbm_gbps",
                       "achieved HBM GB/s since the kind's last reset"),
             reg.gauge("roofline_mfu",
-                      "achieved FLOP/s over the session FLOP roofline "
-                      "(cost-analysis MFU; no flops_per_step needed)"),
+                      "achieved FLOP/s over the device's nameplate peak "
+                      "(cost-analysis MFU)"),
             reg.gauge("roofline_hbm_utilization",
-                      "achieved HBM bytes/s over the session HBM "
-                      "roofline (the %-of-achievable-bound gauge)"),
+                      "achieved HBM bytes/s over the device's nameplate "
+                      "HBM bandwidth"),
         )
 
     def account(self, kind: str, flops: float, bytes_: float,
@@ -262,14 +180,13 @@ class RooflineAccountant:
         """`flops`/`bytes_` are GLOBAL (see ExecCost); `n_devices` is
         how many devices the program spanned, scaling the MFU/HBM
         denominators to the roofline of the participating slice —
-        per-chip session bounds × n. The achieved_* gauges stay global
+        per-chip peaks × n. The achieved_* gauges stay global
         (what the whole mesh delivered)."""
         try:
             if seconds <= 0.0 or (flops <= 0.0 and bytes_ <= 0.0):
                 return
             with self._lock:
-                acc = self._acc.setdefault(kind,
-                                           [0.0, 0.0, 0.0, 1, 0.0])
+                acc = self._acc.setdefault(kind, [0.0, 0.0, 0.0, 1])
                 acc[0] += flops
                 acc[1] += bytes_
                 acc[2] += seconds
@@ -279,11 +196,11 @@ class RooflineAccountant:
             c_flops.inc(flops, kind=kind)
             c_bytes.inc(bytes_, kind=kind)
             c_secs.inc(seconds, kind=kind)
-            # gauges from THIS window: the latest epoch/batch rate
+            # gauges from THIS window: the latest batch's rate
             g_tflops.set(flops / seconds / 1e12, kind=kind)
             g_gbps.set(bytes_ / seconds / 1e9, kind=kind)
             try:
-                hbm_roof, flops_roof = session_roofline(device)
+                hbm_roof, flops_roof = _nameplate(device)
             except UnknownDeviceError:
                 # no roofline for this device: the achieved rates above
                 # stand, the utilization gauges stay unpublished
@@ -297,29 +214,9 @@ class RooflineAccountant:
             log.debug("roofline accounting failed: %s: %s",
                       type(e).__name__, e)
 
-    def account_stall(self, kind: str, stall_seconds: float) -> None:
-        """Input-stall accumulation (ISSUE 15): wall seconds the kind's
-        hot loop spent BLOCKED on its input pipeline (the trainer's
-        prefetch-queue wait) inside the busy window `account` measures.
-        Surfaces in `snapshot(kind)` as `input_stall_seconds` and
-        `input_stall_fraction` — the roofline's answer to "is this fit
-        compute-bound or input-bound": an epoch at 40% MFU with a 0.5
-        stall fraction is a HOST problem, not a kernel problem. Never
-        raises."""
-        try:
-            if stall_seconds <= 0.0:
-                return
-            with self._lock:
-                acc = self._acc.setdefault(kind,
-                                           [0.0, 0.0, 0.0, 1, 0.0])
-                acc[4] += stall_seconds
-        except Exception as e:  # noqa: BLE001 — telemetry must not raise
-            log.debug("roofline stall accounting failed: %s: %s",
-                      type(e).__name__, e)
-
     def reset(self, kind: Optional[str] = None) -> None:
         """Zero the rate accumulators (counters keep accumulating): a
-        reloaded serving model / a fresh fit starts its gauges clean."""
+        reloaded serving model starts its gauges clean."""
         with self._lock:
             if kind is None:
                 self._acc.clear()
@@ -332,19 +229,14 @@ class RooflineAccountant:
         mfu/hbm_utilization divide by that many chips' roofline, like
         the live gauges."""
         with self._lock:
-            f, b, s, n, stall = self._acc.get(
-                kind, (0.0, 0.0, 0.0, 1, 0.0))
+            f, b, s, n = self._acc.get(kind, (0.0, 0.0, 0.0, 1))
         out: Dict[str, Any] = {"flops": f, "bytes": b, "seconds": s,
-                               "devices": n,
-                               "input_stall_seconds": stall}
+                               "devices": n}
         if s > 0:
             out["achieved_tflops"] = f / s / 1e12
             out["achieved_hbm_gbps"] = b / s / 1e9
-            # the input-stall column (ISSUE 15): what share of the busy
-            # window the loop sat blocked on host input
-            out["input_stall_fraction"] = min(1.0, stall / s)
             try:
-                hbm_roof, flops_roof = session_roofline()
+                hbm_roof, flops_roof = _nameplate()
             except UnknownDeviceError:
                 pass        # no roofline: no mfu/hbm_utilization keys
             else:
@@ -358,8 +250,8 @@ _default_lock = threading.Lock()
 
 
 def get_accountant() -> RooflineAccountant:
-    """The process-wide accountant on the default registry — serving and
-    training both publish here, like `get_registry()`."""
+    """The process-wide accountant on the default registry, like
+    `get_registry()`."""
     global _default_accountant
     with _default_lock:
         if _default_accountant is None:

@@ -128,8 +128,7 @@ def update_cost(params) -> Tuple[int, int]:
 def _leaf_update(p, m, v, g, scal, b1: float, b2: float, interpret: bool):
     """One leaf through the kernel: viewed as (rows, last-dim), blocked
     over rows. Leading-dim collapse keeps the minor dim — a free
-    relayout on TPU — unlike the flat 1-D repacking designs
-    `ops/flat_optimizer.py` measured and rejected."""
+    relayout on TPU — unlike a flat 1-D repacking of the leaves."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

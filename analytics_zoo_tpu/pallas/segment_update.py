@@ -150,7 +150,7 @@ def kernel_apply(table, mu, nu, uids, valid, g_slots, scal, *,
         # operands: (uids, valid, scal, table, mu, nu, g_slots) — the
         # big tables alias their outputs: in-place row scatter, no
         # full-table copy (the failure mode of the XLA `.at[].set`
-        # path bench_ncf measured)
+        # path)
         input_output_aliases={3: 0, 4: 1, 5: 2},
         cost_estimate=pl.CostEstimate(flops=flops, bytes_accessed=bytes_,
                                       transcendentals=B * dim),

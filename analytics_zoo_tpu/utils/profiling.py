@@ -127,6 +127,6 @@ def transformer_train_flops(n_params_matmul: int, tokens: int,
                             n_layers: int, seq_len: int,
                             hidden: int, batch: int) -> float:
     """Standard fwd+bwd FLOPs estimate: 6 per matmul-param per token plus
-    attention score/context terms (the bench.py accounting, shared)."""
+    attention score/context terms."""
     return (6.0 * n_params_matmul * tokens
             + 12.0 * n_layers * seq_len ** 2 * hidden * batch)

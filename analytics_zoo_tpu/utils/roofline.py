@@ -1,11 +1,12 @@
 """Per-chip peak numbers for roofline/MFU accounting (docs/ROOFLINE.md).
 
-Single source of truth for the benches (`bench.py`, `bench_ncf.py`) and
-any profiling hook that wants achieved-vs-peak ratios. Values are the
-published per-chip peaks; lookup is by `device_kind` substring, and a
-device the tables do not list raises `UnknownDeviceError`: the bench
-scripts fail on it, the library gauges that divide by a peak stay
-unpublished."""
+The program's one table of peaks, for `chip_smoke.py`, the serving
+accountant (`observability/roofline.py`) and any profiling hook that
+wants achieved-vs-peak ratios; the benchmark keeps its own
+(`benchmark/peaks.json`). Values are the published per-chip peaks;
+lookup is by `device_kind` substring, and a device the tables do not
+list raises `UnknownDeviceError`: scripts fail on it, the library
+gauges that divide by a peak stay unpublished."""
 
 from __future__ import annotations
 

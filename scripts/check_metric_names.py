@@ -2,8 +2,8 @@
 """Static lint for metric names (ISSUE 2 satellite; tier-1 via
 tests/test_metric_names.py).
 
-Scans every Python source under `analytics_zoo_tpu/` (plus the bench
-scripts) for literal registry registrations —
+Scans every Python source under `analytics_zoo_tpu/` (plus `scripts/`
+and `bench_serving.py`) for literal registry registrations —
 `<registry>.counter("name", ...)`, `.gauge(...)`, `.histogram(...)` —
 and enforces the conventions the runtime registry also checks, so a
 violation fails CI before it ever runs:
@@ -42,8 +42,7 @@ CALL_RE = re.compile(
 COUNTER_SUFFIX = ("_total",)
 HIST_SUFFIXES = ("_ms", "_bytes", "_seconds")
 
-DEFAULT_ROOTS = ("analytics_zoo_tpu", "scripts", "bench_serving.py",
-                 "bench.py", "bench_ncf.py")
+DEFAULT_ROOTS = ("analytics_zoo_tpu", "scripts", "bench_serving.py")
 
 # Load-bearing names with their required kinds: families other code
 # (dashboards, the bench JSON, docs tables) depends on existing. A
@@ -72,6 +71,7 @@ REQUIRED = {
     # bench JSON, /healthz, and the docs tables read
     "roofline_flops_total": "counter",
     "roofline_hbm_bytes_total": "counter",
+    "roofline_busy_seconds_total": "counter",
     "roofline_achieved_tflops": "gauge",
     "roofline_achieved_hbm_gbps": "gauge",
     "roofline_mfu": "gauge",
@@ -82,12 +82,6 @@ REQUIRED = {
     "slo_burn_rate": "gauge",
     "slo_met": "gauge",
     "observability_gauge_errors_total": "counter",
-    # fused optimizer kernels (ISSUE 9): the A/B lever bench_ncf and
-    # the roofline docs read, plus the roofline counters the fused-step
-    # correction feeds (already REQUIRED above) — renaming any of these
-    # silently blinds the NCF bound tracking
-    "training_fused_update_ms": "histogram",
-    "roofline_busy_seconds_total": "counter",
     # fleet scale-out (ISSUE 10): the families the fleet gateway's
     # /healthz contract, the fleet bench, and the redelivery zero-loss
     # accounting read — renaming any of these silently blinds the

@@ -4,12 +4,12 @@
 tests/test_fused_optimizer.py).
 
 XLA's HLO cost analysis cannot see inside a Pallas custom call — a
-Mosaic kernel reports ~0 FLOPs/bytes — so the roofline layer
-(`observability/roofline.py`, `trainer._StepCostTracker`) depends on
-each kernel declaring its analytic cost via
+Mosaic kernel reports ~0 FLOPs/bytes — so XLA's scheduler and the
+serving accountant (`observability/roofline.py`) depend on each kernel
+declaring its analytic cost via
 `pl.CostEstimate(flops=..., bytes_accessed=..., ...)`. A kernel shipped
-without one silently blinds the MFU/HBM-utilization gauges for every
-program that embeds it; this lint turns that into a CI failure instead.
+without one silently blinds both for every program that embeds it; this
+lint turns that into a CI failure instead.
 
 Checked statically over the whole `analytics_zoo_tpu/` package: each
 `pallas_call(` call expression (nested parens respected, multi-line

@@ -110,16 +110,3 @@ class TestChipEntryPointsRefuseTheCpu:
         # it never reached init_orca_context, let alone a BERT build
         assert "Initialized" not in proc.stderr
 
-    def test_bench_fails_on_a_device_without_a_published_peak(self):
-        proc = _run(["bench.py"], timeout=300)
-        assert proc.returncode != 0
-        assert "bench leg bert failed" in proc.stderr
-        assert "UnknownDeviceError" in proc.stderr
-        assert "{" not in proc.stdout           # no JSON, no null fields
-
-    def test_bench_parent_stays_off_jax(self):
-        """A parent that has touched jax holds the chip its legs need."""
-        proc = _run(["-c", "import sys, bench; "
-                           "assert 'jax' not in sys.modules, 'jax imported'"],
-                    timeout=60)
-        assert proc.returncode == 0, proc.stderr[-2000:]

@@ -342,11 +342,7 @@ class TestScalingEvidence:
 
 
 class TestGraftEntry:
-    def test_dryrun_multichip(self, monkeypatch):
-        # the fit-scaling part (several timed fits) has its own
-        # dedicated test in test_trainer_sharded.py; skipping it here
-        # keeps this end-to-end dryrun at its pre-ISSUE-7 runtime
-        monkeypatch.setenv("ZOO_DRYRUN_FIT", "0")
+    def test_dryrun_multichip(self):
         from __graft_entry__ import dryrun_multichip
         dryrun_multichip(8)
 

@@ -3,11 +3,8 @@ analysis, on-demand profiler capture, device-memory telemetry, and SLO
 health.
 
 Acceptance scenarios covered here:
-- cost-analysis FLOPs agree with the analytic count within 10% on a
-  matmul-dominated trainer (the MFU-agreement criterion with the
-  denominator held fixed);
-- the HBM-utilization gauge equals XLA bytes / measured seconds over
-  the installed session roofline — the live %-of-achievable number;
+- the accountant's MFU / HBM-utilization gauges equal the accounted
+  work over the measured seconds over the device's nameplate peak;
 - `POST /profile` returns a loadable trace artifact; overlapping
   captures get 409; artifact rotation is bounded; an idle capture adds
   zero steady-state machinery (and the predict path measures within
@@ -41,9 +38,7 @@ from analytics_zoo_tpu.observability import (CaptureActiveError,
                                              StackSampler, cost_of,
                                              get_accountant, get_registry,
                                              leak_check, load_trace_events,
-                                             render_prometheus,
-                                             set_session_roofline)
-from analytics_zoo_tpu.observability import roofline as roofline_mod
+                                             render_prometheus)
 from analytics_zoo_tpu.observability.registry import MetricsRegistry
 from analytics_zoo_tpu.serving import (ClusterServing, InferenceModel,
                                        InputQueue, MemoryBroker, OutputQueue)
@@ -51,13 +46,8 @@ from analytics_zoo_tpu.serving.http_frontend import FrontEnd
 
 
 @pytest.fixture(autouse=True)
-def _clean_session_roofline():
-    """Session roofline is process-global state like the registry —
-    never leak one test's calibration into the next."""
+def _clear_faults():
     yield
-    with roofline_mod._session_lock:
-        roofline_mod._session["hbm_gbps"] = None
-        roofline_mod._session["tflops"] = None
     faults.clear()
 
 
@@ -153,11 +143,14 @@ def isolated_registry():
 
 
 class TestAccountant:
-    def test_account_math_and_session_roofline(self, isolated_registry):
+    def test_account_math_and_session_roofline(self, isolated_registry,
+                                               monkeypatch):
+        from analytics_zoo_tpu.utils import roofline as peaks
         reg = isolated_registry
         acct = RooflineAccountant(registry=reg)
         # a deterministic denominator: achieved GB/s and TFLOP/s known
-        set_session_roofline(hbm_gbps=100.0, tflops=10.0, registry=reg)
+        monkeypatch.setattr(peaks, "peak_hbm", lambda device=None: 100e9)
+        monkeypatch.setattr(peaks, "peak_flops", lambda device=None: 10e12)
         acct.account("train", flops=2e12, bytes_=20e9, seconds=2.0)
         assert reg.get("roofline_flops_total").value(
             kind="train") == 2e12
@@ -170,7 +163,9 @@ class TestAccountant:
             kind="train") == pytest.approx(0.1)
         assert reg.get("roofline_hbm_utilization").value(
             kind="train") == pytest.approx(0.1)
-        assert reg.get("roofline_session_hbm_gbps").value() == 100.0
+        snap = acct.snapshot("train")
+        assert snap["mfu"] == pytest.approx(0.1)
+        assert snap["hbm_utilization"] == pytest.approx(0.1)
 
     def test_reset_starts_gauges_clean_but_counters_accumulate(
             self, isolated_registry):
@@ -195,14 +190,6 @@ class TestNoDefaultPeak:
     """An unlisted device has no peak: the old lookup handed the CPU a
     v5e's 197 TFLOP/s / 819 GB/s and every utilization divided by it."""
 
-    @pytest.fixture()
-    def no_session(self, monkeypatch):
-        from analytics_zoo_tpu.observability import roofline as rmod
-        monkeypatch.delenv("ZOO_SESSION_HBM_GBPS", raising=False)
-        monkeypatch.delenv("ZOO_SESSION_TFLOPS", raising=False)
-        monkeypatch.setattr(rmod, "_session",
-                            {"hbm_gbps": None, "tflops": None})
-
     def test_cpu_device_raises_listed_kind_resolves(self):
         from analytics_zoo_tpu.utils.roofline import (UnknownDeviceError,
                                                       peak_flops, peak_hbm)
@@ -218,8 +205,7 @@ class TestNoDefaultPeak:
         assert peak_flops(V5e()) == 197e12
         assert peak_hbm(V5e()) == 819e9
 
-    def test_utilization_gauges_stay_unpublished(self, isolated_registry,
-                                                 no_session):
+    def test_utilization_gauges_stay_unpublished(self, isolated_registry):
         reg = isolated_registry
         acct = RooflineAccountant(registry=reg)
         acct.account("train", flops=2e12, bytes_=20e9, seconds=2.0,
@@ -232,14 +218,6 @@ class TestNoDefaultPeak:
         snap = acct.snapshot("train")
         assert "mfu" not in snap and "hbm_utilization" not in snap
         assert snap["achieved_tflops"] == pytest.approx(1.0)
-
-    def test_training_mfu_unpublished_on_cpu(self, isolated_registry):
-        from analytics_zoo_tpu.learn.trainer import _TrainingMetrics
-        reg = isolated_registry
-        _TrainingMetrics(reg).epoch(steps=4, n_seen=64, dt=1.0,
-                                    mean_loss=0.5, flops_per_step=1e9)
-        assert reg.get("training_samples_per_sec").value() == 64
-        assert reg.get("training_mfu").label_keys() == []
 
 
 class TestServingRoofline:
@@ -281,102 +259,6 @@ class TestServingRoofline:
         im.predict(np.ones((2, 4), np.float32))
         assert im._exec_cost == {}
         assert get_accountant().snapshot("serving")["seconds"] == 0.0
-
-
-class TestTrainerRoofline:
-    def test_cost_skeleton_lowers_to_the_module_the_call_lowered(
-            self, devices8):
-        """The harvest lowers from avals after the call donated its
-        buffers. Identical module → compiling it finds the executable the
-        call just built; when the skeleton dropped a one-device mesh's
-        shardings the TPU harvest compiled BERT-base a second time."""
-        import jax.numpy as jnp
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from analytics_zoo_tpu.learn.trainer import _StepCostTracker
-        f = jax.jit(lambda p, x, r: (p + x.sum() + jax.random.uniform(r),),
-                    donate_argnums=(0,))
-        for devs in (devices8[:1], devices8):
-            mesh = Mesh(np.array(devs), ("data",))
-            args = (jax.device_put(jnp.ones((4, 4)),
-                                   NamedSharding(mesh, P())),
-                    jax.device_put(jnp.ones((8, 4)),
-                                   NamedSharding(mesh, P("data"))),
-                    jax.random.PRNGKey(0))            # uncommitted
-            assert f.lower(*_StepCostTracker._skeleton(args)).as_text() \
-                == f.lower(*args).as_text()
-
-    def _fit_mlp(self, n_layers, d=64, batch=32, n=128, **fit_kw):
-        from analytics_zoo_tpu.keras import Sequential
-        from analytics_zoo_tpu.keras import layers as L
-        from analytics_zoo_tpu.learn.estimator import Estimator
-        layers = [L.Dense(d, input_shape=(d,))]
-        layers += [L.Dense(d) for _ in range(n_layers - 1)]
-        model = Sequential(layers)
-        est = Estimator.from_keras(model, optimizer="sgd", loss="mse")
-        rs = np.random.RandomState(0)
-        x = rs.rand(n, d).astype(np.float32)
-        y = rs.rand(n, d).astype(np.float32)
-        est.fit((x, y), epochs=1, batch_size=batch, **fit_kw)
-        return d, batch
-
-    def test_cost_flops_agree_with_analytic_within_10pct(self):
-        """The MFU-agreement acceptance with the denominator held
-        fixed: MFU = flops / (dt * peak), and dt/peak are shared, so
-        agreement of the FLOP counts IS agreement of the MFUs. A deep
-        matmul-dominated MLP is where the analytic 6-flops/param/token
-        model is exact (the first layer skips its dx pass, hence deep)."""
-        n_layers = 6
-        d, batch = self._fit_mlp(n_layers)
-        snap = get_accountant().snapshot("train")
-        assert snap["flops"] > 0
-        calls = 128 // 32
-        cost_per_step = snap["flops"] / calls
-        analytic = 6.0 * (n_layers * d * d) * batch
-        assert cost_per_step == pytest.approx(analytic, rel=0.10)
-
-    def test_hbm_utilization_is_live_fraction_of_session_roofline(self):
-        """The BENCH-r05-style number with zero manual math: install a
-        session roofline, fit, and the gauge must equal XLA bytes /
-        measured seconds / the participating slice's roofline (per-chip
-        bound × the step program's device span — the fit runs data-
-        parallel on the conftest 8-device mesh, ISSUE 7)."""
-        set_session_roofline(hbm_gbps=50.0, tflops=5.0)
-        self._fit_mlp(2)
-        snap = get_accountant().snapshot("train")
-        g = get_registry().get("roofline_hbm_utilization")
-        expected = snap["bytes"] / snap["seconds"] \
-            / (50.0 * 1e9 * snap["devices"])
-        assert snap["devices"] == jax.device_count()
-        assert g.value(kind="train") == pytest.approx(expected, rel=1e-6)
-        assert expected > 0
-
-    def test_multi_step_run_scales_to_per_step_cost(self):
-        """XLA cost analysis counts a scan body once, so a
-        steps_per_run=k fit must account the SAME epoch totals as the
-        single-step fit of the same workload — the iteration-count
-        scaling, not the call count, owns the multiplier."""
-        self._fit_mlp(2)
-        single = get_accountant().snapshot("train")["flops"]
-        self._fit_mlp(2, steps_per_run=4)       # resets "train" first
-        multi = get_accountant().snapshot("train")["flops"]
-        assert single > 0
-        assert multi == pytest.approx(single, rel=0.10)
-
-    def test_aot_cached_step_harvests_from_executable(self, tmp_path):
-        """With the persistent compile cache active the step is an
-        AOTFunctionCache: the tracker's post-call harvest reads
-        cost_analysis straight off the built executable (the
-        executables() accessor), and the roofline accounts normally."""
-        get_accountant().reset("train")
-        self._fit_mlp(2, compile_cache_dir=str(tmp_path))
-        snap = get_accountant().snapshot("train")
-        assert snap["flops"] > 0 and snap["seconds"] > 0
-
-    def test_env_gate_disables(self, monkeypatch):
-        monkeypatch.setenv("ZOO_ROOFLINE", "0")
-        get_accountant().reset("train")
-        self._fit_mlp(1)
-        assert get_accountant().snapshot("train")["seconds"] == 0.0
 
 
 # ---------------------------------------------------------------------------

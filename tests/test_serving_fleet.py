@@ -1228,10 +1228,17 @@ class TestFleetObservability:
                         return False
                 return True
             _wait(_joined, msg="survivor spans joining dead uris")
-            doc = coll.assemble(dead[0])
-            assert doc["request_id"] == dead[0]
-            names = {e["name"] for e in doc["traceEvents"]}
-            assert {"wire", "decode", "writeback"} <= names
+
+            # eB records a batch's writeback span once the commit has
+            # returned, so after the result is visible, and its exporter
+            # publishes every 50 ms: the span can arrive one export
+            # after the wire and decode spans that made `_joined` true
+            def _names():
+                return {e["name"]
+                        for e in coll.assemble(dead[0])["traceEvents"]}
+            _wait(lambda: {"wire", "decode", "writeback"} <= _names(),
+                  msg="the survivor's writeback span exported")
+            assert coll.assemble(dead[0])["request_id"] == dead[0]
             # zero orphaned sampled requests: every sampled (rate=1.0)
             # served request has spans in the collector — eA's from its
             # pre-kill publishes, eB's for the claimed work

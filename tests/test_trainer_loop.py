@@ -83,17 +83,6 @@ class TestMultiStepRun:
         np.testing.assert_allclose(pa, pb, rtol=1e-5, atol=1e-6)
 
 
-class TestFlatOptimizerRetired:
-    def test_flag_raises_with_pointer(self):
-        # the bucket-packed sweep was superseded by the fused Pallas
-        # kernels (ISSUE 9): the flag fails fast with a migration hint
-        # instead of silently training a different program
-        x, y = _toy_data(64)
-        m = _toy_model()
-        with pytest.raises(ValueError, match="fused_optimizer"):
-            m.fit(x, y, batch_size=32, nb_epoch=1, flat_optimizer=True)
-
-
 class TestMixedPrecision:
     def test_bf16_compute_converges(self):
         x, y = _toy_data()

@@ -13,11 +13,7 @@ Covered here, all on the conftest 8-device CPU mesh:
   bitwise-identical under sharding;
 - sharded fit → checkpoint → serving sharded placement with IDENTICAL
   layouts (zero resharding: device_put of live fit state is a no-op)
-  and zero XLA compiles when serving warms from the shared cache;
-- roofline/MFU: executable (per-device) and lowered (global) harvest
-  bases agree after normalization, and the hand-fed `training_mfu`
-  agrees with the cost-analysis `roofline_mfu` in a sharded fit;
-- the dryrun fit-scaling bench helper records a coherent curve.
+  and zero XLA compiles when serving warms from the shared cache.
 """
 
 import numpy as np
@@ -188,11 +184,6 @@ class TestShardedFit:
     def test_incompatible_flags_raise(self, fsdp_ctx):
         m = _model()
         x, y = _data()
-        with pytest.raises(ValueError, match="fused_optimizer"):
-            # flat_optimizer is retired outright (ISSUE 9) — the raise
-            # fires before any sharding compatibility checks
-            fit_keras(m, x, y, epochs=1, sharding_rules=True,
-                      flat_optimizer=True, **KW)
         with pytest.raises(ValueError, match="distributed"):
             fit_keras(m, x, y, epochs=1, sharding_rules=True,
                       distributed=False, **KW)
@@ -402,73 +393,6 @@ class TestTrainServeHandoff:
             "warm serving restart recompiled despite the shared cache"
         assert set(im2.warmup_source.values()) == {"cached"}
         im2.close()
-
-
-class TestShardedRoofline:
-    def _reset_session(self):
-        from analytics_zoo_tpu.observability import roofline as rmod
-        with rmod._session_lock:
-            rmod._session["hbm_gbps"] = None
-            rmod._session["tflops"] = None
-
-    def _per_step_flops(self):
-        from analytics_zoo_tpu.observability.roofline import get_accountant
-        snap = get_accountant().snapshot("train")
-        return snap, snap["flops"] / max(1, 128 // 16)
-
-    def test_aot_and_jit_paths_account_same_logical_cost(
-            self, fsdp_ctx, tmp_path, monkeypatch):
-        """The global-vs-per-device fix: an AOT-cached sharded fit must
-        account the SAME logical per-step flops as the plain-jit
-        sharded fit. Before the fix the AOT path harvested the
-        partitioned executable's per-device count — a mesh-dependent
-        2–8x off the model's cost."""
-        monkeypatch.delenv("ZOO_SESSION_HBM_GBPS", raising=False)
-        monkeypatch.delenv("ZOO_SESSION_TFLOPS", raising=False)
-        x, y = _data()
-
-        m1 = _model()
-        fit_keras(m1, x, y, epochs=1, sharding_rules=True, **KW)
-        _, jit_flops = self._per_step_flops()
-
-        m2 = _model()
-        fit_keras(m2, x, y, epochs=1, sharding_rules=True,
-                  compile_cache_dir=str(tmp_path), **KW)
-        snap, aot_flops = self._per_step_flops()
-        assert snap["devices"] == 8
-        assert jit_flops > 0 and aot_flops > 0
-        # both harvest the lowered (unpartitioned) module now: the
-        # counts are the same program's
-        assert aot_flops == pytest.approx(jit_flops, rel=0.05)
-
-    def test_training_and_roofline_mfu_agree(self, fsdp_ctx,
-                                             monkeypatch):
-        """The MFU-agreement acceptance under sharding: feed the
-        XLA-counted GLOBAL per-step flops back in as flops_per_step —
-        the hand-fed `training_mfu` (global work / whole-mesh peak) and
-        the automatic `roofline_mfu{kind=train}` must agree."""
-        import analytics_zoo_tpu.utils.roofline as peaks
-        from analytics_zoo_tpu.observability.registry import get_registry
-        monkeypatch.delenv("ZOO_SESSION_HBM_GBPS", raising=False)
-        monkeypatch.delenv("ZOO_SESSION_TFLOPS", raising=False)
-        self._reset_session()
-        # the CPU devices have no published peak (both gauges would
-        # stay unpublished): stand in a listed chip's for the agreement
-        monkeypatch.setattr(peaks, "peak_flops", lambda dev: 197e12)
-        monkeypatch.setattr(peaks, "peak_hbm", lambda dev: 819e9)
-        x, y = _data()
-        m = _model()
-        fit_keras(m, x, y, epochs=1, sharding_rules=True, **KW)
-        _, per_step = self._per_step_flops()
-        assert per_step > 0
-
-        fit_keras(m, x, y, epochs=1, sharding_rules=True,
-                  flops_per_step=per_step, **KW)
-        reg = get_registry()
-        training_mfu = reg.get("training_mfu").value()
-        roofline_mfu = reg.get("roofline_mfu").value(kind="train")
-        assert training_mfu > 0 and roofline_mfu > 0
-        assert training_mfu == pytest.approx(roofline_mfu, rel=0.05)
 
 
 def _tp_ctx():
@@ -737,34 +661,3 @@ class TestTensorAxis:
         out = im.predict({"ids": x["ids"][:8], "mask": x["mask"][:8]})
         assert np.asarray(out).shape == (8, 2)
         im.close()
-
-
-class TestFitScalingBench:
-    def test_fit_scaling_summary_records_curve(self, fsdp_ctx):
-        """The dryrun_multichip part 1b payload: a coherent scaling
-        curve with the host-core ceiling reported as in PR 3 and the
-        1/fsdp params+opt footprint next to the replicated one."""
-        import sys
-        sys.path.insert(0, str(__import__("pathlib").Path(
-            __file__).resolve().parent.parent))
-        from bench import fit_scaling_summary
-        s = fit_scaling_summary(2, counts=[1, 2], n_samples=64,
-                                batch_size=16, hidden=32, seq_len=8,
-                                n_block=1)
-        assert s["metric"] == "fit_scaling"
-        assert set(s["samples_per_sec"]) == {"1", "2"}
-        assert all(v > 0 for v in s["samples_per_sec"].values())
-        assert s["host_cores"] >= 1
-        assert "efficiency_vs_host_cores" in s
-        assert all(v > 0 for v in s["per_device_peak_hbm_bytes"].values())
-        sh = s["sharded_fsdp"]
-        assert sh["fsdp"] == 2 and sh["samples_per_sec"] > 0
-        # params+opt at fsdp=2: about half the replicated per-device
-        # footprint (count scalar + remainders keep it off exactly 2x)
-        assert sh["params_opt_shrink"] > 1.5
-        # tensor-parallel leg (ISSUE 12): same model, (fsdp×tensor)
-        # factorization — still ~1/n state per device
-        tp = s["sharded_tp"]
-        assert tp["mesh"]["tensor"] >= 2
-        assert tp["samples_per_sec"] > 0
-        assert tp["params_opt_shrink"] > 1.5

@@ -200,6 +200,65 @@ def test_the_input_wait_span_is_the_histograms_observation():
         sum(s.duration for s in spans) * 1e3, rel=1e-6, abs=1e-4)
 
 
+@pytest.mark.parametrize("case, path, fit_kw", [
+    ("device_epoch", "device_epoch", dict(device_cache=True)),
+    ("multi_step", "multi_step", dict(device_cache=False, steps_per_run=3)),
+    ("single_step", "single_step", dict(device_cache=False)),
+    ("sharded", "single_step", dict(device_cache=False,
+                                    sharding_rules=True)),
+    ("aot_cached", "single_step", dict(device_cache=False)),
+])
+def test_a_fit_pays_for_no_accounting(monkeypatch, tmp_path, case, path,
+                                      fit_kw):
+    """The benchmark answers what share of the chip a fit used; the fit
+    itself estimates nothing. A whole fit walks no argument tree for a
+    signature on the loop's behalf (a cost tracker did, once a dispatch,
+    and lowered a sharded step a second time) and leaves no roofline
+    series of kind "train" in the registry. `AOTFunctionCache` keys its
+    executables with the function it imported by name, which the count
+    here does not see: with `compile_cache_dir` the loop itself still
+    walks nothing."""
+    from analytics_zoo_tpu.compile_cache import key
+    monkeypatch.delenv("ZOO_COMPILE_CACHE_DIR", raising=False)
+    if case == "aot_cached":
+        fit_kw = dict(fit_kw, compile_cache_dir=str(tmp_path))
+    walks = []
+    real = key.cheap_signature
+    monkeypatch.setattr(key, "cheap_signature",
+                        lambda tree: walks.append(case) or real(tree))
+    x, y = _data(n=192)
+    _model(width=32).fit(x, y, batch_size=32, nb_epoch=2, **fit_kw)
+    root = [s for s in get_tracer().spans() if s.name == "fit"][-1]
+    assert root.args["path"] == path
+    assert walks == []
+    train = [(name, s["labels"])
+             for name, fam in get_registry().snapshot().items()
+             if name.startswith("roofline_")
+             for s in fam["series"] if s["labels"].get("kind") == "train"]
+    assert train == []
+
+
+def test_a_fused_fit_sweeps_inside_its_step_program_only():
+    """`fused_optimizer=True` traces the optimizer's `fused_apply` once
+    a step program: two epochs of four steps are one trace, and a sweep
+    run anywhere outside the step (to time it, to cost it) would be a
+    trace more."""
+    from analytics_zoo_tpu.ops.optimizers import fused_adam
+    opt = fused_adam(1e-3)
+    traces = []
+
+    def counted(grads, state, params):
+        traces.append(1)
+        return opt.fused_apply(grads, state, params)
+    m = _model(width=32)
+    m.compile(optimizer=opt._replace(fused_apply=counted), loss="mse")
+    x, y = _data(n=128)
+    h = m.fit(x, y, batch_size=32, nb_epoch=2, device_cache=False,
+              fused_optimizer=True)
+    assert len(traces) == 1
+    assert np.isfinite(h["loss"]).all() and h["loss"][1] < h["loss"][0]
+
+
 def test_a_fit_that_raises_leaves_no_span_open(monkeypatch):
     def boom(x):
         raise RuntimeError("no losses today")
