@@ -11,26 +11,41 @@ is (batch·heads, q-blocks, k-blocks) with the k axis innermost and marked
 at any sequence length. Matmuls run in the input dtype (bf16 on the MXU)
 with f32 accumulation; softmax statistics stay f32.
 
+The forward's [block_q, block_k] block is its DMA tile, not its unit of
+work: the body walks a 1024-column tile in two chunks of 512 columns
+(`_fwd_chunk`; narrower tiles whole), one online-softmax update a chunk, so
+that a chunk's two products and its softmax have nothing to wait for but
+the [block_q, 128] statistics of the chunk before and the compiler can run
+the MXU under the vector work. What is done once an element is kept to
+what has to be: 1/sqrt(D) multiplies the q block where it is a power of
+two (D = 16, 64, 256: exact in any float type) and the scores otherwise;
+the row sums stay per-lane partial sums until the flush; dropout selects
+(`_keep_of`, a boolean) and its gain 1/keep meets the accumulator at the
+flush; and in a tile the causal diagonal crosses, the second chunk runs on
+the rows that can see it.
+
 The backward pass is a custom VJP that recomputes the weights from the saved
 output and logsumexp instead of materializing [T,T], at the forward's tiling,
 in ONE kernel (`flash_bwd_fused`): each (q-block, k-block) tile's weights,
 dropout mask and dW are computed once and feed all three gradients — five
 T×T×D products. The kernel walks the DMA tile in column chunks (a quarter of
-`block_k`), so its live f32 intermediates are a quarter of the tile; dK/dV
-accumulate in scratch over the q-blocks of one k-block, dQ in a [T, D]
-scratch that stays on the chip for a whole head-batch. Shapes whose resident
-dQ or whose chunks do not fit scoped VMEM (`_bwd_fused_fits`: very long T,
-oversized tiles) run the same mathematics as two kernels (`flash_dq`,
-`flash_dkv`) that recompute the weights in each — seven products.
+`block_k`, `_bwd_chunk`), so its live f32 intermediates are a quarter of the
+tile; dK/dV accumulate in scratch over the q-blocks of one k-block, dQ in a
+[T, D] scratch that stays on the chip for a whole head-batch. Shapes whose
+resident dQ or whose chunks do not fit scoped VMEM (`_bwd_fused_fits`: very
+long T, oversized tiles) run the same mathematics as two kernels
+(`flash_dq`, `flash_dkv`) that recompute the weights in each — seven
+products.
 
 The residuals. The forward kernel leaves two arrays for the backward: its
 output `[B, H, T, D]` and the rows' log-sum-exp, float32 `[B*H, 1, T]` with
 T on the lanes (blocks `(1, 1, block_q)`, the layout of the additive mask
 rows). The kernels compute with a row statistic as a `[block_q, 128]` array
 equal along its lanes (`_lanes`): the forward, which keeps its running
-max and sum that way, transposes the sum of the two into a row once a
-q-block, and every backward kernel transposes the row block back once a
-tile, outside its chunk loop (`_stat_of`). A `[.., T, 1]` array is never
+max that way (and its running sum as per-lane partial sums, reduced once a
+q-block), transposes the sum of the two into a row at the flush, and every
+backward kernel transposes the row block back once a tile, outside its
+chunk loop (`_stat_of`). A `[.., T, 1]` array is never
 kept: HBM lays a last dimension of 1 out 128 lanes wide, 67 MB a call at
 32 x 4096 rows for 0.5 MB of values, written by the forward, read twice
 by the backward and, saved across a checkpoint, 2 GB of a 32-application
@@ -72,6 +87,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from analytics_zoo_tpu.observability.registry import get_registry
 from analytics_zoo_tpu.pallas.dropout import _byte_threshold
 
 # The forward kernel's two residuals by name, and the `jax.checkpoint` policy
@@ -152,8 +168,10 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
     1024: per-tile work must amortize the DMA + softmax-state overhead —
     measured on v5e at T=2048, 1024×1024 blocks run the fwd+bwd 4.4×
     faster than 128×128 and beat the XLA reference attention (~12 vs
-    ~19 ms fwd). VMEM stays O(block_q·block_k) f32 (~4 MB at 1024²) plus
-    the K/V double buffers."""
+    ~19 ms fwd). The block is the DMA tile; the kernels compute inside it
+    in column chunks (`_fwd_chunk`, `_bwd_chunk`), so VMEM holds
+    O(block_q·chunk) f32 (~2 MB at 1024 x 512) plus the K/V double
+    buffers."""
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("flash_attention: dropout_rate > 0 needs a "
                          "dropout_seed (deterministic in-kernel masks)")
@@ -195,6 +213,11 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
     seed = jnp.asarray(dropout_seed if use_dropout else 0,
                        jnp.int32).reshape(1, 1)
     rate = float(dropout_rate) if use_dropout else 0.0
+    get_registry().gauge(
+        "flash_forward_chunk_columns", "columns of its DMA tile the flash "
+        "forward computes at a time (the tile's width where it is not "
+        "chunked), at the blocks flash_attention last picked").set(
+            _fwd_chunk(block_k), kernel=_kernel_name("flash_fwd", causal))
     return _flash(q, k, v, mask, seed, rate, block_q, block_k,
                   bool(interpret) if interpret is not None else False,
                   bool(causal))
@@ -282,11 +305,11 @@ def _tile_words(s_ref, n_qb, n_kb, qi, ki, shape):
 
 
 def _keep_of(words, rate, lo, hi):
-    """Dropout scale of the tile's columns [lo, hi): 1/keep where kept, 0
-    where dropped. Byte j of a word is the draw of column j·(block_k/4) +
-    (the word's own column), so byte plane j IS the mask of the j-th
-    quarter of the tile and a chunk of columns needs no more than a shift
-    of the words it covers."""
+    """Dropout mask of the tile's columns [lo, hi), boolean: kept or
+    dropped. Byte j of a word is the draw of column j·(block_k/4) + (the
+    word's own column), so byte plane j IS the mask of the j-th quarter of
+    the tile and a chunk of columns needs no more than a shift of the
+    words it covers."""
     plane = words.shape[1]
     t = _byte_threshold(rate)
     bytes_ = []
@@ -295,25 +318,34 @@ def _keep_of(words, rate, lo, hi):
         b = min(hi, (j + 1) * plane) - j * plane
         if a < b:
             bytes_.append((words[:, a:b] >> (8 * j)) & jnp.uint32(0xFF))
+    # side by side as bytes, then ONE compare: Mosaic does not lay boolean
+    # pieces narrower than a lane tile side by side
     bytes_ = bytes_[0] if len(bytes_) == 1 else jnp.concatenate(bytes_,
                                                                 axis=1)
-    return jnp.where(bytes_ < jnp.uint32(t), 256.0 / t, 0.0)
+    return bytes_ < jnp.uint32(t)
+
+
+def _keep_gain(rate):
+    """What a kept weight is multiplied by: the exact 1 / (t/256) of the
+    byte rule (unbiased; the rate is quantized to 1/256 like
+    `pallas/dropout._u8_dropout`)."""
+    return 256.0 / _byte_threshold(rate)
 
 
 def _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki, shape):
-    """Deterministic per-tile dropout scale. Identical bits in the forward
+    """Deterministic per-tile dropout scale, 1/keep where kept and 0 where
+    dropped, for the two-kernel backward. Identical bits in the forward
     and the backward kernels.
 
     The PRNG is the expensive part (~20 cycles/word on v5e when drawing
     one uint32 per element), so draw one word per FOUR elements and use
     each byte as an independent keep-draw: keep iff byte < t, t =
-    round(keep*256), scaled by the exact keep probability t/256 (unbiased;
-    rate quantized to 1/256 like `pallas/dropout._u8_dropout`). Which
-    byte lands on which column is an arbitrary fixed bijection — the mask
-    stays iid Bernoulli and regenerates bit-identically in the backward
-    kernels."""
-    return _keep_of(_tile_words(s_ref, n_qb, n_kb, qi, ki, shape), rate,
+    round(keep*256). Which byte lands on which column is an arbitrary
+    fixed bijection — the mask stays iid Bernoulli and regenerates
+    bit-identically in the backward kernels."""
+    keep = _keep_of(_tile_words(s_ref, n_qb, n_kb, qi, ki, shape), rate,
                     0, shape[1])
+    return jnp.where(keep, _keep_gain(rate), 0.0)
 
 
 # -- a row statistic in the kernels --------------------------------------------
@@ -343,14 +375,57 @@ def _stat_of(row):
     return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
 
 
+def _fwd_chunk(block_k: int) -> int:
+    """Columns the forward computes at a time inside its [block_q,
+    block_k] DMA tile: 512 where the tile holds two or more such chunks,
+    else the whole tile. Its statistics are updated once a chunk and cost
+    what 128 columns of scores cost, so it walks wider chunks than the
+    backward (`_bwd_chunk`): on the v5e halves of a 1024 tile beat
+    quarters and eighths, and a 512 tile whole beats its halves (PERF.md,
+    PR 29)."""
+    return 512 if block_k > 512 and block_k % 512 == 0 else block_k
+
+
+def _scale_on_q(scale: float) -> bool:
+    """Whether 1/sqrt(D) may multiply the [block_q, D] q block in place of
+    every score: only a power of two (D = 16, 64, 256) does so without
+    moving a single rounding, in bfloat16 and in float32."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _lane_sums(p):
+    """[n, w] -> [n, 128]: the row sums of `p` as per-lane partial sums
+    (column c adds into lane c mod 128), no lane reduction."""
+    out = p[:, :_LANES]
+    for lo in range(_LANES, p.shape[1], _LANES):
+        out = out + p[:, lo:lo + _LANES]
+    return out
+
+
 def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
                 s_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc):
+    """The DMA tile is [block_q, block_k]; the body walks it in column
+    chunks (`_fwd_chunk`), one online-softmax update a chunk, so the
+    live f32 intermediates are [block_q, chunk] and a chunk's product
+    waits for the chunk before it only through the [block_q, 128]
+    statistics. `m_sc` is the running max, equal along its lanes; `l_sc`
+    holds the running sum as per-lane PARTIAL sums (`_lane_sums`: the
+    rescale by `alpha` is the same in every lane), reduced along the
+    lanes once a q-block, at the flush. Dropout zeroes the dropped
+    weights a chunk; their gain 1/keep meets the [block_q, D] accumulator
+    once, at the flush. In a tile the causal diagonal crosses (square
+    tiles: qi == ki) the chunk at column `lo` holds nothing for the rows
+    above `lo`, which would leave their statistics as they are: it is
+    computed on the rows from `lo` down."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
+    chunk = _fwd_chunk(block_k)
+    lane_sums = chunk % _LANES == 0
+    on_q = _scale_on_q(scale)
 
     @pl.when(ki == 0)
     def _init():
@@ -360,35 +435,51 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
 
     def tile(masked):
         qb = q_ref[0]                                      # [bq, D]
-        kb = k_ref[0]
-        vb = v_ref[0]
-        mb = m_ref[0]                                      # [1, bk]
-        scores = jnp.dot(qb, kb.T,
-                         preferred_element_type=jnp.float32) * scale + mb
-        if masked:
-            scores = _causal_scores(scores, qi * block_q, ki * block_k)
-        m_prev, l_prev = m_sc[...], l_sc[...]              # [bq, 128]
-        m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - _lanes(m_new, block_k))
+        if on_q:
+            qb = qb * jnp.asarray(scale, qb.dtype)
         if rate > 0.0:
-            p_drop = p * _keep_scale(s_ref, rate, n_qb, n_kb, qi, ki,
-                                     (block_q, block_k))
-        else:
-            p_drop = p
-        acc_sc[...] = acc_sc[...] * _lanes(alpha, acc_sc.shape[1]) + jnp.dot(
-            p_drop.astype(v_ref.dtype), vb,
-            preferred_element_type=jnp.float32)
-        m_sc[...] = m_new
-        l_sc[...] = l_prev * alpha + p.sum(axis=1, keepdims=True)
+            words = _tile_words(s_ref, n_qb, n_kb, qi, ki,
+                                (block_q, block_k))
+        for lo in range(0, block_k, chunk):
+            cols = slice(lo, lo + chunk)
+            below = masked and block_q == block_k and lo > 0
+            rows = slice(lo, None) if below else slice(None)
+            scores = jnp.dot(qb[rows], k_ref[0, cols, :].T,
+                             preferred_element_type=jnp.float32)
+            if not on_q:
+                scores = scores * scale
+            scores = scores + m_ref[0, :, cols]            # [1, chunk]
+            if masked:
+                scores = _causal_scores(
+                    scores, qi * block_q + (lo if below else 0),
+                    ki * block_k + lo)
+            m_prev = m_sc[rows, :]                         # [rows, 128]
+            m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - _lanes(m_new, chunk))     # [rows, chunk]
+            m_sc[rows, :] = m_new
+            l_sc[rows, :] = l_sc[rows, :] * alpha + (
+                _lane_sums(p) if lane_sums
+                else p.sum(axis=1, keepdims=True))
+            if rate > 0.0:
+                p = jnp.where(_keep_of(words[rows], rate, lo, lo + chunk),
+                              p, 0.0)
+            acc_sc[rows, :] = acc_sc[rows, :] * _lanes(
+                alpha, acc_sc.shape[1]) + jnp.dot(
+                    p.astype(v_ref.dtype), v_ref[0, cols, :],
+                    preferred_element_type=jnp.float32)
 
     _on_causal_tiles(causal, qi, ki, block_q, block_k, tile)
 
     @pl.when(ki == n_kb - 1)
     def _flush():
-        o_ref[0] = (acc_sc[...] / _lanes(l_sc[...], acc_sc.shape[1])
-                    ).astype(o_ref.dtype)
-        lse_ref[0] = (m_sc[...] + jnp.log(l_sc[...])).T[:1]    # [1, bq]
+        acc, l = acc_sc[...], l_sc[...]
+        if lane_sums:
+            l = jnp.broadcast_to(l.sum(axis=1, keepdims=True), l.shape)
+        if rate > 0.0:
+            acc = acc * _keep_gain(rate)
+        o_ref[0] = (acc / _lanes(l, acc.shape[1])).astype(o_ref.dtype)
+        lse_ref[0] = (m_sc[...] + jnp.log(l)).T[:1]        # [1, bq]
 
 
 def _attn_cost(n_matmuls, q, extra_f32_out_elems=0, causal=False):
@@ -664,7 +755,9 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
             pnorm = jnp.exp(scores - lse)                  # [bq, chunk]
             dw = jnp.dot(dob, vc.T, preferred_element_type=jnp.float32)
             if rate > 0.0:
-                keep_scale = _keep_of(words, rate, lo, lo + chunk)
+                keep_scale = jnp.where(
+                    _keep_of(words, rate, lo, lo + chunk),
+                    _keep_gain(rate), 0.0)
                 dw = dw * keep_scale
                 dv_p = pnorm * keep_scale
             else:

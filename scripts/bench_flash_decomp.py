@@ -2,19 +2,24 @@
 the backward as ONE kernel (`flash_bwd_fused`) against the dq/dkv pair, at
 the tiling `flash_attention` picks for each sequence length.
 
-    python scripts/bench_flash_decomp.py [rate] [T ...]
+    python scripts/bench_flash_decomp.py [rate] [T ...] [--D 128] [--causal]
+                                         [--heads 16] [--tokens 8192]
 
+The forward line names the column chunk the kernel walks its DMA tile in
+(`_fwd_chunk`); `--D 128 --causal --heads 16 --tokens 8192 0 4096` is the
+seq-4096 decoder fit's call (no dropout, a mask of zeros), timed alone.
 The gate (`_bwd_fused_fits`) decides from the shapes which form a model
 runs; here both are called directly, so the table shows what the gate's
-choice is worth at every T (PERF.md, PR 25, holds one). B·T is held at
-32,768 tokens, H = 12, D = 64, bf16. All three gradients are outputs of the
-timed call: consuming dq alone lets XLA dead-code-eliminate the pair's
+choice is worth at every T (PERF.md, PR 25, holds one). By default B·T is
+held at 32,768 tokens, H = 12, D = 64, bf16. All three gradients are outputs
+of the timed call: consuming dq alone lets XLA dead-code-eliminate the pair's
 dk/dv kernel and times half a backward (docs/ROOFLINE.md round 5). A lone
 jitted kernel reads about 2 ms over its time inside a model's step (operand
 copies around the custom call), the same in both forms: compare the forms
 here, and take a kernel's own time from the benchmark's traced run.
 """
 
+import argparse
 import functools
 import math
 import os
@@ -46,14 +51,24 @@ def timeit(f, *args, iters=10):
 
 
 def main():
-    rate = float(sys.argv[1]) if len(sys.argv) > 1 else 0.1
-    lengths = [int(a) for a in sys.argv[2:]] or [512, 1024, 2048, 4096]
-    H, D = 12, 64
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("rate", nargs="?", type=float, default=0.1)
+    ap.add_argument("lengths", nargs="*", type=int)
+    ap.add_argument("--D", type=int, default=64, help="head width")
+    ap.add_argument("--heads", type=int, default=12)
+    ap.add_argument("--tokens", type=int, default=32768,
+                    help="B x T held at this many tokens")
+    ap.add_argument("--causal", action="store_true",
+                    help="the causal kernels")
+    args = ap.parse_args()
+    rate, causal = args.rate, args.causal
+    lengths = args.lengths or [512, 1024, 2048, 4096]
+    H, D = args.heads, args.D
     # off the chip the kernels run interpreted (rate 0 only: no TPU PRNG):
     # a rehearsal of the script, never a timing
     interpret = jax.default_backend() != "tpu"
     for T in lengths:
-        B = max(1, 32768 // T)
+        B = max(1, args.tokens // T)
         rs = np.random.RandomState(0)
         q, k, v, dout = (jnp.asarray(rs.randn(B, H, T, D) * 0.5, jnp.bfloat16)
                          for _ in range(4))
@@ -65,18 +80,21 @@ def main():
         # includes a copy of q, k and v that a model does not pay
         ms_fwd, (out, res) = timeit(jax.jit(functools.partial(
             fa._flash_fwd, rate=rate, block_q=block, block_k=block,
-            interpret=interpret)), q, k, v, mask, seed)
+            interpret=interpret, causal=causal)), q, k, v, mask, seed)
         flat = tuple(x.reshape(B * H, T, D) for x in (q, k, v))
         operands = flat + (jnp.repeat(mask[:, 0], H, axis=0), seed,
                            dout.reshape(B * H, T, D), res[-1],
                            out.reshape(B * H, T, D))
-        line = (f"RESULT T {T} B {B} blocks {block}x{block} rate {rate}: "
-                f"fwd {ms_fwd:.2f} ms")
+        line = (f"RESULT T {T} B {B} H {H} D {D}"
+                f"{' causal' if causal else ''} blocks {block}x{block} "
+                f"rate {rate}: fwd {ms_fwd:.2f} ms in chunks of "
+                f"{fa._fwd_chunk(block)} columns")
         grads = {}
         for name, form in (("pair", fa._bwd_pair), ("fused", fa._bwd_fused)):
             try:
                 ms, grads[name] = timeit(jax.jit(functools.partial(
-                    form, rate, scale, block, block, interpret)), operands)
+                    form, rate, scale, block, block, interpret, causal)),
+                    operands)
                 line += f", bwd {name} {ms:.2f} ms"
             except Exception as e:  # noqa: BLE001
                 line += (f", bwd {name} FAILED {type(e).__name__}: "

@@ -54,6 +54,23 @@ def _qkv(B=2, H=3, T=256, D=64, seed=0):
             jnp.asarray(rs.randn(B, H, T, D), jnp.float32))
 
 
+def _pad_mask(T, masked, B=1):
+    """Additive padding mask [B, 1, 1, T]: the last `masked` keys out."""
+    return jnp.where(jnp.arange(T)[None, None, None, :] < T - masked,
+                     0.0, -1e9) * jnp.ones((B, 1, 1, T))
+
+
+def _reference_lse(q, k, mask=None, causal=False):
+    """logsumexp over the keys of the reference's scores, [B, H, T]."""
+    T, D = q.shape[2:]
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D)
+    if mask is not None:
+        scores = scores + mask
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores, -1e30)
+    return jax.nn.logsumexp(scores, axis=-1)
+
+
 class TestFlashVJP:
     def test_forward_parity(self):
         q, k, v = _qkv()
@@ -179,20 +196,14 @@ class TestFlashVJP:
         # lanes ([B*H, 1, T], never [.., T, 1], which HBM holds 128 lanes
         # wide), equal to logsumexp of the reference's scores
         q, k, v = _qkv(B=1, H=2, T=T)
-        D = q.shape[-1]
-        mask = jnp.where(jnp.arange(T)[None, None, None, :] < T - 77,
-                         0.0, -1e9) * jnp.ones((1, 1, 1, T))
+        mask = _pad_mask(T, 77)
         _, res = fa._flash_fwd(q, k, v, mask, jnp.zeros((1, 1), jnp.int32),
                                0.0, block, block, True, causal)
         lse = res[-1]
         assert lse.shape == (2, 1, T) and lse.dtype == jnp.float32
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D) + mask
-        if causal:
-            scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores,
-                               -1e30)
         np.testing.assert_allclose(
             np.asarray(lse[:, 0]),
-            np.asarray(jax.nn.logsumexp(scores, axis=-1)[0]),
+            np.asarray(_reference_lse(q, k, mask, causal)[0]),
             rtol=1e-5, atol=1e-5)
         # and the backward that turns it into a column again, in the
         # one-kernel and in the two-kernel form
@@ -264,6 +275,83 @@ class TestFlashVJP:
                                        dropout_seed=jnp.int32(3)))
         o0 = np.asarray(flash_attention(q, k, v))
         assert not np.allclose(o, o0)
+
+
+class TestChunkedForward:
+    """PR 29: the forward walks its DMA tile in column chunks
+    (`_fwd_chunk`) and scales the q block where 1/sqrt(D) is a power of
+    two."""
+
+    @pytest.mark.parametrize("D", [64, 128])
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("padded", [False, True])
+    @pytest.mark.parametrize("block_k", [128, 256, 512, 1024])
+    def test_output_and_log_sum_exp_match_the_reference(self, block_k,
+                                                        padded, causal, D):
+        # 1024 columns a tile are two chunks of 512; 512, 256 and 128 are
+        # one chunk. Causal, the second chunk of a diagonal tile runs on
+        # the lower half of the rows. D = 64 scales q (0.125 is a power
+        # of two), D = 128 the scores.
+        T = 1024
+        assert fa._fwd_chunk(block_k) == min(block_k, 512)
+        assert fa._scale_on_q(1 / np.sqrt(D)) == (D == 64)
+        q, k, v = _qkv(B=1, H=1, T=T, D=D, seed=block_k + D)
+        mask = _pad_mask(T, 77) if padded else None
+        out, res = fa._flash_fwd(
+            q, k, v, jnp.zeros((1, 1, 1, T)) if mask is None else mask,
+            jnp.zeros((1, 1), jnp.int32), 0.0, block_k, block_k, True,
+            causal)
+        np.testing.assert_allclose(
+            np.asarray(out),
+            np.asarray(_reference_attention(q, k, v, mask, causal=causal)),
+            rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(res[-1][:, 0]),
+            np.asarray(_reference_lse(q, k, mask, causal)[0]),
+            rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("t", [1, 230, 255])
+    @pytest.mark.parametrize("plane,lo,hi", [
+        (256, 0, 256), (256, 256, 512), (256, 512, 768),  # one plane each,
+        (256, 768, 1024),                                 # as the backward
+        (256, 0, 512), (256, 512, 1024),     # two, as the forward's chunks
+        (256, 0, 1024), (256, 128, 384), (256, 640, 896),     # straddling
+        (256, 200, 840),
+        (64, 0, 256), (32, 0, 128)])    # tiles of 256 and 128 columns
+    def test_keep_predicate_is_the_byte_extraction(self, plane, lo, hi, t):
+        # the boolean mask is ((word >> 8j) & 0xFF) < t for the byte plane
+        # j each column falls in: held on random words and on the bytes at
+        # the threshold's two sides with every lower bit set
+        rs = np.random.RandomState(lo + hi + t)
+        words = rs.randint(0, 2 ** 32, size=(64, plane), dtype=np.uint64)
+        edge = [(b << 8 * j) | ((1 << 8 * j) - 1)
+                for j in range(4) for b in (max(t - 1, 0), t, 255, 0)]
+        words[0, :len(edge)] = edge[:plane]
+        words[1, :2] = [0, 0xFFFFFFFF]
+        words = words.astype(np.uint32)
+        rate = 1.0 - t / 256.0
+        assert fa._byte_threshold(rate) == t
+        got = np.asarray(fa._keep_of(jnp.asarray(words), rate, lo, hi))
+        cols = np.arange(lo, hi)
+        bytes_ = (words[:, cols % plane] >> (8 * (cols // plane))[None, :]
+                  .astype(np.uint32)) & np.uint32(0xFF)
+        assert got.dtype == bool and got.shape == (64, hi - lo)
+        np.testing.assert_array_equal(got, bytes_ < t)
+        assert fa._keep_gain(rate) == 256.0 / t
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("T,columns", [(2048, 512), (512, 512),
+                                           (256, 256), (384, 128)])
+    def test_gauge_names_the_chunk_of_the_blocks_last_picked(self, T,
+                                                             columns,
+                                                             causal):
+        from analytics_zoo_tpu.observability.registry import get_registry
+        x = jax.ShapeDtypeStruct((1, 1, T, 64), jnp.float32)
+        jax.make_jaxpr(lambda q: flash_attention(
+            q, q, q, interpret=True, causal=causal))(x)
+        gauge = get_registry().get("flash_forward_chunk_columns")
+        assert gauge.value(kernel="flash_fwd_causal" if causal
+                           else "flash_fwd") == columns
 
 
 @pytest.mark.skipif(jax.default_backend() != "tpu",

@@ -94,25 +94,26 @@ def _mosaic_digests(fn, *args):
 
 @pytest.mark.parametrize("B,T,digests", [
     # bert-base-pos2048.fit-seq2048-flash: forward and the one backward
-    (16, 2048, ["27795ef4856c42d2", "b308d3349e820aa7"]),
+    (16, 2048, ["1958ff5770fbb10a", "b308d3349e820aa7"]),
     # the two-kernel backward: forward, dq, dk/dv
-    (2, 8192, ["b17463ae723999e6", "188e4ba79c910010", "32e288c95dbec228"]),
+    (2, 8192, ["536a65b75cd15935", "188e4ba79c910010", "32e288c95dbec228"]),
 ])
 def test_noncausal_kernels_are_pinned_instruction_for_instruction(B, T,
                                                                   digests):
     """The causal flag is static: without it the kernels lower to the
     modules pinned here, so a change meant for the causal form, or for a
-    caller, cannot move the flash cell's kernels unseen. The digests are
-    those of PR 27's tree (the child of commit b2c06b7; made by this
-    function on it), where the log-sum-exp became a `[B*H, 1, T]` row in
-    all four kernels and a row statistic a `[block_q, 128]` array equal
-    along its lanes: the forward transposes its running max and sum into
-    the row at the flush, every backward kernel transposes the row block
-    back once a tile.
-    PR 25's (commit 07fb4ab, `[B*H, T, 1]`) were 6d1e6e9ef29c8231,
-    1db37abbc2ba0f14 and 630378003e1117a3, 374bc763c8822ec1,
-    223abe34395f4d43. A change to the non-causal kernels has to change
-    them here, knowingly."""
+    caller, cannot move the flash cell's kernels unseen. The forward's
+    digests are those of PR 29's tree (the child of commit 0a7f064; made
+    by this function on it), where the forward walks its tile in two
+    column chunks with the scale on the q block, the dropout a select and
+    its gain at the flush: they moved on purpose, from 27795ef4856c42d2
+    and b17463ae723999e6. The three backward kernels' are PR 27's still
+    (commit ba9a553: the log-sum-exp a `[B*H, 1, T]` row, a row statistic
+    a `[block_q, 128]` array): PR 29 left them instruction for
+    instruction. PR 25's (commit 07fb4ab, `[B*H, T, 1]`) were
+    6d1e6e9ef29c8231, 1db37abbc2ba0f14 and 630378003e1117a3,
+    374bc763c8822ec1, 223abe34395f4d43. A change to the non-causal
+    kernels has to change them here, knowingly."""
     q = sds((B, 12, T, 64), jnp.bfloat16)
     mask = sds((B, 1, 1, T), jnp.float32)
 
