@@ -13,7 +13,10 @@ Axis convention (outermost → innermost, i.e. DCN-most → ICI-most):
     data     — data parallel; gradients all-reduce here.
     fsdp     — parameter/optimizer-state sharding (ZeRO-3 style all-gather).
     sequence — sequence/context parallel; ring attention `ppermute`s here.
-    expert   — expert parallel; MoE all-to-all rides here.
+    expert   — expert parallel. Today the name alone: the expert layer
+               (`keras/moe.py`) is told which experts it holds and runs
+               one chip's part without an exchange; no sharding rule or
+               all-to-all rides here yet (ROADMAP M4).
     tensor   — tensor parallel; activation collectives need the fastest links.
 """
 

@@ -17,7 +17,11 @@ ops. This build is TPU-first:
 - a decoder block of today's kind beside them (`TransformerDecoderBlock`):
   RMS norm before and after each branch, bias-free causal self-attention
   with rotary positions, a gated FFN; the causal mask is a flag of the
-  flash kernels, never a [T, T] operand.
+  flash kernels, never a [T, T] operand;
+- its pre-norm sibling (`PreNormDecoderBlock`: one RMS norm before each
+  branch), whose attention and FFN are layers handed to it: latent
+  attention (`keras/latent_attention.py`), a dense `GatedFFN` or an expert
+  layer (`keras/moe.py`).
 """
 
 from __future__ import annotations
@@ -200,15 +204,41 @@ def rotary_tables(seq_len: int, head_dim: int, theta: float = 10000.0):
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def apply_rotary(x, cos, sin):
-    """Rotary positions on x [B, H, T, Dh], rotate-half pairing (dimension
-    i with i + Dh/2), over all Dh dimensions; computed in float32 and
-    returned in x's type."""
+def apply_rotary(x, cos, sin, interleaved: bool = False):
+    """Rotary positions on x [B, H, T, Dh] over all Dh dimensions;
+    computed in float32 and returned in x's type. The pairing is
+    rotate-half (dimension i with i + Dh/2) or, with `interleaved`,
+    neighbours (2i with 2i + 1, as a checkpoint with `rope_interleave`
+    stores them): the interleaved input is de-interleaved first, so the
+    OUTPUT is in rotate-half order either way, [the turned first members |
+    the turned second members]. Scores do not depend on the order of the
+    columns as long as q and k share it."""
     half = x.shape[-1] // 2
     x32 = x.astype(jnp.float32)
-    a, b = x32[..., :half], x32[..., half:]
+    if interleaved:
+        a, b = x32[..., 0::2], x32[..., 1::2]
+    else:
+        a, b = x32[..., :half], x32[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
+
+
+def gated_ffn(params, u, act):
+    """`down(act(gate u) * (up u))` from the three bias-free kernels
+    `ffn_gate_kernel`, `ffn_up_kernel`, `ffn_down_kernel` of `params`
+    (their int8 forms where present), in u's type."""
+    f = act(maybe_int8_matmul(u, params, "ffn_gate_kernel")) \
+        * maybe_int8_matmul(u, params, "ffn_up_kernel")
+    return maybe_int8_matmul(f.astype(u.dtype), params,
+                             "ffn_down_kernel").astype(u.dtype)
+
+
+def gated_ffn_params(rng, hidden_size: int, width: int, init):
+    """The three kernels `gated_ffn` reads."""
+    k1, k2, k3 = jax.random.split(rng, 3)
+    return {"ffn_gate_kernel": init(k1, (hidden_size, width), jnp.float32),
+            "ffn_up_kernel": init(k2, (hidden_size, width), jnp.float32),
+            "ffn_down_kernel": init(k3, (width, hidden_size), jnp.float32)}
 
 
 class CausalSelfAttention(Layer):
@@ -301,11 +331,67 @@ class TransformerDecoderBlock(Layer):
 
     def ffn_branch(self, params, h):
         u = self.norm.call(params["ffn_in_norm"], h)
-        f = self.act(maybe_int8_matmul(u, params, "ffn_gate_kernel")) \
-            * maybe_int8_matmul(u, params, "ffn_up_kernel")
-        f = maybe_int8_matmul(f.astype(h.dtype), params,
-                              "ffn_down_kernel").astype(h.dtype)
-        return h + self.norm.call(params["ffn_out_norm"], f)
+        return h + self.norm.call(params["ffn_out_norm"],
+                                  gated_ffn(params, u, self.act))
+
+    def call(self, params, x, *, training=False, rng=None):
+        h, rotary = x
+        return self.ffn_branch(params,
+                               self.attention_branch(params, h, rotary))
+
+    def compute_output_shape(self, input_shape):
+        return input_shape[0]
+
+
+class GatedFFN(Layer):
+    """Bias-free gated feed-forward layer, `down(act(gate u) * (up u))`
+    (SwiGLU with `silu`)."""
+
+    def __init__(self, hidden_size: int, intermediate_size: int,
+                 hidden_act: str = "silu", init="glorot_uniform", **kw):
+        super().__init__(**kw)
+        self.hidden_size = hidden_size
+        self.intermediate_size = intermediate_size
+        self.act = get_activation(hidden_act)
+        self.init = get_init(init)
+
+    def build(self, rng, input_shape=None):
+        return gated_ffn_params(rng, self.hidden_size,
+                                self.intermediate_size, self.init)
+
+    def call(self, params, u, *, training=False, rng=None):
+        return gated_ffn(params, u, self.act)
+
+
+class PreNormDecoderBlock(Layer):
+    """Pre-norm decoder block, two RMS norms: `h + Attn(RMSNorm(h))`, then
+    `h + FFN(RMSNorm(h))`. The attention is any layer whose `call` takes
+    `[x, (cos, sin)]` (`CausalSelfAttention`,
+    `keras.latent_attention.LatentSelfAttention`), the FFN any layer over
+    `[B, T, H]` (`GatedFFN`, `keras.moe.MoEFeedForward`). `call` takes
+    `[h, (cos, sin)]`; the two branches are methods of their own so that a
+    model can name each in its trace."""
+
+    def __init__(self, attn: Layer, ffn: Layer, rms_eps: float = 1e-6, **kw):
+        super().__init__(**kw)
+        self.attn, self.ffn = attn, ffn
+        self.norm = RMSNormalization(rms_eps, name=self.name + "_norm")
+
+    def build(self, rng, input_shape):
+        shape = input_shape[0] if isinstance(input_shape, list) else input_shape
+        k1, k2 = jax.random.split(rng)
+        return {"attn_norm": self.norm.build(rng, shape),
+                "ffn_norm": self.norm.build(rng, shape),
+                "attn": self.attn.build(k1, shape),
+                "ffn": self.ffn.build(k2, shape)}
+
+    def attention_branch(self, params, h, rotary):
+        return h + self.attn.call(params["attn"], [
+            self.norm.call(params["attn_norm"], h), rotary])
+
+    def ffn_branch(self, params, h):
+        return h + self.ffn.call(params["ffn"],
+                                 self.norm.call(params["ffn_norm"], h))
 
     def call(self, params, x, *, training=False, rng=None):
         h, rotary = x

@@ -72,6 +72,20 @@ iotas, tiles below it run the unmasked body. The causal kernels carry a
 `_causal` suffix on their names and count the causal half in their cost
 estimates. A full `[B,1,T,T]` mask operand still takes the reference path.
 
+Two head widths. Queries and keys may be wider than values (`q, k:
+[B, H, T, Dk]`, `v: [B, H, T, Dv]`; latent attention's keys carry 64 rotary
+columns beside their 128, `keras/latent_attention.py`): every kernel takes
+its widths from its blocks, the scores are scaled by 1/sqrt(Dk), the output
+and dV are Dv wide, dQ and dK are Dk wide. Such kernels carry a `_mla`
+suffix after `_causal` (`_kernel_name`), so a trace tells them from the
+one-width kernels; at Dk == Dv nothing differs from the one-width form,
+instruction for instruction (`tests/test_pallas_tpu_lowering.py` pins the
+modules). A width that is no multiple of 128 lanes (192) is laid out padded
+to the next one in VMEM, which `_bwd_fused_fits` counts: at 192 / 128 the
+one-kernel backward's resident dQ and its q, k and dk blocks are 256 lanes
+wide, so it fits one 1024 tile (T = 1024) and from T = 2048 the backward
+is the pair.
+
 `flash_attention` falls back to a jnp implementation when Pallas is
 unavailable for the current backend (e.g. CPU tests) — same math, no
 tiling; dropout there uses jax.random (different bits, same distribution).
@@ -157,12 +171,13 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     causal: bool = False):
-    """q,k,v: [B, H, T, Dh]. mask: additive [B,1,1,T] (padding) or
+    """q, k: [B, H, T, Dk]; v: [B, H, T, Dv] (Dv may differ from Dk: the
+    scores are scaled by 1/sqrt(Dk)). mask: additive [B,1,1,T] (padding) or
     [B,1,T,T] (full; reference path only). `causal` (static) masks every
     key after the query's own position, inside the kernels. `dropout_rate`
     > 0 needs `dropout_seed` (scalar int32). Differentiable (custom VJP);
     the mask receives a zero cotangent (padding masks are data, not
-    parameters). Returns [B, H, T, Dh].
+    parameters). Returns [B, H, T, Dv].
 
     Block sizes default to the largest 128-multiple divisor of T up to
     1024: per-tile work must amortize the DMA + softmax-state overhead —
@@ -192,7 +207,7 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         return _reference_attention(q, k, v, mask,
                                     dropout_rate if use_dropout else 0.0,
                                     key, causal)
-    B, H, T, D = q.shape
+    B, H, T, _ = q.shape
     if block_q is None:
         block_q = _auto_block(T)
     if block_k is None:
@@ -217,7 +232,8 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         "flash_forward_chunk_columns", "columns of its DMA tile the flash "
         "forward computes at a time (the tile's width where it is not "
         "chunked), at the blocks flash_attention last picked").set(
-            _fwd_chunk(block_k), kernel=_kernel_name("flash_fwd", causal))
+            _fwd_chunk(block_k),
+            kernel=_kernel_name("flash_fwd", causal, _two_widths(q, v)))
     return _flash(q, k, v, mask, seed, rate, block_q, block_k,
                   bool(interpret) if interpret is not None else False,
                   bool(causal))
@@ -268,11 +284,18 @@ def _causal_scores(scores, row0, col0):
     return jnp.where(cols <= rows, scores, _MASKED)
 
 
-def _kernel_name(name, causal):
+def _two_widths(q, v) -> bool:
+    """Keys wider than values (module docstring, "Two head widths")."""
+    return q.shape[-1] != v.shape[-1]
+
+
+def _kernel_name(name, causal, two_widths=False):
     """The name the compiler puts on the kernel's instruction, which the
     benchmark's per-kernel metrics match (docs/ProgrammingGuide/
-    observability.md)."""
-    return name + "_causal" if causal else name
+    observability.md): `_causal` for the causal form, then `_mla` where
+    the keys are wider than the values."""
+    return name + ("_causal" if causal else "") \
+        + ("_mla" if two_widths else "")
 
 
 def _on_causal_tiles(causal, qi, ki, block_q, block_k, tile):
@@ -482,26 +505,31 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
         lse_ref[0] = (m_sc[...] + jnp.log(l)).T[:1]        # [1, bq]
 
 
-def _attn_cost(n_matmuls, q, extra_f32_out_elems=0, causal=False):
-    """Analytic roofline model for one attention kernel over [..., T, D]
-    (check_pallas_cost lint: HLO cost analysis sees ~0 inside a Mosaic
-    call). `n_matmuls` counts the T×T×D matmul-shaped products the
-    kernel runs per head (2 flops each); bytes are the O(T·D) streams —
-    q/k/v-sized reads and writes — NOT the O(T²) scores, which is the
-    IO-aware point of flash attention; exp() is one per score. A causal
-    kernel is counted at the lower triangle: half the products and half
-    the scores (what the algorithm needs; the tiles the diagonal crosses
-    are computed whole)."""
+def _attn_cost(qk_matmuls, v_matmuls, q, v, extra_f32_out_elems=0,
+               causal=False):
+    """Analytic roofline model for one attention kernel over q
+    [..., T, Dk] and v [..., T, Dv] (check_pallas_cost lint: HLO cost
+    analysis sees ~0 inside a Mosaic call). `qk_matmuls` counts the
+    T×T×Dk matmul-shaped products the kernel runs per head (scores, dQ,
+    dK) and `v_matmuls` the T×T×Dv ones (context, dW, dV), 2 flops each;
+    bytes are the O(T·D) streams — q/k/v-sized reads and writes, half of
+    them at each width — NOT the O(T²) scores, which is the IO-aware point
+    of flash attention; exp() is one per score. A causal kernel is counted
+    at the lower triangle: half the products and half the scores (what the
+    algorithm needs; the tiles the diagonal crosses are computed whole)."""
     from jax.experimental import pallas as pl
 
-    *lead, T, D = q.shape
+    *lead, T, Dk = q.shape
+    Dv = v.shape[-1]
     bh = math.prod(lead)
     item = jnp.dtype(q.dtype).itemsize
-    streams = 4 + n_matmuls  # rough: q,k,v(+dout...) in, grads/out out
+    # rough: q,k,v(+dout...) in, grads/out out
+    streams = 4 + qk_matmuls + v_matmuls
     half = 2 if causal else 1
     return pl.CostEstimate(
-        flops=2 * n_matmuls * bh * T * T * D // half,
-        bytes_accessed=bh * T * D * item * streams + extra_f32_out_elems * 4,
+        flops=2 * bh * T * T * (qk_matmuls * Dk + v_matmuls * Dv) // half,
+        bytes_accessed=bh * T * (Dk + Dv) * item * streams // 2
+        + extra_f32_out_elems * 4,
         transcendentals=bh * T * T // half)
 
 
@@ -511,11 +539,12 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     scale = 1.0 / math.sqrt(D)
     n_qb, n_kb = T // block_q, T // block_k
     qf = q.reshape(B * H, T, D)
     kf = k.reshape(B * H, T, D)
-    vf = v.reshape(B * H, T, D)
+    vf = v.reshape(B * H, T, Dv)
     mf = jnp.repeat(mask[:, 0, :, :], H, axis=0)           # [B*H, 1, T]
 
     def kj(i, j):
@@ -529,32 +558,32 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kj(i, j), 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kj(i, j), 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, kj(i, j), 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, kj(i, j))),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        cost_estimate=_attn_cost(2, q,                    # QKᵀ + PV
+        cost_estimate=_attn_cost(1, 1, q, v,              # QKᵀ + PV
                                  extra_f32_out_elems=B * H * T,
                                  causal=causal),
         interpret=interpret,
-        name=_kernel_name("flash_fwd", causal),
+        name=_kernel_name("flash_fwd", causal, _two_widths(q, v)),
     )(qf, kf, vf, mf, seed)
-    out = checkpoint_name(out.reshape(B, H, T, D), FLASH_OUT_NAME)
+    out = checkpoint_name(out.reshape(B, H, T, Dv), FLASH_OUT_NAME)
     lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return out, (q, k, v, mask, seed, out, lse)
 
@@ -678,10 +707,12 @@ def _bwd_chunk(block_k: int) -> int:
 _BWD_FUSED_VMEM_BYTES = 15 * 2 ** 20
 
 
-def _bwd_fused_fits(block_q, block_k, T, D, itemsize) -> bool:
+def _bwd_fused_fits(block_q, block_k, T, D, itemsize, Dv=None) -> bool:
     """Whether the one-kernel backward fits scoped VMEM at these shapes,
     reckoned from what it holds, every [rows, D] buffer padded to 128
-    lanes as Mosaic lays it out at deployment sizes: dq for the whole
+    lanes as Mosaic lays it out at deployment sizes (q, k, dq and dk at
+    the key width `D`; v, dO, O and dv at the value width `Dv`, which is
+    `D` unless given): dq for the whole
     head-batch (f32 scratch plus its double-buffered output block); the
     double-buffered q/dO/O and k/v/dk/dv blocks, the dk/dv accumulators,
     the log-sum-exp's double-buffered [1, block_q] row blocks (8 sublanes
@@ -695,10 +726,12 @@ def _bwd_fused_fits(block_q, block_k, T, D, itemsize) -> bool:
     asks for 0.8-1.0 MiB less at 1024 tiles than with [block_q, 1] column
     blocks (PERF.md, PR 27), the reckoning for 0.44 MiB less."""
     lanes = -(-D // 128) * 128
+    lanes_v = lanes if Dv is None else -(-Dv // 128) * 128
     chunk = _bwd_chunk(block_k)
     resident_dq = T * lanes * (4 + 2 * itemsize)
-    streams = ((6 * block_q + 8 * block_k) * lanes * itemsize
-               + 2 * block_k * lanes * 4
+    streams = ((2 * block_q + 4 * block_k) * lanes * itemsize
+               + (4 * block_q + 4 * block_k) * lanes_v * itemsize
+               + block_k * (lanes + lanes_v) * 4
                + 2 * 8 * block_q * 4 + block_q * _LANES * 4)
     live = block_q * block_k + int(4.5 * block_q * chunk * 4)
     return resident_dq + streams + live <= _BWD_FUSED_VMEM_BYTES
@@ -782,9 +815,10 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _bwd_in_specs(block_q, block_k, D, q_major, causal):
+def _bwd_in_specs(block_q, block_k, D, Dv, q_major, causal):
     """Input BlockSpecs shared by the three backward kernels (q, k, v,
-    mask, seed, dO, lse, O), with the q- and the k-block spec. A
+    mask, seed, dO, lse, O), with the q-, the k- and the v-block spec
+    (q, k `D` wide; v, dO, O `Dv` wide). A
     `q_major` grid is (bh, qi, ki), the other (bh, ki, qi). With `causal`
     the inner axis's blocks stop at the diagonal: a skipped step names the
     nearest needed block, which the pipeline already holds."""
@@ -802,42 +836,47 @@ def _bwd_in_specs(block_q, block_k, D, q_major, causal):
             i, _first_q_block(j, block_q, block_k))))
     q_spec = spec((1, block_q, D), True, lambda b, i: (b, i, 0))
     k_spec = spec((1, block_k, D), False, lambda b, j: (b, j, 0))
+    o_spec = spec((1, block_q, Dv), True, lambda b, i: (b, i, 0))
+    v_spec = spec((1, block_k, Dv), False, lambda b, j: (b, j, 0))
     m_spec = spec((1, 1, block_k), False, lambda b, j: (b, 0, j))
     lse_spec = spec((1, 1, block_q), True, lambda b, i: (b, 0, i))
-    return ([q_spec, k_spec, k_spec, m_spec,
-             pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, lse_spec,
-             q_spec], q_spec, k_spec)
+    return ([q_spec, k_spec, v_spec, m_spec,
+             pl.BlockSpec(memory_space=pltpu.SMEM), o_spec, lse_spec,
+             o_spec], q_spec, k_spec, v_spec)
 
 
 def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    qf = operands[0]
+    qf, vf = operands[0], operands[2]
     BH, T, D = qf.shape
+    Dv = vf.shape[-1]
     n_qb, n_kb = T // block_q, T // block_k
-    in_specs, _, k_spec = _bwd_in_specs(block_q, block_k, D, q_major=False,
-                                        causal=causal)
+    in_specs, _, k_spec, v_spec = _bwd_in_specs(
+        block_q, block_k, D, Dv, q_major=False, causal=causal)
     grad = jax.ShapeDtypeStruct((BH, T, D), qf.dtype)
+    grad_v = jax.ShapeDtypeStruct((BH, T, Dv), qf.dtype)
     return pl.pallas_call(
         functools.partial(_bwd_fused_kernel, rate, scale, n_qb, n_kb,
                           causal),
         grid=(BH, n_kb, n_qb),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, T, D), lambda b, j, i: (b, 0, 0)),
-                   k_spec, k_spec],
-        out_shape=[grad, grad, grad],
+                   k_spec, v_spec],
+        out_shape=[grad, grad, grad_v],
         scratch_shapes=[
             pltpu.VMEM((T, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         # dq is revisited over both inner axes
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        cost_estimate=_attn_cost(5, qf, causal=causal),  # scores, dw, dq,
-        interpret=interpret,                             # dk, dv
-        name=_kernel_name("flash_bwd_fused", causal),
+        # scores, dq, dk at the key width; dw, dv at the value width
+        cost_estimate=_attn_cost(3, 2, qf, vf, causal=causal),
+        interpret=interpret,
+        name=_kernel_name("flash_bwd_fused", causal, _two_widths(qf, vf)),
     )(*operands)
 
 
@@ -845,14 +884,17 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    qf = operands[0]
+    qf, vf = operands[0], operands[2]
     BH, T, D = qf.shape
+    Dv = vf.shape[-1]
+    two = _two_widths(qf, vf)
     n_qb, n_kb = T // block_q, T // block_k
     grad = jax.ShapeDtypeStruct((BH, T, D), qf.dtype)
+    grad_v = jax.ShapeDtypeStruct((BH, T, Dv), qf.dtype)
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
-    in_specs, q_spec, _ = _bwd_in_specs(block_q, block_k, D, q_major=True,
-                                        causal=causal)
+    in_specs, q_spec, _, _ = _bwd_in_specs(block_q, block_k, D, Dv,
+                                           q_major=True, causal=causal)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, rate, scale, n_qb, n_kb, causal),
         grid=(BH, n_qb, n_kb),
@@ -861,26 +903,28 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
         out_shape=grad,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=semantics,
-        cost_estimate=_attn_cost(3, qf, causal=causal),  # scores, dw/ds, dq
+        # scores, dq at the key width; dw at the value width
+        cost_estimate=_attn_cost(2, 1, qf, vf, causal=causal),
         interpret=interpret,
-        name=_kernel_name("flash_dq", causal),
+        name=_kernel_name("flash_dq", causal, two),
     )(*operands)
-    in_specs, _, k_spec = _bwd_in_specs(block_q, block_k, D, q_major=False,
-                                        causal=causal)
+    in_specs, _, k_spec, v_spec = _bwd_in_specs(
+        block_q, block_k, D, Dv, q_major=False, causal=causal)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, rate, scale, n_qb, n_kb, causal),
         grid=(BH, n_kb, n_qb),
         in_specs=in_specs,
-        out_specs=[k_spec, k_spec],
-        out_shape=[grad, grad],
+        out_specs=[k_spec, v_spec],
+        out_shape=[grad, grad_v],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=semantics,
-        cost_estimate=_attn_cost(4, qf, causal=causal),  # scores, dv, ds, dk
+        # scores, dk at the key width; dw, dv at the value width
+        cost_estimate=_attn_cost(2, 2, qf, vf, causal=causal),
         interpret=interpret,
-        name=_kernel_name("flash_dkv", causal),
+        name=_kernel_name("flash_dkv", causal, two),
     )(*operands)
     return dq, dk, dv
 
@@ -888,21 +932,21 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
 def _flash_bwd(rate, block_q, block_k, interpret, causal, res, dout):
     q, k, v, mask, seed, out, lse = res
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     scale = 1.0 / math.sqrt(D)
-    qf, kf, vf, dof, of = (x.reshape(B * H, T, D)
+    qf, kf, vf, dof, of = (x.reshape(B * H, T, x.shape[-1])
                            for x in (q, k, v, dout, out))
     mf = jnp.repeat(mask[:, 0, :, :], H, axis=0)
     # One algorithm, two forms, chosen from the shapes alone: the fused
     # kernel wherever its VMEM need fits, else the pair that pays the
     # duplicated pnorm/dw matmuls with O(block) VMEM at any T. Both run at
     # the forward's tiling — the dropout mask is keyed by tile.
-    fused = _bwd_fused_fits(block_q, block_k, T, D, q.dtype.itemsize)
+    fused = _bwd_fused_fits(block_q, block_k, T, D, q.dtype.itemsize, Dv)
     dq, dk, dv = (_bwd_fused if fused else _bwd_pair)(
         rate, scale, block_q, block_k, interpret, causal,
         (qf, kf, vf, mf, seed, dof, lse, of))
-    shape = (B, H, T, D)
     # padding masks are data, not parameters — zero cotangent
-    return (dq.reshape(shape), dk.reshape(shape), dv.reshape(shape),
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             jnp.zeros_like(mask), jnp.zeros_like(seed))
 
 

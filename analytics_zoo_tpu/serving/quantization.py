@@ -98,6 +98,7 @@ _RAW_INT8_KERNELS = frozenset({
     "qkv_kernel", "out_kernel", "ffn_in_kernel", "ffn_out_kernel",
     "pooler_kernel", "cls_kernel", "ner_kernel", "qa_kernel",
     "ffn_gate_kernel", "ffn_up_kernel", "ffn_down_kernel", "lm_head_kernel",
+    "q_kernel", "kv_a_kernel", "kv_b_kernel",
 })
 
 
@@ -172,8 +173,11 @@ def quantize_model_params(model, params) -> Dict[str, Any]:
                 del out[head]
                 out[head + "_q"], out[head + "_scale"] = q, scale
     from analytics_zoo_tpu.models.looped_decoder import LoopedDecoderLM
-    if isinstance(model, LoopedDecoderLM):
+    from analytics_zoo_tpu.models.moe_decoder import MoEDecoderLM
+    if isinstance(model, (LoopedDecoderLM, MoEDecoderLM)):
         # no layer list either, and the whole tree is the model's own
+        # (an expert layer's router and routed experts have no int8 path
+        # and keep their type: their leaves are not named as raw kernels)
         return _quantize_raw_kernels(out)
     for layer in _iter_layers(model):
         sub = out.get(layer.name)

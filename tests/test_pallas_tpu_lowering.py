@@ -125,6 +125,63 @@ def test_noncausal_kernels_are_pinned_instruction_for_instruction(B, T,
                            q, q, q, mask) == digests
 
 
+@pytest.mark.parametrize("T,digests", [
+    # ouro-2.6b.fit-seq4096: forward and the one backward
+    (4096, ["7a011828263cef96", "2bdaaa74df8dfb6f"]),
+    # the two-kernel backward: forward, dq, dk/dv
+    (8192, ["11d9f0777d1fccc1", "24c1ac587205cd70", "33c5655be04178a7"]),
+])
+def test_causal_kernels_at_one_width_are_pinned_too(T, digests):
+    """PR 30 gave every kernel a second width (keys wider than values).
+    At one width the causal kernels at heads of 128 lower to the modules
+    of PR 29's tree (commit 67b95ef; made by `_mosaic_digests` on it):
+    the generalisation moved no instruction of the accepted cells'
+    kernels, the non-causal ones above included."""
+    q = sds((2, 16, T, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+    assert _mosaic_digests(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) \
+        == digests
+
+
+@pytest.mark.parametrize("T,n_bwd", [(1024, 1), (8192, 2)])
+def test_flash_attention_two_widths_fwd_bwd(T, n_bwd):
+    """Keys 192 wide, values 128 (latent attention): one backward kernel
+    at one 1024 tile, the pair at the expert model's 8192 tokens."""
+    q = sds((2, 32, T, 192), jnp.bfloat16)
+    v = sds((2, 32, T, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, v).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1 + n_bwd
+    assert "flash_fwd_causal_mla" in text
+    assert ("flash_bwd_fused_causal_mla" in text) == (n_bwd == 1)
+    assert ("flash_dq_causal_mla" in text) == (n_bwd == 2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)])
+def test_grouped_matmul_fwd_and_both_gradients(k, n, dtype):
+    """The expert layer's three products at the published widths, 16 held
+    experts, the 2 x 8192 x 6-row buffer: forward, d lhs and d rhs are one
+    Mosaic call each, named for the trace."""
+    from analytics_zoo_tpu.pallas.grouped_matmul import grouped_matmul
+
+    def loss(lhs, rhs, sizes):
+        return grouped_matmul(lhs, rhs, sizes).astype(jnp.float32).sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(
+        sds((98304, k), dtype), sds((16, k, n), dtype),
+        sds((16,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
+        assert name in text
+
+
 @pytest.mark.parametrize("H,D,L", [(4, 64, 256), (2, 8, 128)])
 def test_decode_attention(H, D, L):
     S = 8

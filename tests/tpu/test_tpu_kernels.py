@@ -239,6 +239,140 @@ class TestCausalFlashOnChip:
             == len(kernels)
 
 
+class TestTwoWidthFlashOnChip:
+    """Keys wider than values (latent attention: 192 / 128) at the expert
+    model's fit shape, T = 8192: numbers against plain attention, forward
+    and all three gradients, and which kernels the shapes get."""
+
+    def _qkv(self, T, seed=7):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        return [(jax.random.normal(k, (1, 2, T, d), jnp.float32)
+                 * 0.3).astype(jnp.bfloat16)
+                for k, d in zip(ks, (192, 192, 128))]
+
+    def test_seq8192_k192_v128_forward_and_grads_match_reference(self):
+        from analytics_zoo_tpu.pallas.flash_attention import (
+            _reference_attention, flash_attention)
+        q, k, v = self._qkv(8192)
+        got = flash_attention(q, k, v, causal=True).astype(jnp.float32)
+        assert got.shape == (1, 2, 8192, 128)
+        with jax.default_matmul_precision("highest"):
+            f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+            ref = _reference_attention(*f32, causal=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-2, atol=4e-3)
+
+        def loss(attn, q, k, v):
+            return jnp.sum(attn(q, k, v, causal=True).astype(
+                jnp.float32) ** 2)
+        gf = jax.grad(lambda *a: loss(flash_attention, *a),
+                      argnums=(0, 1, 2))(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            gr = jax.grad(lambda *a: loss(_reference_attention, *a),
+                          argnums=(0, 1, 2))(*f32)
+        for a, b in zip(gf, gr):
+            assert a.shape == b.shape
+            a, b = np.asarray(a.astype(jnp.float32)), np.asarray(b)
+            assert np.linalg.norm(a - b) <= 0.02 * np.linalg.norm(b)
+
+    @pytest.mark.parametrize("T,kernels", [
+        # a key width of 192 lies in 256 lanes (dQ, the q, k and dk blocks):
+        # the one backward kernel fits one 1024 tile, not the fit's 8192
+        (1024, ["flash_bwd_fused_causal_mla", "flash_fwd_causal_mla"]),
+        (8192, ["flash_dkv_causal_mla", "flash_dq_causal_mla",
+                "flash_fwd_causal_mla"]),
+    ])
+    def test_which_backward_the_two_widths_get(self, T, kernels):
+        import re
+
+        from analytics_zoo_tpu.pallas import flash_attention as fa
+        block = fa._auto_block(T)
+        assert fa._bwd_fused_fits(block, block, T, 192, 2, 128) \
+            == (kernels[0] == "flash_bwd_fused_causal_mla")
+        q = jax.ShapeDtypeStruct((2, 32, T, 192), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((2, 32, T, 128), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return fa.flash_attention(q, k, v, causal=True).astype(
+                jnp.float32).sum()
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, v).compile().as_text()
+        assert sorted(set(re.findall(
+            r"(flash_(?:fwd|bwd_fused|dq|dkv)_causal_mla)", text))) \
+            == kernels
+        assert text.count('custom_call_target="tpu_custom_call"') \
+            == len(kernels)
+
+    def test_small_two_width_shape_runs_the_one_kernel_backward(self):
+        from analytics_zoo_tpu.pallas.flash_attention import (
+            _reference_attention, flash_attention)
+        q, k, v = self._qkv(1024, seed=9)
+
+        def loss(attn, q, k, v):
+            return jnp.sum(attn(q, k, v, causal=True).astype(
+                jnp.float32) ** 2)
+        gf = jax.grad(lambda *a: loss(flash_attention, *a),
+                      argnums=(0, 1, 2))(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+            gr = jax.grad(lambda *a: loss(_reference_attention, *a),
+                          argnums=(0, 1, 2))(*f32)
+        for a, b in zip(gf, gr):
+            a, b = np.asarray(a.astype(jnp.float32)), np.asarray(b)
+            assert np.linalg.norm(a - b) <= 0.02 * np.linalg.norm(b)
+
+
+class TestGroupedMatmulOnChip:
+    """The expert layer's grouped products against a loop over the groups:
+    forward and both gradients, with an empty group, a one-row group and a
+    group that takes every row, at the expert model's widths."""
+
+    @pytest.mark.parametrize("sizes", [
+        [700, 0, 1, 300, 999, 256, 0, 512],       # 2768 of 4096 rows
+        [0, 0, 4096, 0, 0, 0, 0, 0],              # one group, every row
+        [0, 0, 0, 0, 0, 0, 0, 1],
+        [0, 0, 0, 0, 0, 0, 0, 0],
+    ])
+    @pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)])
+    def test_matches_a_loop_over_the_groups(self, sizes, k, n):
+        from analytics_zoo_tpu.pallas.grouped_matmul import grouped_matmul
+        m, G = 4096, len(sizes)
+        ks = jax.random.split(jax.random.PRNGKey(3), 3)
+        lhs = (jax.random.normal(ks[0], (m, k)) * 0.5).astype(jnp.bfloat16)
+        rhs = (jax.random.normal(ks[1], (G, k, n)) * 0.05).astype(
+            jnp.bfloat16)
+        cot = jax.random.normal(ks[2], (m, n), jnp.float32)
+        gs = jnp.asarray(sizes, jnp.int32)
+        starts = np.concatenate([[0], np.cumsum(sizes)])
+        rows = jnp.arange(m)
+        owned = (rows < starts[-1])[:, None]
+
+        def system(lhs, rhs):
+            out = grouped_matmul(lhs, rhs, gs).astype(jnp.float32)
+            return jnp.sum(jnp.where(owned, out, 0.0) * cot)
+
+        def loop(lhs, rhs):
+            out = jnp.zeros((m, n), jnp.float32)
+            for g in range(G):
+                own = ((rows >= starts[g]) & (rows < starts[g + 1]))[:, None]
+                out = out + jnp.where(own, lhs @ rhs[g], 0.0)
+            return jnp.sum(out * cot)
+
+        got = jax.jit(jax.value_and_grad(system, argnums=(0, 1)))(lhs, rhs)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(jax.value_and_grad(loop, argnums=(0, 1)))(
+                lhs.astype(jnp.float32), rhs.astype(jnp.float32))
+        scale = max(1.0, abs(float(want[0])))
+        assert abs(float(got[0]) - float(want[0])) <= 0.02 * scale
+        for a, b in zip(got[1], want[1]):
+            a = np.asarray(a.astype(jnp.float32))
+            a = np.where(np.asarray(owned), a, 0.0) if a.shape[0] == m \
+                and a.ndim == 2 else a
+            b = np.asarray(b)
+            assert np.isfinite(a).all()
+            assert np.linalg.norm(a - b) <= 0.02 * np.linalg.norm(b) + 1e-6
+
+
 class TestChunkedForwardOnChip:
     """PR 29: the forward walks its 1024 x 1024 DMA tile in two chunks of
     512 columns, at the two shapes the benchmark's cells run: compiled by
