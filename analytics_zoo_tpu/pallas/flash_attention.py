@@ -31,11 +31,18 @@ dropout mask and dW are computed once and feed all three gradients — five
 T×T×D products. The kernel walks the DMA tile in column chunks (a quarter of
 `block_k`, `_bwd_chunk`), so its live f32 intermediates are a quarter of the
 tile; dK/dV accumulate in scratch over the q-blocks of one k-block, dQ in a
-[T, D] scratch that stays on the chip for a whole head-batch. Shapes whose
-resident dQ or whose chunks do not fit scoped VMEM (`_bwd_fused_fits`: very
-long T, oversized tiles) run the same mathematics as two kernels
+[T, D] scratch that stays on the chip for a whole head-batch. That resident
+dQ grows with T: up to the 15 MiB that fit the scoped VMEM a Mosaic kernel
+gets by default the kernel asks for nothing, over it it asks the compiler
+for what it has reckoned (`vmem_limit_bytes`, `_bwd_fused_vmem_limit`), up
+to half of the TensorCore's VMEM (64 MiB on the v5e: bfloat16 at 1024 tiles
+to T = 53,248 at one width of 128 or under, to T = 25,600 at keys 192 /
+values 128). Longer sequences, and tiles that pass the default at one
+tile of T already (float32 at 1024 tiles, heads of 256, tiles over 1024
+columns or with no 128-aligned quarter to walk), run the same mathematics
+as two kernels
 (`flash_dq`, `flash_dkv`) that recompute the weights in each — seven
-products.
+products — with O(block) VMEM at any T.
 
 The residuals. The forward kernel leaves two arrays for the backward: its
 output `[B, H, T, D]` and the rows' log-sum-exp, float32 `[B*H, 1, T]` with
@@ -81,10 +88,10 @@ suffix after `_causal` (`_kernel_name`), so a trace tells them from the
 one-width kernels; at Dk == Dv nothing differs from the one-width form,
 instruction for instruction (`tests/test_pallas_tpu_lowering.py` pins the
 modules). A width that is no multiple of 128 lanes (192) is laid out padded
-to the next one in VMEM, which `_bwd_fused_fits` counts: at 192 / 128 the
-one-kernel backward's resident dQ and its q, k and dk blocks are 256 lanes
-wide, so it fits one 1024 tile (T = 1024) and from T = 2048 the backward
-is the pair.
+to the next one in VMEM, which `_bwd_fused_vmem_need` counts: at 192 / 128
+the one-kernel backward's resident dQ and its q, k and dk blocks are 256
+lanes wide, so it fits the default scoped VMEM at one 1024 tile (T = 1024)
+and asks for more from T = 2048 (30 MiB at the expert model's T = 8192).
 
 `flash_attention` falls back to a jnp implementation when Pallas is
 unavailable for the current backend (e.g. CPU tests) — same math, no
@@ -228,12 +235,22 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
     seed = jnp.asarray(dropout_seed if use_dropout else 0,
                        jnp.int32).reshape(1, 1)
     rate = float(dropout_rate) if use_dropout else 0.0
+    two = _two_widths(q, v)
     get_registry().gauge(
         "flash_forward_chunk_columns", "columns of its DMA tile the flash "
         "forward computes at a time (the tile's width where it is not "
         "chunked), at the blocks flash_attention last picked").set(
-            _fwd_chunk(block_k),
-            kernel=_kernel_name("flash_fwd", causal, _two_widths(q, v)))
+            _fwd_chunk(block_k), kernel=_kernel_name("flash_fwd", causal, two))
+    limit = _bwd_fused_vmem_limit(block_q, block_k, T, q.shape[-1],
+                                  q.dtype.itemsize, v.shape[-1])
+    asked = get_registry().gauge(
+        "flash_backward_vmem_limit_bytes", "scoped VMEM the flash backward "
+        "asks the compiler for (0: the compiler's default holds it), by the "
+        "backward kernel that runs at the blocks flash_attention last "
+        "picked")
+    for name in (("flash_bwd_fused",) if limit is not None
+                 else ("flash_dq", "flash_dkv")):
+        asked.set(limit or 0, kernel=_kernel_name(name, causal, two))
     return _flash(q, k, v, mask, seed, rate, block_q, block_k,
                   bool(interpret) if interpret is not None else False,
                   bool(causal))
@@ -700,15 +717,45 @@ def _bwd_chunk(block_k: int) -> int:
     return quarter if quarter % 128 == 0 else block_k
 
 
-# What the fused backward may hold of the 16 MiB of scoped VMEM a kernel gets
-# by default on the v5e; the last MiB is room for what `_bwd_fused_fits` does
-# not count (the mask blocks, semaphores). The kernel raises no
-# `vmem_limit_bytes`: what does not fit runs as the pair.
-_BWD_FUSED_VMEM_BYTES = 15 * 2 ** 20
+# Scoped VMEM and the one-kernel backward. A Mosaic kernel gets 16 MiB of
+# scoped VMEM by default, a default of the compiler and not the chip's size
+# (a v5e TensorCore has 128 MiB), and `CompilerParams(vmem_limit_bytes=...)`
+# is how a kernel asks for more. Where the reckoned need
+# (`_bwd_fused_vmem_need`) is within `_BWD_FUSED_VMEM_BYTES` (15 MiB: that
+# default less `_VMEM_ROOM_BYTES` for what the reckoning does not count,
+# the mask blocks and semaphores), the kernel asks nothing and lowers as it
+# always has. Over it the kernel asks for its need, rounded up to a MiB,
+# plus the same room (`_bwd_fused_vmem_limit`), as long as that is within
+# the ceiling (`_bwd_fused_vmem_ceiling`). The ceiling is there for the one
+# buffer that grows with T, the resident dQ: a tile that passes the default
+# at the shortest sequence it serves (float32 at 1024 tiles, a tile with no
+# 128-aligned quarter to walk from 768 columns up, any tile over 1024
+# columns) runs as the pair at every length, as it always has, and so does
+# a sequence whose dQ passes the ceiling.
+_DEFAULT_SCOPED_VMEM_BYTES = 16 * 2 ** 20
+_VMEM_ROOM_BYTES = 2 ** 20
+_BWD_FUSED_VMEM_BYTES = _DEFAULT_SCOPED_VMEM_BYTES - _VMEM_ROOM_BYTES
+# VMEM of the TensorCore the repo is built for, where no TPU is attached
+# (a kernel cross-lowered or compiled ahead of time from a CPU): jax's own
+# table, `jax._src.pallas.mosaic.tpu_info`, case "TPU v5 lite".
+_V5E_VMEM_BYTES = 128 * 2 ** 20
 
 
-def _bwd_fused_fits(block_q, block_k, T, D, itemsize, Dv=None) -> bool:
-    """Whether the one-kernel backward fits scoped VMEM at these shapes,
+def _bwd_fused_vmem_ceiling() -> int:
+    """The most scoped VMEM the one-kernel backward may ask for: half of
+    the TensorCore's VMEM (`pltpu.get_tpu_info()` where the default device
+    is a TPU, else the v5e's), so that nothing of XLA's around the call is
+    squeezed, and never under the compiler's default, which every chip
+    grants."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    vmem = (pltpu.get_tpu_info().vmem_capacity_bytes
+            if jax.devices()[0].platform == "tpu" else _V5E_VMEM_BYTES)
+    return max(vmem // 2, _DEFAULT_SCOPED_VMEM_BYTES)
+
+
+def _bwd_fused_vmem_need(block_q, block_k, T, D, itemsize, Dv=None) -> int:
+    """Bytes of VMEM the one-kernel backward holds at these shapes,
     reckoned from what it holds, every [rows, D] buffer padded to 128
     lanes as Mosaic lays it out at deployment sizes (q, k, dq and dk at
     the key width `D`; v, dO, O and dv at the value width `Dv`, which is
@@ -722,7 +769,9 @@ def _bwd_fused_fits(block_q, block_k, T, D, itemsize, Dv=None) -> bool:
     D = 32..256, bf16 and f32 (PERF.md, PR 25): Mosaic allocates 3.4 such
     intermediates where it pads every buffer (192 head-batches) and as
     little as half the total where it does not (4 head-batches), so the
-    reckoning reads high, never low. With the log-sum-exp as rows Mosaic
+    reckoning reads high, never low (T = 8192 at keys 192 / values 128,
+    64 head-batches: 28.56 MiB reckoned, 22.5 allocated). With the
+    log-sum-exp as rows Mosaic
     asks for 0.8-1.0 MiB less at 1024 tiles than with [block_q, 1] column
     blocks (PERF.md, PR 27), the reckoning for 0.44 MiB less."""
     lanes = -(-D // 128) * 128
@@ -734,7 +783,28 @@ def _bwd_fused_fits(block_q, block_k, T, D, itemsize, Dv=None) -> bool:
                + block_k * (lanes + lanes_v) * 4
                + 2 * 8 * block_q * 4 + block_q * _LANES * 4)
     live = block_q * block_k + int(4.5 * block_q * chunk * 4)
-    return resident_dq + streams + live <= _BWD_FUSED_VMEM_BYTES
+    return resident_dq + streams + live
+
+
+def _bwd_fused_vmem_limit(block_q, block_k, T, D, itemsize, Dv=None):
+    """The backward's form at these shapes, as the `vmem_limit_bytes` its
+    one kernel asks for: 0 where the compiler's default holds it (no limit
+    is passed), the need rounded up to a MiB plus the room where it has to
+    ask, None where it runs as the pair (comment above)."""
+    def need(T):
+        return _bwd_fused_vmem_need(block_q, block_k, T, D, itemsize, Dv)
+    if need(T) <= _BWD_FUSED_VMEM_BYTES:
+        return 0
+    if need(math.lcm(block_q, block_k)) > _BWD_FUSED_VMEM_BYTES:
+        return None             # the tile, not T: no length is helped
+    limit = -(-need(T) // 2 ** 20) * 2 ** 20 + _VMEM_ROOM_BYTES
+    return limit if limit <= _bwd_fused_vmem_ceiling() else None
+
+
+def _bwd_fused_fits(block_q, block_k, T, D, itemsize, Dv=None) -> bool:
+    """Whether the backward runs as one kernel at these shapes."""
+    return _bwd_fused_vmem_limit(block_q, block_k, T, D, itemsize,
+                                 Dv) is not None
 
 
 def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
@@ -857,6 +927,9 @@ def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
         block_q, block_k, D, Dv, q_major=False, causal=causal)
     grad = jax.ShapeDtypeStruct((BH, T, D), qf.dtype)
     grad_v = jax.ShapeDtypeStruct((BH, T, Dv), qf.dtype)
+    # unset (None) wherever the compiler's default holds the kernel
+    limit = _bwd_fused_vmem_limit(block_q, block_k, T, D,
+                                  qf.dtype.itemsize, Dv) or None
     return pl.pallas_call(
         functools.partial(_bwd_fused_kernel, rate, scale, n_qb, n_kb,
                           causal),
@@ -872,7 +945,8 @@ def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
         ],
         # dq is revisited over both inner axes
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=limit),
         # scores, dq, dk at the key width; dw, dv at the value width
         cost_estimate=_attn_cost(3, 2, qf, vf, causal=causal),
         interpret=interpret,
@@ -938,9 +1012,10 @@ def _flash_bwd(rate, block_q, block_k, interpret, causal, res, dout):
                            for x in (q, k, v, dout, out))
     mf = jnp.repeat(mask[:, 0, :, :], H, axis=0)
     # One algorithm, two forms, chosen from the shapes alone: the fused
-    # kernel wherever its VMEM need fits, else the pair that pays the
-    # duplicated pnorm/dw matmuls with O(block) VMEM at any T. Both run at
-    # the forward's tiling — the dropout mask is keyed by tile.
+    # kernel wherever its VMEM need fits the chip (`_bwd_fused_vmem_limit`),
+    # else the pair that pays the duplicated pnorm/dw matmuls with O(block)
+    # VMEM at any T. Both run at the forward's tiling — the dropout mask is
+    # keyed by tile.
     fused = _bwd_fused_fits(block_q, block_k, T, D, q.dtype.itemsize, Dv)
     dq, dk, dv = (_bwd_fused if fused else _bwd_pair)(
         rate, scale, block_q, block_k, interpret, causal,

@@ -2,15 +2,20 @@
 the backward as ONE kernel (`flash_bwd_fused`) against the dq/dkv pair, at
 the tiling `flash_attention` picks for each sequence length.
 
-    python scripts/bench_flash_decomp.py [rate] [T ...] [--D 128] [--causal]
-                                         [--heads 16] [--tokens 8192]
+    python scripts/bench_flash_decomp.py [rate] [T ...] [--D 128] [--Dv 128]
+                                         [--causal] [--heads 16]
+                                         [--tokens 8192]
 
 The forward line names the column chunk the kernel walks its DMA tile in
 (`_fwd_chunk`); `--D 128 --causal --heads 16 --tokens 8192 0 4096` is the
-seq-4096 decoder fit's call (no dropout, a mask of zeros), timed alone.
-The gate (`_bwd_fused_fits`) decides from the shapes which form a model
-runs; here both are called directly, so the table shows what the gate's
-choice is worth at every T (PERF.md, PR 25, holds one). By default B·T is
+seq-4096 decoder fit's call (no dropout, a mask of zeros), timed alone,
+and `--D 192 --Dv 128 --causal --heads 32 --tokens 16384 0 8192` the
+expert fit's (keys wider than values).
+The gate (`_bwd_fused_vmem_limit`) decides from the shapes which form a
+model runs and how much scoped VMEM the one kernel asks for; here both
+forms are called directly (the one kernel asks for what the gate would
+give it), so the table shows what the gate's choice is worth at every T
+(PERF.md, PR 25 and PR 31, hold one each). By default B·T is
 held at 32,768 tokens, H = 12, D = 64, bf16. All three gradients are outputs
 of the timed call: consuming dq alone lets XLA dead-code-eliminate the pair's
 dk/dv kernel and times half a backward (docs/ROOFLINE.md round 5). A lone
@@ -54,7 +59,10 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("rate", nargs="?", type=float, default=0.1)
     ap.add_argument("lengths", nargs="*", type=int)
-    ap.add_argument("--D", type=int, default=64, help="head width")
+    ap.add_argument("--D", type=int, default=64,
+                    help="head width (of the keys, where --Dv is given)")
+    ap.add_argument("--Dv", type=int, default=None,
+                    help="value width (default: --D)")
     ap.add_argument("--heads", type=int, default=12)
     ap.add_argument("--tokens", type=int, default=32768,
                     help="B x T held at this many tokens")
@@ -64,14 +72,15 @@ def main():
     rate, causal = args.rate, args.causal
     lengths = args.lengths or [512, 1024, 2048, 4096]
     H, D = args.heads, args.D
+    Dv = args.Dv or D
     # off the chip the kernels run interpreted (rate 0 only: no TPU PRNG):
     # a rehearsal of the script, never a timing
     interpret = jax.default_backend() != "tpu"
     for T in lengths:
         B = max(1, args.tokens // T)
         rs = np.random.RandomState(0)
-        q, k, v, dout = (jnp.asarray(rs.randn(B, H, T, D) * 0.5, jnp.bfloat16)
-                         for _ in range(4))
+        q, k, v, dout = (jnp.asarray(rs.randn(B, H, T, d) * 0.5, jnp.bfloat16)
+                         for d in (D, D, Dv, Dv))
         mask = jnp.zeros((B, 1, 1, T), jnp.float32)
         seed = jnp.full((1, 1), 7, jnp.int32)
         block = fa._auto_block(T)
@@ -81,11 +90,12 @@ def main():
         ms_fwd, (out, res) = timeit(jax.jit(functools.partial(
             fa._flash_fwd, rate=rate, block_q=block, block_k=block,
             interpret=interpret, causal=causal)), q, k, v, mask, seed)
-        flat = tuple(x.reshape(B * H, T, D) for x in (q, k, v))
+        flat = tuple(x.reshape(B * H, T, -1) for x in (q, k, v))
         operands = flat + (jnp.repeat(mask[:, 0], H, axis=0), seed,
-                           dout.reshape(B * H, T, D), res[-1],
-                           out.reshape(B * H, T, D))
+                           dout.reshape(B * H, T, Dv), res[-1],
+                           out.reshape(B * H, T, Dv))
         line = (f"RESULT T {T} B {B} H {H} D {D}"
+                f"{f' Dv {Dv}' if Dv != D else ''}"
                 f"{' causal' if causal else ''} blocks {block}x{block} "
                 f"rate {rate}: fwd {ms_fwd:.2f} ms in chunks of "
                 f"{fa._fwd_chunk(block)} columns")
@@ -106,9 +116,14 @@ def main():
                       / jnp.max(jnp.abs(b.astype(jnp.float32))))
                 for a, b in zip(grads["fused"], grads["pair"]))
             line += f", fused-pair largest difference {worst:.1e} of max"
-        fits = fa._bwd_fused_fits(block, block, T, D, q.dtype.itemsize)
-        print(line + f"; the gate runs {'fused' if fits else 'pair'}",
-              flush=True)
+        need = fa._bwd_fused_vmem_need(block, block, T, D, q.dtype.itemsize,
+                                       Dv)
+        limit = fa._bwd_fused_vmem_limit(block, block, T, D,
+                                         q.dtype.itemsize, Dv)
+        choice = ("pair" if limit is None else "fused at the default" if
+                  not limit else f"fused asking {limit / 2 ** 20:.0f} MiB")
+        print(line + f"; the gate runs {choice} (reckoned need "
+              f"{need / 2 ** 20:.2f} MiB)", flush=True)
 
 
 if __name__ == "__main__":

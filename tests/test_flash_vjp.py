@@ -143,7 +143,10 @@ class TestFlashVJP:
         (768, 128, ["flash_bwd_fused"]),      # six k-blocks: dq resident
         (2048, 1024, ["flash_bwd_fused"]),    # the seq-2048 fit's shape
         (4096, 1024, ["flash_bwd_fused"]),
-        (8192, 1024, ["flash_dkv", "flash_dq"]),    # dq no longer fits
+        # dq passes the default scoped VMEM: the kernel asks for more
+        (8192, 1024, ["flash_bwd_fused"]),
+        (53248, 1024, ["flash_bwd_fused"]),   # the last under the ceiling
+        (54272, 1024, ["flash_dkv", "flash_dq"]),   # dq passes the ceiling
         (1536, 768, ["flash_dkv", "flash_dq"]),     # no aligned quarter
     ])
     def test_backward_form_follows_the_shapes(self, T, block, kernels):
@@ -154,7 +157,8 @@ class TestFlashVJP:
         # the seq-4096 decoder fit's shape: 16 heads of 128, 1024 tiles
         (4096, 128, ["flash_bwd_fused_causal"]),
         (4096, 64, ["flash_bwd_fused_causal"]),
-        (8192, 128, ["flash_dkv_causal", "flash_dq_causal"]),
+        (8192, 128, ["flash_bwd_fused_causal"]),
+        (65536, 128, ["flash_dkv_causal", "flash_dq_causal"]),
     ])
     def test_causal_backward_form_and_names(self, T, D, kernels):
         # the causal flag changes the kernels' names, never their form
@@ -352,6 +356,28 @@ class TestChunkedForward:
         gauge = get_registry().get("flash_forward_chunk_columns")
         assert gauge.value(kernel="flash_fwd_causal" if causal
                            else "flash_fwd") == columns
+
+
+@pytest.mark.parametrize("T,Dk,Dv,causal,asked", [
+    # the compiler's default holds the one kernel: it asks nothing
+    (2048, 64, 64, False, {"flash_bwd_fused": 0}),
+    # over it the one kernel asks for what it reckoned (the expert fit's
+    # shape: 28.56 MiB, rounded up, and a MiB)
+    (8192, 192, 128, True, {"flash_bwd_fused_causal_mla": 30 * 2 ** 20}),
+    (8192, 128, 128, True, {"flash_bwd_fused_causal": 20 * 2 ** 20}),
+    # dq passes the ceiling: the pair, which asks nothing, by both names
+    (65536, 128, 128, True, {"flash_dq_causal": 0, "flash_dkv_causal": 0}),
+])
+def test_gauge_names_the_vmem_the_backward_asks_for(T, Dk, Dv, causal,
+                                                    asked):
+    from analytics_zoo_tpu.observability.registry import get_registry
+    q = jax.ShapeDtypeStruct((1, 1, T, Dk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 1, T, Dv), jnp.bfloat16)
+    jax.make_jaxpr(lambda q, v: flash_attention(
+        q, q, v, interpret=True, causal=causal))(q, v)
+    gauge = get_registry().get("flash_backward_vmem_limit_bytes")
+    for kernel, limit in asked.items():
+        assert gauge.value(kernel=kernel) == limit
 
 
 @pytest.mark.skipif(jax.default_backend() != "tpu",

@@ -93,17 +93,58 @@ def test_kernel_names_tell_the_two_width_form():
     assert fa._kernel_name("flash_fwd", False, True) == "flash_fwd_mla"
 
 
-@pytest.mark.parametrize("T,D,Dv,fits", [
-    # one width: the gate is what it was (tests/tpu holds it to the chip)
-    (4096, 128, None, True), (5120, 128, None, False),
-    (2048, 64, None, True), (4096, 128, 128, True),
+_MIB = 2 ** 20
+
+
+@pytest.mark.parametrize("T,D,Dv,limit", [
+    # one width, under the 15 MiB that fit the compiler's default scoped
+    # VMEM: one kernel that asks nothing, as ever (tests/tpu holds the
+    # reckoning to the chip)
+    (4096, 128, None, 0), (2048, 64, None, 0), (4096, 128, 128, 0),
+    # over it the kernel asks for its reckoned need, rounded up, and a MiB
+    (5120, 128, None, 17 * _MIB), (8192, 64, None, 20 * _MIB),
     # keys of 192 lie in 256 lanes, in dQ, the q, k and dk blocks alike:
-    # one 1024 tile fits, two do not
-    (1024, 192, 128, True), (2048, 192, 128, False),
-    (8192, 192, 128, False),
+    # one 1024 tile fits the default, two do not; 8192 is the expert fit's
+    (1024, 192, 128, 0), (2048, 192, 128, 18 * _MIB),
+    (8192, 192, 128, 30 * _MIB),
+    # the ceiling is half of the v5e's 128 MiB: the last length under it,
+    # and the first over it, which still gets the pair
+    (25600, 192, 128, 64 * _MIB), (26624, 192, 128, None),
+    (53248, 128, None, 64 * _MIB), (54272, 128, None, None),
 ])
-def test_fused_backward_gate_at_two_widths(T, D, Dv, fits):
-    assert fa._bwd_fused_fits(1024, 1024, T, D, 2, Dv) == fits
+def test_fused_backward_gate_at_two_widths(T, D, Dv, limit):
+    assert fa._bwd_fused_vmem_limit(1024, 1024, T, D, 2, Dv) == limit
+    assert fa._bwd_fused_fits(1024, 1024, T, D, 2, Dv) == (limit is not None)
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("D,Dv", [(64, 64), (128, 128), (192, 128),
+                                  (256, 256)])
+def test_the_backward_asks_for_vmem_only_over_the_default(D, Dv, itemsize):
+    """Over every tile `_auto_block` picks and every length to 64k: no
+    limit is asked wherever the reckoned need is within the 15 MiB that
+    fit today, so those shapes lower as they always have; a limit asked
+    covers the need and a MiB of room and stays within the ceiling; and a
+    tile that passes the default at one tile of T gets the pair at every
+    length."""
+    ceiling = fa._bwd_fused_vmem_ceiling()
+    assert ceiling == 64 * _MIB     # the v5e's, where no TPU is attached
+    for block in (128, 256, 512, 1024):
+        one_tile = fa._bwd_fused_vmem_need(block, block, block, D, itemsize,
+                                           Dv)
+        for T in range(block, 65536 + 1, block):
+            need = fa._bwd_fused_vmem_need(block, block, T, D, itemsize, Dv)
+            limit = fa._bwd_fused_vmem_limit(block, block, T, D, itemsize,
+                                             Dv)
+            if need <= fa._BWD_FUSED_VMEM_BYTES:
+                assert limit == 0, (block, T)
+            elif one_tile > fa._BWD_FUSED_VMEM_BYTES:
+                assert limit is None, (block, T)
+            elif limit is not None:
+                assert need + _MIB <= limit <= ceiling, (block, T)
+                assert limit % _MIB == 0 and limit < need + 2 * _MIB
+            else:
+                assert need + _MIB > ceiling - _MIB, (block, T)
 
 
 def test_attn_cost_counts_each_product_at_its_width():

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from analytics_zoo_tpu.pallas import dropout as dropout_mod
+from analytics_zoo_tpu.pallas import flash_attention as fa
 from analytics_zoo_tpu.pallas.decode_attention import (decode_attention,
                                                        paged_decode_attention)
 from analytics_zoo_tpu.pallas.flash_attention import flash_attention
@@ -37,33 +38,61 @@ def _mosaic_calls(fn, *args) -> int:
     return text.count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("T,n_bwd", [(128, 1), (2048, 1), (8192, 2)])
-def test_flash_attention_fwd_bwd_dropout(T, n_bwd):
-    q = sds((1, 2, T, 64), jnp.bfloat16)
+def _mosaic_calls_and_vmem(fn, *args):
+    """(Mosaic calls in the TPU lowering of `fn`, the scoped-VMEM sizes
+    they ask for): `CompilerParams(vmem_limit_bytes=n)` is written into
+    the call's configuration as a `scoped_memory_configs` entry of size
+    n, and a kernel that leaves it unset has no such entry."""
+    import re
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    asked = re.findall(r'scoped_memory_configs[^\]]*?size\\22: (\d+)', text)
+    assert len(asked) == text.count("scoped_memory_configs")
+    return text.count("tpu_custom_call"), [int(n) for n in asked]
+
+
+_MIB = 2 ** 20
+
+
+@pytest.mark.parametrize("T,dtype,n_bwd,asked", [
+    (128, jnp.bfloat16, 1, []), (2048, jnp.bfloat16, 1, []),
+    (8192, jnp.bfloat16, 1, [20 * _MIB]),
+    (8192, jnp.float32, 2, []), (65536, jnp.bfloat16, 2, []),
+])
+def test_flash_attention_fwd_bwd_dropout(T, dtype, n_bwd, asked):
+    q = sds((1, 2, T, 64), dtype)
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, dropout_rate=0.1,
                               dropout_seed=jnp.int32(3))
         return out.astype(jnp.float32).sum()
-    # one backward kernel wherever its VMEM reckoning fits (seq 2048 runs
-    # 1024x1024 tiles in 256-column chunks); at 8192 tokens dq for a whole
-    # head-batch no longer stays on the chip, so dq and dk/dv are two kernels
-    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) \
-        == 1 + n_bwd
+    # one backward kernel wherever its VMEM reckoning fits the chip (seq
+    # 2048 runs 1024x1024 tiles in 256-column chunks); at 8192 tokens dq
+    # for a whole head-batch passes the compiler's default scoped VMEM and
+    # the one kernel asks for its need, the only call of the three that
+    # asks anything; float32 at 1024 tiles passes the default at one tile
+    # and dq at 65,536 tokens passes the ceiling: dq and dk/dv are two
+    # kernels there, which ask nothing
+    assert _mosaic_calls_and_vmem(jax.grad(loss, argnums=(0, 1, 2)),
+                                  q, q, q) == (1 + n_bwd, asked)
 
 
-@pytest.mark.parametrize("T,D,n_bwd", [(4096, 128, 1), (4096, 64, 1),
-                                       (8192, 128, 2)])
-def test_flash_attention_causal_fwd_bwd(T, D, n_bwd):
+@pytest.mark.parametrize("T,D,n_bwd,asked", [
+    (4096, 128, 1, []), (4096, 64, 1, []), (8192, 128, 1, [20 * _MIB]),
+    (53248, 128, 1, [64 * _MIB]), (54272, 128, 2, []),
+])
+def test_flash_attention_causal_fwd_bwd(T, D, n_bwd, asked):
     q = sds((2, 16, T, D), jnp.bfloat16)
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(
             jnp.float32).sum()
     # the causal flag keeps the backward's form: one kernel at the
-    # seq-4096 decoder fit's shape (16 heads of 128), the pair at 8192
-    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) \
-        == 1 + n_bwd
+    # seq-4096 decoder fit's shape (16 heads of 128) under the default,
+    # one kernel that asks for 20 MiB at 8192 and for the whole ceiling at
+    # the last length under it, the pair past that
+    assert _mosaic_calls_and_vmem(jax.grad(loss, argnums=(0, 1, 2)),
+                                  q, q, q) == (1 + n_bwd, asked)
 
 
 def _mosaic_digests(fn, *args):
@@ -92,11 +121,52 @@ def _mosaic_digests(fn, *args):
     return out
 
 
+@pytest.fixture
+def default_vmem_only(monkeypatch):
+    """A chip whose ceiling is the compiler's default scoped VMEM (the
+    TensorCores before the v5e have 16 MiB in all): what passes it gets
+    the pair, as every shape did before PR 31."""
+    monkeypatch.setattr(fa, "_bwd_fused_vmem_ceiling", lambda: 16 * _MIB)
+
+
+def _noncausal_digests(B, T):
+    q = sds((B, 12, T, 64), jnp.bfloat16)
+    mask = sds((B, 1, 1, T), jnp.float32)
+
+    def loss(q, k, v, m):
+        out = flash_attention(q, k, v, mask=m, dropout_rate=0.1,
+                              dropout_seed=jnp.int32(3))
+        return out.astype(jnp.float32).sum()
+    return _mosaic_digests(jax.grad(loss, argnums=(0, 1, 2)), q, q, q, mask)
+
+
+def _causal_digests(T):
+    q = sds((2, 16, T, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+    return _mosaic_digests(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_the_pair_is_pinned_where_the_default_is_the_ceiling(
+        default_vmem_only):
+    """The two-kernel backward at 8192 tokens: forward, dq, dk/dv, as PR
+    27 (non-causal) and PR 29's tree (causal) pinned them. Since PR 31
+    the v5e runs one kernel at this length, so the pair is reached here
+    through a ceiling of 16 MiB; its modules have not moved."""
+    assert _noncausal_digests(2, 8192) == [
+        "536a65b75cd15935", "188e4ba79c910010", "32e288c95dbec228"]
+    assert _causal_digests(8192) == [
+        "11d9f0777d1fccc1", "24c1ac587205cd70", "33c5655be04178a7"]
+
+
 @pytest.mark.parametrize("B,T,digests", [
     # bert-base-pos2048.fit-seq2048-flash: forward and the one backward
     (16, 2048, ["1958ff5770fbb10a", "b308d3349e820aa7"]),
-    # the two-kernel backward: forward, dq, dk/dv
-    (2, 8192, ["536a65b75cd15935", "188e4ba79c910010", "32e288c95dbec228"]),
+    # 8192 tokens: the same forward as the pair's above, and the one
+    # backward that asks for 20 MiB (PR 31; made by `_mosaic_digests`)
+    (2, 8192, ["536a65b75cd15935", "ce42d9bfe3330642"]),
 ])
 def test_noncausal_kernels_are_pinned_instruction_for_instruction(B, T,
                                                                   digests):
@@ -114,22 +184,14 @@ def test_noncausal_kernels_are_pinned_instruction_for_instruction(B, T,
     6d1e6e9ef29c8231, 1db37abbc2ba0f14 and 630378003e1117a3,
     374bc763c8822ec1, 223abe34395f4d43. A change to the non-causal
     kernels has to change them here, knowingly."""
-    q = sds((B, 12, T, 64), jnp.bfloat16)
-    mask = sds((B, 1, 1, T), jnp.float32)
-
-    def loss(q, k, v, m):
-        out = flash_attention(q, k, v, mask=m, dropout_rate=0.1,
-                              dropout_seed=jnp.int32(3))
-        return out.astype(jnp.float32).sum()
-    assert _mosaic_digests(jax.grad(loss, argnums=(0, 1, 2)),
-                           q, q, q, mask) == digests
+    assert _noncausal_digests(B, T) == digests
 
 
 @pytest.mark.parametrize("T,digests", [
     # ouro-2.6b.fit-seq4096: forward and the one backward
     (4096, ["7a011828263cef96", "2bdaaa74df8dfb6f"]),
-    # the two-kernel backward: forward, dq, dk/dv
-    (8192, ["11d9f0777d1fccc1", "24c1ac587205cd70", "33c5655be04178a7"]),
+    # 8192 tokens: the pair's forward, and the one backward (PR 31)
+    (8192, ["11d9f0777d1fccc1", "f399b5afafb15215"]),
 ])
 def test_causal_kernels_at_one_width_are_pinned_too(T, digests):
     """PR 30 gave every kernel a second width (keys wider than values).
@@ -137,19 +199,16 @@ def test_causal_kernels_at_one_width_are_pinned_too(T, digests):
     of PR 29's tree (commit 67b95ef; made by `_mosaic_digests` on it):
     the generalisation moved no instruction of the accepted cells'
     kernels, the non-causal ones above included."""
-    q = sds((2, 16, T, 128), jnp.bfloat16)
-
-    def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True).astype(
-            jnp.float32).sum()
-    assert _mosaic_digests(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) \
-        == digests
+    assert _causal_digests(T) == digests
 
 
-@pytest.mark.parametrize("T,n_bwd", [(1024, 1), (8192, 2)])
-def test_flash_attention_two_widths_fwd_bwd(T, n_bwd):
+@pytest.mark.parametrize("T,n_bwd,asked", [
+    (1024, 1, None), (8192, 1, 30 * _MIB), (25600, 1, 64 * _MIB),
+    (26624, 2, None)])
+def test_flash_attention_two_widths_fwd_bwd(T, n_bwd, asked):
     """Keys 192 wide, values 128 (latent attention): one backward kernel
-    at one 1024 tile, the pair at the expert model's 8192 tokens."""
+    at one 1024 tile under the compiler's default, one that asks for 30
+    MiB at the expert model's 8192 tokens, the pair past the ceiling."""
     q = sds((2, 32, T, 192), jnp.bfloat16)
     v = sds((2, 32, T, 128), jnp.bfloat16)
 
@@ -162,6 +221,8 @@ def test_flash_attention_two_widths_fwd_bwd(T, n_bwd):
     assert "flash_fwd_causal_mla" in text
     assert ("flash_bwd_fused_causal_mla" in text) == (n_bwd == 1)
     assert ("flash_dq_causal_mla" in text) == (n_bwd == 2)
+    assert text.count("scoped_memory_configs") == (asked is not None)
+    assert (f"size\\22: {asked}}}" in text) == (asked is not None)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])

@@ -313,7 +313,7 @@ def test_each_flash_kernel_carries_its_name(monkeypatch):
     """Cross-lowered for the TPU from here (as
     tests/test_pallas_tpu_lowering.py does): the Mosaic calls of a flash
     forward and backward are named flash_fwd and flash_bwd_fused (2048
-    tokens), flash_dq and flash_dkv (8192 tokens, where the backward is
+    tokens), flash_dq and flash_dkv (65,536 tokens, where the backward is
     two kernels), in the kernel's own attribute and in the name stack
     that the compiler takes the instruction's name from."""
     from analytics_zoo_tpu.pallas.flash_attention import flash_attention
@@ -322,7 +322,7 @@ def test_each_flash_kernel_carries_its_name(monkeypatch):
     def loss(q, k, v):
         return flash_attention(q, k, v).astype(jnp.float32).sum()
     for T, names in ((2048, ["flash_bwd_fused", "flash_fwd"]),
-                     (8192, ["flash_dkv", "flash_dq", "flash_fwd"])):
+                     (65536, ["flash_dkv", "flash_dq", "flash_fwd"])):
         q = jax.ShapeDtypeStruct((1, 2, T, 64), jnp.bfloat16)
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
             q, q, q).lower(lowering_platforms=("tpu",)).as_text(

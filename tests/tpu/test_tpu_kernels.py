@@ -148,29 +148,41 @@ class TestDropoutBackwardAgainstItsOwnMask:
 
 
 class TestFusedBackwardFits:
-    @pytest.mark.parametrize("T,D,dtype", [
-        (4096, 64, jnp.bfloat16), (2048, 128, jnp.bfloat16),
-        (512, 64, jnp.float32), (1536, 64, jnp.bfloat16),
-        (2048, 64, jnp.bfloat16)])      # the seq-2048 fit's own shape
-    def test_shapes_the_gate_admits_compile(self, T, D, dtype):
-        """`_bwd_fused_fits` reckons the kernel's VMEM need from the
+    @pytest.mark.parametrize("T,D,Dv,dtype,mib", [
+        (4096, 64, 64, jnp.bfloat16, 0), (2048, 128, 128, jnp.bfloat16, 0),
+        (512, 64, 64, jnp.float32, 0), (1536, 64, 64, jnp.bfloat16, 0),
+        (2048, 64, 64, jnp.bfloat16, 0),    # the seq-2048 fit's own shape
+        # the largest under the ceiling at each width (PR 31): the kernel
+        # asks for all 64 MiB of it (float32: the longest that still gets
+        # 512 tiles, since a 1024 tile passes the default by itself)
+        (53248, 64, 64, jnp.bfloat16, 64), (53248, 128, 128, jnp.bfloat16, 64),
+        (25600, 192, 128, jnp.bfloat16, 64), (38400, 64, 64, jnp.float32, 63)])
+    def test_shapes_the_gate_admits_compile(self, T, D, Dv, dtype, mib):
+        """`_bwd_fused_vmem_need` reckons the kernel's VMEM need from the
         shapes; the chip's compiler has the last word. Shapes near the
-        reckoning's limit compile (nothing runs) as ONE backward kernel,
-        under the default scoped-VMEM limit."""
+        reckoning's limits compile (nothing runs) as ONE backward kernel:
+        under the default scoped-VMEM limit, and under the ceiling with
+        the limit the kernel asks for."""
         from analytics_zoo_tpu.pallas import flash_attention as fa
         block = fa._auto_block(T)
-        assert fa._bwd_fused_fits(block, block, T, D,
-                                  jnp.dtype(dtype).itemsize)
+        assert fa._bwd_fused_vmem_ceiling() == 64 * 2 ** 20   # a v5e
+        assert fa._bwd_fused_vmem_limit(
+            block, block, T, D, jnp.dtype(dtype).itemsize,
+            Dv) == mib * 2 ** 20
         # many head-batches: Mosaic pads every buffer only at such sizes
-        x = jax.ShapeDtypeStruct((8, 12, T, D), dtype)
+        # (fewer of the longest, whose operands would not fit the HBM)
+        B = 8 if T <= 4096 else 2
+        q = jax.ShapeDtypeStruct((B, 12, T, D), dtype)
+        v = jax.ShapeDtypeStruct((B, 12, T, Dv), dtype)
 
         def loss(q, k, v):
             out = fa.flash_attention(q, k, v, dropout_rate=0.1,
                                      dropout_seed=jnp.int32(3))
             return out.astype(jnp.float32).sum()
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            x, x, x).compile().as_text()
+            q, q, v).compile().as_text()
         assert text.count('custom_call_target="tpu_custom_call"') == 2
+        assert "flash_bwd_fused" in text
 
 
 class TestCausalFlashOnChip:
@@ -209,13 +221,17 @@ class TestCausalFlashOnChip:
                 jnp.float32)[:, :, :3000]))
 
     @pytest.mark.parametrize("T,D,dtype,kernels", [
-        # T = 4096 at 1024 tiles reckons 14.56 MiB of the 15 allowed (the
-        # log-sum-exp as row blocks and one column, PR 27); one more tile
-        # of T (15.56) and dq no longer stays on the chip
+        # T = 4096 at 1024 tiles reckons 14.56 MiB of the 15 that fit the
+        # default (the log-sum-exp as row blocks and one column, PR 27);
+        # one more tile of T (15.56) and the one kernel asks for 17 MiB
+        # (PR 31; the pair before); T = 53248 asks for the ceiling's 64 MiB
+        # and one more tile of T is the pair's
         (4096, 128, jnp.bfloat16, ["flash_bwd_fused_causal",
                                    "flash_fwd_causal"]),
-        (5120, 128, jnp.bfloat16, ["flash_dkv_causal", "flash_dq_causal",
+        (5120, 128, jnp.bfloat16, ["flash_bwd_fused_causal",
                                    "flash_fwd_causal"]),
+        (54272, 128, jnp.bfloat16, ["flash_dkv_causal", "flash_dq_causal",
+                                    "flash_fwd_causal"]),
     ])
     def test_edges_of_the_gate_at_heads_of_128_compile(self, T, D, dtype,
                                                        kernels):
@@ -253,7 +269,11 @@ class TestTwoWidthFlashOnChip:
     def test_seq8192_k192_v128_forward_and_grads_match_reference(self):
         from analytics_zoo_tpu.pallas.flash_attention import (
             _reference_attention, flash_attention)
+        from analytics_zoo_tpu.pallas import flash_attention as fa
         q, k, v = self._qkv(8192)
+        # the backward held below is the one kernel that asks for 30 MiB
+        assert fa._bwd_fused_vmem_limit(1024, 1024, 8192, 192, 2,
+                                        128) == 30 * 2 ** 20
         got = flash_attention(q, k, v, causal=True).astype(jnp.float32)
         assert got.shape == (1, 2, 8192, 128)
         with jax.default_matmul_precision("highest"):
@@ -277,10 +297,13 @@ class TestTwoWidthFlashOnChip:
 
     @pytest.mark.parametrize("T,kernels", [
         # a key width of 192 lies in 256 lanes (dQ, the q, k and dk blocks):
-        # the one backward kernel fits one 1024 tile, not the fit's 8192
+        # the one backward kernel fits the default scoped VMEM at one 1024
+        # tile and asks for 30 MiB at the fit's 8192 (PR 31; the pair
+        # before); past the ceiling (25,600 tokens) the pair
         (1024, ["flash_bwd_fused_causal_mla", "flash_fwd_causal_mla"]),
-        (8192, ["flash_dkv_causal_mla", "flash_dq_causal_mla",
-                "flash_fwd_causal_mla"]),
+        (8192, ["flash_bwd_fused_causal_mla", "flash_fwd_causal_mla"]),
+        (26624, ["flash_dkv_causal_mla", "flash_dq_causal_mla",
+                 "flash_fwd_causal_mla"]),
     ])
     def test_which_backward_the_two_widths_get(self, T, kernels):
         import re
