@@ -7,16 +7,21 @@ all query heads share:
     q            = x Wq                   -> [T, heads, nope + rope]
     [c | kr]     = x Wkva                 -> [T, rank], [T, rope]
     [k_nope | v] = RMSNorm(c) Wkvb        -> [T, heads, nope], [T, heads, v]
-    q_rope, kr   = rotary positions over the `rope` columns
+    q_rope, kr   = rotary positions over the `rope` columns, where the
+                   layer has them (`rotary`)
     k            = [k_nope | kr for every head];  q = [q_nope | q_rope]
     out          = softmax(q k^T / sqrt(nope + rope) + causal) v  Wo
 
 so queries and keys are `nope + rope` wide and values `v` wide (192 and
 128 in the published models): the attention runs through
 `pallas.flash_attention` at two head widths. No bias anywhere, no query
-compression (`q_lora_rank` null). The training path forms k and v whole;
-the latent cache row `[c | kr]` that makes the form worth having when
-serving is not built here (ROADMAP M6).
+compression (`q_lora_rank` null). With `rotary=False` (a config's
+`mla_use_nope`: a hybrid model whose linear-attention layers carry the
+order) no position is applied anywhere: the `rope` columns stay, as
+content, and the one shared key head enters the scores as it is. The
+training path forms k and v whole; the latent cache row `[c | kr]` that
+makes the form worth having when serving is not built here (ROADMAP M6:
+left are that row as a cache entry and the decode step that reads it).
 """
 
 from __future__ import annotations
@@ -39,18 +44,20 @@ class LatentSelfAttention(Layer):
     by every block. The rotary columns are neighbouring pairs
     (2i, 2i + 1), as a checkpoint with `rope_interleave` keeps them; they
     are de-interleaved into rotate-half order, the same for q and k
-    (`apply_rotary`)."""
+    (`apply_rotary`). With `rotary=False` the tables are not read (None
+    will do) and the columns keep their order."""
 
     def __init__(self, hidden_size: int, n_head: int, kv_lora_rank: int,
                  qk_nope_head_dim: int, qk_rope_head_dim: int,
                  v_head_dim: int, rms_eps: float = 1e-6,
-                 use_flash: bool = False, init="glorot_uniform", **kw):
+                 use_flash: bool = False, init="glorot_uniform",
+                 rotary: bool = True, **kw):
         super().__init__(**kw)
         self.hidden_size, self.n_head = hidden_size, n_head
         self.rank = kv_lora_rank
         self.nope, self.rope, self.v_dim = (qk_nope_head_dim,
                                             qk_rope_head_dim, v_head_dim)
-        self.use_flash = use_flash
+        self.use_flash, self.rotary = use_flash, rotary
         self.init = get_init(init)
         self.kv_norm = RMSNormalization(rms_eps, name=self.name + "_kv_norm")
 
@@ -69,7 +76,7 @@ class LatentSelfAttention(Layer):
         }
 
     def call(self, params, x, *, training=False, rng=None):
-        x, (cos, sin) = x
+        x, tables = x
         B, T, _ = x.shape
         n = self.n_head
 
@@ -77,7 +84,9 @@ class LatentSelfAttention(Layer):
             return a.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
 
         def rotary(a):
-            return apply_rotary(a, cos, sin, interleaved=True)
+            if not self.rotary:
+                return a
+            return apply_rotary(a, *tables, interleaved=True)
 
         with jax.named_scope("mla/q_proj"):
             q = heads(maybe_int8_matmul(x, params, "q_kernel")

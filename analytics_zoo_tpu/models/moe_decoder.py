@@ -1,35 +1,43 @@
-"""A causal language model of pre-norm blocks with latent attention and
+"""A causal language model of pre-norm blocks whose token mixer is latent
+attention or a linear-attention layer, layer by layer, over a dense FFN or
 routed experts, on the fit path.
 
     h = E[ids]
-    Block(h):  h = h + MLA(RMSNorm_1(h));  h = h + FFN(RMSNorm_2(h))
-    the first `n_dense_layer` blocks: FFN = a dense gated FFN
-    every later block:               FFN = shared experts + routed experts
+    Block_l(h):  h = h + Mixer_l(RMSNorm_1(h));  h = h + FFN_l(RMSNorm_2(h))
+    Mixer_l: `mixers[l]`: "latent" = multi-head latent attention (MLA),
+             "linear" = Kimi Delta Attention (KDA); latent everywhere
+             unless `mixers` is given
+    FFN_l:   the first `n_dense_layer` blocks a dense gated FFN, every
+             later block shared experts + routed experts
     logits = RMSNorm_f(h) W_head                              (untied)
 
 Blocks are `keras.transformer.PreNormDecoderBlock`s over
-`keras.latent_attention.LatentSelfAttention` and `keras.transformer.
-GatedFFN` or `keras.moe.MoEFeedForward`. The model may be ONE chip's share
-of an expert-parallel deployment: `experts_held` is the range of every
-layer's routed experts that live here (`keras/moe.py`), and `vocab` the
-slice of the vocabulary whose embedding rows and head columns live here;
-everything else is whole.
+`keras.latent_attention.LatentSelfAttention` or `keras.linear_attention.
+KimiDeltaAttention` and `keras.transformer.GatedFFN` or `keras.moe.
+MoEFeedForward`. The model may be ONE chip's share of an expert-parallel
+deployment: `experts_held` is the range of every layer's routed experts
+that live here (`keras/moe.py`), and `vocab` the slice of the vocabulary
+whose embedding rows and head columns live here; everything else is whole.
 
-TPU-first layout, as `models/looped_decoder.py`: the blocks of a kind are
-ONE `[n, ...]` buffer per tensor, `lax.scan`ned, so each kind compiles once
-and its gradients are born stacked. With `remat` every layer is a
-`jax.checkpoint` that keeps its [B, T, H] input and, with `use_flash`, the
-attention kernel's output and log-sum-exp
-(`pallas.flash_attention.save_flash_residuals`), and computes the rest
-again in the backward pass: norms, projections, the router's choice, the
-dispatch and the expert products, but no second attention forward. In
-training `apply` hands the loss `ProjectedLogits`, so the [B, T, vocab]
-logits are never formed whole (`ops/objectives.py`).
+TPU-first layout, as `models/looped_decoder.py`: neighbouring layers of one
+(mixer, FFN) kind are ONE `[n, ...]` buffer per tensor, `lax.scan`ned, so
+each run compiles once and its gradients are born stacked; the runs follow
+one another in the layers' own order (`_runs`). Latent attention alone
+gives the two runs `dense_blocks` and `moe_blocks`; a model with linear
+layers names a run `blocks_<first layer>_<mixer>_<ffn>`. With `remat` every
+layer is a `jax.checkpoint` that keeps its [B, T, H] input and the
+attention kernel's output (with `use_flash` the flash kernel's and its
+log-sum-exp, `pallas.flash_attention.save_flash_residuals`; the linear
+layer's recurrence output always, by its own name), and computes the rest
+again in the backward pass: norms, projections, convolutions and gates, the
+router's choice, the dispatch and the expert products, but no second
+attention forward. In training `apply` hands the loss `ProjectedLogits`, so
+the [B, T, vocab] logits are never formed whole (`ops/objectives.py`).
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,8 +51,28 @@ from analytics_zoo_tpu.keras.transformer import (GatedFFN,
                                                  rotary_tables)
 from analytics_zoo_tpu.observability.registry import get_registry
 from analytics_zoo_tpu.ops.objectives import ProjectedLogits
-from analytics_zoo_tpu.pallas.flash_attention import save_flash_residuals
+from analytics_zoo_tpu.pallas.flash_attention import (FLASH_LSE_NAME,
+                                                      FLASH_OUT_NAME,
+                                                      save_flash_residuals)
 from analytics_zoo_tpu.serving.quantization import maybe_int8_matmul
+
+
+def _runs(mixers, n_dense):
+    """The layers as runs of neighbours of one (mixer, FFN) kind:
+    [(parameter name, mixer, "dense" | "moe", first layer, layers)]. Latent
+    attention alone keeps the two names a tree of such a model has had
+    (`dense_blocks`, `moe_blocks`)."""
+    kinds = [(m, "dense" if l < n_dense else "moe")
+             for l, m in enumerate(mixers)]
+    runs, first = [], 0
+    for l in range(1, len(kinds) + 1):
+        if l == len(kinds) or kinds[l] != kinds[first]:
+            mixer, ffn = kinds[first]
+            name = f"{ffn}_blocks" if "linear" not in mixers \
+                else f"blocks_{first}_{mixer}_{ffn}"
+            runs.append((name, mixer, ffn, first, l - first))
+            first = l
+    return runs
 
 
 class MoEDecoderLM(KerasNet):
@@ -62,22 +90,43 @@ class MoEDecoderLM(KerasNet):
                  routed_scaling_factor: float = 1.0,
                  rope_theta: float = 10000.0, rms_eps: float = 1e-6,
                  hidden_act: str = "silu", use_flash: bool = False,
-                 remat: bool = True, name=None):
+                 remat: bool = True, rotary: bool = True,
+                 mixers: Optional[Sequence[str]] = None,
+                 linear_attention: Optional[Dict] = None, name=None):
+        """`mixers`: one of "latent" / "linear" a layer, in the layers'
+        order (None: latent everywhere); `linear_attention`: the linear
+        layers' own arguments (`keras.linear_attention.
+        KimiDeltaAttention`: n_head, head_dim, conv_size, chunk, ...);
+        `rotary=False` builds the latent layers without positions."""
         super().__init__(name)
         if not 0 <= n_dense_layer < n_layer:
             raise ValueError("MoEDecoderLM needs at least one expert layer "
                              f"after its {n_dense_layer} dense ones")
+        mixers = list(mixers or ["latent"] * n_layer)
+        if len(mixers) != n_layer or set(mixers) - {"latent", "linear"}:
+            raise ValueError(f"MoEDecoderLM: mixers {mixers} must name "
+                             f"\"latent\" or \"linear\" for each of the "
+                             f"{n_layer} layers")
         self.vocab, self.hidden_size = vocab, hidden_size
-        self.n_dense, self.n_moe = n_dense_layer, n_layer - n_dense_layer
         self.rope_dim, self.rope_theta = qk_rope_head_dim, rope_theta
-        self.remat = remat
+        self.remat, self.rotary = remat, rotary
+        # what a layer's checkpoint keeps: the attention kernels' outputs
+        self.kept = save_flash_residuals
         init = jax.nn.initializers.normal(0.02)
 
-        def attention(tag):
+        def mixer(kind, tag):
+            if kind == "linear":
+                # imported where a model has one: the other families'
+                # start-up path does not pay for it
+                from analytics_zoo_tpu.keras.linear_attention import \
+                    KimiDeltaAttention
+                return KimiDeltaAttention(
+                    hidden_size, rms_eps=rms_eps, init=init,
+                    name=f"{self.name}_{tag}_kda", **(linear_attention or {}))
             return LatentSelfAttention(
                 hidden_size, n_head, kv_lora_rank, qk_nope_head_dim,
                 qk_rope_head_dim, v_head_dim, rms_eps=rms_eps,
-                use_flash=use_flash, init=init,
+                use_flash=use_flash, init=init, rotary=rotary,
                 name=f"{self.name}_{tag}_attn")
 
         self.moe = MoEFeedForward(
@@ -86,16 +135,20 @@ class MoEDecoderLM(KerasNet):
             shared_width=n_shared_experts * moe_intermediate_size,
             routed_scaling_factor=routed_scaling_factor,
             hidden_act=hidden_act, init=init, name=self.name + "_moe")
-        self.dense_block = PreNormDecoderBlock(
-            attention("dense"),
-            GatedFFN(hidden_size, intermediate_size, hidden_act, init=init,
-                     name=self.name + "_dense_ffn"),
-            rms_eps, name=self.name + "_dense_block")
-        self.moe_block = PreNormDecoderBlock(
-            attention("moe"), self.moe, rms_eps,
-            name=self.name + "_moe_block")
+        dense_ffn = GatedFFN(hidden_size, intermediate_size, hidden_act,
+                             init=init, name=self.name + "_dense_ffn")
+        # one block a (mixer, FFN) kind; a run of neighbouring layers of
+        # one kind is scanned as one stack
+        self.blocks, self.runs = {}, _runs(mixers, n_dense_layer)
+        for _, kind, ffn, _, _ in self.runs:
+            if (kind, ffn) not in self.blocks:
+                tag = ffn if kind == "latent" else f"{kind}_{ffn}"
+                self.blocks[kind, ffn] = PreNormDecoderBlock(
+                    mixer(kind, tag), self.moe if ffn == "moe" else dense_ffn,
+                    rms_eps, name=f"{self.name}_{tag}_block")
         self.final_norm = RMSNormalization(rms_eps,
                                            name=self.name + "_final_norm")
+        n_linear = mixers.count("linear")
         gauge = get_registry().gauge
         for gname, doc, value in (
                 ("model_experts_routed", "routed experts of an expert "
@@ -107,7 +160,13 @@ class MoEDecoderLM(KerasNet):
                 ("model_shared_experts", "shared experts of an expert "
                  "layer", n_shared_experts),
                 ("model_layer_applications", "block applications in one "
-                 "forward (passes x blocks)", n_layer),
+                 "forward (passes x blocks), whatever each block's mixer",
+                 n_layer),
+                ("model_layers_linear", "layers whose mixer is a linear-"
+                 "attention layer (a state, no keys and values)", n_linear),
+                ("model_layers_full", "layers whose mixer is (latent) "
+                 "softmax attention over the whole sequence",
+                 n_layer - n_linear),
                 ("model_recompute", "1 if every block application is "
                  "recomputed in the backward pass", int(remat)),
                 ("model_recompute_attention_kernel", "1 if the backward "
@@ -116,62 +175,75 @@ class MoEDecoderLM(KerasNet):
                  "residuals saved across it)",
                  int(remat and not use_flash))):
             gauge(gname, doc).set(value, model=self.name)
+        if n_linear:
+            from analytics_zoo_tpu.keras.linear_attention import \
+                RECURRENCE_OUT_NAME
+            self.kept = jax.checkpoint_policies.save_only_these_names(
+                FLASH_OUT_NAME, FLASH_LSE_NAME, RECURRENCE_OUT_NAME)
+            layer = self.blocks[next(k for k in self.blocks
+                                     if k[0] == "linear")].attn
+            gauge("model_linear_chunk", "tokens of a chunk of the linear-"
+                  "attention layers' recurrence").set(layer.chunk,
+                                                      model=self.name)
+            gauge("model_linear_state_bytes", "bytes of one linear-"
+                  "attention layer's state a sequence (heads x dk x dv "
+                  "float32), whatever the sequence's length").set(
+                      4 * layer.n_head * layer.dk * layer.dv,
+                      model=self.name)
 
     def build(self, rng, input_shape=None):
-        k_emb, k_head, *k_blocks = jax.random.split(
-            rng, 2 + self.n_dense + self.n_moe)
+        n_layer = sum(run[4] for run in self.runs)
+        k_emb, k_head, *k_blocks = jax.random.split(rng, 2 + n_layer)
         h_shape = (None, None, self.hidden_size)
-
-        def stacked(block, keys):
-            # each block from its own key, every tensor born [n, ...]
-            return jax.vmap(lambda k: block.build(k, h_shape))(
-                jnp.stack(keys))
-
         p = {
             "word_embeddings": jax.random.normal(
                 k_emb, (self.vocab, self.hidden_size)) * 0.02,
-            "moe_blocks": stacked(self.moe_block, k_blocks[self.n_dense:]),
             "final_norm": self.final_norm.build(rng, h_shape),
             "lm_head_kernel": jax.random.normal(
                 k_head, (self.hidden_size, self.vocab)) * 0.02,
         }
-        if self.n_dense:
-            p["dense_blocks"] = stacked(self.dense_block,
-                                        k_blocks[:self.n_dense])
+        for name, kind, ffn, first, n in self.runs:
+            # each block from its own key, every tensor born [n, ...]
+            p[name] = jax.vmap(
+                lambda k: self.blocks[kind, ffn].build(k, h_shape))(
+                    jnp.stack(k_blocks[first:first + n]))
         return p
 
     def _scan_blocks(self, params, h, rotary, per_moe_layer=None):
-        """The blocks over h [B, T, H]; `per_moe_layer(block params, layer
-        input)`, where given, is stacked over the expert layers and
-        returned beside the hidden state."""
+        """The blocks over h [B, T, H]; `per_moe_layer(block, block params,
+        layer input)`, where given, is stacked over the expert layers, in
+        the layers' order, and returned beside the hidden state."""
+        seen = []
+        for name, kind, ffn, _, _ in self.runs:
+            block = self.blocks[kind, ffn]
 
-        def layer(block, scope):
-            def apply_block(bp, hh):
+            def apply_block(bp, hh, block=block, scope=f"moedec/{ffn}_block"):
                 with jax.named_scope(scope):
                     return block.ffn_branch(
                         bp, block.attention_branch(bp, hh, rotary))
-            if self.remat:
-                return jax.checkpoint(apply_block,
-                                      policy=save_flash_residuals)
-            return apply_block
 
-        if self.n_dense:
-            dense = layer(self.dense_block, "moedec/dense_block")
-            h, _ = jax.lax.scan(lambda a, bp: (dense(bp, a), None), h,
-                                params["dense_blocks"])
-        moe = layer(self.moe_block, "moedec/moe_block")
+            layer = jax.checkpoint(apply_block, policy=self.kept) \
+                if self.remat else apply_block
 
-        def moe_step(a, bp):
-            seen = None if per_moe_layer is None else per_moe_layer(bp, a)
-            return moe(bp, a), seen
+            def step(a, bp, block=block, layer=layer, watch=ffn == "moe"
+                     and per_moe_layer is not None):
+                return layer(bp, a), (per_moe_layer(block, bp, a)
+                                      if watch else None)
 
-        return jax.lax.scan(moe_step, h, params["moe_blocks"])
+            h, out = jax.lax.scan(step, h, params[name])
+            if out is not None:
+                seen.append(out)
+        if len(seen) > 1:
+            return h, jnp.concatenate(seen)
+        return h, (seen[0] if seen else None)
 
     def _embed(self, params, ids):
-        """(embedded ids [B, T, H], the rotary tables of T positions)."""
+        """(embedded ids [B, T, H], the rotary tables of T positions, or
+        None where the latent layers have no positions)."""
         ids = jnp.asarray(ids, jnp.int32)
         return (jnp.take(params["word_embeddings"], ids, axis=0),
-                rotary_tables(ids.shape[1], self.rope_dim, self.rope_theta))
+                rotary_tables(ids.shape[1], self.rope_dim, self.rope_theta)
+                if self.rotary else None)
 
     def hidden(self, params, ids):
         """The final norm's output [B, T, H]."""
@@ -185,24 +257,14 @@ class MoEDecoderLM(KerasNet):
         one forward, [expert layers, B, T, experts per token] int32, out
         of all `n_routed_experts`, whatever is held here."""
         h, rotary = self._embed(params, ids)
-        block = self.moe_block
 
-        def choice(bp, hh):
+        def choice(block, bp, hh):
             hh = block.attention_branch(bp, hh, rotary)
             experts, _ = self.moe.routing(
                 bp["ffn"], block.norm.call(bp["ffn_norm"], hh))
             return experts.reshape(h.shape[:2] + (-1,))
 
         return self._scan_blocks(params, h, rotary, choice)[1]
-
-    def routing_counts(self, params, ids):
-        """Token-slots per routed expert in every expert layer of one
-        forward, [expert layers, n_routed_experts] int32; each row sums to
-        tokens x experts per token."""
-        choice = self.expert_choice(params, ids)
-        return (choice.reshape(choice.shape[0], -1, 1) == jnp.arange(
-            self.moe.n_routed, dtype=choice.dtype)).sum(axis=1,
-                                                        dtype=jnp.int32)
 
     def forward(self, params, ids):
         """Logits [B, T, vocab]."""

@@ -98,7 +98,7 @@ _RAW_INT8_KERNELS = frozenset({
     "qkv_kernel", "out_kernel", "ffn_in_kernel", "ffn_out_kernel",
     "pooler_kernel", "cls_kernel", "ner_kernel", "qa_kernel",
     "ffn_gate_kernel", "ffn_up_kernel", "ffn_down_kernel", "lm_head_kernel",
-    "q_kernel", "kv_a_kernel", "kv_b_kernel",
+    "q_kernel", "kv_a_kernel", "kv_b_kernel", "k_kernel", "v_kernel",
 })
 
 

@@ -298,11 +298,14 @@ def test_training_output_is_unformed_logits_and_remat_changes_no_number():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
 
-def test_routing_counts_count_every_slot_and_the_gauges_say_what_is_held():
+def test_the_choice_counts_every_slot_and_the_gauges_say_what_is_held():
     model = _model()
     params = model.build(jax.random.PRNGKey(0))
     ids = _ids()
-    counts = np.asarray(jax.jit(model.routing_counts)(params, ids))
+    choice = np.asarray(jax.jit(model.expert_choice)(params, ids))
+    assert choice.shape == (2,) + ids.shape + (3,)
+    counts = np.stack([np.bincount(layer.reshape(-1), minlength=ROUTED)
+                       for layer in choice])
     assert counts.shape == (2, ROUTED)
     assert (counts.sum(axis=1) == ids.size * 3).all()
     snap = get_registry().snapshot()
@@ -342,6 +345,259 @@ def test_fit_through_the_estimator_lowers_the_loss():
     from analytics_zoo_tpu.learn.estimator import Estimator
     from analytics_zoo_tpu.ops import objectives
     model = _model()
+    model.params = model.build(jax.random.PRNGKey(0))
+    x = _ids(n=16, T=32)
+    est = Estimator.from_keras(
+        model, optimizer=optax.adamw(1e-2),
+        loss=objectives.get("sparse_categorical_crossentropy",
+                            from_logits=True))
+    hist = est.fit({"x": x, "y": np.roll(x, -1, axis=1)}, epochs=4,
+                   batch_size=8, mixed_precision=True)
+    assert np.isfinite(hist["loss"]).all()
+    assert hist["loss"][-1] < hist["loss"][0]
+
+
+# -- a per-layer mixer pattern (linear and latent attention) -----------------
+from benchmark.reference import kimi_linear as hybrid_reference  # noqa: E402
+
+HYBRID_CFG = dict(
+    CFG, num_experts_per_token=3, num_hidden_layers=4,
+    first_k_dense_replace=1, rms_norm_eps=1e-5, experts_held=[4, 8],
+    linear_attn_config={"full_attn_layers": [3], "kda_layers": [1, 2, 4],
+                        "num_heads": 2, "head_dim": 16})
+HYBRID_MIXERS = ["linear", "linear", "latent", "linear"]
+
+
+def _hybrid(**kw):
+    kw.setdefault("mixers", HYBRID_MIXERS)
+    kw.setdefault("linear_attention", dict(n_head=2, head_dim=16,
+                                           v_head_dim=24, chunk=16))
+    return MoEDecoderLM(
+        vocab=211, hidden_size=H, n_layer=len(kw["mixers"]), n_head=2,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, intermediate_size=96, moe_intermediate_size=WIDTH,
+        n_routed_experts=ROUTED, num_experts_per_tok=3, n_shared_experts=2,
+        experts_held=(4, 8), routed_scaling_factor=2.448, rms_eps=1e-5,
+        rotary=False, name="hybrid_test", **kw)
+
+
+def test_a_mixer_pattern_is_runs_of_neighbours_in_the_layers_own_order():
+    from analytics_zoo_tpu.models.moe_decoder import _runs
+    assert _runs(["latent"] * 3, 1) == [
+        ("dense_blocks", "latent", "dense", 0, 1),
+        ("moe_blocks", "latent", "moe", 1, 2)]
+    # the benchmark's cut of the hybrid: layers 1-5 as published
+    assert _runs(["linear", "linear", "linear", "latent", "linear"], 1) == [
+        ("blocks_0_linear_dense", "linear", "dense", 0, 1),
+        ("blocks_1_linear_moe", "linear", "moe", 1, 2),
+        ("blocks_3_latent_moe", "latent", "moe", 3, 1),
+        ("blocks_4_linear_moe", "linear", "moe", 4, 1)]
+    params = jax.eval_shape(_hybrid().build, jax.random.PRNGKey(0))
+    assert sorted(k for k in params if "blocks" in k) == [
+        "blocks_0_linear_dense", "blocks_1_linear_moe",
+        "blocks_2_latent_moe", "blocks_3_linear_moe"]
+    assert params["blocks_1_linear_moe"]["attn"]["q_kernel"].shape \
+        == (1, H, 32)
+    assert "q_conv" not in params["blocks_2_latent_moe"]["attn"]
+    assert [r[1:] for r in hybrid_reference.layer_runs(HYBRID_CFG)] \
+        == [(m, f, n) for _, m, f, _, n in _hybrid().runs]
+    with pytest.raises(ValueError, match="mixers"):
+        _hybrid(mixers=["latent", "windowed", "latent", "latent"])
+
+
+def test_latent_attention_alone_is_the_model_it_was_bit_for_bit():
+    """No pattern, or a pattern of latent attention alone: the tree, the
+    names and the logits of the expert model (`dense_blocks`,
+    `moe_blocks`), held to its own reference as before."""
+    ids = _ids()
+    plain, named = _model(), _model(mixers=["latent"] * 3)
+    a = plain.build(jax.random.PRNGKey(0))
+    b = named.build(jax.random.PRNGKey(0))
+    assert sorted(a) == ["dense_blocks", "final_norm", "lm_head_kernel",
+                         "moe_blocks", "word_embeddings"]
+    assert jax.tree_util.tree_structure(a) == jax.tree_util.tree_structure(b)
+    for x, y in zip(jax.tree_util.tree_leaves(a),
+                    jax.tree_util.tree_leaves(b)):
+        np.testing.assert_array_equal(x, y)
+    np.testing.assert_array_equal(plain.apply(a, ids), named.apply(b, ids))
+    text = [jax.jit(m.apply).lower(a, ids).as_text() for m in (plain, named)]
+    assert text[0] == text[1]
+
+
+def test_rotary_is_a_switch_and_the_columns_stay_as_content():
+    ids = _ids()
+    params = _model().build(jax.random.PRNGKey(0))
+    without = _model(rotary=False)
+    assert without._embed(params, ids)[1] is None      # no tables are made
+    got = without.apply(params, ids)
+    assert _rel(got, _model().apply(params, ids)) > 1e-3
+    # the shared key head still enters the scores
+    dropped = jax.tree_util.tree_map(lambda a: a, params)
+    dropped["moe_blocks"]["attn"]["kv_a_kernel"] = \
+        params["moe_blocks"]["attn"]["kv_a_kernel"].at[:, :, 32:].set(0.0)
+    assert _rel(without.apply(dropped, ids), got) > 1e-4
+
+
+def test_hybrid_model_matches_its_reference_on_logits_and_every_leaf():
+    model = _hybrid()
+    params = model.build(jax.random.PRNGKey(0))
+    ids = _ids(T=48)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda p, x: model.apply(p, x))(params, ids)
+    want, own = jax.jit(lambda p, x: hybrid_reference.reference_forward(
+        p, x, HYBRID_CFG))(params, ids)
+    np.testing.assert_allclose(got, want, atol=5e-5)
+    choice = jax.jit(model.expert_choice)(params, ids)
+    assert choice.shape == own.shape == (3, 2, 48, 3)
+    assert np.mean(np.sort(choice, -1) == np.sort(own, -1)) > 0.99
+    batch = {"x": ids, "y": _ids(T=48, seed=1)}
+
+    def system_loss(p):
+        logp = jax.nn.log_softmax(model.apply(p, batch["x"]), axis=-1)
+        return -jnp.mean(jnp.take_along_axis(
+            logp, batch["y"][..., None], axis=-1))
+
+    with jax.default_matmul_precision("highest"):
+        g_sys = jax.jit(jax.grad(system_loss))(params)
+    g_ref = jax.jit(jax.grad(lambda p: hybrid_reference.reference_loss(
+        p, batch, HYBRID_CFG)))(params)
+    flat_sys = jax.tree_util.tree_leaves_with_path(g_sys)
+    flat_ref = jax.tree_util.tree_leaves(g_ref)
+    assert len(flat_sys) == len(flat_ref)
+    seen_kda = 0
+    for (path, a), b in zip(flat_sys, flat_ref):
+        name = jax.tree_util.keystr(path)
+        if "'bias'" in name and "router" in name:
+            assert not np.asarray(a).any() and not np.asarray(b).any()
+        else:
+            assert _rel(a, b) < 3e-4, name
+        seen_kda += "A_log" in name or "dt_bias" in name or "_conv" in name
+    assert seen_kda == 3 * 5        # three runs with linear layers
+
+
+def test_hybrid_remat_changes_no_number_and_the_gauges_say_what_is_built():
+    ids = _ids(T=32)
+    params = _hybrid().build(jax.random.PRNGKey(0))
+    grads = [jax.grad(lambda p: jnp.sum(_hybrid(remat=remat).apply(
+        p, ids, training=True).materialize() ** 2))(params)
+        for remat in (True, False)]
+    for a, b in zip(*map(jax.tree_util.tree_leaves, grads)):
+        assert float(jnp.linalg.norm(a - b)) \
+            <= 1e-4 * float(jnp.linalg.norm(b)) + 1e-9
+    snap = get_registry().snapshot()
+
+    def gauge(name):
+        return [s["value"] for s in snap[name]["series"]
+                if s["labels"].get("model") == "hybrid_test"][0]
+    assert gauge("model_layers_linear") == 3
+    assert gauge("model_layers_full") == 1
+    assert gauge("model_layer_applications") == 4
+    assert gauge("model_linear_chunk") == 16
+    assert gauge("model_linear_state_bytes") == 4 * 2 * 16 * 24
+    assert gauge("model_experts_held") == 4
+
+
+def _kernel_calls(jaxpr, counts):
+    """`pallas_call`s of a jaxpr and of everything nested in it, by the
+    kernel's name; a scan's or a checkpoint's body counts once."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] = counts.get(eqn.params["name"], 0) + 1
+            continue
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(v, "jaxpr", v)
+                if hasattr(sub, "eqns"):
+                    _kernel_calls(sub, counts)
+    return counts
+
+
+def test_the_recurrences_output_crosses_a_layers_checkpoint_by_its_own_name():
+    from analytics_zoo_tpu.keras.linear_attention import RECURRENCE_OUT_NAME
+    from analytics_zoo_tpu.pallas import flash_attention as fa
+    assert RECURRENCE_OUT_NAME not in (fa.FLASH_OUT_NAME, fa.FLASH_LSE_NAME)
+    # latent attention alone keeps the policy it had, the same object
+    assert _model().kept is fa.save_flash_residuals
+    # 16 rows of batch x heads: two groups of 8 under their own checkpoints
+    kw = dict(mixers=["linear", "linear"], n_dense_layer=1,
+              linear_attention=dict(n_head=8, head_dim=16, v_head_dim=24,
+                                    chunk=16, interpret=True))
+    ids = _ids(T=32)
+
+    def calls(model):
+        params = jax.eval_shape(model.build, jax.random.PRNGKey(0))
+        return _kernel_calls(jax.make_jaxpr(jax.grad(
+            lambda p: jnp.sum(model.apply(p, ids, training=True)
+                              .materialize() ** 2)))(params).jaxpr, {})
+
+    kept = _hybrid(**kw)
+    assert kept.kept is not fa.save_flash_residuals
+    # a run (dense, expert) of one layer each: the forward kernel in the
+    # forward pass and once more, a group of heads at a time, in the
+    # backward pass; the layer's own recomputation does not run it
+    assert calls(kept) == {"kda_chunk_fwd": 4, "kda_chunk_bwd": 2}
+    dropped = _hybrid(**kw)
+    dropped.kept = fa.save_flash_residuals
+    assert calls(dropped) == {"kda_chunk_fwd": 6, "kda_chunk_bwd": 2}
+
+
+def test_int8_rewrite_reaches_the_linear_layers_projections():
+    from analytics_zoo_tpu.serving.quantization import quantize_model_params
+    model = _hybrid()
+    params = jax.device_get(model.build(jax.random.PRNGKey(0)))
+    q = quantize_model_params(model, params)
+    attn = q["blocks_0_linear_dense"]["attn"]
+    for name in ("q_kernel", "k_kernel", "v_kernel", "out_kernel"):
+        assert name + "_q" in attn and name not in attn
+    for name in ("q_conv", "A_log", "dt_bias", "decay_b_kernel",
+                 "beta_kernel"):
+        assert name in attn                 # gates and filters keep theirs
+    ids = _ids(T=32)
+    assert 1e-4 < _rel(model.apply(q, ids), model.apply(params, ids)) < 0.3
+
+
+def test_the_shares_add_up_at_the_hybrids_router_32_ranges_of_8():
+    """The 256-wide router, 8 a token, 32 ranges of 8 held experts: the
+    routed parts, with the shared expert counted once, sum to the uncut
+    reference's whole layer, forward and the input's gradient."""
+    from analytics_zoo_tpu.keras.transformer import gated_ffn
+    cfg = dict(CFG, num_experts_per_tok=8, routed_scaling_factor=2.446)
+
+    def layer(held):
+        return MoEFeedForward(H, 16, 256, 8, experts_held=held,
+                              shared_width=16, routed_scaling_factor=2.446)
+    params = layer((0, 256)).build(jax.random.PRNGKey(0))
+    u = jnp.asarray(np.random.default_rng(3).normal(size=(1, 24, H)),
+                    jnp.float32)
+    cot = jnp.asarray(np.random.default_rng(4).normal(size=(1, 24, H)),
+                      jnp.float32)
+
+    def shares(u):
+        total = jnp.zeros_like(u)
+        for first in range(0, 256, 8):
+            held = (first, first + 8)
+            total = total + layer(held).routed(_share_of(params, held), u)
+        return total + gated_ffn(params["shared"], u, jax.nn.silu)
+
+    def whole(u):
+        with jax.default_matmul_precision("highest"):
+            return reference._moe(u, params, cfg, (0, 256), None, {},
+                                  False)[0]
+
+    with jax.default_matmul_precision("highest"):
+        got, g_got = jax.value_and_grad(
+            lambda a: jnp.sum(shares(a) * cot))(u)
+    want, g_want = jax.value_and_grad(lambda a: jnp.sum(whole(a) * cot))(u)
+    assert abs(float(got) - float(want)) <= 1e-4 * abs(float(want)) + 1e-4
+    assert _rel(g_got, g_want) < 1e-5
+
+
+def test_hybrid_fit_through_the_estimator_lowers_the_loss():
+    import optax
+
+    from analytics_zoo_tpu.learn.estimator import Estimator
+    from analytics_zoo_tpu.ops import objectives
+    model = _hybrid()
     model.params = model.build(jax.random.PRNGKey(0))
     x = _ids(n=16, T=32)
     est = Estimator.from_keras(

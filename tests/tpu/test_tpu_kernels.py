@@ -295,6 +295,41 @@ class TestTwoWidthFlashOnChip:
             a, b = np.asarray(a.astype(jnp.float32)), np.asarray(b)
             assert np.linalg.norm(a - b) <= 0.02 * np.linalg.norm(b)
 
+    def test_seq16384_k192_v128_matches_plain_attention_head_by_head(self):
+        """The hybrid model's one latent layer: T = 16,384 at two widths,
+        where the one-kernel backward asks for 46 MiB of scoped VMEM;
+        forward and all three gradients against plain attention, a head
+        at a time (a head's 16,384 x 16,384 float32 scores are 1 GB)."""
+        from analytics_zoo_tpu.pallas import flash_attention as fa
+        T = 16384
+        q, k, v = self._qkv(T, seed=11)
+        assert fa._bwd_fused_vmem_limit(1024, 1024, T, 192, 2,
+                                        128) == 46 * 2 ** 20
+
+        def flash_loss(q, k, v):
+            out = fa.flash_attention(q, k, v, causal=True)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
+        (_, got), gf = jax.jit(jax.value_and_grad(
+            flash_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+        @jax.checkpoint
+        def one_head(qkv):
+            qh, kh, vh = (a.astype(jnp.float32)[None, None] for a in qkv)
+            return fa._reference_attention(qh, kh, vh, causal=True)[0, 0]
+
+        def plain_loss(q, k, v):
+            out = jax.lax.map(one_head, (q[0], k[0], v[0]))[None]
+            return jnp.sum(out ** 2), out
+        with jax.default_matmul_precision("highest"):
+            (_, ref), gr = jax.jit(jax.value_and_grad(
+                plain_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                                   np.asarray(ref), rtol=2e-2, atol=4e-3)
+        for a, b in zip(gf, gr):
+            a = np.asarray(a.astype(jnp.float32))
+            b = np.asarray(b.astype(jnp.float32))
+            assert np.linalg.norm(a - b) <= 0.02 * np.linalg.norm(b)
+
     @pytest.mark.parametrize("T,kernels", [
         # a key width of 192 lies in 256 lanes (dQ, the q, k and dk blocks):
         # the one backward kernel fits the default scoped VMEM at one 1024
@@ -302,6 +337,7 @@ class TestTwoWidthFlashOnChip:
         # before); past the ceiling (25,600 tokens) the pair
         (1024, ["flash_bwd_fused_causal_mla", "flash_fwd_causal_mla"]),
         (8192, ["flash_bwd_fused_causal_mla", "flash_fwd_causal_mla"]),
+        (16384, ["flash_bwd_fused_causal_mla", "flash_fwd_causal_mla"]),
         (26624, ["flash_dkv_causal_mla", "flash_dq_causal_mla",
                  "flash_fwd_causal_mla"]),
     ])
@@ -394,6 +430,116 @@ class TestGroupedMatmulOnChip:
             b = np.asarray(b)
             assert np.isfinite(a).all()
             assert np.linalg.norm(a - b) <= 0.02 * np.linalg.norm(b) + 1e-6
+
+
+class TestDeltaRuleOnChip:
+    """The chunked gated delta rule (`pallas/delta_rule.py`) at the hybrid
+    model's fit shape, T = 16,384 and 32 heads of 128: the kernels
+    `kda_chunk_fwd` and `kda_chunk_bwd` compiled by the chip's compiler and
+    held, output and all five gradients, to the token-by-token recurrence
+    of the benchmark's plain reference (float32, nested so that its
+    gradient keeps 128 states and not 16,384), at the assumed
+    initialisation's decay and at four times it: no inf, no NaN."""
+
+    def _inputs(self, decay, dtype, T=16384, n=32, d=128, seed=3):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+        q = jax.random.normal(ks[0], (n, T, d))
+        k = jax.random.normal(ks[1], (n, T, d))
+        q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+        k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+        v = jax.nn.silu(jax.random.normal(ks[2], (n, T, d)))
+        # rates log-uniform in [1, 16) a head, steps in (0.001, 0.1) a
+        # channel, a token's own softplus argument around them
+        rate = jnp.exp(jax.random.uniform(ks[3], (n, 1, 1), minval=0.0,
+                                          maxval=np.log(16.0)))
+        step = jnp.exp(jax.random.uniform(ks[4], (n, 1, d),
+                                          minval=np.log(1e-3),
+                                          maxval=np.log(1e-1)))
+        raw = step + jnp.log(-jnp.expm1(-step)) \
+            + 0.5 * jax.random.normal(ks[5], (n, T, d))
+        g = -decay * rate * jax.nn.softplus(raw)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[6], (n, T)))
+        return (q.astype(dtype), k.astype(dtype), v.astype(dtype),
+                g.astype(jnp.float32), beta.astype(jnp.float32))
+
+    @staticmethod
+    def _reference(q, k, v, g, beta):
+        from benchmark.reference import kimi_linear as reference
+        f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+        with jax.default_matmul_precision("highest"):
+            return reference._delta_rule(
+                *(jnp.moveaxis(a, 0, 1)[None] for a in f32),
+                jnp.exp(jnp.moveaxis(g, 0, 1)[None]),
+                jnp.moveaxis(beta, 0, 1)[None], True)[0].transpose(1, 0, 2)
+
+    @pytest.mark.parametrize("decay", [1.0, 4.0])
+    def test_seq16384_forward_and_five_gradients_match_the_recurrence(
+            self, decay):
+        from analytics_zoo_tpu.pallas import delta_rule as dr
+        args = self._inputs(decay, jnp.bfloat16)
+        cot = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+
+        def loss(fn):
+            def inner(*a):
+                out = fn(*a).astype(jnp.float32)
+                return jnp.sum(out * cot), out
+            return jax.jit(jax.value_and_grad(inner, argnums=(0, 1, 2, 3, 4),
+                                              has_aux=True))
+        (_, got), gs = loss(lambda *a: dr.gated_delta_rule(*a, chunk=64))(
+            *args)
+        (_, want), gr = loss(self._reference)(*args)
+        rel = [float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))]
+        assert bool(jnp.isfinite(got).all())
+        # error that the state carries along: the last quarter against
+        # the first
+        quarter = got.shape[1] // 4
+        by_quarter = [float(jnp.linalg.norm(
+            (got - want)[:, i * quarter:(i + 1) * quarter])
+            / jnp.linalg.norm(want[:, i * quarter:(i + 1) * quarter]))
+            for i in range(4)]
+        for a, b in zip(gs, gr):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            assert bool(jnp.isfinite(a).all())
+            rel.append(float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)))
+        print(f"kda_onchip decay={decay} rel_err out,q,k,v,g,beta="
+              f"{[round(r, 5) for r in rel]} out_by_quarter="
+              f"{[round(r, 5) for r in by_quarter]}")
+        assert rel[0] < 0.02 and by_quarter[3] < 0.03
+        assert max(rel[1:]) < 0.05, rel
+
+    def test_float32_forward_as_the_forward_check_runs_it(self):
+        from analytics_zoo_tpu.pallas import delta_rule as dr
+        args = self._inputs(1.0, jnp.float32, T=2048, n=8)
+        got = jax.jit(lambda *a: dr.gated_delta_rule(*a, chunk=64))(*args)
+        want = jax.jit(self._reference)(*args)
+        assert got.dtype == jnp.float32
+        assert float(jnp.linalg.norm(got - want)
+                     / jnp.linalg.norm(want)) < 0.01
+
+    def test_compiled_step_names_both_kernels_for_the_metrics(self):
+        import json
+        import os
+        import re
+
+        from analytics_zoo_tpu.pallas import delta_rule as dr
+        from benchmark import trace_reduce
+        x = jax.ShapeDtypeStruct((8, 512, 128), jnp.bfloat16)
+        f = jax.ShapeDtypeStruct((8, 512, 128), jnp.float32)
+        b = jax.ShapeDtypeStruct((8, 512), jnp.float32)
+        text = jax.jit(jax.grad(lambda *a: dr.gated_delta_rule(
+            *a, chunk=64).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3, 4))).lower(x, x, x, f, b).compile().as_text()
+        kernels = [trace_reduce.op_name(re.sub(r"^\s*(ROOT )?", "", ln))
+                   for ln in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln]
+        assert sum("kda_chunk_fwd" in k for k in kernels) == 1, kernels
+        assert sum("kda_chunk_bwd" in k for k in kernels) == 1, kernels
+        metrics_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))), "benchmark", "layer_metrics")
+        with open(os.path.join(metrics_dir, "kda_time_share.json")) as fh:
+            pattern = re.compile(json.load(fh)["pattern"])
+        assert all(pattern.search(k) for k in kernels), kernels
 
 
 class TestChunkedForwardOnChip:
