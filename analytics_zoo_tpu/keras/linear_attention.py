@@ -17,10 +17,13 @@ channel by channel and corrected by the delta rule.
     out   = y Wo
 
 The recurrence runs chunked, forward and backward
-(`pallas/delta_rule.py`: what a chunk needs of itself in XLA for all
-chunks at once, the pass over the chunks in the kernels `kda_chunk_fwd`
-and `kda_chunk_bwd`); the decay is accumulated and exponentiated in
-float32 whatever the step's type. The layer's state is [heads, dk, dv]
+(`pallas/delta_rule.py`: on the TPU what a chunk needs of itself in the
+kernels `delta_prepare_fwd` and `delta_prepare_bwd`, a chunk in VMEM, and
+the pass over the chunks in the kernels `kda_chunk_fwd` and
+`kda_chunk_bwd`; off the TPU, and for a chunk or widths the preparation's
+kernels do not take, the preparation in XLA for all chunks at once; the
+benchmark's per-kernel metrics match the four names); the decay is
+accumulated and exponentiated in float32 whatever the step's type. The layer's state is [heads, dk, dv]
 float32 a sequence, whatever its length; the training path starts every
 sequence from zero and returns no state (a cache entry, snapshots and a
 decode step are what serving would add: ROADMAP M6).

@@ -19,33 +19,59 @@ sequence into chunks of C tokens. With G_r = g_1 + ... + g_r inside a chunk
                                                             j <= i
     S_C = Diag(exp(G_C)) S_0 + (K * exp(G_C - G))^T U
 
-`chunk_prepare` computes what every chunk needs of itself, for all chunks
-at once, in XLA (W, U~, Q * exp(G), K * exp(G_C - G), P, exp(G_C));
-`chunk_scan` is the pass over the chunks, which is linear in the state:
-the kernel `kda_chunk_fwd` keeps S^T in VMEM along a sequential chunk axis
-with blocks of heads on a parallel one, `kda_chunk_bwd` walks the chunks
-backwards with dS^T in VMEM (custom VJP; the state each chunk started from
-is written by the forward that the backward belongs to and read again, so
-nothing is solved twice). The names are what the compiler puts on the
-instructions, which the benchmark's per-kernel metrics match.
+What every chunk needs of itself (W, U~, Q * exp(G), K * exp(G_C - G), P,
+exp(G_C)) depends on no other chunk. On the TPU two kernels compute it
+with the chunk in VMEM, 8 heads of one chunk a grid step, every step its
+own: `delta_prepare_fwd`, and `delta_prepare_bwd` for the gradient (custom
+VJP), which keeps nothing but q, k, v, g, beta and walks the way to the six
+results again before it walks back; no [.., C, C] matrix, no float32 copy
+of q, k, v and no exponential ever reaches HBM. `chunk_prepare` is the
+same in XLA, all chunks at once: the path off the TPU and for shapes the
+kernels do not take (`_prepare_fits`: a chunk that is a power of two of 16
+or more, channels in whole lane tiles), and what the tests hold the
+kernels to. `chunk_scan` is the pass over the chunks, which is linear in
+the state: the kernel `kda_chunk_fwd` keeps S^T in VMEM along a sequential
+chunk axis with blocks of heads on a parallel one, `kda_chunk_bwd` walks
+the chunks backwards with dS^T in VMEM (custom VJP; the state each chunk
+started from is written by the forward that the backward belongs to and
+read again, so nothing is solved twice). The four names are what the
+compiler puts on the instructions, and the benchmark's per-kernel metrics
+match them: `kda_[a-z_]+` the scan's (`kda_time_share`, `kda_roofline`,
+whose work counts the scan alone), `delta_prepare_(fwd|bwd)` the
+preparation's (`kda_prepare_time_share`): a preparation kernel must not be
+named `kda_`.
 
 **No exponent is ever positive.** Dividing by the cumulative decay
 (`K / exp(G)`) overflows float32 once a chunk's decay passes e^88, which a
-per-channel gate reaches inside 64 tokens. So the score-like matrices A and
-P are built from sub-blocks of `_SUB` tokens: the blocks on the diagonal
-pairwise, `exp(G_i - G_j)` formed for every pair i >= j and channel
-(`_pair_scores`, whose gradient forms them again instead of keeping
-[.., 16, 16, dk] of them), the blocks under it relative to the row block's
-own boundary B (the cumulative decay just before its first token):
-`(x_i exp(G_i - B)) . (k_j exp(B - G_j))`, both exponents <= 0 because j
-lies before the boundary and i after it. Decay is accumulated and
-exponentiated in float32; the products run in the type of q (bfloat16
-under mixed precision) with float32 accumulation, the triangular solve in
-float32.
+per-channel gate reaches inside 64 tokens. So a score between row i and
+column j < i is always taken relative to a boundary B between them,
+`(x_i exp(G_i - B)) . (k_j exp(B - G_j))`, both exponents <= 0.
+`chunk_prepare` does so between sub-blocks of `_SUB` tokens (B the
+cumulative decay just before the row block's first token) and forms the
+blocks on the diagonal pairwise, `exp(G_i - G_j)` for every pair i >= j
+and channel (`_pair_scores`, whose gradient forms them again instead of
+keeping [.., 16, 16, dk] of them). The kernels apply the boundary rule all
+the way down: the chunk is halved, each half again, to single tokens
+(`_halves`); at the level of half length h a token of a lower half is
+scaled by the decay from its half's first token to itself and a token of
+an upper half by the decay after it to its half's end (`_level_decays`:
+sums of g's own terms, found half by half with one shift a level), and ONE
+masked [C, dk] x [dk, C] product on the MXU gives the level's part of A
+and P for all blocks of the chunk: log2(C) exponentials a token and
+channel where the pairwise form takes `_SUB`, and no reduction over
+lanes. Decay is accumulated and exponentiated in float32; the products
+between sub-blocks run in the type of q (bfloat16 under mixed precision)
+with float32 accumulation, those inside a sub-block and the triangular
+solve in float32 (on the MXU: `_dot32`, several bfloat16 passes). The
+kernels solve by the same halving: the inverse of I + A on the blocks of
+2h from that on the blocks of h and the level's own part of A, two
+products a level, then one product with Diag(beta) [K exp(G) | V]; the
+gradient of the solve is that inverse transposed,
+R = (I + A)^-T [dW | dU~], dA = -R [W | U~]^T under the diagonal.
 
-Off the TPU (CPU tests) `chunk_scan` is a `lax.scan` over the chunks in
-plain `jax.numpy`, or the kernels through the Pallas interpreter with
-`interpret=True`.
+Off the TPU (CPU tests) `chunk_prepare` prepares and `chunk_scan` is a
+`lax.scan` over the chunks in plain `jax.numpy`; `interpret=True` runs all
+four kernels through the Pallas interpreter.
 """
 
 from __future__ import annotations
@@ -61,6 +87,9 @@ import jax.numpy as jnp
 _SUB = 16
 # heads a grid step of the kernels takes (the largest that divides B x H)
 _HEADS_PER_STEP = (8, 4, 2, 1)
+# of those, the heads whose preparation is written level by level side by
+# side, so that one head's products fill another's waits
+_HEADS_ABREAST = 4
 # rows of batch x heads whose chunks are prepared, scanned and
 # differentiated at once (`gated_delta_rule`)
 _ROWS_AT_ONCE = 8
@@ -175,6 +204,360 @@ def chunk_prepare(q, k, v, g, beta, chunk: int):
             p.astype(cdt).reshape(flat), jnp.exp(last[:, :, 0]))
 
 
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _heads_per_step(n: int) -> int:
+    return next(h for h in _HEADS_PER_STEP if n % h == 0)
+
+
+# ------------------------------------------- the preparation as kernels
+
+def _takes_kernels(interpret) -> bool:
+    return bool(interpret) or jax.default_backend() == "tpu"
+
+
+def _prepare_fits(chunk: int, dk: int, dv: int, interpret) -> bool:
+    """Whether `delta_prepare_fwd` / `delta_prepare_bwd` take a chunk:
+    halves down to single tokens want a power of two, the products in
+    q's type between sub-blocks want `_SUB` tokens or more, and the
+    compiler wants whole lane tiles of the channels."""
+    return (chunk >= _SUB and chunk & (chunk - 1) == 0
+            and (bool(interpret) or (dk % 128 == 0 and dv % 128 == 0)))
+
+
+def _dot32(a, b, contract):
+    """A float32 product on the MXU: several passes, float32 to the last
+    bit the unit keeps."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _halves(C: int, width: int):
+    """The halving of a chunk of C tokens (a power of two), finest first:
+    for every half length h = 1, 2, .. C / 2 the tuple (h, low [C, width]:
+    token t lies in the lower half of its block of 2h, pair [C, C]: row i
+    lies in the lower half and column j in the upper half of ONE block of
+    2h). Every pair j < i is in exactly one level's `pair`. Last, the
+    diagonal [C, C]."""
+    t = jax.lax.broadcasted_iota(jnp.int32, (C, width), 0)
+    i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    differ = i ^ j                  # its highest bit: the level of (i, j)
+    levels = []
+    for b in range(C.bit_length() - 1):
+        h = 1 << b
+        levels.append((h, (t & h) != 0,
+                       (differ >= h) & (differ < 2 * h) & ((i & h) != 0)))
+    return levels, i == j
+
+
+def _level_decays(g, levels):
+    """g [C, dk] float32 (<= 0) -> (the exponent of every level [C, dk],
+    G, G_C - G), all sums of g's own terms and so never positive. A
+    level's exponent is, for a token of a lower half, the decay from its
+    half's first token to itself, and for a token of an upper half the
+    decay after it to its half's end: a row against a column of one block
+    multiplies the two into exp(G_i - G_j). Half by half: what a half of
+    length h knows of itself (`inc`, the sum from its first token; `rev`,
+    the sum after the token to its end) gives the half of 2h by one shift
+    of h tokens."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    C = g.shape[0]
+    inc, rev, exponents = g, jnp.zeros_like(g), []
+    for h, low, _ in levels:
+        exponents.append(jnp.where(low, inc, rev))
+        whole = inc + rev                       # one number a half
+        inc = inc + jnp.where(low, pltpu.roll(whole, h, 0), 0.0)
+        rev = rev + jnp.where(low, 0.0, pltpu.roll(whole, C - h, 0))
+    return exponents, inc, rev
+
+
+def _level_decays_transposed(d_exponents, d_inc, d_rev, levels):
+    """The transpose of `_level_decays`: cotangents of its results -> dg."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    C = d_inc.shape[0]
+    for (h, low, _), d_e in reversed(list(zip(levels, d_exponents))):
+        d_whole = pltpu.roll(jnp.where(low, d_inc, 0.0), C - h, 0) \
+            + pltpu.roll(jnp.where(low, 0.0, d_rev), h, 0)
+        d_inc = d_inc + d_whole + jnp.where(low, d_e, 0.0)
+        d_rev = d_rev + d_whole + jnp.where(low, 0.0, d_e)
+    return d_inc
+
+
+def _column(row, eye):                      # [1, C] -> [C, 1]
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _chunk_scores(q, k, v, g, beta, levels, eye):
+    """One chunk of one head in VMEM, as far as the solve: q, k [C, dk], v
+    [C, dv] in the step's type, g [C, dk] float32, beta [C, 1] float32 ->
+    P, KK under the diagonal, every level's own part of A = Diag(beta) KK,
+    the decayed copies in float32 and what a gradient wants of the way
+    there."""
+    f32, cdt, C = jnp.float32, q.dtype, q.shape[0]
+    qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
+    exponents, G, G_rest = _level_decays(g, levels)
+    p = jnp.where(eye, jnp.sum(qf * kf, axis=1, keepdims=True), 0.0)
+    kk, a_levels, scaled = jnp.zeros((C, C), f32), [], []
+    for (h, _, pair), exponent in zip(levels, exponents):
+        e = jnp.exp(exponent)
+        qe, ke = qf * e, kf * e
+        rows = jnp.concatenate([qe, ke], axis=0)
+        if h < _SUB:                # inside a sub-block: float32 products
+            both = _dot32(rows, ke, _NT)
+        else:                       # between sub-blocks: q's type
+            both = _dot(rows.astype(cdt), ke.astype(cdt), _NT)
+        p = p + jnp.where(pair, both[:C], 0.0)
+        kk_h = jnp.where(pair, both[C:], 0.0)
+        kk = kk + kk_h
+        a_levels.append(beta * kk_h)
+        scaled.append((e, qe, ke))
+    eg, eg_rest = jnp.exp(G), jnp.exp(G_rest)
+    return dict(cdt=cdt, qf=qf, kf=kf, vf=vf, beta=beta, p=p, kk=kk,
+                a=a_levels, scaled=scaled, eg=eg, eg_rest=eg_rest,
+                kg=kf * eg, qg=qf * eg, kd=kf * eg_rest)
+
+
+def _inverses(a_levels_by_head, eye):
+    """(I + A)^-1 [C, C] float32 of every head's chunk, from the levels'
+    parts of A: the inverse on the blocks of 2h from that on the blocks
+    of h, [[X, 0], [Z, Y]]^-1 = [[X^-1, 0], [-Y^-1 Z X^-1, Y^-1]]. Level
+    by level for all heads, because a level's two products wait for each
+    other and another head's do not."""
+    inverses = [eye.astype(jnp.float32) - a[0] for a in a_levels_by_head]
+    for level in range(1, len(a_levels_by_head[0])):
+        left = [_dot32(t, a[level], _NN)
+                for t, a in zip(inverses, a_levels_by_head)]
+        inverses = [t - _dot32(u, t, _NN) for t, u in zip(inverses, left)]
+    return inverses
+
+
+def _prepare_chunks(refs, first, abreast, levels, eye):
+    """`abreast` heads from `first` on of the five operand refs: each
+    one's `_chunk_scores` with its inverse and W, U~ float32 added."""
+    chunks = [_chunk_scores(*(ref[first + r] for ref in refs[:4]),
+                            _column(refs[4][first + r, 0], eye), levels, eye)
+              for r in range(abreast)]
+    for c, inverse in zip(chunks, _inverses([c["a"] for c in chunks], eye)):
+        c.update(inverse=inverse,
+                 w=_dot32(inverse, c["beta"] * c["kg"], _NN),
+                 ut=_dot32(inverse, c["beta"] * c["vf"], _NN))
+    return chunks
+
+
+def _heads_abreast(heads: int) -> int:
+    return next(a for a in (_HEADS_ABREAST, 2, 1) if heads % a == 0)
+
+
+def _prepare_fwd_kernel(heads, q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref,
+                        ut_ref, qg_ref, kd_ref, p_ref, decay_ref):
+    """One chunk of `heads` heads."""
+    C, dk = q_ref.shape[1:]
+    levels, eye = _halves(C, dk)
+    abreast = _heads_abreast(heads)
+
+    def group(i, carry):
+        first = i * abreast
+        chunks = _prepare_chunks((q_ref, k_ref, v_ref, g_ref, beta_ref),
+                                 first, abreast, levels, eye)
+        for r, c in enumerate(chunks):
+            for ref, name in ((w_ref, "w"), (ut_ref, "ut"), (qg_ref, "qg"),
+                              (kd_ref, "kd"), (p_ref, "p")):
+                ref[first + r] = c[name].astype(ref.dtype)
+            decay_ref[first + r, 0] = c["eg"][C - 1:]
+        return carry
+
+    jax.lax.fori_loop(0, heads // abreast, group, None)
+
+
+def _solve_gradient(c, d_w, d_ut):
+    """The solve's gradient is the same inverse transposed:
+    R = (I + A)^-T [dW | dU~], dA = -R [W | U~]^T (its caller's masks keep
+    what lies under the diagonal)."""
+    r_w = _dot32(c["inverse"], d_w, _TN)
+    r_u = _dot32(c["inverse"], d_ut, _TN)
+    return r_w, r_u, -(_dot32(r_w, c["w"], _NT) + _dot32(r_u, c["ut"], _NT))
+
+
+def _chunk_gradient(c, solved, cotangents, levels, eye, last):
+    """One chunk of one head, back along the way `_prepare_chunks` went: c
+    its results, `solved` its `_solve_gradient`, `cotangents` those of
+    Q exp(G), K exp(G_C - G), P (float32) and exp(G_C) [1, dk] -> dq, dk,
+    dv, dg [C, .] and dbeta [C, 1], float32."""
+    (r_w, r_u, d_a), (d_qg, d_kd, d_p, d_decay) = solved, cotangents
+    C, cdt = d_p.shape[0], c["cdt"]
+    beta, eg, kg = c["beta"], c["eg"], c["kg"]
+    d_beta = jnp.sum(r_w * kg, axis=1, keepdims=True) \
+        + jnp.sum(r_u * c["vf"], axis=1, keepdims=True) \
+        + jnp.sum(d_a * c["kk"], axis=1, keepdims=True)
+    d_kg, d_kk = beta * r_w, beta * d_a
+    on_diagonal = jnp.sum(jnp.where(eye, d_p, 0.0), axis=1, keepdims=True)
+    d_q = d_qg * eg + on_diagonal * c["kf"]
+    d_k = d_kg * eg + d_kd * c["eg_rest"] + on_diagonal * c["qf"]
+    d_G = d_qg * c["qg"] + d_kg * kg + jnp.where(last, d_decay * eg, 0.0)
+    d_exponents = []
+    for (h, _, pair), (e, qe, ke) in zip(levels, c["scaled"]):
+        both = jnp.concatenate([jnp.where(pair, d_p, 0.0),
+                                jnp.where(pair, d_kk, 0.0)], axis=0)
+        rows = jnp.concatenate([qe, ke], axis=0)
+        if h < _SUB:
+            by_rows = _dot32(both, ke, _NN)
+            by_columns = _dot32(both, rows, _TN)
+        else:
+            both = both.astype(cdt)
+            by_rows = _dot(both, ke.astype(cdt), _NN)
+            by_columns = _dot(both, rows.astype(cdt), _TN)
+        d_qe, d_ke = by_rows[:C], by_rows[C:] + by_columns
+        d_q, d_k = d_q + d_qe * e, d_k + d_ke * e
+        d_exponents.append(d_qe * qe + d_ke * ke)
+    d_g = _level_decays_transposed(d_exponents, d_G, d_kd * c["kd"], levels)
+    return d_q, d_k, beta * r_u, d_g, d_beta
+
+
+def _prepare_bwd_kernel(heads, q_ref, k_ref, v_ref, g_ref, beta_ref, dw_ref,
+                        dut_ref, dqg_ref, dkd_ref, dp_ref, ddecay_ref,
+                        dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref):
+    """One chunk of `heads` heads: the way to the six results again, then
+    back along it."""
+    f32 = jnp.float32
+    C, dk = q_ref.shape[1:]
+    levels, eye = _halves(C, dk)
+    last = jax.lax.broadcasted_iota(jnp.int32, (C, dk), 0) == C - 1
+    abreast = _heads_abreast(heads)
+
+    def group(i, carry):
+        first = i * abreast
+        chunks = _prepare_chunks((q_ref, k_ref, v_ref, g_ref, beta_ref),
+                                 first, abreast, levels, eye)
+        solved = [_solve_gradient(c, dw_ref[first + r].astype(f32),
+                                  dut_ref[first + r].astype(f32))
+                  for r, c in enumerate(chunks)]
+        for r, c in enumerate(chunks):
+            cotangents = [ref[first + r].astype(f32) for ref in (
+                dqg_ref, dkd_ref, dp_ref)] + [ddecay_ref[first + r, 0]]
+            *rows, d_beta = _chunk_gradient(c, solved[r], cotangents, levels,
+                                            eye, last)
+            for ref, a in zip((dq_ref, dk_ref, dv_ref, dg_ref), rows):
+                ref[first + r] = a.astype(ref.dtype)
+            dbeta_ref[first + r, 0] = jnp.sum(jnp.where(eye, d_beta, 0.0),
+                                              axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, heads // abreast, group, None)
+
+
+def _prepare_specs(heads, C, dk, dv):
+    """Block specs of a chunk of q, k, v, g, beta [N, n, 1, C] and of the
+    six results (exp(G_C) as [N, n, 1, dk])."""
+    from jax.experimental import pallas as pl
+
+    def rows(width):
+        return pl.BlockSpec((heads, C, width), lambda i, c: (i, c, 0))
+
+    def row(width):
+        return pl.BlockSpec((heads, 1, 1, width), lambda i, c: (i, c, 0, 0))
+
+    return [rows(dk), rows(dk), rows(dv), rows(dk), row(C)], \
+        [rows(dk), rows(dv), rows(dk), rows(dk), rows(C), row(dk)]
+
+
+def _prepare_call(kernel, name, operands, out_shape, chunk, interpret):
+    """`pl.pallas_call` of one of the two kernels: a grid of groups of
+    heads x chunks, every step its own. Operands q, k, v, g, beta
+    [N, n, 1, chunk] and, for the gradient, the six cotangents."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    q, v = operands[0], operands[2]
+    (N, T, dk), dv = q.shape, v.shape[-1]
+    heads, halves = _heads_per_step(N), chunk.bit_length() - 1
+    ins, outs = _prepare_specs(heads, chunk, dk, dv)
+    backward = len(operands) > len(ins)
+    # the way to the six results is walked once forward and about three
+    # times over for the gradient
+    passes = 3 if backward else 1
+    return pl.pallas_call(
+        functools.partial(kernel, heads),
+        out_shape=out_shape,
+        grid=(N // heads, T // chunk),
+        in_specs=ins + outs if backward else ins,
+        out_specs=ins if backward else outs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        cost_estimate=pl.CostEstimate(
+            flops=passes * 2 * N * T * chunk * (
+                2 * halves * dk + 2 * halves * chunk + dk + dv),
+            transcendentals=passes * N * T * dk * (halves + 2),
+            bytes_accessed=sum(a.size * a.dtype.itemsize for a in operands)
+            + sum(s.size * s.dtype.itemsize for s in out_shape)),
+        interpret=interpret,
+        name=name,
+    )(*operands)
+
+
+def _prepare_kernel_fwd(q, k, v, g, beta, chunk, interpret):
+    (N, T, dk), dv, n = q.shape, v.shape[-1], q.shape[1] // chunk
+    *rows, decay = _prepare_call(
+        _prepare_fwd_kernel, "delta_prepare_fwd",
+        (q, k, v, g, beta.reshape(N, n, 1, chunk)),
+        [jax.ShapeDtypeStruct((N, T, w), q.dtype)
+         for w in (dk, dv, dk, dk, chunk)]
+        + [jax.ShapeDtypeStruct((N, n, 1, dk), jnp.float32)],
+        chunk, interpret)
+    return (*rows, decay[:, :, 0, :])
+
+
+def _prepare_kernel_bwd(q, k, v, g, beta, cotangents, chunk, interpret):
+    (N, T, _), n = q.shape, q.shape[1] // chunk
+    *d_rows, d_decay = cotangents
+    *grads, d_beta = _prepare_call(
+        _prepare_bwd_kernel, "delta_prepare_bwd",
+        (q, k, v, g, beta.reshape(N, n, 1, chunk), *d_rows,
+         d_decay[:, :, None, :]),
+        [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (q, k, v, g)]
+        + [jax.ShapeDtypeStruct((N, n, 1, chunk), jnp.float32)],
+        chunk, interpret)
+    return (*grads, d_beta.reshape(N, T))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _prepare_kernels(q, k, v, g, beta, chunk, interpret):
+    return _prepare_kernel_fwd(q, k, v, g, beta, chunk, interpret)
+
+
+def _prepare_kernels_fwd(q, k, v, g, beta, chunk, interpret):
+    # nothing but the operands is kept: the gradient walks the way again
+    return _prepare_kernel_fwd(q, k, v, g, beta, chunk, interpret), \
+        (q, k, v, g, beta)
+
+
+def _prepare_kernels_bwd(chunk, interpret, res, cotangents):
+    return _prepare_kernel_bwd(*res, cotangents, chunk, interpret)
+
+
+_prepare_kernels.defvjp(_prepare_kernels_fwd, _prepare_kernels_bwd)
+
+
+def _prepare(q, k, v, g, beta, chunk: int, interpret):
+    """`chunk_prepare` by the kernels where `chunk_scan` takes its own and
+    the shapes fit them, in XLA everywhere else."""
+    if _takes_kernels(interpret) and _prepare_fits(
+            chunk, q.shape[-1], v.shape[-1], interpret):
+        return _prepare_kernels(q, k, v, g.astype(jnp.float32),
+                                beta.astype(jnp.float32), chunk,
+                                bool(interpret))
+    return chunk_prepare(q, k, v, g, beta, chunk)
+
+
 # ---------------------------------------------------------------- the scan
 
 def _scan_plain(w, ut, qg, kd, p, decay):
@@ -205,14 +588,6 @@ def _scan_plain(w, ut, qg, kd, p, decay):
                                    per_chunk(qg), per_chunk(kd),
                                    per_chunk(p), decay.transpose(1, 0, 2)))
     return o.transpose(1, 0, 2, 3).reshape(N, T, -1)
-
-
-def _dot(a, b, contract):
-    return jax.lax.dot_general(a, b, (contract, ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
 
 def _fwd_kernel(heads, w_ref, ut_ref, qg_ref, kd_ref, p_ref, decay_ref,
@@ -270,10 +645,6 @@ def _bwd_kernel(heads, w_ref, ut_ref, qg_ref, kd_ref, p_ref, decay_ref,
         ddecay_ref[h, 0] = jnp.sum(ds * s_lo.astype(jnp.float32), axis=0,
                                    keepdims=True)
         dst_sc[h] = ds * decay + _dot(do, qg, _TN) - _dot(du_lo, w, _TN)
-
-
-def _heads_per_step(n: int) -> int:
-    return next(h for h in _HEADS_PER_STEP if n % h == 0)
 
 
 def _specs(heads, C, dk, dv, at):
@@ -381,7 +752,7 @@ _scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
 def chunk_scan(w, ut, qg, kd, p, decay, interpret: Optional[bool] = None):
     """The pass over the chunks: `chunk_prepare`'s results -> O [N, T, dv]
     in their type, from S_0 = 0. Differentiable in all six."""
-    if not (interpret or jax.default_backend() == "tpu"):
+    if not _takes_kernels(interpret):
         return _scan_plain(w, ut, qg, kd, p, decay)
     return _scan_kernels(w, ut, qg, kd, p, decay, bool(interpret))
 
@@ -408,7 +779,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
 
     def core(rows):
         with jax.named_scope("kda/chunk_prepare"):
-            prepared = chunk_prepare(*rows, chunk)
+            prepared = _prepare(*rows, chunk, interpret)
         with jax.named_scope("kda/chunk_scan"):
             return chunk_scan(*prepared, interpret=interpret)
 

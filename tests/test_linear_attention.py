@@ -94,6 +94,80 @@ def test_kernels_and_the_plain_scan_agree_on_every_gradient():
         assert _rel(a, b) < 1e-5
 
 
+_DECAYS = [
+    ("assumed_decay", {}),
+    ("decay_four_times_stronger", {"decay": 4.0}),
+    ("decay_that_overflows_a_division", {"decay": 20.0}),
+    ("g_minus_100_a_token_and_channel", {"g": -100.0}),
+    ("alpha_one_the_plain_delta_rule", {"decay": 0.0}),
+    ("beta_zero_the_state_only_decays", {"beta": 0.0}),
+]
+
+
+def _prepare_inputs(chunk, g=None, **kw):
+    q, k, v, log_decay, beta = _inputs(N=2, T=2 * chunk, dk=128, dv=128, **kw)
+    if g is not None:
+        log_decay = jnp.full_like(log_decay, g)
+    return q, k, v, log_decay, beta
+
+
+def _close(got, want, what):
+    assert bool(jnp.isfinite(got).all()), what
+    assert float(jnp.linalg.norm(got - want)) \
+        <= 2e-5 * float(jnp.linalg.norm(want)) + 1e-6, what
+
+
+@pytest.mark.parametrize("chunk, what, kw",
+                         [(64, *case) for case in _DECAYS]
+                         + [(32, *_DECAYS[1])],
+                         ids=[c[0] for c in _DECAYS] + ["chunk_32"])
+def test_prepare_kernels_match_xla_in_six_results_and_five_gradients(
+        chunk, what, kw):
+    """`delta_prepare_fwd` and `delta_prepare_bwd` through the interpreter
+    against XLA's `chunk_prepare` and `jax.vjp` of it."""
+    args = _prepare_inputs(chunk, **kw)
+    want, want_vjp = jax.vjp(lambda *a: dr.chunk_prepare(*a, chunk), *args)
+    got, got_vjp = jax.vjp(
+        lambda *a: dr._prepare_kernels(*a, chunk, True), *args)
+    names = "W U~ Qexp(G) Kexp(G_C-G) P exp(G_C)".split()
+    for name, a, b in zip(names, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        _close(a, b, (what, name))
+    cotangents = tuple(jax.random.normal(jax.random.PRNGKey(7 + i), a.shape)
+                       for i, a in enumerate(want))
+    for name, a, b in zip("q k v g beta".split(), got_vjp(cotangents),
+                          want_vjp(cotangents)):
+        assert a.shape == b.shape, name
+        _close(a, b, (what, "d" + name))
+
+
+@pytest.mark.parametrize("chunk, dk, fits", [
+    (64, 128, True), (32, 128, True), (16, 256, True),
+    (24, 128, False),            # no power of two: its halves are not whole
+    (8, 128, False),             # under a sub-block
+    (64, 96, False),             # the compiler wants whole lane tiles
+])
+def test_the_shapes_decide_which_preparation_runs(chunk, dk, fits):
+    assert dr._prepare_fits(chunk, dk, 128, False) is fits
+    assert dr._prepare_fits(chunk, dk, 128, True) is (chunk in (16, 32, 64))
+
+
+def test_a_chunk_the_kernels_do_not_take_is_prepared_in_xla(monkeypatch):
+    """A chunk of 24: `_prepare` hands XLA's results back, whatever the
+    backend, and the scan's kernels take them as they take the others."""
+    args = _inputs(N=2, T=48, dk=128, dv=128)
+    monkeypatch.setattr(dr, "_prepare_kernels", None)   # would raise if run
+    for a, b in zip(dr._prepare(*args, 24, True),
+                    dr.chunk_prepare(*args, 24)):
+        np.testing.assert_array_equal(a, b)
+    got, got_grads = _value_and_grads(
+        lambda *a: dr.gated_delta_rule(*a, chunk=24, interpret=True), args)
+    want, want_grads = _value_and_grads(dr.recurrent_delta_rule, args)
+    assert _rel(got, want) < 1e-5
+    for a, b in zip(got_grads, want_grads):
+        assert _rel(a, b) < 1e-4
+
+
 def test_rows_are_taken_a_group_at_a_time_and_nothing_moves():
     args = _inputs(N=2 * dr._ROWS_AT_ONCE, T=64)
     got, got_grads = _value_and_grads(
@@ -107,14 +181,18 @@ def test_rows_are_taken_a_group_at_a_time_and_nothing_moves():
         assert _rel(a, jnp.concatenate([h[1][i] for h in halves])) < 1e-5
 
 
-def test_no_exponent_is_positive_at_any_decay():
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["xla_prepares", "kernels_interpreted"])
+def test_no_exponent_is_positive_at_any_decay(interpret):
     """At 100 a token and channel every form that divides by the
     cumulative decay is inf / inf; the pairwise blocks and the blocks
-    relative to a boundary are exact zeros and ones."""
+    relative to a boundary (XLA), and the halves relative to theirs (the
+    kernels), are exact zeros and ones."""
     q, k, v, g, beta = _inputs(T=64, decay=0.0)
     g = jnp.full_like(g, -100.0)
     got, grads = _value_and_grads(
-        lambda *a: dr.gated_delta_rule(*a, chunk=64), (q, k, v, g, beta))
+        lambda *a: dr.gated_delta_rule(*a, chunk=64, interpret=interpret),
+        (q, k, v, g, beta))
     want = dr.recurrent_delta_rule(q, k, v, g, beta)
     assert bool(jnp.isfinite(got).all()) and _rel(got, want) < 1e-5
     assert all(bool(jnp.isfinite(a).all()) for a in grads)

@@ -532,13 +532,16 @@ def test_the_recurrences_output_crosses_a_layers_checkpoint_by_its_own_name():
 
     kept = _hybrid(**kw)
     assert kept.kept is not fa.save_flash_residuals
-    # a run (dense, expert) of one layer each: the forward kernel in the
-    # forward pass and once more, a group of heads at a time, in the
-    # backward pass; the layer's own recomputation does not run it
-    assert calls(kept) == {"kda_chunk_fwd": 4, "kda_chunk_bwd": 2}
+    # a run (dense, expert) of one layer each: the forward kernels (the
+    # chunks' preparation and the pass over them) in the forward pass and
+    # once more, a group of heads at a time, in the backward pass; the
+    # layer's own recomputation does not run them
+    assert calls(kept) == {"delta_prepare_fwd": 4, "kda_chunk_fwd": 4,
+                           "kda_chunk_bwd": 2, "delta_prepare_bwd": 2}
     dropped = _hybrid(**kw)
     dropped.kept = fa.save_flash_residuals
-    assert calls(dropped) == {"kda_chunk_fwd": 6, "kda_chunk_bwd": 2}
+    assert calls(dropped) == {"delta_prepare_fwd": 6, "kda_chunk_fwd": 6,
+                              "kda_chunk_bwd": 2, "delta_prepare_bwd": 2}
 
 
 def test_int8_rewrite_reaches_the_linear_layers_projections():

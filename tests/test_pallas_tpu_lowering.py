@@ -297,11 +297,12 @@ def test_segment_adam_update_does_not_lower():
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_delta_rule_chunk_kernels_lower_under_their_names(dtype):
-    """The pass over the chunks of the KDA layers (`pallas/delta_rule.py`)
-    at the benchmark's widths: forward and backward lower for the TPU, in
-    the step's bfloat16 and in the forward check's float32, under the
-    names the per-kernel metrics match; rows of batch x heads are taken 8
-    at a time, so 16 rows are one `lax.map` of either kernel."""
+    """The KDA layers' kernels (`pallas/delta_rule.py`) at the benchmark's
+    widths: the chunk's preparation and the pass over the chunks, forward
+    and backward, lower for the TPU, in the step's bfloat16 and in the
+    forward check's float32, under the names the per-kernel metrics match;
+    rows of batch x heads are taken 8 at a time, so 16 rows are one
+    `lax.map` of each kernel."""
     from analytics_zoo_tpu.pallas import delta_rule as dr
     N, T, d = 16, 256, 128
     x = sds((N, T, d), dtype)
@@ -311,8 +312,12 @@ def test_delta_rule_chunk_kernels_lower_under_their_names(dtype):
         return dr.gated_delta_rule(*a, chunk=64).astype(jnp.float32).sum()
     fwd = jax.jit(lambda *a: dr.gated_delta_rule(*a, chunk=64)).trace(
         *args).lower(lowering_platforms=("tpu",)).as_text()
-    assert fwd.count("tpu_custom_call") == 1 and "kda_chunk_fwd" in fwd
+    assert fwd.count("tpu_custom_call") == 2
+    assert "delta_prepare_fwd" in fwd and "kda_chunk_fwd" in fwd
     bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).trace(
         *args).lower(lowering_platforms=("tpu",)).as_text()
-    assert "kda_chunk_fwd" in bwd and "kda_chunk_bwd" in bwd
+    for name in ("delta_prepare_fwd", "kda_chunk_fwd", "kda_chunk_bwd",
+                 "delta_prepare_bwd"):
+        assert name in bwd, name
+    assert "triangular" not in bwd.lower()
     assert dr._heads_per_step(16) == 8 and dr._heads_per_step(6) == 2

@@ -435,8 +435,9 @@ class TestGroupedMatmulOnChip:
 class TestDeltaRuleOnChip:
     """The chunked gated delta rule (`pallas/delta_rule.py`) at the hybrid
     model's fit shape, T = 16,384 and 32 heads of 128: the kernels
-    `kda_chunk_fwd` and `kda_chunk_bwd` compiled by the chip's compiler and
-    held, output and all five gradients, to the token-by-token recurrence
+    `delta_prepare_fwd`, `kda_chunk_fwd`, `kda_chunk_bwd` and
+    `delta_prepare_bwd` compiled by the chip's compiler and held, output
+    and all five gradients, to the token-by-token recurrence
     of the benchmark's plain reference (float32, nested so that its
     gradient keeps 128 states and not 16,384), at the assumed
     initialisation's decay and at four times it: no inf, no NaN."""
@@ -534,12 +535,27 @@ class TestDeltaRuleOnChip:
                    if 'custom_call_target="tpu_custom_call"' in ln]
         assert sum("kda_chunk_fwd" in k for k in kernels) == 1, kernels
         assert sum("kda_chunk_bwd" in k for k in kernels) == 1, kernels
+        # the chunk's preparation by its own two kernels (8 rows are one
+        # group, so nothing is computed again here), and nothing of XLA's
+        # triangular solve
+        assert sum("delta_prepare_fwd" in k for k in kernels) == 1, kernels
+        assert sum("delta_prepare_bwd" in k for k in kernels) == 1, kernels
+        assert "InvertDiagBlocksLowerTriangular" not in text
         metrics_dir = os.path.join(
             os.path.dirname(os.path.dirname(os.path.dirname(
                 os.path.abspath(__file__)))), "benchmark", "layer_metrics")
-        with open(os.path.join(metrics_dir, "kda_time_share.json")) as fh:
-            pattern = re.compile(json.load(fh)["pattern"])
-        assert all(pattern.search(k) for k in kernels), kernels
+        patterns = {}
+        for metric in ("kda_time_share", "kda_roofline",
+                       "kda_prepare_time_share"):
+            with open(os.path.join(metrics_dir, metric + ".json")) as fh:
+                patterns[metric] = re.compile(json.load(fh)["pattern"])
+        for k in kernels:
+            scan = bool(patterns["kda_time_share"].search(k))
+            assert scan == bool(patterns["kda_roofline"].search(k)), k
+            # every Pallas call is the scan's or the preparation's
+            assert scan != bool(
+                patterns["kda_prepare_time_share"].search(k)), k
+            assert scan == ("kda_chunk" in k), k
 
 
 class TestChunkedForwardOnChip:
