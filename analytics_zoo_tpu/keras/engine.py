@@ -25,6 +25,7 @@ strings for loss/optimizer/metrics resolve through the reference registries
 from __future__ import annotations
 
 import collections
+import contextlib
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -60,6 +61,11 @@ class Layer:
     # True for layers carrying non-gradient state (e.g. BatchNorm moving
     # stats); they implement call_and_state.
     stateful = False
+
+    # A `jax.named_scope` a functional `Model` runs this layer under:
+    # metadata of the compiled program (device time by scope,
+    # `observability/device_time.py`), never a different program.
+    scope: Optional[str] = None
 
     # -- subclass API ------------------------------------------------------
     def build(self, rng: jax.Array, input_shape: Shape) -> Params:
@@ -590,8 +596,13 @@ class Model(KerasNet):
                 rng, sub = jax.random.split(rng)
             else:
                 sub = None
-            y, upd = node.layer.call_and_state(
-                params[node.layer.name], arg, training=training, rng=sub)
+            # (a nested model is a node too, and has no scope of its own)
+            scope = getattr(node.layer, "scope", None)
+            with jax.named_scope(scope) if scope \
+                    else contextlib.nullcontext():
+                y, upd = node.layer.call_and_state(
+                    params[node.layer.name], arg, training=training,
+                    rng=sub)
             values[id(node)] = y
             if upd:
                 updates.setdefault(node.layer.name, {}).update(upd)

@@ -177,16 +177,22 @@ class TransformerEncoderBlock(Layer):
         r1 = r2 = None
         if rng is not None:
             rng, r1, r2 = jax.random.split(rng, 3)
-        a = self.attn.call(params["attn"], x, training=training, rng=r1,
-                           mask=mask)
-        x = self.ln1.call(params["ln1"], x + a)
-        h = self.act(maybe_int8_matmul(x, params, "ffn_in_kernel")
-                     + params["ffn_in_bias"])
-        h = maybe_int8_matmul(h, params, "ffn_out_kernel") \
-            + params["ffn_out_bias"]
-        if training and r2 is not None and self.hidden_dropout > 0:
-            h = _dropout(r2, self.hidden_dropout, h)
-        return self.ln2.call(params["ln2"], x + h)
+        # the scopes name the program's parts (device time by scope,
+        # `observability/device_time.py`); they change no operation
+        with jax.named_scope("bert/block/attention"):
+            a = self.attn.call(params["attn"], x, training=training,
+                               rng=r1, mask=mask)
+        with jax.named_scope("bert/block/attention_output_norm"):
+            x = self.ln1.call(params["ln1"], x + a)
+        with jax.named_scope("bert/block/ffn"):
+            h = self.act(maybe_int8_matmul(x, params, "ffn_in_kernel")
+                         + params["ffn_in_bias"])
+            h = maybe_int8_matmul(h, params, "ffn_out_kernel") \
+                + params["ffn_out_bias"]
+            if training and r2 is not None and self.hidden_dropout > 0:
+                h = _dropout(r2, self.hidden_dropout, h)
+        with jax.named_scope("bert/block/ffn_output_norm"):
+            return self.ln2.call(params["ln2"], x + h)
 
     def compute_output_shape(self, input_shape):
         if isinstance(input_shape, list):
@@ -604,14 +610,16 @@ class BERT(Layer):
         ids = jnp.asarray(ids, jnp.int32)
         token_type = jnp.asarray(token_type, jnp.int32)
         T = ids.shape[1]
-        h = (jnp.take(params["word_embeddings"], ids, axis=0)
-             + params["position_embeddings"][None, :T]
-             + jnp.take(params["token_type_embeddings"], token_type, axis=0))
-        h = self.emb_ln.call(params["emb_ln"], h)
-        if training and rng is not None and self.hidden_drop > 0:
-            rng, sub = jax.random.split(rng)
-            h = _dropout(sub, self.hidden_drop, h)
-        mask = self.make_mask(attn_mask)
+        with jax.named_scope("bert/embeddings"):
+            h = (jnp.take(params["word_embeddings"], ids, axis=0)
+                 + params["position_embeddings"][None, :T]
+                 + jnp.take(params["token_type_embeddings"], token_type,
+                            axis=0))
+            h = self.emb_ln.call(params["emb_ln"], h)
+            if training and rng is not None and self.hidden_drop > 0:
+                rng, sub = jax.random.split(rng)
+                h = _dropout(sub, self.hidden_drop, h)
+            mask = self.make_mask(attn_mask)
         if self.stacked:
             h = self._scan_blocks(params["blocks"], h, mask, training, rng)
         else:
@@ -634,9 +642,10 @@ class BERT(Layer):
                 else:
                     h = blk.call(params[blk.name], [h, mask],
                                  training=training, rng=sub)
-        pooled = jnp.tanh(maybe_int8_matmul(h[:, 0], params,
-                                            "pooler_kernel")
-                          + params["pooler_bias"])
+        with jax.named_scope("bert/pooler_head"):
+            pooled = jnp.tanh(maybe_int8_matmul(h[:, 0], params,
+                                                "pooler_kernel")
+                              + params["pooler_bias"])
         if self.pooled_only:
             return pooled
         return h, pooled

@@ -308,6 +308,15 @@ class Estimator:
         self.model.params = self.model._remap_loaded(params)
         self._resume_epoch = int(meta.get("epoch", 0)) if meta else 0
 
+    def program_scopes(self):
+        """`{module: {instruction: {scope, direction, also}}}` of the last
+        fit's step program, made on request from the shapes the fit
+        dispatched with (`trainer.program_scopes`; what a
+        `fit(profile_steps=...)` capture is joined to:
+        docs/ProgrammingGuide/observability.md "Device time by scope")."""
+        from analytics_zoo_tpu.learn.trainer import program_scopes
+        return program_scopes(self.model)
+
     # -- inference ---------------------------------------------------------
     def predict(self, data, batch_per_thread: int = 32, feature_cols=None
                 ) -> np.ndarray:

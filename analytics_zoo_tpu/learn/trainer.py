@@ -84,6 +84,12 @@ class _TrainingMetrics:
             "spent blocked on the prefetch queue (0 = device-bound, "
             "1 = fully input-bound; the measured verdict on whether "
             "a fit needs more pipeline_workers)")
+        self.device_time_share = reg.gauge(
+            "training_device_time_share",
+            "share (%) of the device's operation time in the last "
+            "`fit(profile_steps=...)` capture by the step program's own "
+            "scopes (first three parts) and direction "
+            "(`observability/device_time.py`)")
         self.fit_phase_ms = reg.histogram(
             "training_fit_phase_ms",
             "wall time of each leaf span of a fit call (`_FitTrace`), "
@@ -105,6 +111,15 @@ class _TrainingMetrics:
             sizes = mesh.axis_sizes
         for ax, size in sizes.items():
             self.mesh_axis.set(size, axis=ax)
+
+    def device_time_shares(self, rows) -> None:
+        """The last capture's breakdown; a scope the capture before had
+        and this one has not reads 0."""
+        for key in self.device_time_share.label_keys():
+            self.device_time_share.set(0.0, **dict(key))
+        for row in rows:
+            self.device_time_share.set(row["share_pct"], scope=row["scope"],
+                                       direction=row["direction"])
 
     def epoch(self, steps: int, n_seen: int, dt: float, mean_loss: float):
         step_ms = dt / max(steps, 1) * 1e3
@@ -631,6 +646,67 @@ def _jit_donated(fn, shardings, batch_key: str, n_extra_out: int):
     out_sh = (shardings["params"], shardings["opt"]) + (rep,) * n_extra_out
     return jax.jit(fn, donate_argnums=(0, 1),
                    in_shardings=in_sh, out_shardings=out_sh)
+
+
+class _StepProgram:
+    """What `program_scopes` needs of the step program a fit built, kept
+    beside it in `model._train_cache`: the jitted function, the abstract
+    arguments of the fit's first dispatch (shape and dtype of every leaf,
+    and the sharding of a committed one; never a buffer) and, once asked for, the table. A fit
+    that nobody asks pays for the tuple of shapes and nothing else: no
+    lowering, no text, no parse."""
+
+    __slots__ = ("jitted", "abstract_args", "scopes")
+
+    def __init__(self, jitted):
+        self.jitted = jitted
+        self.abstract_args = None
+        self.scopes = None
+
+    def saw(self, args) -> None:
+        """The arguments of a dispatch, as shapes. Another dataset's
+        length under the same `_train_cache` key is another program:
+        its table is made anew."""
+        # an uncommitted array's placement is no part of the program: with
+        # it in the shapes the lowering differs from the dispatch's and
+        # the compile is a fresh one (35-64 s a cell on the chip, PR 34)
+        abstract = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=a.sharding
+                if getattr(a, "committed", False) else None), args)
+        if abstract != self.abstract_args:
+            self.abstract_args, self.scopes = abstract, None
+
+
+def program_scopes(model) -> Dict[str, Dict[str, Dict[str, Any]]]:
+    """`{module: {instruction: {scope, direction, also}}}` of the step
+    program of `model`'s last fit (`observability/device_time.py`), made
+    on request: the jitted step is lowered again from the shapes the fit
+    dispatched with and asked for its executable, which JAX answers from
+    memory while the process still holds the fit's (else from the
+    persistent cache, else by compiling), and the compiled text is
+    parsed. Memoised beside the step
+    in `model._train_cache`, so under its key. `{}` before any fit."""
+    cached = getattr(model, "_train_cache", None)
+    program = cached[2] if cached is not None else None
+    if program is None or program.abstract_args is None:
+        return {}
+    if program.scopes is None:
+        from analytics_zoo_tpu.observability.device_time import scope_table
+        t0 = time.perf_counter()
+        lowered = program.jitted.lower(*program.abstract_args)
+        t1 = time.perf_counter()
+        compiled = lowered.compile()
+        t2 = time.perf_counter()
+        text = compiled.as_text()
+        t3 = time.perf_counter()
+        program.scopes = scope_table(text)
+        log.info("step program's scopes: lowered in %.2f s, executable in "
+                 "%.2f s, text (%d bytes) in %.2f s, %d instructions "
+                 "placed in %.2f s", t1 - t0, t2 - t1, len(text), t3 - t2,
+                 sum(len(m) for m in program.scopes.values()),
+                 time.perf_counter() - t3)
+    return program.scopes
 
 
 def build_train_step(apply_fn: Callable, loss_fn: Callable,
@@ -1284,7 +1360,7 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
                          shard_desc)
         cached = getattr(model, "_train_cache", None)
         if cached is not None and cached[0] == cache_key:
-            train_step = cached[1]
+            train_step, step_program = cached[1:]
         else:
             if use_device_cache:
                 builder = functools.partial(
@@ -1297,6 +1373,7 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
                 apply_and_state_fn=getattr(model, "apply_and_state", None),
                 mixed_precision=mixed_precision, lazy_specs=lazy_specs,
                 fused=fused, shardings=step_shardings)
+            step_program = _StepProgram(train_step)
             if cc_dir:
                 # persistent compilation cache: AOT-serialize the step/run
                 # executable per input signature — a re-run in a fresh
@@ -1322,7 +1399,7 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
                      fused, shard_desc])
                 train_step = AOTFunctionCache(train_step, get_cache(cc_dir),
                                               step_fp, sharding=shard_desc)
-            model._train_cache = (cache_key, train_step)
+            model._train_cache = (cache_key, train_step, step_program)
         ckpt_mgr = None
         if model._checkpoint_path:
             from analytics_zoo_tpu.learn.checkpoint import (CheckpointManager,
@@ -1362,6 +1439,26 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
                 profile_dir or os.environ.get("ZOO_PROFILE_DIR")
                 or "zoo_profiles")
 
+        def _profile_stop():
+            """End the capture and say where its device time went by the
+            program's own scopes (`observability/device_time.py`): the
+            one place a fit asks for its step program's table."""
+            manifest = profiler.stop()
+            profile_state["active"] = False
+            profile_state["done"] = True
+            history.setdefault("profile_artifacts", []).append(
+                manifest["dir"])
+            log.info("profiler capture written to %s (%d files)",
+                     manifest["dir"], len(manifest["files"]))
+            from analytics_zoo_tpu.observability import device_time
+            report = device_time.write_report(manifest["dir"],
+                                              program_scopes(model))
+            rows = device_time.at_depth(report["rows"], 3)
+            log.info("device time by scope (%s, %.4f s of operations)\n%s",
+                     report["device_source"], report["total_s"],
+                     device_time.format_rows(rows))
+            telemetry.device_time_shares(rows)
+
         def _profile_tick(it: int):
             """Crossing-edge profiler control: start when the iteration
             counter reaches `start`, stop once it reaches `stop` (multi-step
@@ -1374,18 +1471,14 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
                     profiler.start(tag=f"fit-it{it}")
                     profile_state["active"] = True
                 elif profile_state["active"] and it >= p_stop:
-                    manifest = profiler.stop()
-                    profile_state["active"] = False
-                    profile_state["done"] = True
-                    history.setdefault("profile_artifacts", []).append(
-                        manifest["dir"])
-                    log.info("profiler capture written to %s (%d files)",
-                             manifest["dir"], len(manifest["files"]))
+                    _profile_stop()
             except Exception as e:  # noqa: BLE001 — profiling must never
                 # take down the fit it watches
                 log.warning("profiler capture failed: %s: %s",
                             type(e).__name__, e)
                 profile_state["done"] = True
+
+        first_iteration = iteration
 
         def _call_step(steps, xb, yb):
             """Every branch's train_step dispatch of `steps` steps funnels
@@ -1398,11 +1491,13 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
             with trace.phase("dispatch", "epoch", steps=steps,
                              iteration=iteration):
                 rng, step_rng = jax.random.split(rng)
+                args = (params, opt_state, xb, yb, step_rng)
+                if iteration == first_iteration:
+                    step_program.saw(args)
                 _profile_tick(iteration)
                 return _step_with_watchdog(
-                    train_step, (params, opt_state, xb, yb, step_rng),
-                    step_retries, step_timeout_s, telemetry.step_retries,
-                    iteration)
+                    train_step, args, step_retries, step_timeout_s,
+                    telemetry.step_retries, iteration)
 
         def _ckpt_extra(ep: int, finished: bool) -> Dict[str, Any]:
             """Checkpoint sidecar: everything auto-resume needs for bitwise
@@ -1644,9 +1739,7 @@ def fit_keras(model, x, y=None, batch_size: int = 32, epochs: int = 1,
                 # a fit that ends (or dies) inside the window still leaves
                 # a finished, loadable artifact behind
                 try:
-                    manifest = profiler.stop()
-                    history.setdefault("profile_artifacts", []).append(
-                        manifest["dir"])
+                    _profile_stop()
                 except Exception:  # noqa: BLE001 — already tearing down
                     pass
             if reporter is not None:

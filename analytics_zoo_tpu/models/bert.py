@@ -86,10 +86,11 @@ class BERTClassifier(_BERTTask):
             rng, sub = jax.random.split(rng)
         pooled = self.bert.call(params[self.bert.name], inputs,
                                 training=training, rng=sub)
-        if training and rng is not None and self.dropout > 0:
-            pooled = _dropout(rng, self.dropout, pooled)
-        return maybe_int8_matmul(pooled, params, "cls_kernel") \
-            + params["cls_bias"]
+        with jax.named_scope("bert/pooler_head"):
+            if training and rng is not None and self.dropout > 0:
+                pooled = _dropout(rng, self.dropout, pooled)
+            return maybe_int8_matmul(pooled, params, "cls_kernel") \
+                + params["cls_bias"]
 
     def compute_output_shape(self, input_shape):
         return (None, self.num_classes)

@@ -88,30 +88,36 @@ class NeuralCF(Recommender):
     def build_model(self) -> Model:
         # input: [B, 2] of (user_id, item_id) — `neuralcf.py:55-57`
         inp = Input(shape=(2,))
-        user = L.Select(1, 0)(inp)
-        item = L.Select(1, 1)(inp)
-        mlp_user = L.Flatten()(
-            L.Embedding(self.user_count + 1, self.user_embed,
-                        init="uniform", name="ncf_mlp_user")(user))
-        mlp_item = L.Flatten()(
-            L.Embedding(self.item_count + 1, self.item_embed,
-                        init="uniform", name="ncf_mlp_item")(item))
-        x = L.merge([mlp_user, mlp_item], mode="concat")
+
+        def under(scope, layer):
+            layer.scope = scope     # names the program's part, no more
+            return layer
+        user = under("ncf/embeddings", L.Select(1, 0))(inp)
+        item = under("ncf/embeddings", L.Select(1, 1))(inp)
+
+        def table(name, count, width, ids):
+            return under("ncf/embeddings", L.Flatten())(
+                under("ncf/embeddings", L.Embedding(
+                    count + 1, width, init="uniform", name=name))(ids))
+        mlp_user = table("ncf_mlp_user", self.user_count, self.user_embed,
+                         user)
+        mlp_item = table("ncf_mlp_item", self.item_count, self.item_embed,
+                         item)
+        x = under("ncf/mlp", L.Merge(mode="concat"))([mlp_user, mlp_item])
         for units in self.hidden_layers:
-            x = L.Dense(units, activation="relu")(x)
+            x = under("ncf/mlp", L.Dense(units, activation="relu"))(x)
         table_names = ["ncf_mlp_user", "ncf_mlp_item"]
         if self.include_mf:
             assert self.mf_embed > 0
-            mf_user = L.Flatten()(
-                L.Embedding(self.user_count + 1, self.mf_embed,
-                            init="uniform", name="ncf_mf_user")(user))
-            mf_item = L.Flatten()(
-                L.Embedding(self.item_count + 1, self.mf_embed,
-                            init="uniform", name="ncf_mf_item")(item))
-            gmf = L.merge([mf_user, mf_item], mode="mul")
-            x = L.merge([x, gmf], mode="concat")
+            mf_user = table("ncf_mf_user", self.user_count, self.mf_embed,
+                            user)
+            mf_item = table("ncf_mf_item", self.item_count, self.mf_embed,
+                            item)
+            gmf = under("ncf/gmf", L.Merge(mode="mul"))([mf_user, mf_item])
+            x = under("ncf/head", L.Merge(mode="concat"))([x, gmf])
             table_names += ["ncf_mf_user", "ncf_mf_item"]
-        out = L.Dense(self.class_num, activation="softmax")(x)
+        out = under("ncf/head",
+                    L.Dense(self.class_num, activation="softmax"))(x)
         model = Model(inp, out)
 
         # Declare the embedding tables for the lazy row-sparse optimizer

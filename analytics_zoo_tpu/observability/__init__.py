@@ -23,7 +23,15 @@ plus the deep-profiling layer that makes the stack self-measuring.
   gauges and a leak assertion for tests.
 - `SLOObjectives` / `SLOTracker` — declarative latency/availability
   objectives with burn-rate gauges and the `/healthz` readiness input.
+- `device_time` — device time of a profiler capture by the program's own
+  `jax.named_scope`s (the compiled program's table of instruction ->
+  scope joined to the capture; `python -m ...observability.device_time`).
+- `programs` — `xla_program_obtain_ms{how, during}` and the span
+  `fit.obtain_program`: how the process got each executable (compiled,
+  or loaded from the persistent cache), listened for from import on.
 """
+
+from analytics_zoo_tpu.observability import programs as _programs
 
 from analytics_zoo_tpu.observability.capture import (CaptureActiveError,
                                                      ProfileCapture,
@@ -51,6 +59,8 @@ from analytics_zoo_tpu.observability.tracing import (Span, Tracer,
                                                      span_coverage,
                                                      span_from_dict,
                                                      span_to_dict)
+
+_programs.install()
 
 __all__ = [
     "CONTENT_TYPE", "CaptureActiveError", "Counter", "DeviceMemoryLeak",

@@ -165,6 +165,12 @@ class Tracer:
             stack = self._local.stack = []
         return stack
 
+    def open_root(self) -> Optional[_ScopedSpan]:
+        """The outermost scoped span the calling thread has open, or
+        None: what the work at hand belongs to."""
+        stack = self._stack()
+        return stack[0] if stack else None
+
     def add_sink(self, fn) -> None:
         """Register `fn(span)` to observe every finished span."""
         self._sinks.append(fn)

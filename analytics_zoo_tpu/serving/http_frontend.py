@@ -372,6 +372,22 @@ class _Handler(BaseHTTPRequestHandler):
         except Exception as e:  # noqa: BLE001 — frontend must not die
             self._send(500, {"error": f"{type(e).__name__}: {e}"})
             return
+        try:
+            # device time by the served program's own scopes, beside the
+            # capture (`device_time_by_scope.json`); without a table
+            # every event reads `unmatched` and the file says so
+            from analytics_zoo_tpu.observability import device_time
+            scopes = getattr(getattr(self.server.serving, "model", None),
+                             "program_scopes", None)
+            report = device_time.write_report(
+                manifest["dir"], scopes() if scopes else {})
+            manifest["files"].append(device_time.REPORT_FILE)
+            manifest["device_time"] = {
+                k: report[k] for k in ("device_source", "total_s", "note")
+                if k in report}
+        except Exception as e:  # noqa: BLE001 — the capture stands
+            manifest["device_time"] = {
+                "error": f"{type(e).__name__}: {e}"}
         self._send(200, manifest)
 
     def _trace(self):

@@ -756,6 +756,21 @@ class InferenceModel:
                 return ex(params, x)
         return self._jit(params, x)
 
+    def program_scopes(self) -> Dict[str, Dict[str, Dict[str, Any]]]:
+        """`{module: {instruction: {scope, direction, also}}}` of the
+        warmed AOT executables that can give their compiled text
+        (`observability/device_time.py`; what `POST /profile` joins its
+        capture to). `{}` where the model serves through the jit wrapper
+        alone: a capture's events then read `unmatched`."""
+        from analytics_zoo_tpu.observability import device_time
+        tables = []
+        for ex in self._aot.values():
+            try:
+                tables.append(device_time.scope_table(ex.as_text()))
+            except Exception:  # noqa: BLE001 — a re-treed or deserialised
+                continue       # executable that gives no text
+        return device_time.merge_tables(tables)
+
     def _warm_executable(self, replica_idx: int, params, batch,
                          target_device_id=None) -> str:
         """Cache-backed warmup for one (replica, bucket): consult the

@@ -141,22 +141,11 @@ def stage_fit(compiles: CompileCounter, bert_kw=BERT_BASE, seq_len=128,
     return est
 
 
-def _lower_train_program(est, data, batch: int):
-    """StableHLO text of the jitted program the fit just ran (the object the
-    trainer memoized on the model), lowered on the shapes it ran with."""
-    cache_key, step = est.model._train_cache
-    step = getattr(step, "wrapped", step)
-    params = est.model.params
-    opt_state = jax.eval_shape(est.model.optimizer.init, params)
-    sds = jax.ShapeDtypeStruct
-    if "devcache" in cache_key:         # whole epoch over resident data
-        xs = [sds(a.shape, a.dtype) for a in data["x"]]
-        ys = sds(data["y"].shape, data["y"].dtype)
-    else:                               # steps_per_run=2 scan program
-        xs = [sds((2, batch) + a.shape[1:], a.dtype) for a in data["x"]]
-        ys = sds((2, batch), data["y"].dtype)
-    rng = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    return step.lower(params, opt_state, xs, ys, rng).as_text()
+def _lower_train_program(est):
+    """StableHLO text of the jitted program the fit just ran, lowered on
+    the shapes the trainer kept of its dispatch (`_StepProgram`)."""
+    program = est.model._train_cache[2]
+    return program.jitted.lower(*program.abstract_args).as_text()
 
 
 def stage_fit_flash(compiles: CompileCounter, bert_kw=BERT_BASE,
@@ -172,7 +161,7 @@ def stage_fit_flash(compiles: CompileCounter, bert_kw=BERT_BASE,
     cold, warm, losses = _fit_twice(est, data, batch, compiles,
                                     get_context().mesh)
 
-    text = _lower_train_program(est, data, batch)
+    text = _lower_train_program(est)
     n_kernels = text.count("tpu_custom_call")
     # one forward and at least one backward kernel per block
     assert n_kernels >= 2 * bert_kw["n_block"], (
