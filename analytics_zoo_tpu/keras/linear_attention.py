@@ -28,6 +28,19 @@ float32 a sequence, whatever its length; the training path starts every
 sequence from zero and returns no state (a cache entry, snapshots and a
 decode step are what serving would add: ROADMAP M6).
 
+The stage between the projections and the recurrence (the filter, SiLU,
+the L2 norm, and the way from [B, T, heads * d] to the recurrence's rows
+[B * heads, T, d]) runs on one of two paths, and the input decides which
+(`KimiDeltaAttention._qkv_rows`; no option, no environment variable): on
+the TPU (or under `interpret=True`), with heads a multiple of 128 wide and
+a sequence of whole tiles of 16 tokens, the kernels `qkv_short_conv_fwd` /
+`qkv_short_conv_bwd` of `pallas/short_conv.py`, which filter in float32
+with a tile of tokens in VMEM, write rows directly and keep the projection
+and the taps alone for the gradient; off the TPU and for every other
+width or length `_conv_unit`, XLA, in the step's type, under a
+`jax.checkpoint` that keeps as little. `_conv_unit` is what the tests
+hold the kernels to.
+
 The recurrence's output carries the name `RECURRENCE_OUT_NAME`
 (`jax.ad_checkpoint.checkpoint_name`): a `jax.checkpoint` whose policy
 saves that name (`models/moe_decoder.py`'s does) keeps it, as a flash
@@ -48,6 +61,8 @@ from jax.ad_checkpoint import checkpoint_name
 from analytics_zoo_tpu.keras.engine import Layer
 from analytics_zoo_tpu.keras.layers import RMSNormalization, get_init
 from analytics_zoo_tpu.pallas.delta_rule import gated_delta_rule
+from analytics_zoo_tpu.pallas.short_conv import (short_conv_fits,
+                                                 short_conv_rows)
 from analytics_zoo_tpu.serving.quantization import maybe_int8_matmul
 
 
@@ -155,6 +170,19 @@ class KimiDeltaAttention(Layer):
             a = (_unit(a) * unit_scale).astype(projected.dtype)
         return self._rows(a)
 
+    def _qkv_rows(self, projected, taps, unit_scale):
+        """`_conv_unit` by the kernels where they take the projection's
+        shape (`short_conv_fits`), in XLA everywhere else."""
+        if short_conv_fits(projected.shape, self.n_head, taps.shape[0],
+                           self.interpret):
+            return short_conv_rows(projected, taps, self.n_head, unit_scale,
+                                   _L2_EPS, self.interpret)
+        # a checkpoint of its own: a gradient keeps the projection and not
+        # the dozen [B, T, n * d] arrays, a third of them float32, between
+        # it and the recurrence (the kernels' gradient keeps as little)
+        return jax.checkpoint(self._conv_unit, static_argnums=(2,))(
+            projected, taps, unit_scale)
+
     def _gates(self, params, x):
         """(g rows [B * n, T, dk] float32, beta rows [B * n, T] float32)."""
         B, T, _ = x.shape
@@ -181,17 +209,14 @@ class KimiDeltaAttention(Layer):
         cdt = x.dtype
         # the elementwise stages are checkpoints of their own: a gradient
         # keeps their inputs (the projections, x, the recurrence's output)
-        # and not the dozen [B, T, n * d] arrays, a third of them float32,
-        # between the projections and the recurrence
-        conv_unit = jax.checkpoint(self._conv_unit, static_argnums=(2,))
         with jax.named_scope("kda/qkv_proj"):
             q = maybe_int8_matmul(x, params, "q_kernel").astype(cdt)
             k = maybe_int8_matmul(x, params, "k_kernel").astype(cdt)
             v = maybe_int8_matmul(x, params, "v_kernel").astype(cdt)
         with jax.named_scope("kda/short_conv"):
-            q = conv_unit(q, params["q_conv"], self.dk ** -0.5)
-            k = conv_unit(k, params["k_conv"], 1.0)
-            v = conv_unit(v, params["v_conv"], None)
+            q = self._qkv_rows(q, params["q_conv"], self.dk ** -0.5)
+            k = self._qkv_rows(k, params["k_conv"], 1.0)
+            v = self._qkv_rows(v, params["v_conv"], None)
         with jax.named_scope("kda/gates"):
             g, beta = jax.checkpoint(self._gates)(params, x)
         o = checkpoint_name(

@@ -11,9 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from analytics_zoo_tpu.keras.linear_attention import (KimiDeltaAttention,
+from analytics_zoo_tpu.keras.linear_attention import (_L2_EPS,
+                                                      KimiDeltaAttention,
                                                       causal_depthwise_conv)
 from analytics_zoo_tpu.pallas import delta_rule as dr
+from analytics_zoo_tpu.pallas import short_conv as sc
 from benchmark.reference import kimi_linear as reference
 
 
@@ -276,3 +278,139 @@ def test_bfloat16_keeps_the_decay_in_float32():
     full = layer.call(jax.tree_util.tree_map(
         lambda a: a.astype(jnp.float32), params), x.astype(jnp.float32))
     assert _rel(out.astype(jnp.float32), full) < 0.05
+
+
+# ------------------------------------------ the q/k/v stage as kernels
+
+_STAGE = {"q_norm_and_scale": 128 ** -0.5, "k_norm": 1.0, "v_plain": None}
+
+
+def _stage_inputs(dtype, B=2, T=96, n=2, w=128, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    projected = jax.random.normal(ks[0], (B, T, n * w)).astype(dtype)
+    taps = jax.random.uniform(ks[1], (4, n * w), minval=-0.5, maxval=0.5)
+    cot = jax.random.normal(ks[2], (B * n, T, w)).astype(dtype)
+    return projected, taps, cot
+
+
+def _wide_layer(**kw):
+    return KimiDeltaAttention(64, 2, 128, chunk=16, name="kda_wide",
+                              init=jax.nn.initializers.normal(0.1), **kw)
+
+
+def _stage_pair(projected, taps, cot, scale, tile=32):
+    """((rows, d projection, d taps) of the kernels through the
+    interpreter, the same of XLA's `_conv_unit`)."""
+    layer = _wide_layer()
+    sides = []
+    for fn in (lambda x, t: sc.short_conv_rows(x, t, 2, scale, _L2_EPS,
+                                                True, tile=tile),
+               lambda x, t: layer._conv_unit(x, t, scale)):
+        rows, vjp = jax.vjp(fn, projected, taps)
+        sides.append((rows, *vjp(cot)))
+    return sides
+
+
+@pytest.mark.parametrize("which", list(_STAGE))
+def test_short_conv_kernels_match_xla_in_float32(which):
+    """`qkv_short_conv_fwd` / `qkv_short_conv_bwd` against `_conv_unit` and
+    `jax.vjp` of it: B = 2 (rows head-major), three tiles of 32 tokens."""
+    got, want = _stage_pair(*_stage_inputs(jnp.float32), _STAGE[which])
+    for name, a, b in zip(("rows", "d_projection", "d_taps"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.isfinite(a).all()), name
+        assert _rel(a, b) < 2e-6, (which, name)
+
+
+@pytest.mark.parametrize("which", list(_STAGE))
+def test_short_conv_kernels_match_xla_within_bfloat16_rounding(which):
+    """bfloat16 in and out: the kernels filter in float32, so they stand
+    no further from `_conv_unit` in float32 on the same inputs than ONE
+    rounding of the result (2^-9 an element), and no further than
+    `_conv_unit` in bfloat16 does."""
+    projected, taps, cot = _stage_inputs(jnp.bfloat16)
+    f32 = jnp.float32
+    got, xla = _stage_pair(projected, taps, cot, _STAGE[which])
+    _, exact = _stage_pair(projected.astype(f32), taps, cot.astype(f32),
+                           _STAGE[which])
+    for name, a, b, c in zip(("rows", "d_projection", "d_taps"), got, xla,
+                             exact):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        ours, theirs = _rel(a.astype(f32), c), _rel(b.astype(f32), c)
+        assert ours < 2.0 ** -9, (which, name, ours)
+        assert ours <= theirs, (which, name, ours, theirs)
+
+
+@pytest.mark.parametrize("tile", [16, 32, 96])
+def test_short_conv_history_is_zeros_then_the_tile_before(tile):
+    """Token 0 sees three zeros, and a tile's first tokens see the last
+    tokens of the tile before: against the filter written out in numpy, at
+    every token, with rows [B * n, T, w] head-major."""
+    projected, taps, _ = _stage_inputs(jnp.float32, T=96, seed=3)
+    rows = np.asarray(sc.short_conv_rows(projected, taps, 2, None, _L2_EPS,
+                                         True, tile=tile))
+    x, f = np.asarray(projected, np.float64), np.asarray(taps, np.float64)
+    c = np.zeros_like(x)
+    for t in range(96):
+        for i in range(4):
+            if t - 3 + i >= 0:
+                c[:, t] += f[i] * x[:, t - 3 + i]
+    want = (c / (1.0 + np.exp(-c))).reshape(2, 96, 2, 128).transpose(
+        0, 2, 1, 3).reshape(4, 96, 128)
+    np.testing.assert_allclose(rows, want, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("shape, heads, taps, interpret, fits", [
+    ((1, 16384, 4096), 32, 4, True, True),      # the benchmark's
+    ((2, 96, 256), 2, 4, True, True),
+    ((2, 96, 256), 2, 4, None, False),          # off the TPU
+    ((2, 40, 32), 2, 4, True, False),           # heads 16 wide
+    ((2, 96, 192), 2, 4, True, False),          # heads 96 wide
+    ((2, 100, 256), 2, 4, True, False),         # no whole tiles of 16
+    ((2, 96, 256), 2, 12, True, False),         # more history than 8 rows
+])
+def test_the_shapes_decide_which_qkv_stage_runs(shape, heads, taps,
+                                                interpret, fits):
+    assert sc.short_conv_fits(shape, heads, taps, interpret) is fits
+
+
+def test_a_head_the_kernels_do_not_take_runs_xla(monkeypatch):
+    """The small layer of this file (heads 16 and 24 wide) under
+    `interpret=True`: the stage is XLA's, the recurrence's kernels run."""
+    import analytics_zoo_tpu.keras.linear_attention as la
+    monkeypatch.setattr(la, "short_conv_rows", None)    # would raise if run
+    layer, plain = _layer(interpret=True), _layer()
+    params = layer.build(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, 48))
+    assert _rel(layer.call(params, x), plain.call(params, x)) < 1e-5
+    with pytest.raises(TypeError):
+        _wide_layer(interpret=True).call(
+            _wide_layer().build(jax.random.PRNGKey(0)),
+            jax.random.normal(jax.random.PRNGKey(1), (1, 32, 64)))
+
+
+@pytest.mark.parametrize("dtype, limit", [(jnp.float32, 2e-4),
+                                          (jnp.bfloat16, 0.05)],
+                         ids=["float32", "bfloat16"])
+def test_layer_agrees_on_both_paths_in_output_and_every_gradient(dtype,
+                                                                 limit):
+    """Heads 128 wide: `interpret=True` takes the stage's kernels (and the
+    recurrence's), `None` on the CPU is XLA all the way."""
+    kernels, xla = _wide_layer(interpret=True), _wide_layer()
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(dtype), xla.build(jax.random.PRNGKey(0)))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, 64)).astype(dtype)
+    cot = jax.random.normal(jax.random.PRNGKey(2), (2, 48, 64))
+
+    def loss(layer):
+        return lambda p, x: jnp.sum(
+            layer.call(p, x).astype(jnp.float32) * cot)
+    f32 = jnp.float32
+    assert _rel(kernels.call(params, x).astype(f32),
+                xla.call(params, x).astype(f32)) < limit
+    got = jax.grad(loss(kernels), argnums=(0, 1))(params, x)
+    want = jax.grad(loss(xla), argnums=(0, 1))(params, x)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype, path
+        assert _rel(a.astype(f32), b.astype(f32)) < limit, path

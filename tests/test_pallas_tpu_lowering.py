@@ -321,3 +321,55 @@ def test_delta_rule_chunk_kernels_lower_under_their_names(dtype):
         assert name in bwd, name
     assert "triangular" not in bwd.lower()
     assert dr._heads_per_step(16) == 8 and dr._heads_per_step(6) == 2
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kda_layer_lowers_with_the_short_conv_kernels_by_name(dtype):
+    """A KDA layer's step at the benchmark's head width (128) and the tile
+    its 16,384 tokens are cut into (512; two of them here): the q/k/v
+    stage (`pallas/short_conv.py`) lowers for the TPU as
+    `qkv_short_conv_fwd`, once each for q, k and v, and its gradient as
+    `qkv_short_conv_bwd`, the names `kda_short_conv_time_share` matches,
+    beside the recurrence's four kernels."""
+    from analytics_zoo_tpu.keras.linear_attention import KimiDeltaAttention
+    from analytics_zoo_tpu.pallas import short_conv as sc
+    layer = KimiDeltaAttention(256, 4, 128, name="kda_lowered")
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, dtype),
+        jax.eval_shape(layer.build, jax.random.PRNGKey(0)))
+    x = sds((1, 1024, 256), dtype)
+    assert sc.short_conv_fits((1, 1024, 512), 4, 4, None)
+    assert sc._tile(16384) == sc._tile(1024) == 512
+
+    def loss(p, x):
+        return layer.call(p, x).astype(jnp.float32).sum()
+    fwd = jax.jit(layer.call).trace(params, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert fwd.count("qkv_short_conv_fwd") >= 3
+    assert "qkv_short_conv_bwd" not in fwd
+    assert fwd.count("tpu_custom_call") == 5     # + the recurrence's two
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    for name in ("qkv_short_conv_fwd", "qkv_short_conv_bwd",
+                 "delta_prepare_fwd", "delta_prepare_bwd", "kda_chunk_fwd",
+                 "kda_chunk_bwd"):
+        assert name in bwd, name
+    # of the hybrid cell's per-kernel metrics the stage's own reads the two
+    # names, and no other does (`kda_time_share` reads every `kda_...`)
+    import json
+    import os
+    import re
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        cell_metrics = [
+            m["name"] for m in json.load(fh)["per_layer"]
+            if "kimi-linear-48b-a3b.fit-seq16384-b1" in m.get("workloads", [])]
+    patterns = {}
+    for metric in cell_metrics:
+        with open(os.path.join(root, "benchmark", "layer_metrics",
+                               metric + ".json")) as fh:
+            patterns[metric] = json.load(fh).get("pattern")
+    for name in ("qkv_short_conv_fwd", "qkv_short_conv_bwd"):
+        assert {m for m, pattern in patterns.items() if pattern and re.search(
+            pattern, name + ".7@tpu_custom_call")} \
+            == {"kda_short_conv_time_share"}, name

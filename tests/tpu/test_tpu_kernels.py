@@ -1101,3 +1101,55 @@ class TestKernelNamesOnChip:
                           ("flash_dq_time_share", 0),
                           ("flash_dkv_time_share", 0)):
             assert matched(metric) == n, (metric, kernels)
+
+
+class TestShortConvOnChip:
+    """The KDA layer's q/k/v stage (`pallas/short_conv.py`) at the hybrid
+    model's fit shape, one of q, k, v `[1, 16384, 4096]` as 32 heads of
+    128: `qkv_short_conv_fwd` and `qkv_short_conv_bwd` compiled by the
+    chip's compiler and held, rows and both gradients, to XLA's
+    `_conv_unit` in float32 on the same values."""
+
+    def _sides(self, dtype, scale, T=16384, n=32, w=128):
+        from analytics_zoo_tpu.keras.linear_attention import (
+            _L2_EPS, KimiDeltaAttention)
+        from analytics_zoo_tpu.pallas import short_conv as sc
+        ks = jax.random.split(jax.random.PRNGKey(4), 3)
+        x = jax.random.normal(ks[0], (1, T, n * w)).astype(dtype)
+        taps = jax.random.uniform(ks[1], (4, n * w), minval=-0.5, maxval=0.5)
+        cot = jax.random.normal(ks[2], (n, T, w)).astype(dtype)
+        layer = KimiDeltaAttention(2304, n, w, name="kda_onchip")
+        assert sc.short_conv_fits(x.shape, n, 4, None)
+
+        def both(fn):
+            def run(x, taps, cot):
+                rows, vjp = jax.vjp(fn, x, taps)
+                return (rows, *vjp(cot))
+            return jax.jit(run)
+        f32 = jnp.float32
+        got = both(lambda x, t: sc.short_conv_rows(x, t, n, scale, _L2_EPS))(
+            x, taps, cot)
+        want = both(lambda x, t: layer._conv_unit(x, t, scale))(
+            x.astype(f32), taps, cot.astype(f32))
+        rel = []
+        for a, b in zip(got, want):
+            assert a.shape == b.shape and bool(jnp.isfinite(a).all())
+            rel.append(float(jnp.linalg.norm(a.astype(f32) - b)
+                             / jnp.linalg.norm(b)))
+        assert got[0].dtype == got[1].dtype == dtype
+        print(f"short_conv_onchip {jnp.dtype(dtype).name} scale={scale} "
+              f"rel_err rows,d_projection,d_taps={rel}")
+        return rel
+
+    @pytest.mark.parametrize("scale", [128 ** -0.5, None],
+                             ids=["q_norm_and_scale", "v_plain"])
+    def test_seq16384_bfloat16_rows_and_both_gradients(self, scale):
+        rows, d_projection, d_taps = self._sides(jnp.bfloat16, scale)
+        # one rounding of a result to bfloat16: 2^-9 an element
+        assert rows < 2.0 ** -9 and d_projection < 2.0 ** -9
+        assert d_taps < 1e-4                  # summed in float32
+
+    @pytest.mark.parametrize("scale", [1.0, None],
+                             ids=["k_norm", "v_plain"])
+    def test_float32_as_the_forward_check_runs_it(self, scale):
+        assert max(self._sides(jnp.float32, scale, T=4096)) < 1e-5
