@@ -3,7 +3,9 @@
     s        = sigmoid(u Wg)              float32, [N, n_routed_experts]
     choice   = top-k of (s + b)           b: the router's correction bias; it
                                           chooses, it does not weigh
-    w_e      = s_e / (sum of s over the k chosen + 1e-20) * scale
+    w_e      = s_e / (sum of s over the k chosen + eps) * scale
+                                          eps: 1e-20 unless given (LFM2's
+                                          published code adds 1e-6)
     MoE(u)   = SwiGLU^shared(u) + sum over the chosen e HELD HERE of
                w_e * SwiGLU^e(u)
 
@@ -44,17 +46,17 @@ from analytics_zoo_tpu.keras.transformer import gated_ffn, gated_ffn_params
 from analytics_zoo_tpu.pallas.grouped_matmul import grouped_matmul
 
 
-def route(u, kernel, bias, top_k: int, scale: float):
+def route(u, kernel, bias, top_k: int, scale: float, eps: float = 1e-20):
     """Token features u [N, H] -> (experts [N, k] int32, weights [N, k]
     float32): sigmoid scores in float32, the k largest of score + bias
-    chosen, weighed by the scores alone (normalised over the k, times
-    `scale`)."""
+    chosen, weighed by the scores alone (normalised over the k, `eps`
+    added to their sum, times `scale`)."""
     scores = jax.nn.sigmoid(jnp.dot(
         u.astype(jnp.float32), kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(scores, experts, axis=-1)
-    w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    w = w / (w.sum(axis=-1, keepdims=True) + eps)
     return experts.astype(jnp.int32), w * scale
 
 
@@ -134,13 +136,15 @@ class MoEFeedForward(Layer):
     routed experts (module docstring). `experts_held` = (first, end) is a
     contiguous range of the `n_routed_experts`; None holds all of them.
     `shared_width` is the width of the shared experts taken as ONE gated
-    FFN (`n_shared_experts * moe_intermediate_size`); 0 has none."""
+    FFN (`n_shared_experts * moe_intermediate_size`); 0 has none.
+    `norm_eps` is added to the chosen scores' sum (`route`)."""
 
     def __init__(self, hidden_size: int, expert_width: int,
                  n_routed_experts: int, num_experts_per_tok: int,
                  experts_held: Optional[Tuple[int, int]] = None,
                  shared_width: int = 0, routed_scaling_factor: float = 1.0,
-                 hidden_act: str = "silu", init="glorot_uniform", **kw):
+                 hidden_act: str = "silu", init="glorot_uniform",
+                 norm_eps: float = 1e-20, **kw):
         super().__init__(**kw)
         first, end = experts_held or (0, n_routed_experts)
         if not 0 <= first < end <= n_routed_experts:
@@ -150,7 +154,7 @@ class MoEFeedForward(Layer):
         self.n_routed, self.top_k = n_routed_experts, num_experts_per_tok
         self.first, self.n_held = first, end - first
         self.shared_width = shared_width
-        self.scale = routed_scaling_factor
+        self.scale, self.norm_eps = routed_scaling_factor, norm_eps
         self.act = get_activation(hidden_act)
         self.init = get_init(init)
 
@@ -181,7 +185,7 @@ class MoEFeedForward(Layer):
         """(experts [N, k], weights [N, k]) of u [..., H]'s N tokens."""
         r = params["router"]
         return route(u.reshape(-1, self.hidden_size), r["kernel"], r["bias"],
-                     self.top_k, self.scale)
+                     self.top_k, self.scale, self.norm_eps)
 
     def _dispatch(self, experts):
         """The sorted buffer's bookkeeping from the choice [N, k]: `order`

@@ -1,30 +1,35 @@
 """A causal language model of pre-norm blocks whose token mixer is latent
-attention or a linear-attention layer, layer by layer, over a dense FFN or
-routed experts, on the fit path.
+attention, a linear-attention layer, a gated short convolution or
+grouped-query attention, layer by layer, over a dense FFN or routed
+experts, on the fit path.
 
     h = E[ids]
     Block_l(h):  h = h + Mixer_l(RMSNorm_1(h));  h = h + FFN_l(RMSNorm_2(h))
     Mixer_l: `mixers[l]`: "latent" = multi-head latent attention (MLA),
-             "linear" = Kimi Delta Attention (KDA); latent everywhere
-             unless `mixers` is given
+             "linear" = Kimi Delta Attention (KDA), "conv" = LFM2's gated
+             short convolution, "gqa" = grouped-query attention with
+             per-head q/k norms; latent everywhere unless `mixers` is given
     FFN_l:   the first `n_dense_layer` blocks a dense gated FFN, every
              later block shared experts + routed experts
-    logits = RMSNorm_f(h) W_head                              (untied)
+    logits = RMSNorm_f(h) W_head     (untied; with `tie_embeddings`
+                                      W_head = E^T, the embedding's slice)
 
 Blocks are `keras.transformer.PreNormDecoderBlock`s over
-`keras.latent_attention.LatentSelfAttention` or `keras.linear_attention.
-KimiDeltaAttention` and `keras.transformer.GatedFFN` or `keras.moe.
-MoEFeedForward`. The model may be ONE chip's share of an expert-parallel
-deployment: `experts_held` is the range of every layer's routed experts
-that live here (`keras/moe.py`), and `vocab` the slice of the vocabulary
-whose embedding rows and head columns live here; everything else is whole.
+`keras.latent_attention.LatentSelfAttention`, `keras.linear_attention.
+KimiDeltaAttention`, `keras.gated_conv.GatedShortConv` or
+`keras.grouped_attention.GroupedQueryAttention` and
+`keras.transformer.GatedFFN` or `keras.moe.MoEFeedForward`. The model may
+be ONE chip's share of an expert-parallel deployment: `experts_held` is
+the range of every layer's routed experts that live here (`keras/moe.py`),
+and `vocab` the slice of the vocabulary whose embedding rows and head
+columns live here; everything else is whole.
 
 TPU-first layout, as `models/looped_decoder.py`: neighbouring layers of one
 (mixer, FFN) kind are ONE `[n, ...]` buffer per tensor, `lax.scan`ned, so
 each run compiles once and its gradients are born stacked; the runs follow
 one another in the layers' own order (`_runs`). Latent attention alone
-gives the two runs `dense_blocks` and `moe_blocks`; a model with linear
-layers names a run `blocks_<first layer>_<mixer>_<ffn>`. With `remat` every
+gives the two runs `dense_blocks` and `moe_blocks`; a model with other
+mixers names a run `blocks_<first layer>_<mixer>_<ffn>`. With `remat` every
 layer is a `jax.checkpoint` that keeps its [B, T, H] input and the
 attention kernel's output (with `use_flash` the flash kernel's and its
 log-sum-exp, `pallas.flash_attention.save_flash_residuals`; the linear
@@ -68,7 +73,7 @@ def _runs(mixers, n_dense):
     for l in range(1, len(kinds) + 1):
         if l == len(kinds) or kinds[l] != kinds[first]:
             mixer, ffn = kinds[first]
-            name = f"{ffn}_blocks" if "linear" not in mixers \
+            name = f"{ffn}_blocks" if set(mixers) == {"latent"} \
                 else f"blocks_{first}_{mixer}_{ffn}"
             runs.append((name, mixer, ffn, first, l - first))
             first = l
@@ -92,23 +97,36 @@ class MoEDecoderLM(KerasNet):
                  hidden_act: str = "silu", use_flash: bool = False,
                  remat: bool = True, rotary: bool = True,
                  mixers: Optional[Sequence[str]] = None,
-                 linear_attention: Optional[Dict] = None, name=None):
-        """`mixers`: one of "latent" / "linear" a layer, in the layers'
-        order (None: latent everywhere); `linear_attention`: the linear
-        layers' own arguments (`keras.linear_attention.
+                 linear_attention: Optional[Dict] = None,
+                 conv: Optional[Dict] = None, gqa: Optional[Dict] = None,
+                 tie_embeddings: bool = False, router_eps: float = 1e-20,
+                 name=None):
+        """`mixers`: one of "latent" / "linear" / "conv" / "gqa" a layer,
+        in the layers' order (None: latent everywhere); `linear_attention`:
+        the linear layers' own arguments (`keras.linear_attention.
         KimiDeltaAttention`: n_head, head_dim, conv_size, chunk, ...);
-        `rotary=False` builds the latent layers without positions."""
+        `conv`: the conv layers' (`keras.gated_conv.GatedShortConv`:
+        conv_size); `gqa`: the grouped-query layers' (n_kv_head,
+        head_dim; `n_head` query heads; their rotary tables are head_dim
+        wide, and a model has no latent layer beside them);
+        `rotary=False` builds the latent layers without positions;
+        `tie_embeddings`: the head is the embedding's transpose;
+        `router_eps`: added to the chosen scores' sum (`keras.moe.route`)."""
         super().__init__(name)
         if not 0 <= n_dense_layer < n_layer:
             raise ValueError("MoEDecoderLM needs at least one expert layer "
                              f"after its {n_dense_layer} dense ones")
         mixers = list(mixers or ["latent"] * n_layer)
-        if len(mixers) != n_layer or set(mixers) - {"latent", "linear"}:
-            raise ValueError(f"MoEDecoderLM: mixers {mixers} must name "
-                             f"\"latent\" or \"linear\" for each of the "
-                             f"{n_layer} layers")
+        kinds = {"latent", "linear", "conv", "gqa"}
+        if len(mixers) != n_layer or set(mixers) - kinds \
+                or {"latent", "gqa"} <= set(mixers):
+            raise ValueError(f"MoEDecoderLM: mixers {mixers} must name one "
+                             f"of {sorted(kinds)} for each of the {n_layer} "
+                             "layers, never latent beside gqa")
         self.vocab, self.hidden_size = vocab, hidden_size
-        self.rope_dim, self.rope_theta = qk_rope_head_dim, rope_theta
+        self.rope_dim = gqa["head_dim"] if "gqa" in mixers \
+            else qk_rope_head_dim
+        self.rope_theta, self.tie = rope_theta, tie_embeddings
         self.remat, self.rotary = remat, rotary
         # what a layer's checkpoint keeps: the attention kernels' outputs
         self.kept = save_flash_residuals
@@ -123,6 +141,18 @@ class MoEDecoderLM(KerasNet):
                 return KimiDeltaAttention(
                     hidden_size, rms_eps=rms_eps, init=init,
                     name=f"{self.name}_{tag}_kda", **(linear_attention or {}))
+            if kind == "conv":
+                from analytics_zoo_tpu.keras.gated_conv import GatedShortConv
+                return GatedShortConv(hidden_size, init=init,
+                                      name=f"{self.name}_{tag}_conv",
+                                      **(conv or {}))
+            if kind == "gqa":
+                from analytics_zoo_tpu.keras.grouped_attention import \
+                    GroupedQueryAttention
+                return GroupedQueryAttention(
+                    hidden_size, n_head, rms_eps=rms_eps,
+                    use_flash=use_flash, init=init,
+                    name=f"{self.name}_{tag}_gqa", **gqa)
             return LatentSelfAttention(
                 hidden_size, n_head, kv_lora_rank, qk_nope_head_dim,
                 qk_rope_head_dim, v_head_dim, rms_eps=rms_eps,
@@ -134,7 +164,8 @@ class MoEDecoderLM(KerasNet):
             num_experts_per_tok, experts_held=experts_held,
             shared_width=n_shared_experts * moe_intermediate_size,
             routed_scaling_factor=routed_scaling_factor,
-            hidden_act=hidden_act, init=init, name=self.name + "_moe")
+            hidden_act=hidden_act, init=init, norm_eps=router_eps,
+            name=self.name + "_moe")
         dense_ffn = GatedFFN(hidden_size, intermediate_size, hidden_act,
                              init=init, name=self.name + "_dense_ffn")
         # one block a (mixer, FFN) kind; a run of neighbouring layers of
@@ -164,9 +195,15 @@ class MoEDecoderLM(KerasNet):
                  n_layer),
                 ("model_layers_linear", "layers whose mixer is a linear-"
                  "attention layer (a state, no keys and values)", n_linear),
-                ("model_layers_full", "layers whose mixer is (latent) "
-                 "softmax attention over the whole sequence",
-                 n_layer - n_linear),
+                ("model_layers_full", "layers whose mixer is (latent or "
+                 "grouped-query) softmax attention over the whole sequence",
+                 mixers.count("latent") + mixers.count("gqa")),
+                ("model_layers_conv", "layers whose mixer is a gated short "
+                 "convolution (a K - 1 token history, no keys and values)",
+                 mixers.count("conv")),
+                ("model_layers_gqa", "layers whose mixer is grouped-query "
+                 "attention (K/V heads serving several query heads)",
+                 mixers.count("gqa")),
                 ("model_recompute", "1 if every block application is "
                  "recomputed in the backward pass", int(remat)),
                 ("model_recompute_attention_kernel", "1 if the backward "
@@ -199,9 +236,10 @@ class MoEDecoderLM(KerasNet):
             "word_embeddings": jax.random.normal(
                 k_emb, (self.vocab, self.hidden_size)) * 0.02,
             "final_norm": self.final_norm.build(rng, h_shape),
-            "lm_head_kernel": jax.random.normal(
-                k_head, (self.hidden_size, self.vocab)) * 0.02,
         }
+        if not self.tie:
+            p["lm_head_kernel"] = jax.random.normal(
+                k_head, (self.hidden_size, self.vocab)) * 0.02
         for name, kind, ffn, first, n in self.runs:
             # each block from its own key, every tensor born [n, ...]
             p[name] = jax.vmap(
@@ -268,14 +306,17 @@ class MoEDecoderLM(KerasNet):
 
     def forward(self, params, ids):
         """Logits [B, T, vocab]."""
+        if self.tie:
+            return self.hidden(params, ids) @ params["word_embeddings"].T
         return maybe_int8_matmul(self.hidden(params, ids), params,
                                  "lm_head_kernel")
 
     def apply(self, params, inputs, *, training=False, rng=None):
         if not training:
             return self.forward(params, inputs)
-        return ProjectedLogits(self.hidden(params, inputs),
-                               params["lm_head_kernel"])
+        head = params["word_embeddings"].T if self.tie \
+            else params["lm_head_kernel"]
+        return ProjectedLogits(self.hidden(params, inputs), head)
 
     def compute_output_shape(self, input_shape):
         return (None, input_shape[1], self.vocab)

@@ -93,6 +93,18 @@ the one-kernel backward's resident dQ and its q, k and dk blocks are 256
 lanes wide, so it fits the default scoped VMEM at one 1024 tile (T = 1024)
 and asks for more from T = 2048 (30 MiB at the expert model's T = 8192).
 
+Grouped-query heads. K and V may have fewer heads than q (`q: [B, H, T,
+Dk]`, `k: [B, H / group, T, Dk]`; LFM2's attention layers: 32 query heads
+on 8 K/V heads, `keras/grouped_attention.py`): query head h reads K/V head
+h // group. The kernels are the same; only the K/V BlockSpecs index
+`b // group` over the flattened heads, so K and V are never repeated to H
+heads in HBM. The backward kernels write dK and dV a QUERY head, in
+float32, and `_flash_bwd` sums a group's in one reduction: the one-kernel
+backward keeps a query head's dQ resident in VMEM, which a group's four
+would pass at the lengths served. Such kernels carry a `_gqa` suffix last
+(`_kernel_name`); at group 1 every kernel and its index maps are what they
+were, instruction for instruction.
+
 `flash_attention` falls back to a jnp implementation when Pallas is
 unavailable for the current backend (e.g. CPU tests) — same math, no
 tiling; dropout there uses jax.random (different bits, same distribution).
@@ -123,14 +135,21 @@ def _reference_attention(q, k, v, mask=None, dropout_rate: float = 0.0,
                          dropout_key=None, causal: bool = False):
     """Exact O(L²) attention — the shared non-flash numerics (also what
     `keras.transformer.dot_product_attention` delegates to). `causal`
-    adds a materialised lower-triangular [T, T] mask."""
-    depth = q.shape[-1]
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(depth)
+    adds a materialised lower-triangular [T, T] mask. K and V may have
+    fewer heads than q, a whole fraction of them (grouped-query heads:
+    query head h reads K/V head h // group): they are read grouped, never
+    repeated."""
+    B, H, T, depth = q.shape
+    group = H // k.shape[1]
+    if group > 1:
+        mask = None if mask is None else mask[:, :, None]
+        q = q.reshape(B, H // group, group, T, depth)
+        k, v = k[:, :, None], v[:, :, None]
+    scores = jnp.einsum("...qd,...kd->...qk", q, k) / math.sqrt(depth)
     scores = scores.astype(jnp.float32)
     if mask is not None:
         scores = scores + mask
     if causal:
-        T = q.shape[2]
         scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores,
                            _MASKED)
     weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -138,7 +157,8 @@ def _reference_attention(q, k, v, mask=None, dropout_rate: float = 0.0,
         keep = 1.0 - dropout_rate
         m = jax.random.bernoulli(dropout_key, keep, weights.shape)
         weights = jnp.where(m, weights / keep, 0.0)
-    return jnp.einsum("bhqk,bhkd->bhqd", weights, v)
+    out = jnp.einsum("...qk,...kd->...qd", weights, v)
+    return out.reshape(B, H, T, v.shape[-1]) if group > 1 else out
 
 
 # what a masked score reads: finite, so a row's running maximum never
@@ -178,9 +198,10 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     causal: bool = False):
-    """q, k: [B, H, T, Dk]; v: [B, H, T, Dv] (Dv may differ from Dk: the
-    scores are scaled by 1/sqrt(Dk)). mask: additive [B,1,1,T] (padding) or
-    [B,1,T,T] (full; reference path only). `causal` (static) masks every
+    """q: [B, H, T, Dk]; k: [B, Hkv, T, Dk]; v: [B, Hkv, T, Dv] (Dv may
+    differ from Dk: the scores are scaled by 1/sqrt(Dk); Hkv may be a whole
+    fraction of H, grouped-query heads, module docstring). mask: additive
+    [B,1,1,T] (padding) or [B,1,T,T] (full; reference path only). `causal` (static) masks every
     key after the query's own position, inside the kernels. `dropout_rate`
     > 0 needs `dropout_seed` (scalar int32). Differentiable (custom VJP);
     the mask receives a zero cotangent (padding masks are data, not
@@ -215,6 +236,9 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
                                     dropout_rate if use_dropout else 0.0,
                                     key, causal)
     B, H, T, _ = q.shape
+    if H % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"flash_attention: {k.shape[1]} K/V heads do not "
+                         f"divide {H} query heads into groups")
     if block_q is None:
         block_q = _auto_block(T)
     if block_k is None:
@@ -235,14 +259,15 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
     seed = jnp.asarray(dropout_seed if use_dropout else 0,
                        jnp.int32).reshape(1, 1)
     rate = float(dropout_rate) if use_dropout else 0.0
-    two = _two_widths(q, v)
+    two, gqa = _two_widths(q, v), _group(q, k) > 1
     get_registry().gauge(
         "flash_forward_chunk_columns", "columns of its DMA tile the flash "
         "forward computes at a time (the tile's width where it is not "
         "chunked), at the blocks flash_attention last picked").set(
-            _fwd_chunk(block_k), kernel=_kernel_name("flash_fwd", causal, two))
+            _fwd_chunk(block_k),
+            kernel=_kernel_name("flash_fwd", causal, two, gqa))
     limit = _bwd_fused_vmem_limit(block_q, block_k, T, q.shape[-1],
-                                  q.dtype.itemsize, v.shape[-1])
+                                  q.dtype.itemsize, v.shape[-1], gqa)
     asked = get_registry().gauge(
         "flash_backward_vmem_limit_bytes", "scoped VMEM the flash backward "
         "asks the compiler for (0: the compiler's default holds it), by the "
@@ -250,7 +275,7 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         "picked")
     for name in (("flash_bwd_fused",) if limit is not None
                  else ("flash_dq", "flash_dkv")):
-        asked.set(limit or 0, kernel=_kernel_name(name, causal, two))
+        asked.set(limit or 0, kernel=_kernel_name(name, causal, two, gqa))
     return _flash(q, k, v, mask, seed, rate, block_q, block_k,
                   bool(interpret) if interpret is not None else False,
                   bool(causal))
@@ -306,13 +331,20 @@ def _two_widths(q, v) -> bool:
     return q.shape[-1] != v.shape[-1]
 
 
-def _kernel_name(name, causal, two_widths=False):
+def _group(q, k) -> int:
+    """Query heads a K/V head serves (module docstring, "Grouped-query
+    heads")."""
+    return q.shape[-3] // k.shape[-3]
+
+
+def _kernel_name(name, causal, two_widths=False, gqa=False):
     """The name the compiler puts on the kernel's instruction, which the
     benchmark's per-kernel metrics match (docs/ProgrammingGuide/
     observability.md): `_causal` for the causal form, then `_mla` where
-    the keys are wider than the values."""
+    the keys are wider than the values, then `_gqa` where a K/V head
+    serves several query heads."""
     return name + ("_causal" if causal else "") \
-        + ("_mla" if two_widths else "")
+        + ("_mla" if two_widths else "") + ("_gqa" if gqa else "")
 
 
 def _on_causal_tiles(causal, qi, ki, block_q, block_k, tile):
@@ -523,7 +555,7 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
 
 
 def _attn_cost(qk_matmuls, v_matmuls, q, v, extra_f32_out_elems=0,
-               causal=False):
+               causal=False, group=1):
     """Analytic roofline model for one attention kernel over q
     [..., T, Dk] and v [..., T, Dv] (check_pallas_cost lint: HLO cost
     analysis sees ~0 inside a Mosaic call). `qk_matmuls` counts the
@@ -533,7 +565,9 @@ def _attn_cost(qk_matmuls, v_matmuls, q, v, extra_f32_out_elems=0,
     them at each width — NOT the O(T²) scores, which is the IO-aware point
     of flash attention; exp() is one per score. A causal kernel is counted
     at the lower triangle: half the products and half the scores (what the
-    algorithm needs; the tiles the diagonal crosses are computed whole)."""
+    algorithm needs; the tiles the diagonal crosses are computed whole).
+    With grouped-query heads (`group` > 1) the K and V the kernel reads
+    are counted once a K/V head, the other streams once a query head."""
     from jax.experimental import pallas as pl
 
     *lead, T, Dk = q.shape
@@ -543,10 +577,12 @@ def _attn_cost(qk_matmuls, v_matmuls, q, v, extra_f32_out_elems=0,
     # rough: q,k,v(+dout...) in, grads/out out
     streams = 4 + qk_matmuls + v_matmuls
     half = 2 if causal else 1
+    streamed = bh * T * (Dk + Dv) * item * streams // 2
+    if group > 1:
+        streamed -= bh * T * (Dk + Dv) * item * (group - 1) // group
     return pl.CostEstimate(
         flops=2 * bh * T * T * (qk_matmuls * Dk + v_matmuls * Dv) // half,
-        bytes_accessed=bh * T * (Dk + Dv) * item * streams // 2
-        + extra_f32_out_elems * 4,
+        bytes_accessed=streamed + extra_f32_out_elems * 4,
         transcendentals=bh * T * T // half)
 
 
@@ -556,12 +592,12 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
-    Dv = v.shape[-1]
+    Dv, group = v.shape[-1], _group(q, k)
     scale = 1.0 / math.sqrt(D)
     n_qb, n_kb = T // block_q, T // block_k
     qf = q.reshape(B * H, T, D)
-    kf = k.reshape(B * H, T, D)
-    vf = v.reshape(B * H, T, Dv)
+    kf = k.reshape(B * H // group, T, D)
+    vf = v.reshape(B * H // group, T, Dv)
     mf = jnp.repeat(mask[:, 0, :, :], H, axis=0)           # [B*H, 1, T]
 
     def kj(i, j):
@@ -569,13 +605,19 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
         return jnp.minimum(j, _last_k_block(i, block_q, block_k)) \
             if causal else j
 
+    def kv(b):
+        # query head b reads K/V head b // group, never a repeated copy
+        return b // group if group > 1 else b
+
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, rate, scale, n_qb, n_kb, causal),
         grid=(B * H, n_qb, n_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kj(i, j), 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, kj(i, j), 0)),
+            pl.BlockSpec((1, block_k, D),
+                         lambda b, i, j: (kv(b), kj(i, j), 0)),
+            pl.BlockSpec((1, block_k, Dv),
+                         lambda b, i, j: (kv(b), kj(i, j), 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, kj(i, j))),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
@@ -596,9 +638,10 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=_attn_cost(1, 1, q, v,              # QKᵀ + PV
                                  extra_f32_out_elems=B * H * T,
-                                 causal=causal),
+                                 causal=causal, group=group),
         interpret=interpret,
-        name=_kernel_name("flash_fwd", causal, _two_widths(q, v)),
+        name=_kernel_name("flash_fwd", causal, _two_widths(q, v),
+                          group > 1),
     )(qf, kf, vf, mf, seed)
     out = checkpoint_name(out.reshape(B, H, T, Dv), FLASH_OUT_NAME)
     lse = checkpoint_name(lse, FLASH_LSE_NAME)
@@ -754,7 +797,8 @@ def _bwd_fused_vmem_ceiling() -> int:
     return max(vmem // 2, _DEFAULT_SCOPED_VMEM_BYTES)
 
 
-def _bwd_fused_vmem_need(block_q, block_k, T, D, itemsize, Dv=None) -> int:
+def _bwd_fused_vmem_need(block_q, block_k, T, D, itemsize, Dv=None,
+                         gqa=False) -> int:
     """Bytes of VMEM the one-kernel backward holds at these shapes,
     reckoned from what it holds, every [rows, D] buffer padded to 128
     lanes as Mosaic lays it out at deployment sizes (q, k, dq and dk at
@@ -773,7 +817,8 @@ def _bwd_fused_vmem_need(block_q, block_k, T, D, itemsize, Dv=None) -> int:
     64 head-batches: 28.56 MiB reckoned, 22.5 allocated). With the
     log-sum-exp as rows Mosaic
     asks for 0.8-1.0 MiB less at 1024 tiles than with [block_q, 1] column
-    blocks (PERF.md, PR 27), the reckoning for 0.44 MiB less."""
+    blocks (PERF.md, PR 27), the reckoning for 0.44 MiB less. With
+    grouped-query heads (`gqa`) the dk/dv blocks are float32."""
     lanes = -(-D // 128) * 128
     lanes_v = lanes if Dv is None else -(-Dv // 128) * 128
     chunk = _bwd_chunk(block_k)
@@ -783,16 +828,20 @@ def _bwd_fused_vmem_need(block_q, block_k, T, D, itemsize, Dv=None) -> int:
                + block_k * (lanes + lanes_v) * 4
                + 2 * 8 * block_q * 4 + block_q * _LANES * 4)
     live = block_q * block_k + int(4.5 * block_q * chunk * 4)
+    if gqa:
+        streams += 2 * block_k * (lanes + lanes_v) * (4 - itemsize)
     return resident_dq + streams + live
 
 
-def _bwd_fused_vmem_limit(block_q, block_k, T, D, itemsize, Dv=None):
+def _bwd_fused_vmem_limit(block_q, block_k, T, D, itemsize, Dv=None,
+                          gqa=False):
     """The backward's form at these shapes, as the `vmem_limit_bytes` its
     one kernel asks for: 0 where the compiler's default holds it (no limit
     is passed), the need rounded up to a MiB plus the room where it has to
     ask, None where it runs as the pair (comment above)."""
     def need(T):
-        return _bwd_fused_vmem_need(block_q, block_k, T, D, itemsize, Dv)
+        return _bwd_fused_vmem_need(block_q, block_k, T, D, itemsize, Dv,
+                                    gqa)
     if need(T) <= _BWD_FUSED_VMEM_BYTES:
         return 0
     if need(math.lcm(block_q, block_k)) > _BWD_FUSED_VMEM_BYTES:
@@ -801,10 +850,11 @@ def _bwd_fused_vmem_limit(block_q, block_k, T, D, itemsize, Dv=None):
     return limit if limit <= _bwd_fused_vmem_ceiling() else None
 
 
-def _bwd_fused_fits(block_q, block_k, T, D, itemsize, Dv=None) -> bool:
+def _bwd_fused_fits(block_q, block_k, T, D, itemsize, Dv=None,
+                    gqa=False) -> bool:
     """Whether the backward runs as one kernel at these shapes."""
     return _bwd_fused_vmem_limit(block_q, block_k, T, D, itemsize,
-                                 Dv) is not None
+                                 Dv, gqa) is not None
 
 
 def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
@@ -885,10 +935,12 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _bwd_in_specs(block_q, block_k, D, Dv, q_major, causal):
+def _bwd_in_specs(block_q, block_k, D, Dv, q_major, causal, group=1):
     """Input BlockSpecs shared by the three backward kernels (q, k, v,
-    mask, seed, dO, lse, O), with the q-, the k- and the v-block spec
-    (q, k `D` wide; v, dO, O `Dv` wide). A
+    mask, seed, dO, lse, O), with the q-, the k- and the v-block spec of
+    a query head, which the gradients are written by (q, k `D` wide; v,
+    dO, O `Dv` wide). With grouped-query heads (`group` > 1) the k and v
+    INPUT blocks are those of K/V head b // group. A
     `q_major` grid is (bh, qi, ki), the other (bh, ki, qi). With `causal`
     the inner axis's blocks stop at the diagonal: a skipped step names the
     nearest needed block, which the pipeline already holds."""
@@ -910,26 +962,41 @@ def _bwd_in_specs(block_q, block_k, D, Dv, q_major, causal):
     v_spec = spec((1, block_k, Dv), False, lambda b, j: (b, j, 0))
     m_spec = spec((1, 1, block_k), False, lambda b, j: (b, 0, j))
     lse_spec = spec((1, 1, block_q), True, lambda b, i: (b, 0, i))
-    return ([q_spec, k_spec, v_spec, m_spec,
+    k_in, v_in = k_spec, v_spec
+    if group > 1:
+        k_in = spec((1, block_k, D), False, lambda b, j: (b // group, j, 0))
+        v_in = spec((1, block_k, Dv), False,
+                    lambda b, j: (b // group, j, 0))
+    return ([q_spec, k_in, v_in, m_spec,
              pl.BlockSpec(memory_space=pltpu.SMEM), o_spec, lse_spec,
              o_spec], q_spec, k_spec, v_spec)
+
+
+def _grads(qf, vf, group):
+    """The backward kernels' outputs dq, dk, dv: a query head's each, in
+    q's type; with grouped-query heads dk and dv in float32, for the sum
+    over a group's query heads (`_flash_bwd`) to round once."""
+    BH, T, D = qf.shape
+    kv_type = qf.dtype if group == 1 else jnp.float32
+    return (jax.ShapeDtypeStruct((BH, T, D), qf.dtype),
+            jax.ShapeDtypeStruct((BH, T, D), kv_type),
+            jax.ShapeDtypeStruct((BH, T, vf.shape[-1]), kv_type))
 
 
 def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    qf, vf = operands[0], operands[2]
+    qf, kf, vf = operands[:3]
     BH, T, D = qf.shape
-    Dv = vf.shape[-1]
+    Dv, group = vf.shape[-1], _group(qf, kf)
     n_qb, n_kb = T // block_q, T // block_k
     in_specs, _, k_spec, v_spec = _bwd_in_specs(
-        block_q, block_k, D, Dv, q_major=False, causal=causal)
-    grad = jax.ShapeDtypeStruct((BH, T, D), qf.dtype)
-    grad_v = jax.ShapeDtypeStruct((BH, T, Dv), qf.dtype)
+        block_q, block_k, D, Dv, q_major=False, causal=causal, group=group)
+    grad, grad_k, grad_v = _grads(qf, vf, group)
     # unset (None) wherever the compiler's default holds the kernel
     limit = _bwd_fused_vmem_limit(block_q, block_k, T, D,
-                                  qf.dtype.itemsize, Dv) or None
+                                  qf.dtype.itemsize, Dv, group > 1) or None
     return pl.pallas_call(
         functools.partial(_bwd_fused_kernel, rate, scale, n_qb, n_kb,
                           causal),
@@ -937,7 +1004,7 @@ def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, T, D), lambda b, j, i: (b, 0, 0)),
                    k_spec, v_spec],
-        out_shape=[grad, grad, grad_v],
+        out_shape=[grad, grad_k, grad_v],
         scratch_shapes=[
             pltpu.VMEM((T, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
@@ -948,9 +1015,11 @@ def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=limit),
         # scores, dq, dk at the key width; dw, dv at the value width
-        cost_estimate=_attn_cost(3, 2, qf, vf, causal=causal),
+        cost_estimate=_attn_cost(3, 2, qf, vf, causal=causal,
+                                 group=group),
         interpret=interpret,
-        name=_kernel_name("flash_bwd_fused", causal, _two_widths(qf, vf)),
+        name=_kernel_name("flash_bwd_fused", causal, _two_widths(qf, vf),
+                          group > 1),
     )(*operands)
 
 
@@ -958,17 +1027,17 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    qf, vf = operands[0], operands[2]
+    qf, kf, vf = operands[:3]
     BH, T, D = qf.shape
-    Dv = vf.shape[-1]
-    two = _two_widths(qf, vf)
+    Dv, group = vf.shape[-1], _group(qf, kf)
+    two, gqa = _two_widths(qf, vf), group > 1
     n_qb, n_kb = T // block_q, T // block_k
-    grad = jax.ShapeDtypeStruct((BH, T, D), qf.dtype)
-    grad_v = jax.ShapeDtypeStruct((BH, T, Dv), qf.dtype)
+    grad, grad_k, grad_v = _grads(qf, vf, group)
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     in_specs, q_spec, _, _ = _bwd_in_specs(block_q, block_k, D, Dv,
-                                           q_major=True, causal=causal)
+                                           q_major=True, causal=causal,
+                                           group=group)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, rate, scale, n_qb, n_kb, causal),
         grid=(BH, n_qb, n_kb),
@@ -978,27 +1047,29 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=semantics,
         # scores, dq at the key width; dw at the value width
-        cost_estimate=_attn_cost(2, 1, qf, vf, causal=causal),
+        cost_estimate=_attn_cost(2, 1, qf, vf, causal=causal,
+                                 group=group),
         interpret=interpret,
-        name=_kernel_name("flash_dq", causal, two),
+        name=_kernel_name("flash_dq", causal, two, gqa),
     )(*operands)
     in_specs, _, k_spec, v_spec = _bwd_in_specs(
-        block_q, block_k, D, Dv, q_major=False, causal=causal)
+        block_q, block_k, D, Dv, q_major=False, causal=causal, group=group)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, rate, scale, n_qb, n_kb, causal),
         grid=(BH, n_kb, n_qb),
         in_specs=in_specs,
         out_specs=[k_spec, v_spec],
-        out_shape=[grad, grad_v],
+        out_shape=[grad_k, grad_v],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=semantics,
         # scores, dk at the key width; dw, dv at the value width
-        cost_estimate=_attn_cost(2, 2, qf, vf, causal=causal),
+        cost_estimate=_attn_cost(2, 2, qf, vf, causal=causal,
+                                 group=group),
         interpret=interpret,
-        name=_kernel_name("flash_dkv", causal, two),
+        name=_kernel_name("flash_dkv", causal, two, gqa),
     )(*operands)
     return dq, dk, dv
 
@@ -1006,9 +1077,9 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
 def _flash_bwd(rate, block_q, block_k, interpret, causal, res, dout):
     q, k, v, mask, seed, out, lse = res
     B, H, T, D = q.shape
-    Dv = v.shape[-1]
+    Dv, group = v.shape[-1], _group(q, k)
     scale = 1.0 / math.sqrt(D)
-    qf, kf, vf, dof, of = (x.reshape(B * H, T, x.shape[-1])
+    qf, kf, vf, dof, of = (x.reshape(-1, T, x.shape[-1])
                            for x in (q, k, v, dout, out))
     mf = jnp.repeat(mask[:, 0, :, :], H, axis=0)
     # One algorithm, two forms, chosen from the shapes alone: the fused
@@ -1016,10 +1087,17 @@ def _flash_bwd(rate, block_q, block_k, interpret, causal, res, dout):
     # else the pair that pays the duplicated pnorm/dw matmuls with O(block)
     # VMEM at any T. Both run at the forward's tiling — the dropout mask is
     # keyed by tile.
-    fused = _bwd_fused_fits(block_q, block_k, T, D, q.dtype.itemsize, Dv)
+    fused = _bwd_fused_fits(block_q, block_k, T, D, q.dtype.itemsize, Dv,
+                            group > 1)
     dq, dk, dv = (_bwd_fused if fused else _bwd_pair)(
         rate, scale, block_q, block_k, interpret, causal,
         (qf, kf, vf, mf, seed, dof, lse, of))
+    if group > 1:
+        # a K/V head's gradient: its group's query heads' summed, in one
+        # reduction (the kernels keep a query head's dq resident, so a
+        # group's four would not fit VMEM at the lengths served)
+        dk, dv = (g.reshape(B, H // group, group, T, g.shape[-1]).sum(
+            axis=2).astype(k.dtype) for g in (dk, dv))
     # padding masks are data, not parameters — zero cotangent
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             jnp.zeros_like(mask), jnp.zeros_like(seed))

@@ -101,20 +101,49 @@ def _head_sum(a):
     return jnp.sum(a, axis=-1, keepdims=True)
 
 
-def _filtered(x_ref, halo_ref, taps, cols, r0, rows, first_tile):
-    """Rows r0 .. r0 + rows of one head of the tile: (the K copies of x
-    [rows, w] float32 that the taps multiply, the last of them x itself;
-    the filter's output c)."""
+def _filtered(read, read_halo, taps, r0, rows, first_tile):
+    """The filter core, shared with `pallas/gated_conv.py`: rows r0 ..
+    r0 + rows of one block of columns of a tile, whose input `read(start,
+    size)` gives as [size, w] and `read_halo()` as the 16 rows before the
+    tile, in any type: (the K copies of the input in float32 that the taps
+    multiply, the last of them the input itself; the filter's output c).
+    K is `len(taps)`, static."""
     f32, K = jnp.float32, len(taps)
     # the rows before r0: the tile's own, or before its first the halo's
-    own = x_ref[_rows_at(jnp.maximum(r0, _HALO) - _HALO, _HALO), cols]
-    before = jnp.where(r0 == 0, halo_ref[:, cols], own).astype(f32)
+    own = read(jnp.maximum(r0, _HALO) - _HALO, _HALO)
+    before = jnp.where(r0 == 0, read_halo(), own).astype(f32)
     before = jnp.where(jnp.logical_and(r0 == 0, first_tile), 0.0,
                        before[_HALO - _SEEN:])
-    x = x_ref[_rows_at(r0, rows), cols].astype(f32)
+    x = read(r0, rows).astype(f32)
     seen = jnp.concatenate([before, x], axis=0)
     copies = [_roll_rows(seen, K - 1 - i)[_SEEN:] for i in range(K - 1)] + [x]
     return copies, sum(t * s for t, s in zip(taps, copies))
+
+
+def _taps_sums(sums, d_c, copies):
+    """The taps' gradient of a block added to `sums`, one [8, w] float32
+    partial sum a tap: d_c times each copy, summed down to the 8 sublanes
+    of a tile (the lane reduction is left for once a head)."""
+    rows, w = d_c.shape
+    return tuple(s + (d_c * x).reshape(rows // _SEEN, _SEEN, w).sum(axis=0)
+                 for s, x in zip(sums, copies))
+
+
+def _filter_transposed(taps, d_c, after):
+    """The filter's gradient to its input on a block of rows: d_x[t] = sum_i
+    taps[i] * d_c[t + (K - 1) - i], with `after` [8, w] the cotangent of
+    the first rows after the block (zeros after the sequence's end)."""
+    K, rows = len(taps), d_c.shape[0]
+    ahead = jnp.concatenate([d_c, after], axis=0)
+    return taps[K - 1] * d_c + sum(
+        taps[i] * _roll_rows(ahead, -(K - 1 - i))[:rows]
+        for i in range(K - 1))
+
+
+def _reader(x_ref, halo_ref, cols):
+    """`_filtered`'s two readers over one array's columns `cols`."""
+    return (lambda start, size: x_ref[_rows_at(start, size), cols],
+            lambda: halo_ref[:, cols])
 
 
 def _head_taps(taps_ref, cols, rows):
@@ -139,8 +168,9 @@ def _fwd_kernel(heads, w, scale, eps, x_ref, halo_ref, taps_ref, out_ref):
     def block(i, carry):
         h, r0 = i // blocks, (i % blocks) * rows
         cols = _head_columns(h, w)
-        _, c = _filtered(x_ref, halo_ref, _head_taps(taps_ref, cols, rows),
-                         cols, r0, rows, first_tile)
+        _, c = _filtered(*_reader(x_ref, halo_ref, cols),
+                         _head_taps(taps_ref, cols, rows), r0, rows,
+                         first_tile)
         a = c * jax.nn.sigmoid(c)
         if scale is not None:
             a = a * (jax.lax.rsqrt(_head_sum(a * a) + eps) * scale)
@@ -178,8 +208,8 @@ def _bwd_kernel(heads, w, scale, eps, x_ref, halo_ref, taps_ref, do_ref,
         def block(j, carry):
             after, sums = carry
             r0 = (blocks - 1 - j) * rows
-            copies, c = _filtered(x_ref, halo_ref, taps, cols, r0, rows,
-                                  first_tile)
+            copies, c = _filtered(*_reader(x_ref, halo_ref, cols), taps,
+                                  r0, rows, first_tile)
             gate = jax.nn.sigmoid(c)
             a = c * gate
             d_a = do_ref[h, _rows_at(r0, rows), :].astype(f32)
@@ -187,15 +217,8 @@ def _bwd_kernel(heads, w, scale, eps, x_ref, halo_ref, taps_ref, do_ref,
                 r = jax.lax.rsqrt(_head_sum(a * a) + eps)
                 d_a = (d_a - a * (r * r * _head_sum(d_a * a))) * (r * scale)
             d_c = d_a * (gate + a * (1.0 - gate))
-            # the taps' gradient, summed down to the 8 sublanes of a tile
-            sums = tuple(
-                s + (d_c * x).reshape(rows // _SEEN, _SEEN, w).sum(axis=0)
-                for s, x in zip(sums, copies))
-            # d_x[t] = sum_i taps[i] * d_c[t + (K - 1) - i]
-            ahead = jnp.concatenate([d_c, after], axis=0)
-            d_x = taps[K - 1] * d_c + sum(
-                taps[i] * _roll_rows(ahead, -(K - 1 - i))[:rows]
-                for i in range(K - 1))
+            sums = _taps_sums(sums, d_c, copies)
+            d_x = _filter_transposed(taps, d_c, after)
             dx_ref[_rows_at(r0, rows), cols] = d_x.astype(dx_ref.dtype)
             return d_c[:_SEEN], sums
 
