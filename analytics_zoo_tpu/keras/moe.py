@@ -28,6 +28,19 @@ are both known), never a scatter-add; the buffer's places past the held
 slots are never written by the products, so what leaves the buffer is
 selected by the slots' `held` mask (`_gather_sum`).
 
+Where the layer holds fewer experts than it routes, and the kernels run (on
+the TPU, or with `interpret`), the rows move by `pallas.moe_rows` instead
+of XLA's gathers, and visit the held places alone: their grids follow the
+held count (the sum of the group sizes), in both directions, forward, in
+the recomputation and backward. The buffer stays N x k rows and drop-free,
+but its places past the held count are then written by nobody, and every
+consumer selects them away: the grouped products by their own rows (never
+by a product), the `act(gate) * up` pass runs over them and nothing reads
+its results there, and the combine reads held places alone. With every
+expert held every slot is held, the kernels would move XLA's rows, and
+XLA's gathers stay; off the TPU they stay too, and are the kernels'
+reference.
+
 The router's matmul, sigmoid and top-k run in float32 at the highest
 matmul precision whatever the step's type, as the published code has it:
 the choice is discontinuous, and a bfloat16 score flips it for many tokens.
@@ -35,6 +48,7 @@ the choice is discontinuous, and a bfloat16 score flips it for many tokens.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -43,6 +57,7 @@ import jax.numpy as jnp
 from analytics_zoo_tpu.keras.engine import Layer
 from analytics_zoo_tpu.keras.layers import get_activation, get_init
 from analytics_zoo_tpu.keras.transformer import gated_ffn, gated_ffn_params
+from analytics_zoo_tpu.pallas import moe_rows
 from analytics_zoo_tpu.pallas.grouped_matmul import grouped_matmul
 
 
@@ -77,55 +92,85 @@ def _gather_sum(rows, index, keep, weight=None):
     return acc
 
 
-@jax.custom_vjp
-def _to_sorted(x, order, position, held):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _to_sorted(x, order, position, held, count, kernels):
     """Dispatch: x [N, H] -> [N * k, H], row `order[p] // k` of x at
     sorted place p (slot `order[p]` is token `order[p] // k`'s). Places
     past the held slots hold absent slots' tokens: real rows that no
-    grouped product reads. `position` [N, k] is the inverse of `order`
-    and `held` [N, k] says which slots chose an expert held here: the
-    gradient is the gather back through them, a token's held slots added
-    up (the places of the others were never written)."""
-    return x[order // position.shape[1]]
+    grouped product reads (with `kernels`, unwritten). `position` [N, k]
+    is the inverse of `order` and `held` [N, k] says which slots chose an
+    expert held here: the gradient is the gather back through them, a
+    token's held slots added up (the places of the others were never
+    written). `count` [1] is the number of held slots; `kernels` None moves
+    the rows by XLA's gathers, a bool by `pallas.moe_rows` (its
+    `interpret`)."""
+    if kernels is None:
+        return x[order // position.shape[1]]
+    return moe_rows.gather(x, order // position.shape[1], count,
+                           interpret=kernels)
 
 
-def _to_sorted_fwd(x, order, position, held):
-    return _to_sorted(x, order, position, held), (position, held)
+def _to_sorted_fwd(x, order, position, held, count, kernels):
+    return _to_sorted(x, order, position, held, count, kernels), (
+        position, held, count)
 
 
-def _to_sorted_bwd(res, g):
-    position, held = res
-    return _gather_sum(g, position, held).astype(g.dtype), None, None, None
+def _to_sorted_bwd(kernels, res, g):
+    position, held, count = res
+    if kernels is None:
+        dx = _gather_sum(g, position, held).astype(g.dtype)
+    else:
+        dx = moe_rows.combine(g, count, position, held, interpret=kernels)
+    return dx, None, None, None, None
 
 
 _to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
 
 
-@jax.custom_vjp
-def _from_sorted(ys, weights, order, position, held):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _from_sorted(ys, weights, order, position, held, count, kernels):
     """Combine: the sorted buffer's rows ys [N * k, H] back to their
-    tokens, each held slot's row times its weight [N, k], added up:
-    [N, H] float32. The gradient reaches ys by ONE gather of the tokens'
-    cotangent rows (row `order[p] // k` at place p, times its slot's
-    weight) and the weights by a row-wise product in sorted order."""
-    return _gather_sum(ys, position, held, weights)
+    tokens, each held slot's row times its weight [N, k], added up in
+    float32: [N, H] float32 (with `kernels`, written once in ys's type).
+    The gradient reaches ys by ONE gather of the tokens' cotangent rows
+    (row `order[p] // k` at place p, times its slot's weight) and the
+    weights by a row-wise product in sorted order."""
+    if kernels is None:
+        return _gather_sum(ys, position, held, weights)
+    return moe_rows.combine(ys, count, position, held, weights,
+                            interpret=kernels)
 
 
-def _from_sorted_fwd(ys, weights, order, position, held):
-    return _from_sorted(ys, weights, order, position, held), (
-        ys, weights, order, position, held)
+def _from_sorted_fwd(ys, weights, order, position, held, count, kernels):
+    return _from_sorted(ys, weights, order, position, held, count,
+                        kernels), (ys, weights, order, position, held, count)
 
 
-def _from_sorted_bwd(res, g):
-    ys, weights, order, position, held = res
-    rows = g.astype(ys.dtype)[order // position.shape[1]]   # [N * k, H]
-    d_ys = rows * weights.reshape(-1)[order][:, None].astype(ys.dtype)
-    # a held slot's weight moves the output along its expert's row; the
-    # rows of the places past the held slots are unwritten: selected away
-    along = jnp.sum(ys.astype(jnp.float32) * rows.astype(jnp.float32),
-                    axis=1)
-    d_w = jnp.where(held, along[position], 0.0)
-    return d_ys, d_w.astype(weights.dtype), None, None, None
+def _from_sorted_bwd(kernels, res, g):
+    ys, weights, order, position, held, count = res
+    if kernels is None:
+        rows = g.astype(ys.dtype)[order // position.shape[1]]  # [N * k, H]
+        d_ys = rows * weights.reshape(-1)[order][:, None].astype(ys.dtype)
+        # a held slot's weight moves the output along its expert's row;
+        # the rows of the places past the held slots are unwritten:
+        # selected away
+        along = jnp.sum(ys.astype(jnp.float32) * rows.astype(jnp.float32),
+                        axis=1)
+        d_w = jnp.where(held, along[position], 0.0)
+    else:
+        # the same rows, products and sums, at the held places alone; the
+        # weights go to sorted order and the products back by sorting on
+        # the permutation's keys, which moves the same values as XLA's
+        # scalar gathers in a third of their time
+        w_sorted = jax.lax.sort((position.reshape(-1), weights.reshape(-1)),
+                                num_keys=1)[1]
+        d_ys, along = moe_rows.gather(
+            g.astype(ys.dtype), order // position.shape[1], count,
+            scale=w_sorted.astype(ys.dtype).astype(jnp.float32),
+            dot_with=ys, interpret=kernels)
+        along = jax.lax.sort((order, along), num_keys=1)[1]
+        d_w = jnp.where(held, along.reshape(held.shape), 0.0)
+    return d_ys, d_w.astype(weights.dtype), None, None, None, None
 
 
 _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
@@ -137,14 +182,17 @@ class MoEFeedForward(Layer):
     contiguous range of the `n_routed_experts`; None holds all of them.
     `shared_width` is the width of the shared experts taken as ONE gated
     FFN (`n_shared_experts * moe_intermediate_size`); 0 has none.
-    `norm_eps` is added to the chosen scores' sum (`route`)."""
+    `norm_eps` is added to the chosen scores' sum (`route`). `interpret`
+    runs the Pallas kernels (grouped products, row moves) through the
+    interpreter off the TPU."""
 
     def __init__(self, hidden_size: int, expert_width: int,
                  n_routed_experts: int, num_experts_per_tok: int,
                  experts_held: Optional[Tuple[int, int]] = None,
                  shared_width: int = 0, routed_scaling_factor: float = 1.0,
                  hidden_act: str = "silu", init="glorot_uniform",
-                 norm_eps: float = 1e-20, **kw):
+                 norm_eps: float = 1e-20,
+                 interpret: Optional[bool] = None, **kw):
         super().__init__(**kw)
         first, end = experts_held or (0, n_routed_experts)
         if not 0 <= first < end <= n_routed_experts:
@@ -157,6 +205,11 @@ class MoEFeedForward(Layer):
         self.scale, self.norm_eps = routed_scaling_factor, norm_eps
         self.act = get_activation(hidden_act)
         self.init = get_init(init)
+        self.interpret = interpret
+        # the row kernels carry dispatch and combine where some slots are
+        # absent; with the whole layer here every slot is held
+        self.row_kernels = (self.n_held < n_routed_experts
+                            and moe_rows.takes_kernels(interpret))
 
     def build(self, rng, input_shape=None):
         k_r, k_s, k_e = jax.random.split(rng, 3)
@@ -201,22 +254,36 @@ class MoEFeedForward(Layer):
                  ).sum(axis=0, dtype=jnp.int32)
         return order, position, sizes, held
 
+    def _row_kernels(self, x) -> Optional[bool]:
+        """How this call moves rows (`_to_sorted`'s `kernels`): None by
+        XLA's gathers, else the row kernels' `interpret`."""
+        if self.row_kernels and moe_rows.fits(
+                x.shape[0], self.hidden_size, x.dtype, self.interpret):
+            return bool(self.interpret)
+        return None
+
     def routed(self, params, u):
         """This chip's part of the routed experts' sum, u [B, T, H] ->
         [B, T, H]."""
         x = u.reshape(-1, self.hidden_size)
+        kernels = self._row_kernels(x)
         with jax.named_scope("moe/router"):
             experts, weights = self.routing(params, u)
         with jax.named_scope("moe/dispatch"):
             order, position, sizes, held = self._dispatch(experts)
-            xs = _to_sorted(x, order, position, held)
+            count = sizes.sum(dtype=jnp.int32).reshape(1)
+            xs = _to_sorted(x, order, position, held, count, kernels)
         with jax.named_scope("moe/experts"):
             e = params["experts"]
-            f = self.act(grouped_matmul(xs, e["gate_kernel"], sizes)) \
-                * grouped_matmul(xs, e["up_kernel"], sizes)
-            ys = grouped_matmul(f.astype(x.dtype), e["down_kernel"], sizes)
+
+            def gmm(lhs, rhs):
+                return grouped_matmul(lhs, rhs, sizes, self.interpret)
+
+            f = self.act(gmm(xs, e["gate_kernel"])) * gmm(xs, e["up_kernel"])
+            ys = gmm(f.astype(x.dtype), e["down_kernel"])
         with jax.named_scope("moe/combine"):
-            out = _from_sorted(ys, weights, order, position, held)
+            out = _from_sorted(ys, weights, order, position, held, count,
+                               kernels)
         return out.astype(u.dtype).reshape(u.shape)
 
     def call(self, params, u, *, training=False, rng=None):

@@ -190,6 +190,10 @@ class MoEDecoderLM(KerasNet):
                  "chooses", num_experts_per_tok),
                 ("model_shared_experts", "shared experts of an expert "
                  "layer", n_shared_experts),
+                ("model_moe_row_kernels", "1 if the expert layers' dispatch "
+                 "and combine move the held rows alone, by the row kernels "
+                 "(pallas/moe_rows.py), 0 if by XLA's gathers over all "
+                 "N x k token-slots", int(self.moe.row_kernels)),
                 ("model_layer_applications", "block applications in one "
                  "forward (passes x blocks), whatever each block's mixer",
                  n_layer),

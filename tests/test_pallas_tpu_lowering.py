@@ -243,6 +243,33 @@ def test_grouped_matmul_fwd_and_both_gradients(k, n, dtype):
         assert name in text
 
 
+@pytest.mark.parametrize("held,rows", [((0, 8), 8), ((0, 256), 0)])
+def test_expert_layer_moves_rows_by_the_row_kernels_by_name(held, rows):
+    """Kimi's expert layer (hidden 2304, 8 of 256 routed experts held, 8 a
+    token) at 2048 tokens, forward and backward: gather forward, combine's
+    pack and combine forward, combine backward's gather, dispatch backward's
+    pack and combine (each gather packs its token rows first), and a whole
+    layer keeps XLA's gathers."""
+    from analytics_zoo_tpu.keras.moe import MoEFeedForward
+    layer = MoEFeedForward(2304, 1024, 256, 8, experts_held=held)
+    params = jax.eval_shape(layer.build, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, jnp.bfloat16),
+                                    params)
+
+    def loss(p, u):
+        return layer.routed(p, u).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(
+        params, sds((1, 2048, 2304), jnp.bfloat16)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert layer.row_kernels == bool(rows)
+    assert text.count("moe_rows_gather") >= 2 * bool(rows)
+    names = ("moe_rows_gather", "moe_rows_gather_pack", "moe_rows_combine",
+             "moe_rows_combine_pack")
+    calls = sum(text.count(f'name = "{n}"') for n in names)
+    assert text.count("tpu_custom_call") == 9 + calls
+    assert all((n in text) == bool(rows) for n in names)
+
+
 @pytest.mark.parametrize("H,D,L", [(4, 64, 256), (2, 8, 128)])
 def test_decode_attention(H, D, L):
     S = 8
