@@ -9,6 +9,14 @@
     MoE(u)   = SwiGLU^shared(u) + sum over the chosen e HELD HERE of
                w_e * SwiGLU^e(u)
 
+With `score="softmax"` (SmallThinker's router) s = softmax(u Wg) over all
+the routed experts, the choice is the top-k of s (no correction bias: the
+layer has none) and the weights are normalised over the k as above. The
+router may read another tensor than the experts do: `call`'s `route_from`
+(SmallThinker's router placed before attention, to which
+`keras.transformer.PreNormDecoderBlock` hands the block's normalised
+input); `hidden_act` "relu" makes the experts ReGLU.
+
 Under expert parallelism a layer's experts are spread over chips; this
 layer is one chip's part. It routes over all `n_routed_experts` (the
 router, its normalisation over all k chosen and the shared experts are
@@ -61,15 +69,26 @@ from analytics_zoo_tpu.pallas import moe_rows
 from analytics_zoo_tpu.pallas.grouped_matmul import grouped_matmul
 
 
-def route(u, kernel, bias, top_k: int, scale: float, eps: float = 1e-20):
+def route(u, kernel, bias, top_k: int, scale: float, eps: float = 1e-20,
+          score: str = "sigmoid"):
     """Token features u [N, H] -> (experts [N, k] int32, weights [N, k]
     float32): sigmoid scores in float32, the k largest of score + bias
     chosen, weighed by the scores alone (normalised over the k, `eps`
-    added to their sum, times `scale`)."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        u.astype(jnp.float32), kernel.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    added to their sum, times `scale`). `score="softmax"`: softmax scores
+    over all experts, the k largest chosen, no bias (`bias` None)."""
+    if score not in ("sigmoid", "softmax") \
+            or (score == "softmax") != (bias is None):
+        raise ValueError(f"route: score {score!r} with bias "
+                         f"{'None' if bias is None else 'given'}: a sigmoid "
+                         "router has a correction bias, a softmax one none")
+    logits = jnp.dot(u.astype(jnp.float32), kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, experts = jax.lax.top_k(scores, top_k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(scores, experts, axis=-1)
     w = w / (w.sum(axis=-1, keepdims=True) + eps)
     return experts.astype(jnp.int32), w * scale
@@ -182,16 +201,18 @@ class MoEFeedForward(Layer):
     contiguous range of the `n_routed_experts`; None holds all of them.
     `shared_width` is the width of the shared experts taken as ONE gated
     FFN (`n_shared_experts * moe_intermediate_size`); 0 has none.
-    `norm_eps` is added to the chosen scores' sum (`route`). `interpret`
-    runs the Pallas kernels (grouped products, row moves) through the
-    interpreter off the TPU."""
+    `norm_eps` is added to the chosen scores' sum (`route`), whose
+    `score` ("sigmoid" or "softmax") `router_score` is. `call` routes on
+    its `route_from` where given, else on `u`. `interpret` runs the
+    Pallas kernels (grouped products, row moves) through the interpreter
+    off the TPU."""
 
     def __init__(self, hidden_size: int, expert_width: int,
                  n_routed_experts: int, num_experts_per_tok: int,
                  experts_held: Optional[Tuple[int, int]] = None,
                  shared_width: int = 0, routed_scaling_factor: float = 1.0,
                  hidden_act: str = "silu", init="glorot_uniform",
-                 norm_eps: float = 1e-20,
+                 norm_eps: float = 1e-20, router_score: str = "sigmoid",
                  interpret: Optional[bool] = None, **kw):
         super().__init__(**kw)
         first, end = experts_held or (0, n_routed_experts)
@@ -203,6 +224,7 @@ class MoEFeedForward(Layer):
         self.first, self.n_held = first, end - first
         self.shared_width = shared_width
         self.scale, self.norm_eps = routed_scaling_factor, norm_eps
+        self.score = router_score
         self.act = get_activation(hidden_act)
         self.init = get_init(init)
         self.interpret = interpret
@@ -219,12 +241,13 @@ class MoEFeedForward(Layer):
         experts = jax.vmap(lambda k: gated_ffn_params(
             k, H, self.expert_width, self.init))(
                 jax.random.split(k_e, self.n_held))
+        router = {"kernel": self.init(k_r, (H, self.n_routed), jnp.float32)}
+        if self.score == "sigmoid":
+            # a leaf of zeros: it shifts the choice alone, so its gradient
+            # is exactly zero
+            router["bias"] = jnp.zeros((self.n_routed,), jnp.float32)
         p = {
-            "router": {
-                "kernel": self.init(k_r, (H, self.n_routed), jnp.float32),
-                # a leaf of zeros: it shifts the choice alone, so its
-                # gradient is exactly zero
-                "bias": jnp.zeros((self.n_routed,), jnp.float32)},
+            "router": router,
             # [held, H, I], [held, H, I], [held, I, H]
             "experts": {name[len("ffn_"):]: kernel
                         for name, kernel in experts.items()},
@@ -237,8 +260,9 @@ class MoEFeedForward(Layer):
     def routing(self, params, u):
         """(experts [N, k], weights [N, k]) of u [..., H]'s N tokens."""
         r = params["router"]
-        return route(u.reshape(-1, self.hidden_size), r["kernel"], r["bias"],
-                     self.top_k, self.scale, self.norm_eps)
+        return route(u.reshape(-1, self.hidden_size), r["kernel"],
+                     r.get("bias"), self.top_k, self.scale, self.norm_eps,
+                     self.score)
 
     def _dispatch(self, experts):
         """The sorted buffer's bookkeeping from the choice [N, k]: `order`
@@ -262,13 +286,14 @@ class MoEFeedForward(Layer):
             return bool(self.interpret)
         return None
 
-    def routed(self, params, u):
+    def routed(self, params, u, route_from=None):
         """This chip's part of the routed experts' sum, u [B, T, H] ->
-        [B, T, H]."""
+        [B, T, H], routed on `route_from` where given, else on u."""
         x = u.reshape(-1, self.hidden_size)
         kernels = self._row_kernels(x)
         with jax.named_scope("moe/router"):
-            experts, weights = self.routing(params, u)
+            experts, weights = self.routing(
+                params, u if route_from is None else route_from)
         with jax.named_scope("moe/dispatch"):
             order, position, sizes, held = self._dispatch(experts)
             count = sizes.sum(dtype=jnp.int32).reshape(1)
@@ -286,8 +311,8 @@ class MoEFeedForward(Layer):
                                kernels)
         return out.astype(u.dtype).reshape(u.shape)
 
-    def call(self, params, u, *, training=False, rng=None):
-        out = self.routed(params, u)
+    def call(self, params, u, *, training=False, rng=None, route_from=None):
+        out = self.routed(params, u, route_from)
         if self.shared_width:
             with jax.named_scope("moe/shared_experts"):
                 out = out + gated_ffn(params["shared"], u, self.act)

@@ -376,11 +376,19 @@ class PreNormDecoderBlock(Layer):
     `keras.latent_attention.LatentSelfAttention`), the FFN any layer over
     `[B, T, H]` (`GatedFFN`, `keras.moe.MoEFeedForward`). `call` takes
     `[h, (cos, sin)]`; the two branches are methods of their own so that a
-    model can name each in its trace."""
+    model can name each in its trace (`branches` runs both in order).
 
-    def __init__(self, attn: Layer, ffn: Layer, rms_eps: float = 1e-6, **kw):
+    `route_before_attention`: the FFN is an expert layer whose router reads
+    the block's normalised input, the tensor the attention reads, and not
+    the FFN's own (SmallThinker's router placed before attention): `a =
+    RMSNorm_1(h)`, `h' = h + Attn(a)`, `h' + FFN(RMSNorm_2(h'); route
+    from a)`."""
+
+    def __init__(self, attn: Layer, ffn: Layer, rms_eps: float = 1e-6,
+                 route_before_attention: bool = False, **kw):
         super().__init__(**kw)
         self.attn, self.ffn = attn, ffn
+        self.route_before_attention = route_before_attention
         self.norm = RMSNormalization(rms_eps, name=self.name + "_norm")
 
     def build(self, rng, input_shape):
@@ -391,18 +399,32 @@ class PreNormDecoderBlock(Layer):
                 "attn": self.attn.build(k1, shape),
                 "ffn": self.ffn.build(k2, shape)}
 
-    def attention_branch(self, params, h, rotary):
-        return h + self.attn.call(params["attn"], [
-            self.norm.call(params["attn_norm"], h), rotary])
+    def attention_branch(self, params, h, rotary, a=None):
+        """`h + Attn(a)`, `a` the block's normalised input (computed here
+        unless given)."""
+        if a is None:
+            a = self.norm.call(params["attn_norm"], h)
+        return h + self.attn.call(params["attn"], [a, rotary])
 
-    def ffn_branch(self, params, h):
-        return h + self.ffn.call(params["ffn"],
-                                 self.norm.call(params["ffn_norm"], h))
+    def ffn_branch(self, params, h, route_from=None):
+        u = self.norm.call(params["ffn_norm"], h)
+        if route_from is None:
+            return h + self.ffn.call(params["ffn"], u)
+        return h + self.ffn.call(params["ffn"], u, route_from=route_from)
+
+    def branches(self, params, h, rotary):
+        """The block on h: both branches, the router handed the block's
+        normalised input where it reads that."""
+        if not self.route_before_attention:
+            return self.ffn_branch(params,
+                                   self.attention_branch(params, h, rotary))
+        a = self.norm.call(params["attn_norm"], h)
+        return self.ffn_branch(
+            params, self.attention_branch(params, h, rotary, a), route_from=a)
 
     def call(self, params, x, *, training=False, rng=None):
         h, rotary = x
-        return self.ffn_branch(params,
-                               self.attention_branch(params, h, rotary))
+        return self.branches(params, h, rotary)
 
     def compute_output_shape(self, input_shape):
         return input_shape[0]

@@ -1,16 +1,21 @@
 """A causal language model of pre-norm blocks whose token mixer is latent
 attention, a linear-attention layer, a gated short convolution or
-grouped-query attention, layer by layer, over a dense FFN or routed
-experts, on the fit path.
+grouped-query attention (over the whole sequence or a sliding window),
+layer by layer, over a dense FFN or routed experts, on the fit path.
 
     h = E[ids]
     Block_l(h):  h = h + Mixer_l(RMSNorm_1(h));  h = h + FFN_l(RMSNorm_2(h))
     Mixer_l: `mixers[l]`: "latent" = multi-head latent attention (MLA),
              "linear" = Kimi Delta Attention (KDA), "conv" = LFM2's gated
              short convolution, "gqa" = grouped-query attention with
-             per-head q/k norms; latent everywhere unless `mixers` is given
+             per-head q/k norms, "global" = grouped-query attention with
+             no positions (NoPE), "window" = grouped-query attention over
+             a sliding window of `gqa["window"]` keys; latent everywhere
+             unless `mixers` is given
     FFN_l:   the first `n_dense_layer` blocks a dense gated FFN, every
-             later block shared experts + routed experts
+             later block shared experts + routed experts (with
+             `route_before_attention` the router reads RMSNorm_1(h), the
+             mixer's input, and not RMSNorm_2(h))
     logits = RMSNorm_f(h) W_head     (untied; with `tie_embeddings`
                                       W_head = E^T, the embedding's slice)
 
@@ -29,7 +34,8 @@ TPU-first layout, as `models/looped_decoder.py`: neighbouring layers of one
 each run compiles once and its gradients are born stacked; the runs follow
 one another in the layers' own order (`_runs`). Latent attention alone
 gives the two runs `dense_blocks` and `moe_blocks`; a model with other
-mixers names a run `blocks_<first layer>_<mixer>_<ffn>`. With `remat` every
+mixers names a run `blocks_<first layer>_<mixer>_<ffn>`, and traces a
+"global" or "window" run under `moedec/<mixer>/`. With `remat` every
 layer is a `jax.checkpoint` that keeps its [B, T, H] input and the
 attention kernel's output (with `use_flash` the flash kernel's and its
 log-sum-exp, `pallas.flash_attention.save_flash_residuals`; the linear
@@ -100,31 +106,36 @@ class MoEDecoderLM(KerasNet):
                  linear_attention: Optional[Dict] = None,
                  conv: Optional[Dict] = None, gqa: Optional[Dict] = None,
                  tie_embeddings: bool = False, router_eps: float = 1e-20,
-                 name=None):
+                 router_score: str = "sigmoid",
+                 route_before_attention: bool = False, name=None):
         """`mixers`: one of "latent" / "linear" / "conv" / "gqa" a layer,
         in the layers' order (None: latent everywhere); `linear_attention`:
         the linear layers' own arguments (`keras.linear_attention.
         KimiDeltaAttention`: n_head, head_dim, conv_size, chunk, ...);
         `conv`: the conv layers' (`keras.gated_conv.GatedShortConv`:
         conv_size); `gqa`: the grouped-query layers' (n_kv_head,
-        head_dim; `n_head` query heads; their rotary tables are head_dim
-        wide, and a model has no latent layer beside them);
-        `rotary=False` builds the latent layers without positions;
-        `tie_embeddings`: the head is the embedding's transpose;
-        `router_eps`: added to the chosen scores' sum (`keras.moe.route`)."""
+        head_dim, qk_norm, and the "window" layers' window; `n_head`
+        query heads; their rotary tables are head_dim wide, and a model
+        has no latent layer beside them); `rotary=False` builds the latent
+        layers without positions; `tie_embeddings`: the head is the
+        embedding's transpose; `router_eps`: added to the chosen scores'
+        sum, `router_score`: "sigmoid" or "softmax" (`keras.moe.route`);
+        `route_before_attention`: the expert layers' router reads the
+        block's normalised input (`keras.transformer.PreNormDecoderBlock`)."""
         super().__init__(name)
         if not 0 <= n_dense_layer < n_layer:
             raise ValueError("MoEDecoderLM needs at least one expert layer "
                              f"after its {n_dense_layer} dense ones")
         mixers = list(mixers or ["latent"] * n_layer)
-        kinds = {"latent", "linear", "conv", "gqa"}
+        grouped = {"gqa", "global", "window"}
+        kinds = {"latent", "linear", "conv"} | grouped
         if len(mixers) != n_layer or set(mixers) - kinds \
-                or {"latent", "gqa"} <= set(mixers):
+                or ("latent" in mixers and grouped & set(mixers)):
             raise ValueError(f"MoEDecoderLM: mixers {mixers} must name one "
                              f"of {sorted(kinds)} for each of the {n_layer} "
                              "layers, never latent beside gqa")
         self.vocab, self.hidden_size = vocab, hidden_size
-        self.rope_dim = gqa["head_dim"] if "gqa" in mixers \
+        self.rope_dim = gqa["head_dim"] if grouped & set(mixers) \
             else qk_rope_head_dim
         self.rope_theta, self.tie = rope_theta, tie_embeddings
         self.remat, self.rotary = remat, rotary
@@ -146,13 +157,18 @@ class MoEDecoderLM(KerasNet):
                 return GatedShortConv(hidden_size, init=init,
                                       name=f"{self.name}_{tag}_conv",
                                       **(conv or {}))
-            if kind == "gqa":
+            if kind in grouped:
                 from analytics_zoo_tpu.keras.grouped_attention import \
                     GroupedQueryAttention
+                opts = {k: v for k, v in gqa.items() if k != "window"}
+                if kind == "global":
+                    opts["rotary"] = False
+                if kind == "window":
+                    opts["window"] = gqa["window"]
                 return GroupedQueryAttention(
                     hidden_size, n_head, rms_eps=rms_eps,
                     use_flash=use_flash, init=init,
-                    name=f"{self.name}_{tag}_gqa", **gqa)
+                    name=f"{self.name}_{tag}_gqa", **opts)
             return LatentSelfAttention(
                 hidden_size, n_head, kv_lora_rank, qk_nope_head_dim,
                 qk_rope_head_dim, v_head_dim, rms_eps=rms_eps,
@@ -165,7 +181,7 @@ class MoEDecoderLM(KerasNet):
             shared_width=n_shared_experts * moe_intermediate_size,
             routed_scaling_factor=routed_scaling_factor,
             hidden_act=hidden_act, init=init, norm_eps=router_eps,
-            name=self.name + "_moe")
+            router_score=router_score, name=self.name + "_moe")
         dense_ffn = GatedFFN(hidden_size, intermediate_size, hidden_act,
                              init=init, name=self.name + "_dense_ffn")
         # one block a (mixer, FFN) kind; a run of neighbouring layers of
@@ -176,10 +192,12 @@ class MoEDecoderLM(KerasNet):
                 tag = ffn if kind == "latent" else f"{kind}_{ffn}"
                 self.blocks[kind, ffn] = PreNormDecoderBlock(
                     mixer(kind, tag), self.moe if ffn == "moe" else dense_ffn,
-                    rms_eps, name=f"{self.name}_{tag}_block")
+                    rms_eps, route_before_attention=route_before_attention
+                    and ffn == "moe", name=f"{self.name}_{tag}_block")
         self.final_norm = RMSNormalization(rms_eps,
                                            name=self.name + "_final_norm")
         n_linear = mixers.count("linear")
+        window = (gqa or {}).get("window") if "window" in mixers else None
         gauge = get_registry().gauge
         for gname, doc, value in (
                 ("model_experts_routed", "routed experts of an expert "
@@ -201,13 +219,26 @@ class MoEDecoderLM(KerasNet):
                  "attention layer (a state, no keys and values)", n_linear),
                 ("model_layers_full", "layers whose mixer is (latent or "
                  "grouped-query) softmax attention over the whole sequence",
-                 mixers.count("latent") + mixers.count("gqa")),
+                 mixers.count("latent") + mixers.count("gqa")
+                 + mixers.count("global")),
                 ("model_layers_conv", "layers whose mixer is a gated short "
                  "convolution (a K - 1 token history, no keys and values)",
                  mixers.count("conv")),
                 ("model_layers_gqa", "layers whose mixer is grouped-query "
                  "attention (K/V heads serving several query heads)",
-                 mixers.count("gqa")),
+                 sum(mixers.count(m) for m in grouped)),
+                ("model_layers_window", "layers whose mixer is grouped-query "
+                 "attention over a sliding window of keys",
+                 mixers.count("window")),
+                ("model_attention_window", "keys a query of the sliding-"
+                 "window layers sees, its own included (0: no such layer)",
+                 window or 0),
+                ("model_layers_nope", "attention layers that give q and k no "
+                 "positions (NoPE)", mixers.count("global")
+                 + (0 if rotary else mixers.count("latent"))),
+                ("model_router_softmax", "1 if the expert layers' router "
+                 "scores by a softmax over the routed experts, 0 if by a "
+                 "sigmoid", int(router_score == "softmax")),
                 ("model_recompute", "1 if every block application is "
                  "recomputed in the backward pass", int(remat)),
                 ("model_recompute_attention_kernel", "1 if the backward "
@@ -259,10 +290,12 @@ class MoEDecoderLM(KerasNet):
         for name, kind, ffn, _, _ in self.runs:
             block = self.blocks[kind, ffn]
 
-            def apply_block(bp, hh, block=block, scope=f"moedec/{ffn}_block"):
+            scope = f"moedec/{kind}/{ffn}_block" \
+                if kind in ("global", "window") else f"moedec/{ffn}_block"
+
+            def apply_block(bp, hh, block=block, scope=scope):
                 with jax.named_scope(scope):
-                    return block.ffn_branch(
-                        bp, block.attention_branch(bp, hh, rotary))
+                    return block.branches(bp, hh, rotary)
 
             layer = jax.checkpoint(apply_block, policy=self.kept) \
                 if self.remat else apply_block
@@ -301,9 +334,12 @@ class MoEDecoderLM(KerasNet):
         h, rotary = self._embed(params, ids)
 
         def choice(block, bp, hh):
-            hh = block.attention_branch(bp, hh, rotary)
-            experts, _ = self.moe.routing(
-                bp["ffn"], block.norm.call(bp["ffn_norm"], hh))
+            if block.route_before_attention:
+                u = block.norm.call(bp["attn_norm"], hh)
+            else:
+                hh = block.attention_branch(bp, hh, rotary)
+                u = block.norm.call(bp["ffn_norm"], hh)
+            experts, _ = self.moe.routing(bp["ffn"], u)
             return experts.reshape(h.shape[:2] + (-1,))
 
         return self._scan_blocks(params, h, rotary, choice)[1]

@@ -79,6 +79,20 @@ iotas, tiles below it run the unmasked body. The causal kernels carry a
 `_causal` suffix on their names and count the causal half in their cost
 estimates. A full `[B,1,T,T]` mask operand still takes the reference path.
 
+A sliding window is a second static flag beside it (`window=W`, causal
+only): query i sees keys i - W < j <= i, the band. The inner grid axis
+walks the band's blocks alone: a q-block's steps start at the k-block of
+its first row's earliest key (`_first_k_block`) and a k-block's at its
+first q-block (`_last_q_block` ends them), so a tile wholly left of the
+band is never visited and its blocks are never fetched; the index maps
+clamp from below as they clamp from above. Tiles an edge of the band
+crosses are masked from the same two iotas (`_band_scores`); the forward's
+diagonal chunking stays with the tiles only the diagonal crosses. Such
+kernels carry a `_window` suffix last (`_kernel_name`) and count the band's
+pairs in their cost estimates (`_band_pairs`). Without it every kernel,
+its grid and its index maps are what they were, instruction for
+instruction (`tests/test_pallas_tpu_lowering.py` pins the modules).
+
 Two head widths. Queries and keys may be wider than values (`q, k:
 [B, H, T, Dk]`, `v: [B, H, T, Dv]`; latent attention's keys carry 64 rotary
 columns beside their 128, `keras/latent_attention.py`): every kernel takes
@@ -132,10 +146,12 @@ save_flash_residuals = jax.checkpoint_policies.save_only_these_names(
 
 
 def _reference_attention(q, k, v, mask=None, dropout_rate: float = 0.0,
-                         dropout_key=None, causal: bool = False):
+                         dropout_key=None, causal: bool = False,
+                         window: Optional[int] = None):
     """Exact O(L²) attention — the shared non-flash numerics (also what
     `keras.transformer.dot_product_attention` delegates to). `causal`
-    adds a materialised lower-triangular [T, T] mask. K and V may have
+    adds a materialised lower-triangular [T, T] mask, `window` (with
+    `causal`) the band i - window < j <= i in its place. K and V may have
     fewer heads than q, a whole fraction of them (grouped-query heads:
     query head h reads K/V head h // group): they are read grouped, never
     repeated."""
@@ -150,8 +166,10 @@ def _reference_attention(q, k, v, mask=None, dropout_rate: float = 0.0,
     if mask is not None:
         scores = scores + mask
     if causal:
-        scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores,
-                           _MASKED)
+        seen = jnp.tril(jnp.ones((T, T), bool))
+        if window is not None:
+            seen = jnp.logical_and(seen, jnp.triu(seen, 1 - window))
+        scores = jnp.where(seen, scores, _MASKED)
     weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     if dropout_rate > 0.0 and dropout_key is not None:
         keep = 1.0 - dropout_rate
@@ -197,12 +215,14 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    causal: bool = False):
+                    causal: bool = False, window: Optional[int] = None):
     """q: [B, H, T, Dk]; k: [B, Hkv, T, Dk]; v: [B, Hkv, T, Dv] (Dv may
     differ from Dk: the scores are scaled by 1/sqrt(Dk); Hkv may be a whole
     fraction of H, grouped-query heads, module docstring). mask: additive
     [B,1,1,T] (padding) or [B,1,T,T] (full; reference path only). `causal` (static) masks every
-    key after the query's own position, inside the kernels. `dropout_rate`
+    key after the query's own position, inside the kernels; `window`
+    (static, with `causal`) every key `window` or more before it too, and
+    the kernels skip the tiles wholly left of that band. `dropout_rate`
     > 0 needs `dropout_seed` (scalar int32). Differentiable (custom VJP);
     the mask receives a zero cotangent (padding masks are data, not
     parameters). Returns [B, H, T, Dv].
@@ -218,6 +238,10 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("flash_attention: dropout_rate > 0 needs a "
                          "dropout_seed (deterministic in-kernel masks)")
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"flash_attention: a window ({window}) is a band "
+                         "under the diagonal: it needs causal=True and at "
+                         "least one key")
     use_dropout = dropout_rate > 0.0
     if mask is not None and mask.ndim == 4 and mask.shape[2] != 1:
         # full [B,1,T,T] masks always take the exact reference path — the
@@ -225,7 +249,7 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         key = jax.random.PRNGKey(dropout_seed) if use_dropout else None
         return _reference_attention(q, k, v, mask,
                                     dropout_rate if use_dropout else 0.0,
-                                    key, causal)
+                                    key, causal, window)
     if not (_flash_supported(mask) or interpret):
         key = None
         if use_dropout:
@@ -234,7 +258,7 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
                                      else dropout_seed)
         return _reference_attention(q, k, v, mask,
                                     dropout_rate if use_dropout else 0.0,
-                                    key, causal)
+                                    key, causal, window)
     B, H, T, _ = q.shape
     if H % k.shape[1] or k.shape[1] != v.shape[1]:
         raise ValueError(f"flash_attention: {k.shape[1]} K/V heads do not "
@@ -254,7 +278,7 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         maskp = jnp.pad(mask, ((0, 0), (0, 0), (0, 0), (0, pad)),
                         constant_values=-1e9)
         out = flash_attention(qp, kp, vp, maskp, dropout_rate, dropout_seed,
-                              block_q, block_k, interpret, causal)
+                              block_q, block_k, interpret, causal, window)
         return out[:, :, :T]
     seed = jnp.asarray(dropout_seed if use_dropout else 0,
                        jnp.int32).reshape(1, 1)
@@ -265,7 +289,7 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         "forward computes at a time (the tile's width where it is not "
         "chunked), at the blocks flash_attention last picked").set(
             _fwd_chunk(block_k),
-            kernel=_kernel_name("flash_fwd", causal, two, gqa))
+            kernel=_kernel_name("flash_fwd", causal, two, gqa, window))
     limit = _bwd_fused_vmem_limit(block_q, block_k, T, q.shape[-1],
                                   q.dtype.itemsize, v.shape[-1], gqa)
     asked = get_registry().gauge(
@@ -275,19 +299,21 @@ def flash_attention(q, k, v, mask: Optional[jax.Array] = None,
         "picked")
     for name in (("flash_bwd_fused",) if limit is not None
                  else ("flash_dq", "flash_dkv")):
-        asked.set(limit or 0, kernel=_kernel_name(name, causal, two, gqa))
+        asked.set(limit or 0,
+                  kernel=_kernel_name(name, causal, two, gqa, window))
     return _flash(q, k, v, mask, seed, rate, block_q, block_k,
                   bool(interpret) if interpret is not None else False,
-                  bool(causal))
+                  bool(causal), None if window is None else int(window))
 
 
 # ---------------------------------------------------------------------------
 # custom-VJP core (assumes T % lcm(block_q, block_k) == 0, mask [B,1,1,T])
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, mask, seed, rate, block_q, block_k, interpret, causal):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, mask, seed, rate, block_q, block_k, interpret, causal,
+           window):
     out, _ = _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k,
-                        interpret, causal)
+                        interpret, causal, window)
     return out
 
 
@@ -316,6 +342,62 @@ def _first_q_block(ki, block_q, block_k):
     return (ki * block_k) // block_q
 
 
+# -- the band of a sliding window, tile by tile -------------------------------
+# With `window` W key c is seen by query r iff r - W < c <= r: the causal
+# tests above and their mirror images at the band's left edge. The grid's
+# inner axis walks a q-block's k-blocks from `_first_k_block` (a k-major
+# grid a k-block's q-blocks up to `_last_q_block`), as many steps as the
+# widest band needs (`_band_steps`); a step past the band names the last
+# block it needs, which the pipeline holds.
+_BAND = "band"          # a tile's mask kind where the left edge crosses it
+
+
+def _tile_reaches_band(qi, ki, block_q, block_k, window):
+    """Not wholly left of the band: its last column is seen by its first
+    row."""
+    return ki * block_k + (block_k - 1) > qi * block_q - window
+
+
+def _tile_right_of_edge(qi, ki, block_q, block_k, window):
+    """Wholly right of the band's left edge: its first column is seen by
+    its last row (if the diagonal lets it)."""
+    return ki * block_k > qi * block_q + (block_q - 1) - window
+
+
+def _first_k_block(qi, block_q, block_k, window):
+    """The first k-block a q-block needs: its first row's earliest key."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _last_q_block(ki, block_q, block_k, window, n_qb):
+    """The last q-block that needs a k-block: its last column's latest
+    query."""
+    return jnp.minimum((ki * block_k + (block_k - 1) + (window - 1))
+                       // block_q, n_qb - 1)
+
+
+def _band_steps(window, block_q, block_k, n_qb, n_kb, q_major):
+    """The inner grid axis's length: the most k-blocks a q-block needs
+    (`q_major`) or q-blocks a k-block needs, over the band; with no window
+    every block."""
+    if window is None:
+        return n_kb if q_major else n_qb
+    if q_major:
+        return max((qi * block_q + block_q - 1) // block_k
+                   - max(qi * block_q - (window - 1), 0) // block_k + 1
+                   for qi in range(n_qb))
+    return max(min((ki * block_k + block_k - 1 + window - 1) // block_q,
+                   n_qb - 1) - (ki * block_k) // block_q + 1
+               for ki in range(n_kb))
+
+
+def _band_pairs(T: int, window: int) -> int:
+    """(query, key) pairs of the band over T positions: row i sees
+    min(i + 1, window) keys."""
+    w = min(window, T)
+    return w * (w + 1) // 2 + (T - w) * w
+
+
 def _causal_scores(scores, row0, col0):
     """`scores` of query rows row0.. and key columns col0.. with every key
     after the query's own position set to `_MASKED`. Column 0 of the first
@@ -324,6 +406,23 @@ def _causal_scores(scores, row0, col0):
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     return jnp.where(cols <= rows, scores, _MASKED)
+
+
+def _band_scores(scores, row0, col0, window):
+    """`_causal_scores` with every key `window` or more before the query's
+    own position masked too."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    return jnp.where(jnp.logical_and(cols <= rows, cols > rows - window),
+                     scores, _MASKED)
+
+
+def _masked_scores(scores, row0, col0, masked, window):
+    """A masked tile's scores: the band's two edges where the left one
+    crosses it (`_BAND`), else the diagonal's."""
+    if masked == _BAND:
+        return _band_scores(scores, row0, col0, window)
+    return _causal_scores(scores, row0, col0)
 
 
 def _two_widths(q, v) -> bool:
@@ -337,24 +436,44 @@ def _group(q, k) -> int:
     return q.shape[-3] // k.shape[-3]
 
 
-def _kernel_name(name, causal, two_widths=False, gqa=False):
+def _kernel_name(name, causal, two_widths=False, gqa=False, window=None):
     """The name the compiler puts on the kernel's instruction, which the
     benchmark's per-kernel metrics match (docs/ProgrammingGuide/
     observability.md): `_causal` for the causal form, then `_mla` where
     the keys are wider than the values, then `_gqa` where a K/V head
-    serves several query heads."""
+    serves several query heads, then `_window` where a sliding window
+    bounds the band."""
     return name + ("_causal" if causal else "") \
-        + ("_mla" if two_widths else "") + ("_gqa" if gqa else "")
+        + ("_mla" if two_widths else "") + ("_gqa" if gqa else "") \
+        + ("_window" if window is not None else "")
 
 
-def _on_causal_tiles(causal, qi, ki, block_q, block_k, tile):
+def _on_causal_tiles(causal, qi, ki, block_q, block_k, tile, window=None,
+                     n_qb=None):
     """Run `tile(masked)` for grid step (qi, ki): always and unmasked
     without `causal`; else not at all above the diagonal, masked where the
-    diagonal crosses the tile, unmasked below it."""
+    diagonal crosses the tile, unmasked below it. With `window` not at all
+    left of the band or at a q-block past the last (`n_qb`: a k-major
+    grid's steps past the band), masked with the band's edges
+    (`masked` = `_BAND`) where its left edge crosses the tile."""
     from jax.experimental import pallas as pl
 
     if not causal:
         tile(False)
+        return
+    if window is not None:
+        right = _tile_right_of_edge(qi, ki, block_q, block_k, window)
+        diagonal = _tile_unmasked(qi, ki, block_q, block_k)
+        needed = jnp.logical_and(
+            jnp.logical_and(_tile_needed(qi, ki, block_q, block_k),
+                            _tile_reaches_band(qi, ki, block_q, block_k,
+                                               window)), qi < n_qb)
+        pl.when(jnp.logical_and(needed, jnp.logical_and(right, diagonal)))(
+            lambda: tile(False))
+        pl.when(jnp.logical_and(needed, jnp.logical_and(
+            right, jnp.logical_not(diagonal))))(lambda: tile(True))
+        pl.when(jnp.logical_and(needed, jnp.logical_not(right)))(
+            lambda: tile(_BAND))
         return
     unmasked = _tile_unmasked(qi, ki, block_q, block_k)
     pl.when(unmasked)(lambda: tile(False))
@@ -474,8 +593,8 @@ def _lane_sums(p):
     return out
 
 
-def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
-                s_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc):
+def _fwd_kernel(rate, scale, n_qb, n_kb, causal, window, q_ref, k_ref, v_ref,
+                m_ref, s_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc):
     """The DMA tile is [block_q, block_k]; the body walks it in column
     chunks (`_fwd_chunk`), one online-softmax update a chunk, so the
     live f32 intermediates are [block_q, chunk] and a chunk's product
@@ -488,18 +607,21 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
     once, at the flush. In a tile the causal diagonal crosses (square
     tiles: qi == ki) the chunk at column `lo` holds nothing for the rows
     above `lo`, which would leave their statistics as they are: it is
-    computed on the rows from `lo` down."""
+    computed on the rows from `lo` down. With `window` the grid's third
+    axis counts steps from the q-block's first k-block of the band."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
+    ki = step if window is None \
+        else _first_k_block(qi, block_q, block_k, window) + step
     chunk = _fwd_chunk(block_k)
     lane_sums = chunk % _LANES == 0
     on_q = _scale_on_q(scale)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_sc[...] = jnp.zeros_like(acc_sc)
         m_sc[...] = jnp.full_like(m_sc, -jnp.inf)
@@ -514,7 +636,7 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
                                 (block_q, block_k))
         for lo in range(0, block_k, chunk):
             cols = slice(lo, lo + chunk)
-            below = masked and block_q == block_k and lo > 0
+            below = masked is True and block_q == block_k and lo > 0
             rows = slice(lo, None) if below else slice(None)
             scores = jnp.dot(qb[rows], k_ref[0, cols, :].T,
                              preferred_element_type=jnp.float32)
@@ -522,9 +644,9 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
                 scores = scores * scale
             scores = scores + m_ref[0, :, cols]            # [1, chunk]
             if masked:
-                scores = _causal_scores(
+                scores = _masked_scores(
                     scores, qi * block_q + (lo if below else 0),
-                    ki * block_k + lo)
+                    ki * block_k + lo, masked, window)
             m_prev = m_sc[rows, :]                         # [rows, 128]
             m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -541,9 +663,10 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
                     p.astype(v_ref.dtype), v_ref[0, cols, :],
                     preferred_element_type=jnp.float32)
 
-    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile)
+    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile, window, n_qb)
 
-    @pl.when(ki == n_kb - 1)
+    @pl.when(step == _band_steps(window, block_q, block_k, n_qb, n_kb,
+                                 True) - 1)
     def _flush():
         acc, l = acc_sc[...], l_sc[...]
         if lane_sums:
@@ -555,7 +678,7 @@ def _fwd_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
 
 
 def _attn_cost(qk_matmuls, v_matmuls, q, v, extra_f32_out_elems=0,
-               causal=False, group=1):
+               causal=False, group=1, window=None):
     """Analytic roofline model for one attention kernel over q
     [..., T, Dk] and v [..., T, Dv] (check_pallas_cost lint: HLO cost
     analysis sees ~0 inside a Mosaic call). `qk_matmuls` counts the
@@ -567,7 +690,9 @@ def _attn_cost(qk_matmuls, v_matmuls, q, v, extra_f32_out_elems=0,
     at the lower triangle: half the products and half the scores (what the
     algorithm needs; the tiles the diagonal crosses are computed whole).
     With grouped-query heads (`group` > 1) the K and V the kernel reads
-    are counted once a K/V head, the other streams once a query head."""
+    are counted once a K/V head, the other streams once a query head. A
+    sliding window's kernel is counted at the band's pairs
+    (`_band_pairs`)."""
     from jax.experimental import pallas as pl
 
     *lead, T, Dk = q.shape
@@ -580,6 +705,12 @@ def _attn_cost(qk_matmuls, v_matmuls, q, v, extra_f32_out_elems=0,
     streamed = bh * T * (Dk + Dv) * item * streams // 2
     if group > 1:
         streamed -= bh * T * (Dk + Dv) * item * (group - 1) // group
+    if window is not None:
+        pairs = _band_pairs(T, window)
+        return pl.CostEstimate(
+            flops=2 * bh * pairs * (qk_matmuls * Dk + v_matmuls * Dv),
+            bytes_accessed=streamed + extra_f32_out_elems * 4,
+            transcendentals=bh * pairs)
     return pl.CostEstimate(
         flops=2 * bh * T * T * (qk_matmuls * Dk + v_matmuls * Dv) // half,
         bytes_accessed=streamed + extra_f32_out_elems * 4,
@@ -587,7 +718,7 @@ def _attn_cost(qk_matmuls, v_matmuls, q, v, extra_f32_out_elems=0,
 
 
 def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
-               causal):
+               causal, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -602,6 +733,9 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
 
     def kj(i, j):
         # a skipped step names the block it already holds: no DMA
+        if window is not None:
+            return jnp.minimum(_first_k_block(i, block_q, block_k, window)
+                               + j, _last_k_block(i, block_q, block_k))
         return jnp.minimum(j, _last_k_block(i, block_q, block_k)) \
             if causal else j
 
@@ -610,8 +744,10 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
         return b // group if group > 1 else b
 
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, rate, scale, n_qb, n_kb, causal),
-        grid=(B * H, n_qb, n_kb),
+        functools.partial(_fwd_kernel, rate, scale, n_qb, n_kb, causal,
+                          window),
+        grid=(B * H, n_qb,
+              _band_steps(window, block_q, block_k, n_qb, n_kb, True)),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D),
@@ -638,10 +774,10 @@ def _flash_fwd(q, k, v, mask, seed, rate, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=_attn_cost(1, 1, q, v,              # QKᵀ + PV
                                  extra_f32_out_elems=B * H * T,
-                                 causal=causal, group=group),
+                                 causal=causal, group=group, window=window),
         interpret=interpret,
         name=_kernel_name("flash_fwd", causal, _two_widths(q, v),
-                          group > 1),
+                          group > 1, window),
     )(qf, kf, vf, mf, seed)
     out = checkpoint_name(out.reshape(B, H, T, Dv), FLASH_OUT_NAME)
     lse = checkpoint_name(lse, FLASH_LSE_NAME)
@@ -657,19 +793,21 @@ def _delta(do_ref, o_ref):
                    * o_ref[0].astype(jnp.float32), axis=1, keepdims=True)
 
 
-def _dq_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
-               s_ref, do_ref, lse_ref, o_ref, dq_ref, dq_sc):
+def _dq_kernel(rate, scale, n_qb, n_kb, causal, window, q_ref, k_ref, v_ref,
+               m_ref, s_ref, do_ref, lse_ref, o_ref, dq_ref, dq_sc):
     """Standalone dq (accumulate over ki in scratch): half of the
     two-kernel backward for shapes the fused kernel's VMEM need rules out
     — see _flash_bwd."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
+    ki = step if window is None \
+        else _first_k_block(qi, block_q, block_k, window) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
@@ -684,7 +822,8 @@ def _dq_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
         scores = jnp.dot(qb, kb.T,
                          preferred_element_type=jnp.float32) * scale + mb
         if masked:
-            scores = _causal_scores(scores, qi * block_q, ki * block_k)
+            scores = _masked_scores(scores, qi * block_q, ki * block_k,
+                                    masked, window)
         pnorm = jnp.exp(scores - lse)                      # softmax weights
         dw = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
         if rate > 0.0:
@@ -694,24 +833,28 @@ def _dq_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
         dq_sc[...] += jnp.dot(ds.astype(k_ref.dtype), kb,
                               preferred_element_type=jnp.float32)
 
-    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile)
+    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile, window, n_qb)
 
-    @pl.when(ki == n_kb - 1)
+    @pl.when(step == _band_steps(window, block_q, block_k, n_qb, n_kb,
+                                 True) - 1)
     def _flush():
         dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
-                s_ref, do_ref, lse_ref, o_ref, dk_ref, dv_ref, dk_sc, dv_sc):
+def _dkv_kernel(rate, scale, n_qb, n_kb, causal, window, q_ref, k_ref, v_ref,
+                m_ref, s_ref, do_ref, lse_ref, o_ref, dk_ref, dv_ref, dk_sc,
+                dv_sc):
     """dk/dv-only companion of _dq_kernel."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
     block_k = k_ref.shape[1]
     block_q = q_ref.shape[1]
+    qi = step if window is None \
+        else _first_q_block(ki, block_q, block_k) + step
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
@@ -727,7 +870,8 @@ def _dkv_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
         scores = jnp.dot(qb, kb.T,
                          preferred_element_type=jnp.float32) * scale + mb
         if masked:
-            scores = _causal_scores(scores, qi * block_q, ki * block_k)
+            scores = _masked_scores(scores, qi * block_q, ki * block_k,
+                                    masked, window)
         pnorm = jnp.exp(scores - lse)                      # [bq, bk]
         dw = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
         if rate > 0.0:
@@ -743,9 +887,10 @@ def _dkv_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref, m_ref,
         dv_sc[...] += jnp.dot(dv_p.T.astype(do_ref.dtype), dob,
                               preferred_element_type=jnp.float32)
 
-    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile)
+    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile, window, n_qb)
 
-    @pl.when(qi == n_qb - 1)
+    @pl.when(step == _band_steps(window, block_q, block_k, n_qb, n_kb,
+                                 False) - 1)
     def _flush():
         dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
@@ -857,9 +1002,9 @@ def _bwd_fused_fits(block_q, block_k, T, D, itemsize, Dv=None,
                                  Dv, gqa) is not None
 
 
-def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
-                      m_ref, s_ref, do_ref, lse_ref, o_ref, dq_ref, dk_ref,
-                      dv_ref, dq_sc, dk_sc, dv_sc):
+def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, window, q_ref, k_ref,
+                      v_ref, m_ref, s_ref, do_ref, lse_ref, o_ref, dq_ref,
+                      dk_ref, dv_ref, dq_sc, dk_sc, dv_sc):
     """ONE backward kernel: the weights, dW and the dropout mask of a tile
     are computed once and feed dq, dk and dv — 5 matmuls a tile where the
     dq/dkv pair runs 7 (and one mask regeneration where it runs two).
@@ -869,22 +1014,33 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
     dk/dv accumulate over qi in scratch rows of their chunk. dq has the
     transposed accumulation order: its [T, D] f32 scratch and its output
     block belong to the head-batch (block index (b, 0, 0)), so they stay
-    in VMEM over both inner grid axes and go back to HBM once."""
+    in VMEM over both inner grid axes and go back to HBM once. With
+    `window` a q-block's dq rows start at its first k-block of the band
+    and are final at its last, which the walk visits in that order."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
     block_k = k_ref.shape[1]
     block_q = q_ref.shape[1]
+    qi = step if window is None \
+        else _first_q_block(ki, block_q, block_k) + step
     chunk = _bwd_chunk(block_k)
     rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
-    @pl.when(qi == 0)
+    def at_k(none, band):
+        """Whether this step is the q-block's k-block `none` without a
+        window, `band(qi)` with one (and a q-block of the sequence)."""
+        if window is None:
+            return ki == none
+        return jnp.logical_and(qi < n_qb, ki == band(qi))
+
+    @pl.when(step == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    @pl.when(ki == 0)
+    @pl.when(at_k(0, lambda i: _first_k_block(i, block_q, block_k, window)))
     def _init_dq():
         dq_sc[rows, :] = jnp.zeros((block_q, dq_sc.shape[1]), jnp.float32)
 
@@ -903,8 +1059,8 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
             scores = jnp.dot(qb, kc.T, preferred_element_type=jnp.float32) \
                 * scale + m_ref[0, :, cols]
             if masked:
-                scores = _causal_scores(scores, qi * block_q,
-                                        ki * block_k + lo)
+                scores = _masked_scores(scores, qi * block_q,
+                                        ki * block_k + lo, masked, window)
             pnorm = jnp.exp(scores - lse)                  # [bq, chunk]
             dw = jnp.dot(dob, vc.T, preferred_element_type=jnp.float32)
             if rate > 0.0:
@@ -923,19 +1079,21 @@ def _bwd_fused_kernel(rate, scale, n_qb, n_kb, causal, q_ref, k_ref, v_ref,
             dv_sc[cols, :] += jnp.dot(dv_p.T.astype(do_ref.dtype), dob,
                                       preferred_element_type=jnp.float32)
 
-    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile)
+    _on_causal_tiles(causal, qi, ki, block_q, block_k, tile, window, n_qb)
 
-    @pl.when(ki == n_kb - 1)
+    @pl.when(at_k(n_kb - 1, lambda i: _last_k_block(i, block_q, block_k)))
     def _flush_dq():
         dq_ref[0, rows, :] = (dq_sc[rows, :] * scale).astype(dq_ref.dtype)
 
-    @pl.when(qi == n_qb - 1)
+    @pl.when(step == _band_steps(window, block_q, block_k, n_qb, n_kb,
+                                 False) - 1)
     def _flush():
         dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _bwd_in_specs(block_q, block_k, D, Dv, q_major, causal, group=1):
+def _bwd_in_specs(block_q, block_k, D, Dv, q_major, causal, group=1,
+                  window=None, n_qb=None):
     """Input BlockSpecs shared by the three backward kernels (q, k, v,
     mask, seed, dO, lse, O), with the q-, the k- and the v-block spec of
     a query head, which the gradients are written by (q, k `D` wide; v,
@@ -943,12 +1101,22 @@ def _bwd_in_specs(block_q, block_k, D, Dv, q_major, causal, group=1):
     INPUT blocks are those of K/V head b // group. A
     `q_major` grid is (bh, qi, ki), the other (bh, ki, qi). With `causal`
     the inner axis's blocks stop at the diagonal: a skipped step names the
-    nearest needed block, which the pipeline already holds."""
+    nearest needed block, which the pipeline already holds. With `window`
+    the inner axis counts steps from the band's first block, and a step
+    past the band's last names that last one."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     def spec(shape, of_q, at):
         axis = 1 if of_q == q_major else 2
+        if window is not None and axis == 2:
+            if q_major:
+                return pl.BlockSpec(shape, lambda b, i, j: at(b, jnp.minimum(
+                    _first_k_block(i, block_q, block_k, window) + j,
+                    _last_k_block(i, block_q, block_k))))
+            return pl.BlockSpec(shape, lambda b, j, i: at(b, jnp.minimum(
+                _first_q_block(j, block_q, block_k) + i,
+                _last_q_block(j, block_q, block_k, window, n_qb))))
         if not causal or axis == 1:
             return pl.BlockSpec(shape, lambda *g: at(g[0], g[axis]))
         if q_major:     # inner axis walks k-blocks: none past the diagonal
@@ -983,7 +1151,8 @@ def _grads(qf, vf, group):
             jax.ShapeDtypeStruct((BH, T, vf.shape[-1]), kv_type))
 
 
-def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
+def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, window,
+               operands):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -992,15 +1161,17 @@ def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
     Dv, group = vf.shape[-1], _group(qf, kf)
     n_qb, n_kb = T // block_q, T // block_k
     in_specs, _, k_spec, v_spec = _bwd_in_specs(
-        block_q, block_k, D, Dv, q_major=False, causal=causal, group=group)
+        block_q, block_k, D, Dv, q_major=False, causal=causal, group=group,
+        window=window, n_qb=n_qb)
     grad, grad_k, grad_v = _grads(qf, vf, group)
     # unset (None) wherever the compiler's default holds the kernel
     limit = _bwd_fused_vmem_limit(block_q, block_k, T, D,
                                   qf.dtype.itemsize, Dv, group > 1) or None
     return pl.pallas_call(
         functools.partial(_bwd_fused_kernel, rate, scale, n_qb, n_kb,
-                          causal),
-        grid=(BH, n_kb, n_qb),
+                          causal, window),
+        grid=(BH, n_kb,
+              _band_steps(window, block_q, block_k, n_qb, n_kb, False)),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, T, D), lambda b, j, i: (b, 0, 0)),
                    k_spec, v_spec],
@@ -1016,14 +1187,15 @@ def _bwd_fused(rate, scale, block_q, block_k, interpret, causal, operands):
             vmem_limit_bytes=limit),
         # scores, dq, dk at the key width; dw, dv at the value width
         cost_estimate=_attn_cost(3, 2, qf, vf, causal=causal,
-                                 group=group),
+                                 group=group, window=window),
         interpret=interpret,
         name=_kernel_name("flash_bwd_fused", causal, _two_widths(qf, vf),
-                          group > 1),
+                          group > 1, window),
     )(*operands)
 
 
-def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
+def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, window,
+              operands):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1037,10 +1209,13 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     in_specs, q_spec, _, _ = _bwd_in_specs(block_q, block_k, D, Dv,
                                            q_major=True, causal=causal,
-                                           group=group)
+                                           group=group, window=window,
+                                           n_qb=n_qb)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, rate, scale, n_qb, n_kb, causal),
-        grid=(BH, n_qb, n_kb),
+        functools.partial(_dq_kernel, rate, scale, n_qb, n_kb, causal,
+                          window),
+        grid=(BH, n_qb,
+              _band_steps(window, block_q, block_k, n_qb, n_kb, True)),
         in_specs=in_specs,
         out_specs=q_spec,
         out_shape=grad,
@@ -1048,15 +1223,18 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
         compiler_params=semantics,
         # scores, dq at the key width; dw at the value width
         cost_estimate=_attn_cost(2, 1, qf, vf, causal=causal,
-                                 group=group),
+                                 group=group, window=window),
         interpret=interpret,
-        name=_kernel_name("flash_dq", causal, two, gqa),
+        name=_kernel_name("flash_dq", causal, two, gqa, window),
     )(*operands)
     in_specs, _, k_spec, v_spec = _bwd_in_specs(
-        block_q, block_k, D, Dv, q_major=False, causal=causal, group=group)
+        block_q, block_k, D, Dv, q_major=False, causal=causal, group=group,
+        window=window, n_qb=n_qb)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, rate, scale, n_qb, n_kb, causal),
-        grid=(BH, n_kb, n_qb),
+        functools.partial(_dkv_kernel, rate, scale, n_qb, n_kb, causal,
+                          window),
+        grid=(BH, n_kb,
+              _band_steps(window, block_q, block_k, n_qb, n_kb, False)),
         in_specs=in_specs,
         out_specs=[k_spec, v_spec],
         out_shape=[grad_k, grad_v],
@@ -1067,14 +1245,15 @@ def _bwd_pair(rate, scale, block_q, block_k, interpret, causal, operands):
         compiler_params=semantics,
         # scores, dk at the key width; dw, dv at the value width
         cost_estimate=_attn_cost(2, 2, qf, vf, causal=causal,
-                                 group=group),
+                                 group=group, window=window),
         interpret=interpret,
-        name=_kernel_name("flash_dkv", causal, two, gqa),
+        name=_kernel_name("flash_dkv", causal, two, gqa, window),
     )(*operands)
     return dq, dk, dv
 
 
-def _flash_bwd(rate, block_q, block_k, interpret, causal, res, dout):
+def _flash_bwd(rate, block_q, block_k, interpret, causal, window, res,
+               dout):
     q, k, v, mask, seed, out, lse = res
     B, H, T, D = q.shape
     Dv, group = v.shape[-1], _group(q, k)
@@ -1090,7 +1269,7 @@ def _flash_bwd(rate, block_q, block_k, interpret, causal, res, dout):
     fused = _bwd_fused_fits(block_q, block_k, T, D, q.dtype.itemsize, Dv,
                             group > 1)
     dq, dk, dv = (_bwd_fused if fused else _bwd_pair)(
-        rate, scale, block_q, block_k, interpret, causal,
+        rate, scale, block_q, block_k, interpret, causal, window,
         (qf, kf, vf, mf, seed, dof, lse, of))
     if group > 1:
         # a K/V head's gradient: its group's query heads' summed, in one

@@ -202,6 +202,29 @@ def test_causal_kernels_at_one_width_are_pinned_too(T, digests):
     assert _causal_digests(T) == digests
 
 
+@pytest.mark.parametrize("shapes,digests", [
+    # lfm2-8b-a1b.fit-seq16384-conv: 32 query heads on 8 K/V heads of 64
+    (((1, 32, 16384, 64), (1, 8, 16384, 64), (1, 8, 16384, 64)),
+     ["4ad0c75467314bc3", "bbaa1c9d9ed9be6a"]),
+    # kanana-2-30b-a3b.fit-seq8192-b2: keys 192 wide, values 128
+    (((2, 32, 8192, 192), (2, 32, 8192, 192), (2, 32, 8192, 128)),
+     ["1ad078689095a379", "9dde3494dfaf7553"]),
+])
+def test_grouped_and_two_width_kernels_are_pinned_too(shapes, digests):
+    """The sliding window is a static flag: without it (`window=None`) the
+    grouped-query and two-width causal kernels of the accepted cells lower
+    to the modules they lowered to before the flag existed (made by
+    `_mosaic_digests` on the tree of commit f0c2843), as the non-causal
+    and one-width causal kernels pinned above do."""
+    q, k, v = (sds(s, jnp.bfloat16) for s in shapes)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=None).astype(
+            jnp.float32).sum()
+    assert _mosaic_digests(jax.grad(loss, argnums=(0, 1, 2)),
+                           q, k, v) == digests
+
+
 @pytest.mark.parametrize("T,n_bwd,asked", [
     (1024, 1, None), (8192, 1, 30 * _MIB), (25600, 1, 64 * _MIB),
     (26624, 2, None)])
