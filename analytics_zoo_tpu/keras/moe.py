@@ -111,8 +111,8 @@ def _gather_sum(rows, index, keep, weight=None):
     return acc
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _to_sorted(x, order, position, held, count, kernels):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _to_sorted(x, order, position, held, count, route, kernels):
     """Dispatch: x [N, H] -> [N * k, H], row `order[p] // k` of x at
     sorted place p (slot `order[p]` is token `order[p] // k`'s). Places
     past the held slots hold absent slots' tokens: real rows that no
@@ -122,32 +122,33 @@ def _to_sorted(x, order, position, held, count, kernels):
     token's held slots added up (the places of the others were never
     written). `count` [1] is the number of held slots; `kernels` None moves
     the rows by XLA's gathers, a bool by `pallas.moe_rows` (its
-    `interpret`)."""
+    `interpret`), whose combine fetches the rows `route`
+    (`moe_rows.plan`, None without kernels) lists."""
     if kernels is None:
         return x[order // position.shape[1]]
     return moe_rows.gather(x, order // position.shape[1], count,
                            interpret=kernels)
 
 
-def _to_sorted_fwd(x, order, position, held, count, kernels):
-    return _to_sorted(x, order, position, held, count, kernels), (
-        position, held, count)
+def _to_sorted_fwd(x, order, position, held, count, route, kernels):
+    return _to_sorted(x, order, position, held, count, route, kernels), (
+        position, held, count, route)
 
 
 def _to_sorted_bwd(kernels, res, g):
-    position, held, count = res
+    position, held, count, route = res
     if kernels is None:
         dx = _gather_sum(g, position, held).astype(g.dtype)
     else:
-        dx = moe_rows.combine(g, count, position, held, interpret=kernels)
-    return dx, None, None, None, None
+        dx = moe_rows.combine(g, count, route, interpret=kernels)
+    return dx, None, None, None, None, None
 
 
 _to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _from_sorted(ys, weights, order, position, held, count, kernels):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _from_sorted(ys, weights, order, position, held, count, route, kernels):
     """Combine: the sorted buffer's rows ys [N * k, H] back to their
     tokens, each held slot's row times its weight [N, k], added up in
     float32: [N, H] float32 (with `kernels`, written once in ys's type).
@@ -156,12 +157,12 @@ def _from_sorted(ys, weights, order, position, held, count, kernels):
     weights by a row-wise product in sorted order."""
     if kernels is None:
         return _gather_sum(ys, position, held, weights)
-    return moe_rows.combine(ys, count, position, held, weights,
-                            interpret=kernels)
+    return moe_rows.combine(ys, count, route, weights, interpret=kernels)
 
 
-def _from_sorted_fwd(ys, weights, order, position, held, count, kernels):
-    return _from_sorted(ys, weights, order, position, held, count,
+def _from_sorted_fwd(ys, weights, order, position, held, count, route,
+                     kernels):
+    return _from_sorted(ys, weights, order, position, held, count, route,
                         kernels), (ys, weights, order, position, held, count)
 
 
@@ -189,7 +190,7 @@ def _from_sorted_bwd(kernels, res, g):
             dot_with=ys, interpret=kernels)
         along = jax.lax.sort((order, along), num_keys=1)[1]
         d_w = jnp.where(held, along.reshape(held.shape), 0.0)
-    return d_ys, d_w.astype(weights.dtype), None, None, None, None
+    return d_ys, d_w.astype(weights.dtype), None, None, None, None, None
 
 
 _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
@@ -297,7 +298,11 @@ class MoEFeedForward(Layer):
         with jax.named_scope("moe/dispatch"):
             order, position, sizes, held = self._dispatch(experts)
             count = sizes.sum(dtype=jnp.int32).reshape(1)
-            xs = _to_sorted(x, order, position, held, count, kernels)
+            # the combine's list of held rows, made once for the combine
+            # forward and the dispatch's backward
+            route = None if kernels is None else moe_rows.plan(
+                position, held, self.hidden_size, x.dtype)
+            xs = _to_sorted(x, order, position, held, count, route, kernels)
         with jax.named_scope("moe/experts"):
             e = params["experts"]
 
@@ -308,7 +313,7 @@ class MoEFeedForward(Layer):
             ys = gmm(f.astype(x.dtype), e["down_kernel"])
         with jax.named_scope("moe/combine"):
             out = _from_sorted(ys, weights, order, position, held, count,
-                               kernels)
+                               route, kernels)
         return out.astype(u.dtype).reshape(u.shape)
 
     def call(self, params, u, *, training=False, rng=None, route_from=None):

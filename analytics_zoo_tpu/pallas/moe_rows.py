@@ -23,7 +23,18 @@ as `grouped_matmul`'s grid is from the group sizes.
     moe_rows_combine_pack  tokens a gather reads, the buffer's first
                            `count` places a combine reads
 
-Rows travel one DMA a row, a tile's DMAs all started and then all waited.
+Rows travel one DMA a row, and the fetches run as a pipeline over the
+grid: step s starts tile s's row DMAs into one of two buffers, then waits
+for tile s - 1's (started by step s - 1 into the other) and computes with
+them, so the DMA engine moves one tile while the vector unit works on the
+one before and the output block of the one before that is written back.
+The grid has one step more than there are tiles: the first step only
+starts, the last only waits and computes. A step waits for its rows 8 at
+a time (one wait a descriptor of 8 rows' bytes), its list of rows padded
+to a whole number of 8 by rows that are fetched and never used: a gather
+fetches the next places' token rows, a combine the buffer's place 0 (held
+wherever a tile holds a row) into 8 spare slots.
+
 A DMA moves whole tiles of the second-minor dimension (8 rows of an
 [R, H] array: Mosaic refuses a 1-row slice), so a row of [R, H] cannot be
 fetched alone; a row of an [R, 1, W] array is a tile of its own. Rows are
@@ -32,10 +43,17 @@ each holding column i in its low half and column i + H / 2 in its high
 half (W = H / 2, the rows' own bytes), rows of another type widened to
 float32 (W = H).
 
-The combine moves each token's kept slots to the front first (`_by_rank`):
-a token's fetches are then a loop over its kept slots, and 8 tokens' r-th
-slots are added only where one of them keeps r + 1 slots or more; the sum
-stays in the order of j.
+The combine's rows come from a list that XLA makes ahead (`plan`, once a
+layer call, for the combine forward and the dispatch's backward alike),
+by one sort of the N x k slots: a token tile's held rows, rank-major (a
+token's kept slots moved to its front, `_by_rank`; then every token's
+first kept slot, every token's second, ...), each with its place in the
+buffer and its VMEM slot (token n's r-th kept slot lands in r * tn + n).
+The kernel's issue loop runs over the tile's held rows alone. 8 tokens'
+sum is carried in registers over their ranks, up to the most slots one of
+them keeps (that most, too, made ahead), in the order of j, and written
+once. The token tile is 128, halved while two buffers of k x tn rows and
+the blocks would not fit the VMEM a kernel may use.
 
 What a kernel does not visit it does not write: the gather's places past
 `count` are unspecified (whatever the output's memory held), as are its
@@ -47,15 +65,20 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 # places of the buffer a gather step fetches
 _TILE_ROWS = 256
-# tokens a combine step writes: its k x 128 fetched rows sit in VMEM at once
+# tokens a combine step writes, at most (`_combine_tile`)
 _TILE_TOKENS = 128
+# VMEM a row kernel's two buffers and its blocks may take, of the 16 MiB a
+# kernel may use unless told otherwise (`_gather_tile`, `_combine_tile`)
+_VMEM_BUDGET = 14 * 2 ** 20
+# rows a wait counts, and the multiple a step's list of rows is padded to
+_CHUNK = 8
 # an int32 vector lies in HBM in tiles of 1024 entries; a block of one in
 # SMEM is a whole number of them
 _INDEX_BLOCK = 1024
@@ -133,21 +156,33 @@ def _start_row(src_hbm, buf, sem, row, slot):
     pltpu.make_async_copy(src_hbm.at[row], buf.at[slot], sem).start()
 
 
-def _wait_rows(src_hbm, buf, sem, n):
-    """Waits for `n` row DMAs started on `sem` (a wait counts one row's
-    bytes, whichever row it names)."""
+def _wait_chunks(src_hbm, buf, sem, n):
+    """Waits for `n` x 8 row DMAs started on `sem`: one wait a descriptor
+    of 8 rows (a wait counts its descriptor's bytes, whichever rows it
+    names)."""
+    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     def wait(_, carry):
-        pltpu.make_async_copy(src_hbm.at[0], buf.at[0], sem).wait()
+        pltpu.make_async_copy(src_hbm.at[pl.ds(0, _CHUNK)],
+                              buf.at[pl.ds(0, _CHUNK)], sem).wait()
         return carry
     jax.lax.fori_loop(0, n, wait, 0)
 
 
-def _word_rows(buf, start, n: int):
-    """Rows start .. start + n of a [R, 1, W] VMEM buffer as [n, W]."""
-    from jax.experimental import pallas as pl
-    return buf[pl.ds(start, n), 0, :]
+def _start_chunk(c, one, unroll: bool):
+    """one(r) for the rows r of chunk c: unrolled on the chip, where the
+    scheduler overlaps the 8 DMAs' scalar work; a loop in the interpreter
+    (the same DMAs in a fraction of the trace)."""
+    def body(u, carry):
+        one(c * _CHUNK + u)
+        return carry
+    jax.lax.fori_loop(0, _CHUNK, body, 0, unroll=unroll)
+
+
+def _chunks(rows):
+    """The waits of `rows` fetched rows, the list padded to 8s."""
+    return (rows + _CHUNK - 1) // _CHUNK
 
 
 def _column(row):
@@ -160,8 +195,8 @@ def _row(col):
     return jnp.broadcast_to(col, (col.shape[0], 128)).T[:1, :]
 
 
-def _gather_kernel(tm, per_block, packed, scaled, dotted, count_ref,
-                   src_ref, *refs):
+def _gather_kernel(tm, n_tiles, per_block, packed, scaled, dotted, unroll,
+                   count_ref, src_ref, *refs):
     from jax.experimental import pallas as pl
 
     refs = list(refs)
@@ -171,24 +206,59 @@ def _gather_kernel(tm, per_block, packed, scaled, dotted, count_ref,
     out_ref = refs.pop(0)
     dot_ref = refs.pop(0) if dotted else None
     buf, sem = refs
-    i = pl.program_id(0)
-    # the places of this tile below the count, and this tile's part of the
-    # index block
-    live = jnp.clip(count_ref[0] - i * tm, 0, tm)
-    first = (i % per_block) * tm
+    s = pl.program_id(0)
+    count = count_ref[0]
 
-    def start(r, carry):
-        _start_row(words_hbm, buf, sem, src_ref[first + r], r)
+    def live(t):
+        """The places of tile t below the count."""
+        return jnp.clip(count - t * tm, 0, tm)
+
+    # tile s's rows into buffer s % 2; its part of the index block
+    first = (s % per_block) * tm
+    to, on = buf.at[s % 2], sem.at[s % 2]
+
+    def start(c, carry):
+        def one(r):
+            _start_row(words_hbm, to, on, src_ref[first + r], r)
+        _start_chunk(c, one, unroll)
         return carry
-    jax.lax.fori_loop(0, live, start, 0)
-    _wait_rows(words_hbm, buf, sem, live)
-    rows = _unpack(_word_rows(buf, 0, tm), packed)
-    if dotted:
-        dot_ref[...] = _row(jnp.sum(
-            ys_ref[...].astype(jnp.float32) * rows, axis=1, keepdims=True))
-    if scaled:
-        rows = rows * _column(scale_ref[...])
-    out_ref[...] = rows.astype(out_ref.dtype)
+    jax.lax.fori_loop(0, jnp.where(s < n_tiles, _chunks(live(s)), 0), start,
+                      0)
+
+    @pl.when(s > 0)
+    def _():
+        t = s - 1
+        _wait_chunks(words_hbm, buf.at[t % 2], sem.at[t % 2],
+                     _chunks(live(t)))
+        rows = _unpack(buf[t % 2, :, 0, :], packed)
+        if dotted:
+            dot_ref[...] = _row(jnp.sum(
+                ys_ref[...].astype(jnp.float32) * rows, axis=1,
+                keepdims=True))
+        if scaled:
+            rows = rows * _column(scale_ref[...])
+        out_ref[...] = rows.astype(out_ref.dtype)
+
+
+def _gather_tile(M: int, hidden: int, dtype, dotted: bool) -> int:
+    """The gather's buffer tile: at most 256 places, halved while two
+    buffers of word rows, the output's (and with `dotted` the second
+    operand's) two blocks and the rows in float32 would take more than
+    `_VMEM_BUDGET`."""
+    width, size = _words(dtype, hidden)[1], jnp.dtype(dtype).itemsize
+    per_row = (2 * width * 4
+               + (2 * hidden * size + hidden * 4) * (2 if dotted else 1))
+    cap = _TILE_ROWS
+    while True:
+        tm = _tile(M, cap)
+        if tm * per_row <= _VMEM_BUDGET or tm <= 16:
+            return tm
+        cap = tm // 2
+
+
+def _lagged(i):
+    """The tile a step computes with: the one before the step's own."""
+    return jnp.maximum(i - 1, 0)
 
 
 def gather(x, src, count, scale=None, dot_with=None,
@@ -205,38 +275,41 @@ def gather(x, src, count, scale=None, dot_with=None,
     M, (N, H) = src.shape[0], x.shape
     packed = _packed(x.dtype)
     wtype, width = _words(x.dtype, H)
-    tm = _tile(M, _TILE_ROWS)
+    tm = _gather_tile(M, H, x.dtype, dot_with is not None)
     n_tiles = M // tm
     idx, block, per_block = _index_blocks(src.astype(jnp.int32), tm)
     steps = (count.reshape(1)[0] + tm - 1) // tm
-    row_spec = pl.BlockSpec((None, 1, tm), lambda i, c: (i, 0, 0))
-    in_specs = [pl.BlockSpec((block,), lambda i, c: (i // per_block,),
-                             memory_space=pltpu.SMEM)]
+    row_spec = pl.BlockSpec((None, 1, tm), lambda i, c: (_lagged(i), 0, 0))
+    in_specs = [pl.BlockSpec(
+        (block,), lambda i, c: (jnp.minimum(i, n_tiles - 1) // per_block,),
+        memory_space=pltpu.SMEM)]
     operands = [idx]
     if scale is not None:
         in_specs.append(row_spec)
         operands.append(scale.astype(jnp.float32).reshape(n_tiles, 1, tm))
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     operands.append(pack(x, N, "moe_rows_gather_pack", interpret))
+    tile_spec = pl.BlockSpec((tm, H), lambda i, c: (_lagged(i), 0))
     out_shape = [jax.ShapeDtypeStruct((M, H), x.dtype)]
-    out_specs = [pl.BlockSpec((tm, H), lambda i, c: (i, 0))]
+    out_specs = [tile_spec]
     if dot_with is not None:
-        in_specs.append(pl.BlockSpec((tm, H), lambda i, c: (i, 0)))
+        in_specs.append(tile_spec)
         operands.append(dot_with)
         out_shape.append(jax.ShapeDtypeStruct((n_tiles, 1, tm), jnp.float32))
         out_specs.append(row_spec)
     rows = M // 4                          # a guess: the grid is data
     out = pl.pallas_call(
-        functools.partial(_gather_kernel, tm, per_block, packed,
-                          scale is not None, dot_with is not None),
+        functools.partial(_gather_kernel, tm, n_tiles, per_block, packed,
+                          scale is not None, dot_with is not None,
+                          not interpret),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             in_specs=in_specs,
             out_specs=out_specs,
-            grid=(steps,),
-            scratch_shapes=[pltpu.VMEM((tm, 1, width), wtype),
-                            pltpu.SemaphoreType.DMA(())],
+            grid=(steps + 1,),
+            scratch_shapes=[pltpu.VMEM((2, tm, 1, width), wtype),
+                            pltpu.SemaphoreType.DMA((2,))],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -288,121 +361,184 @@ def pack(rows, count, name: str, interpret: Optional[bool] = None):
     )(count, rows)
 
 
-def _combine_kernel(tn, k, tok_per_block, slot_per_block, packed, weighted,
-                    tile_held_ref, kept_ref, pos_ref, keep_ref, *refs):
+def _combine_kernel(tn, k, n_tiles, per_block, most_per_block, packed,
+                    weighted, unroll, held_ref, place_ref, slot_ref,
+                    most_ref, kept_ref, *refs):
     from jax.experimental import pallas as pl
 
     if weighted:
-        w_ref, words_hbm, out_ref, buf, acc, groups, sem = refs
+        w_ref, words_hbm, out_ref, buf, sem = refs
     else:
         w_ref = None
-        words_hbm, out_ref, buf, acc, groups, sem = refs
-    i = pl.program_id(0)
-    tok0 = (i % tok_per_block) * tn
-    slot0 = (i % slot_per_block) * tn * k
-    for g in range(tn // 8):
-        groups[g] = 0
+        words_hbm, out_ref, buf, sem = refs
+    s = pl.program_id(0)
+    # tile s's held rows into buffer s % 2, 8 at a time: its list's entries
+    # (place in the buffer, VMEM slot) in its index block
+    first = (s % per_block) * (k * tn)
+    to, on = buf.at[s % 2], sem.at[s % 2]
 
-    def start(n, carry):
-        # the token's kept slots are its first `kept` (`_by_rank`); slot
-        # (n, r) lands in buf[r * tn + n]
-        kept = kept_ref[tok0 + n]
-
-        def one(r, c):
-            _start_row(words_hbm, buf, sem, pos_ref[slot0 + n * k + r],
-                       r * tn + n)
-            return c
-        jax.lax.fori_loop(0, kept, one, 0)
-        # the most slots one of 8 tokens keeps
-        groups[n // 8] = jnp.maximum(groups[n // 8], kept)
+    def start(c, carry):
+        def one(r):
+            _start_row(words_hbm, to, on, place_ref[first + r],
+                       slot_ref[first + r])
+        _start_chunk(c, one, unroll)
         return carry
-    jax.lax.fori_loop(0, tn, start, 0)
-    acc[...] = jnp.zeros(acc.shape, jnp.float32)
-    _wait_rows(words_hbm, buf, sem, tile_held_ref[i])
+    jax.lax.fori_loop(0, jnp.where(
+        s < n_tiles, _chunks(held_ref[jnp.minimum(s, n_tiles - 1)]), 0),
+        start, 0)
 
-    def add(g, carry):
-        # _gather_sum's sum, 8 tokens at a time, in the order of j; a slot
-        # none of the 8 keeps would add zeros and is passed over
-        most = groups[g]
-        rows8 = pl.ds(pl.multiple_of(g * 8, 8), 8)
-        for j in range(k):
-            @pl.when(j < most)
-            def _():
-                rows = _unpack(_word_rows(buf, j * tn + g * 8, 8), packed)
+    @pl.when(s > 0)
+    def _():
+        t = s - 1
+        fetched = buf.at[t % 2]
+        _wait_chunks(words_hbm, fetched, sem.at[t % 2],
+                     _chunks(held_ref[t]))
+        group0 = (t % most_per_block) * (tn // 8)
+
+        def group(g, carry):
+            # _gather_sum's sum of 8 tokens, in the order of j, carried in
+            # registers over the ranks one of them keeps
+            rows8 = pl.ds(pl.multiple_of(g * 8, 8), 8)
+            kept = kept_ref[rows8, :]
+
+            def rank(j, acc):
+                rows = _unpack(fetched[pl.ds(pl.multiple_of(
+                    j * tn + g * 8, 8), 8), 0, :], packed)
                 # a slot not kept was not fetched: selected away
-                piece = jnp.where(keep_ref[rows8, j:j + 1] > 0, rows, 0.0)
+                piece = jnp.where(kept > j, rows, 0.0)
                 if weighted:
-                    piece = piece * w_ref[rows8, j:j + 1]
-                acc[rows8, :] = acc[rows8, :] + piece
-        return carry
-    jax.lax.fori_loop(0, tn // 8, add, 0)
-    out_ref[...] = acc[...].astype(out_ref.dtype)
+                    piece = piece * w_ref[j, rows8, :]
+                return acc + piece
+            out_ref[rows8, :] = jax.lax.fori_loop(
+                0, most_ref[group0 + g], rank,
+                jnp.zeros((8, out_ref.shape[1]), jnp.float32)
+            ).astype(out_ref.dtype)
+            return carry
+        jax.lax.fori_loop(0, tn // 8, group, 0)
 
 
-def _by_rank(position, keep, weights):
-    """A token's kept slots moved to the front, in the order of j: column r
-    of the results is the token's r-th kept slot (its position, a kept
-    flag, its weight). The kernel adds a token's columns in order, so the
-    sum is still in the order of j, and 8 tokens' column r is visited
-    only where one of them keeps r + 1 slots or more."""
+def _by_rank(keep, *values):
+    """A token's kept slots moved to the front, in the order of j: the mask
+    of the kept-first columns and each of `values` [N, k] moved alike
+    (column r: the token's r-th kept slot's). The kernel adds a token's
+    columns in order, so the sum is still in the order of j."""
     k = keep.shape[1]
     rank = jnp.cumsum(keep, axis=1, dtype=jnp.int32) - 1
     # [N, j, r]: slot j is the token's r-th kept one
     at = jnp.logical_and(keep[:, :, None],
                          rank[:, :, None] == jnp.arange(k)[None, None, :])
-    position = jnp.sum(jnp.where(at, position[:, :, None], 0), axis=1)
-    if weights is not None:
-        weights = jnp.sum(jnp.where(at, weights[:, :, None], 0.0), axis=1)
     held = keep.sum(axis=1, keepdims=True, dtype=jnp.int32)
-    return position, jnp.arange(k)[None, :] < held, weights
+    return (jnp.arange(k)[None, :] < held, *(
+        jnp.sum(jnp.where(at, v[:, :, None], jnp.zeros((), v.dtype)), axis=1)
+        for v in values))
 
 
-def combine(rows, count, position, keep, weights=None,
+def _combine_tile(n_tokens: int, k: int, hidden: int, dtype) -> int:
+    """The combine's token tile: at most 128, halved while two buffers of
+    k x tn word rows (and the 8 spare ones), the output's two blocks and
+    the weights' two (a rank's column lane-padded) would take more than
+    `_VMEM_BUDGET`."""
+    width = _words(dtype, hidden)[1]
+    cap = _TILE_TOKENS
+    while True:
+        tn = _tile(n_tokens, cap)
+        need = 2 * ((k * tn + _CHUNK) * width * 4
+                    + tn * hidden * jnp.dtype(dtype).itemsize
+                    + k * tn * 128 * 4)
+        if need <= _VMEM_BUDGET or tn <= 16:
+            return tn
+        cap = tn // 2
+
+
+class Plan(NamedTuple):
+    """A combine's rows, listed ahead (`plan`): the slots held here,
+    [N, k]; each token tile's held rows, [tiles]; per tile, k x tn entries
+    of the place in the buffer and the VMEM slot of its held rows,
+    rank-major, then padding; and the most slots one of 8 tokens keeps,
+    [N / 8]."""
+    held: jax.Array
+    tile_held: jax.Array
+    place: jax.Array
+    slot: jax.Array
+    most: jax.Array
+
+
+def plan(position, held, hidden: int, dtype) -> Plan:
+    """The list of the held rows that `combine` fetches for the slots at
+    `position` [N, k] where `held` [N, k], rows of `hidden` values of
+    `dtype`: one sort of the N x k slots, tile by tile, that moves the held
+    ones to their tile's front in rank-major order. A tile's entries past
+    its held rows name place 0 and the spare slots k x tn + (m mod 8)."""
+    N, k = held.shape
+    tn = _combine_tile(N, k, hidden, dtype)
+    n_tiles = N // tn
+    keep, position = _by_rank(held, position.astype(jnp.int32))
+
+    def by_tile(a):
+        """[N, k] -> [tiles, k x tn]: tile, rank, token."""
+        return a.reshape(n_tiles, tn, k).transpose(0, 2, 1).reshape(
+            n_tiles, k * tn)
+    live, entry = by_tile(keep), jnp.arange(k * tn, dtype=jnp.int32)
+    key, place = jax.lax.sort(
+        (jnp.where(live, entry, k * tn + entry),
+         jnp.where(live, by_tile(position), 0)), dimension=1, num_keys=1)
+    slot = jnp.where(key < k * tn, key, k * tn + entry % _CHUNK)
+    kept = keep.sum(axis=1, dtype=jnp.int32)
+    return Plan(held, kept.reshape(n_tiles, tn).sum(axis=1, dtype=jnp.int32),
+                place.reshape(-1), slot.reshape(-1),
+                kept.reshape(N // 8, 8).max(axis=1))
+
+
+def combine(rows, count, route: Plan, weights=None,
             interpret: Optional[bool] = None):
     """out [N, H] in the type of the buffer rows [M, H] (its first
-    count[0] places read, no other): token n's kept slots' rows, at
-    position [N, k], added up in float32 in the order of j (each times
-    weights [N, k] where given)."""
+    count[0] places read, no other): token n's kept slots' rows, at the
+    places `route` (`plan`) lists, added up in float32 in the order of j
+    (each times weights [N, k] where given)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    N, k = position.shape
+    N, k = route.held.shape
     H, dtype, packed = rows.shape[1], rows.dtype, _packed(rows.dtype)
     words = pack(rows, count, "moe_rows_combine_pack", interpret)
-    tn = _tile(N, _TILE_TOKENS)
-    position, keep, weights = _by_rank(position, keep, weights)
-    kept, tok_block, tok_per_block = _index_blocks(
-        keep.sum(axis=1, dtype=jnp.int32), tn)
-    pos, slot_block, slot_per_block = _index_blocks(
-        position.astype(jnp.int32).reshape(-1), tn * k)
-    tile_held = keep.reshape(N // tn, tn * k).sum(axis=1, dtype=jnp.int32)
-    slot_spec = pl.BlockSpec((tn, k), lambda i, t: (i, 0))
-    operands = [kept, pos, keep.astype(jnp.float32)]
+    n_tiles = route.tile_held.shape[0]
+    tn = N // n_tiles
+    place, block, per_block = _index_blocks(route.place, k * tn)
+    slot = _index_blocks(route.slot, k * tn)[0]
+    most, most_block, most_per_block = _index_blocks(route.most, tn // 8)
+    list_spec = pl.BlockSpec(
+        (block,), lambda i, t: (jnp.minimum(i, n_tiles - 1) // per_block,),
+        memory_space=pltpu.SMEM)
+    operands = [place, slot, most,
+                route.held.sum(axis=1, keepdims=True, dtype=jnp.int32)]
     in_specs = [
-        pl.BlockSpec((tok_block,), lambda i, t: (i // tok_per_block,),
+        list_spec, list_spec,
+        pl.BlockSpec((most_block,),
+                     lambda i, t: (_lagged(i) // most_per_block,),
                      memory_space=pltpu.SMEM),
-        pl.BlockSpec((slot_block,), lambda i, t: (i // slot_per_block,),
-                     memory_space=pltpu.SMEM),
-        slot_spec]
+        pl.BlockSpec((tn, 1), lambda i, t: (_lagged(i), 0))]
     if weights is not None:
-        operands.append(weights.astype(jnp.float32))
-        in_specs.append(slot_spec)
+        # rank r's weights [k, N, 1]: a rank's 8 tokens are a column
+        _, weights = _by_rank(route.held, weights.astype(jnp.float32))
+        operands.append(weights.T[:, :, None])
+        in_specs.append(pl.BlockSpec((k, tn, 1),
+                                     lambda i, t: (0, _lagged(i), 0)))
     operands.append(words)
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     return pl.pallas_call(
-        functools.partial(_combine_kernel, tn, k, tok_per_block,
-                          slot_per_block, packed, weights is not None),
+        functools.partial(_combine_kernel, tn, k, n_tiles, per_block,
+                          most_per_block, packed, weights is not None,
+                          not interpret),
         out_shape=jax.ShapeDtypeStruct((N, H), dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((tn, H), lambda i, t: (i, 0)),
-            grid=(N // tn,),
+            out_specs=pl.BlockSpec((tn, H), lambda i, t: (_lagged(i), 0)),
+            grid=(n_tiles + 1,),
             scratch_shapes=[
-                pltpu.VMEM((k * tn, 1, words.shape[2]), words.dtype),
-                pltpu.VMEM((tn, H), jnp.float32),
-                pltpu.SMEM((tn // 8,), jnp.int32),
-                pltpu.SemaphoreType.DMA(())],
+                pltpu.VMEM((2, k * tn + _CHUNK, 1, words.shape[2]),
+                           words.dtype),
+                pltpu.SemaphoreType.DMA((2,))],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -412,4 +548,4 @@ def combine(rows, count, position, keep, weights=None,
             + N * H * jnp.dtype(dtype).itemsize),
         interpret=interpret,
         name="moe_rows_combine",
-    )(tile_held, *operands)
+    )(route.tile_held, *operands)
